@@ -197,6 +197,13 @@ def _build_parser() -> argparse.ArgumentParser:
 
 def _run_bn(args) -> int:
     from lighthouse_tpu.client.builder import ClientBuilder, ClientConfig
+    from lighthouse_tpu.common import compile_cache
+
+    # before the first compile: the fused verify programs cost minutes
+    # of XLA each, and a node that sets no persistent cache pays them on
+    # every start unless the AOT store hits
+    if args.bls_backend != "fake":
+        compile_cache.configure()
 
     # one-shot routing calibration: measure host-vs-device pair-hash
     # rates and pick the merkle device thresholds for THIS host (the

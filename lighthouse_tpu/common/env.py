@@ -47,8 +47,8 @@ _register("LHTPU_DEVICE_FINAL_EXP", None,
           "1/0 forces the final-exponentiation hard part on/off device; "
           "unset = on for TPU, host path for XLA-CPU.")
 _register("LHTPU_NO_CACHE_GUARD", None,
-          "Any non-empty value disables the XLA mmap-headroom raise and "
-          "the compile-cache fallback guard (ops/cache_guard).")
+          "Any non-empty value disables the vm.max_map_count raise the "
+          "test suite makes before XLA:CPU compiles (ops/cache_guard).")
 _register("LHTPU_SHA_DEVICE_MIN", None,
           "Pin the device-vs-host SHA-256 routing threshold (pair "
           "count); unset = one-shot startup micro-calibration.")

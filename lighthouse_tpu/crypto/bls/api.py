@@ -597,23 +597,17 @@ def resolve_auto_backend() -> str:
     if env:
         _resolve_backend(env)  # fail fast on a typo'd override
         return env
-    try:
-        import jax
+    # a device probe that RAISES propagates: the node fails to start with
+    # that error instead of pinning itself to pure Python for its life
+    import jax
 
-        platform = jax.devices()[0].platform
-    except Exception as e:
-        # a failed device probe silently pinning the node to the host
-        # backend is exactly the "worst silent fallback" class — count it
-        from lighthouse_tpu.common.metrics import record_swallowed
-
-        record_swallowed("bls.auto_backend_probe", e)
-        return "reference"
+    platform = jax.devices()[0].platform
     return "tpu" if platform == "tpu" else "reference"
 
 
 # --- offload supervisor: backend health ladder + crash-safe recovery ---------
 #
-# A single device fault (XLA compile error, wedged kernel, relay drop,
+# A single device fault (XLA compile error, wedged kernel, lost device,
 # corrupt readback) must never surface to a verification caller as an
 # exception or a wrong verdict: consensus work bounds LIVENESS on
 # verification availability, not just throughput.  The supervisor wraps
@@ -847,6 +841,11 @@ class _Supervisor:
             try:
                 fn = _resolve_backend(rung)
                 ok = self._call_with_watchdog(rung, fn, sets, kwargs)
+            except _faults.PROGRAM_FAULTS:
+                # the device module failed to import or trace: loud, not
+                # a breaker fault (and no half-open probe left wedged)
+                breaker.record_failure("raise")
+                raise
             except Exception as e:
                 kind = _faults.classify(e)
                 # fault first, then the breaker transition: the flight

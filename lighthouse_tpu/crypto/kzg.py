@@ -423,9 +423,6 @@ def _kzg_fused_check(lhs_points, lhs_scalars, pis, r_pows,
     )
     from lighthouse_tpu.ops.bls_backend import _final_exp_is_one
 
-    from lighthouse_tpu.ops import cache_guard
-
-    cache_guard.install()
     global _KZG_FUSED_JIT
     if _KZG_FUSED_JIT is None:
         def _kzg_fused(xs, ys, digits, xqa, xqb, yqa, yqb):

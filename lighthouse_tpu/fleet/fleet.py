@@ -234,6 +234,11 @@ class ProcessFleet:
         # drills never pay the AOT compile storm, and each child keeps
         # its flight dumps under its own datadir (the builder default)
         child_env.setdefault("LHTPU_AOT_STORE", "0")
+        # one process per chip: these children run fake BLS at a few
+        # dozen validators by construction, so on a machine with a chip
+        # none of them may take it from its siblings (a real-BLS fleet
+        # is one child per chip and does not go through this pin)
+        child_env["JAX_PLATFORMS"] = "cpu"
         child_env.update(self.env)
         child_env.update(node.extra_env)
         os.makedirs(node.datadir, exist_ok=True)

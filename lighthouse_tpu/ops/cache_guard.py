@@ -1,41 +1,27 @@
-"""XLA:CPU process hardening: mmap headroom + compile-cache fallback guard.
+"""XLA:CPU process hardening: mmap headroom for the test suite.
 
 ROOT CAUSE (round 5, measured): every "compile-cache segfault" seen in
-rounds 4-5 — blamed in turn on the zstd writer, `executable.serialize()`
-(AOT export), `deserialize_executable`, and finally plain
-`backend_compile_and_load` — was the kernel's `vm.max_map_count`
-ceiling (default 65,530).  XLA:CPU mmaps tens of thousands of regions
-(one `test_device_pairing` run peaks >61k VMAs); past the ceiling mmap
-fails, XLA does not check, and the process segfaults in whatever path
-is active.  That is why the faulting frame kept moving and why
-"fresh-process" repros crashed too: one large fused program is enough
-to cross the line.
+rounds 4-5 was the kernel's `vm.max_map_count` ceiling (default 65,530).
+XLA:CPU mmaps tens of thousands of regions (one `test_device_pairing`
+run peaks >61k VMAs); past the ceiling mmap fails, XLA does not check,
+and the process segfaults in whatever path is active.
 
-Fix layers:
-
-1. `ensure_map_headroom()` raises the ceiling to 262,144 (root-only
-   write to /proc/sys/vm/max_map_count — this container runs as root).
-   Verified: the exact workload that segfaulted at ~65k maps completes
-   green at 61,600+ maps with the raised ceiling.
-2. If the raise FAILS (non-root host), `install()` falls back to
-   filtering the persistent compile cache for the known-heaviest fused
-   programs on the CPU backend — they recompile per process (minutes)
-   instead of pushing serialize/deserialize traffic near the ceiling.
-   TPU cache traffic is untouched either way.
+`ensure_map_headroom()` raises the ceiling to 262,144 (root-only write
+to /proc/sys/vm/max_map_count).  It is called once, from
+`tests/conftest.py`: a host-global sysctl write is a test-harness
+concern, not something the verify hot path does on a node.
 """
 
 from __future__ import annotations
 
 from lighthouse_tpu.common import env as envreg
 
-_GUARDED_NAMES = ("_pipeline_fused", "_kzg_fused", "_blinded_fold")
 _MAP_TARGET = 262144
 _MAP_PATH = "/proc/sys/vm/max_map_count"
 
 
 def _log():
-    # lazy: common.logging pulls in the metrics registry, and cache_guard
-    # must stay importable before anything else in the package
+    # lazy: common.logging pulls in the metrics registry
     from lighthouse_tpu.common.logging import Logger
 
     return Logger("cache_guard")
@@ -45,8 +31,10 @@ def ensure_map_headroom() -> bool:
     """Best-effort raise of vm.max_map_count to _MAP_TARGET.
 
     Returns True when the ceiling is at/above target (already, or after
-    our write), False when it could not be raised — callers fall back
-    to the cache guard."""
+    our write), False when it could not be raised or
+    LHTPU_NO_CACHE_GUARD opts out of the write."""
+    if envreg.get("LHTPU_NO_CACHE_GUARD"):
+        return False
     try:
         with open(_MAP_PATH) as f:
             if int(f.read()) >= _MAP_TARGET:
@@ -62,75 +50,5 @@ def ensure_map_headroom() -> bool:
                         target=_MAP_TARGET, path=_MAP_PATH)
         return raised
     except (OSError, ValueError):
-        # unwritable/missing sysctl or a non-numeric readback — the
-        # install() fallback layer takes over
+        # unwritable/missing sysctl or a non-numeric readback
         return False
-
-
-def install() -> None:
-    """Raise the map ceiling; install the cache filter only if that fails.
-
-    LHTPU_NO_CACHE_GUARD=1 opts out of both layers (for debugging the
-    guard itself, or on hosts where the operator manages the sysctl)."""
-    if envreg.get("LHTPU_NO_CACHE_GUARD"):
-        return
-    if ensure_map_headroom():
-        return
-    # The fallback monkey-patches jax PRIVATE internals; a jax upgrade
-    # that moves/resignatures them must degrade to a logged no-op, not
-    # an ImportError at process start.
-    try:
-        from jax._src import compilation_cache as cc
-        from jax._src import compiler as jc
-    except Exception:
-        _log().warn("jax._src internals unavailable; "
-                    "compile-cache guard degraded to no-op")
-        return
-    import inspect
-
-    try:
-        n_put = len(inspect.signature(cc.put_executable_and_time).parameters)
-        n_read = len(inspect.signature(jc._cache_read).parameters)
-    except (AttributeError, TypeError, ValueError):
-        n_put = n_read = -1
-    # the wrappers below replicate these exact signatures (jax 0.4.x);
-    # this check is what surfaced an earlier arity drift in _cache_read
-    if n_put != 5 or n_read != 4:
-        _log().warn("jax._src compile-cache API changed; "
-                    "compile-cache guard degraded to no-op",
-                    put_params=n_put, read_params=n_read)
-        return
-    if not getattr(cc, "_lhtpu_write_guard", False):
-        orig_put = cc.put_executable_and_time
-
-        def guarded_put(cache_key, module_name, executable, backend,
-                        compile_time):
-            try:
-                platform = backend.platform
-            except AttributeError:
-                platform = "?"
-            if platform == "cpu" and any(n in module_name
-                                         for n in _GUARDED_NAMES):
-                return None
-            return orig_put(cache_key, module_name, executable, backend,
-                            compile_time)
-
-        cc.put_executable_and_time = guarded_put
-        cc._lhtpu_write_guard = True
-
-    if not getattr(jc, "_lhtpu_read_guard", False):
-        orig_read = jc._cache_read
-
-        def guarded_read(module_name, cache_key, compile_options, backend):
-            try:
-                platform = backend.platform
-            except AttributeError:
-                platform = "?"
-            if platform == "cpu" and any(n in module_name
-                                         for n in _GUARDED_NAMES):
-                return None, None
-            return orig_read(module_name, cache_key, compile_options,
-                             backend)
-
-        jc._cache_read = guarded_read
-        jc._lhtpu_read_guard = True

@@ -705,7 +705,7 @@ def g2_subgroup_verdict_batch(xqa, xqb, yqa, yqb) -> jax.Array:
 
     Folds the residue zero-tests (bi.is_zero_mod_p_device) into the same
     program as g2_subgroup_check_batch so callers fetch one bool row
-    instead of six Fq limb rows (one ~80 ms relay round trip each)."""
+    instead of six Fq limb rows."""
     d1, d2, Z = g2_subgroup_check_batch(xqa, xqb, yqa, yqb)
     return (_fq2_zero_mod_p(d1) & _fq2_zero_mod_p(d2)
             & ~_fq2_zero_mod_p(Z))

@@ -23,7 +23,7 @@ Design notes (TPU-first, see README "Epoch processing"):
   the slow integer-division path.
 - **int64 lanes under a scoped x64 context.**  Balances/scores/epochs
   need 64 bits; the kernels trace and run inside
-  ``jax.experimental.enable_x64`` so the rest of the process keeps the
+  ``jax.enable_x64`` so the rest of the process keeps the
   default 32-bit world (the BLS limb kernels are explicit-dtype and
   unaffected).  ``FAR_FUTURE_EPOCH`` (2**64-1) is clamped host-side to
   ``state_transition.epoch_device.EPOCH_CLAMP`` (1<<62 — large enough
@@ -54,7 +54,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from lighthouse_tpu.common import device_telemetry as _dtel
 from lighthouse_tpu.ops import program_store as _pstore
@@ -188,7 +187,7 @@ def epoch_pass_device(columns: dict, tables: dict, params: np.ndarray, *,
     parallel/epoch_sharded — the same program runs mesh-partitioned.
     """
     fn = _epoch_pass_jit()
-    with enable_x64():
+    with jax.enable_x64():
         col_sh = tbl_sh = None
         if shardings is not None:
             col_sh, tbl_sh = shardings

@@ -604,8 +604,16 @@ def run_with_deadline(fn, timeout_s: float, thread_name: str, what: str):
     return box.get("ok")
 
 
+#: a fault of the PROGRAM, not of the device: a device module that does
+#: not import or trace (a moved jax API, a typo, a wrong arity).  The
+#: supervised seams re-raise these instead of filing a breaker fault —
+#: a ladder that absorbs them serves every batch from the reference
+#: rung with exit code 0 and nobody learns the device path is dead.
+PROGRAM_FAULTS = (ImportError, AttributeError, NameError, TypeError)
+
+
 def classify(exc: BaseException) -> str:
-    """Fault taxonomy for metrics/health accounting: hang | compile | raise."""
+    """Fault classes for metrics/health accounting: hang | compile | raise."""
     if isinstance(exc, WatchdogTimeout):
         return "hang"
     if isinstance(exc, InjectedCompileFault):

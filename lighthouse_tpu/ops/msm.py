@@ -47,7 +47,7 @@ import numpy as np
 from lighthouse_tpu.common import device_telemetry as _dtel
 from lighthouse_tpu.common.metrics import REGISTRY, record_swallowed
 from lighthouse_tpu.ops import bigint as bi
-from lighthouse_tpu.ops import cache_guard, ec
+from lighthouse_tpu.ops import ec
 from lighthouse_tpu.ops import program_store as _pstore
 
 # AOT program-store coverage (lhlint LH606): the whole family is
@@ -166,7 +166,6 @@ _blinded_fold = _dtel.instrument(
 def fold_device(xs, ys, digits, n_segments: int):
     """One plain-track dispatch -> HOST Jacobian rows (X, Y, Z)
     uint32[n_segments, L]."""
-    cache_guard.install()   # mmap headroom before any XLA compile
     X, Y, Z = jax.device_get(_fold_kernel(
         jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(digits),
         int(n_segments)))
@@ -176,13 +175,11 @@ def fold_device(xs, ys, digits, n_segments: int):
 def gather_fold_device(tx, ty, lane_idx, digits, n_segments: int):
     """One gather-track dispatch (device arrays in, device arrays out —
     the caller owns placement/sharding and the device_get)."""
-    cache_guard.install()   # mmap headroom before any XLA compile
     return _gather_fold(tx, ty, lane_idx, digits, int(n_segments))
 
 
 def blinded_fold_device(X, Y, Z, ux, uy, n_segments: int):
     """One blinded-track dispatch (host lane rows in, device rows out)."""
-    cache_guard.install()   # mmap headroom before any XLA compile
     return _blinded_fold(jnp.asarray(X), jnp.asarray(Y), jnp.asarray(Z),
                          ux, uy, int(n_segments))
 
@@ -377,7 +374,6 @@ def calibrate_device_thresholds(sample_lanes: int = 2,
     xs = jnp.asarray(ec.ints_to_mont_limbs([p[0] for p in pts]))
     ys = jnp.asarray(ec.ints_to_mont_limbs([p[1] for p in pts]))
     dg = jnp.asarray(ec.scalars_to_digits(ks, n_bits=256))
-    cache_guard.install()   # mmap headroom before any XLA compile
     # compile outside the timing (persistent cache makes this a load)
     jax.block_until_ready(_fold_kernel(xs, ys, dg, 1))
     dev_rate = _measure_rate(

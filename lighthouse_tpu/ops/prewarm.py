@@ -43,6 +43,7 @@ import time
 
 from lighthouse_tpu.common import env as envreg
 from lighthouse_tpu.common import flight_recorder as _flight
+from lighthouse_tpu.common.logging import Logger
 from lighthouse_tpu.common.metrics import REGISTRY, record_swallowed
 from lighthouse_tpu.ops import program_store
 
@@ -451,10 +452,19 @@ def run(stop_event=None, force: bool = False) -> dict:
         _record_outcome("skipped", len(_dtel.manifest_ids()))
         return report
     t0 = time.perf_counter()
-    from lighthouse_tpu.ops import cache_guard
-
-    cache_guard.install()   # mmap headroom before any XLA compile/load
-    _import_owners()
+    try:
+        _import_owners()
+    except Exception as e:
+        # a device module that no longer imports (a jax upgrade moved an
+        # API) is a fault of the program: every entry would serve from
+        # plain jit, cold.  Report it loudly instead of dying silently
+        # on the prewarm thread.
+        record_swallowed("prewarm.import_owners", e)
+        report["import_error"] = f"{type(e).__name__}: {e}"
+        Logger("prewarm").error(
+            "aot prewarm aborted: a device module failed to import",
+            error=report["import_error"])
+        return report
     scale = _resolve_scale()
     report.update({"ran": True, "scale": scale})
 

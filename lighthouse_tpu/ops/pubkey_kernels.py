@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from lighthouse_tpu.ops import bigint as bi
-from lighthouse_tpu.ops import cache_guard, ec
+from lighthouse_tpu.ops import ec
 from lighthouse_tpu.ops import msm as _msm
 
 # the fused gather+fold program itself lives on the unified MSM plane
@@ -57,7 +57,6 @@ def table_from_rows(rows_x: np.ndarray, rows_y: np.ndarray) -> tuple:
     padded to a power of two (the padding rows replicate row 0: never
     referenced — lane_idx only names real rows — but keep the gather
     in-bounds)."""
-    cache_guard.install()   # mmap headroom before any XLA compile
     n = len(rows_x)
     if n == 0:
         rows_x, rows_y = mont_rows([(1, 2)])
@@ -88,7 +87,6 @@ def gather_fold(table, row_of_lane: np.ndarray, scalars: np.ndarray,
     the jit shape is a pure function of (lanes_pow2, groups_pow2).
     ``shardings=(lane_sh, table_sh)`` places lanes over a mesh and
     replicates the table (the parallel/msm_sharded rung)."""
-    cache_guard.install()   # mmap headroom before any XLA compile
     n = len(row_of_lane)
     if n == 0 or n_groups == 0:
         L = bi.L

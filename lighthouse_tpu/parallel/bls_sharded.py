@@ -45,7 +45,7 @@ def _sharded_miller_reduce(mesh, per_dev: int):
     cached = _SHARDED_JIT_CACHE.get(key)
     if cached is not None:
         return cached
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_dev = mesh.devices.size
@@ -65,7 +65,7 @@ def _sharded_miller_reduce(mesh, per_dev: int):
         local, mesh=mesh,
         in_specs=(spec,) * 6 + (P("data"),),
         out_specs=P(None, None),
-        check_rep=False))
+        check_vma=False))
     fn = _dtel.instrument(
         "parallel/bls_sharded.py::_sharded_miller_reduce@shard_map", fn)
     _SHARDED_JIT_CACHE[key] = fn

@@ -43,7 +43,7 @@ from lighthouse_tpu.processor.admission import (
 
 
 class WorkType(Enum):
-    """Work taxonomy (reference Work enum, lib.rs:552-618)."""
+    """Work kinds (reference Work enum, lib.rs:552-618)."""
 
     # highest priority: chain structure
     CHAIN_SEGMENT = auto()

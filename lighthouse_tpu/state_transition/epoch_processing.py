@@ -45,6 +45,7 @@ import numpy as np
 from lighthouse_tpu import types as T
 from lighthouse_tpu.common import env as envreg
 from lighthouse_tpu.common.metrics import REGISTRY, record_swallowed
+from lighthouse_tpu.ops.faults import PROGRAM_FAULTS as _PROGRAM_FAULTS
 from lighthouse_tpu.state_transition import misc
 
 # Participation flag indices / weights (altair).
@@ -264,6 +265,10 @@ def _maybe_device_epoch(state, spec: T.ChainSpec, fork: str):
     try:
         with tracing.span("epoch.device_pass", backend=backend, n=n):
             out = epoch_device.prepare_and_run(state, spec, fork, backend)
+    except _PROGRAM_FAULTS:
+        # the device module failed to import or trace — a fault of the
+        # program, not of the device: loud, never a breaker fault
+        raise
     except Exception as exc:  # device fault: recover on reference
         record_epoch_fault(backend, type(exc).__name__)
         _breaker_fault()

@@ -31,8 +31,14 @@ def interop_secret_key(index: int) -> bls.SecretKey:
 
 
 @lru_cache(maxsize=None)
+def interop_public_key(index: int) -> bls.PublicKey:
+    """One ~4 ms pure-Python scalar multiplication per index, paid once
+    per process whoever asks (genesis, a harness, chip_smoke.py)."""
+    return interop_secret_key(index).public_key()
+
+
 def interop_pubkey(index: int) -> bytes:
-    return interop_secret_key(index).public_key().to_bytes()
+    return interop_public_key(index).to_bytes()
 
 
 def interop_validators(n: int, spec: T.ChainSpec) -> T.Validators:

@@ -27,6 +27,8 @@ import hashlib
 
 import numpy as np
 
+from lighthouse_tpu.ops.faults import PROGRAM_FAULTS
+
 
 def compute_shuffled_index(index: int, count: int, seed: bytes, rounds: int) -> int:
     """Single-index forward shuffle (spec semantics, scalar)."""
@@ -129,6 +131,8 @@ def shuffle_list(indices: np.ndarray, seed: bytes, rounds: int, *,
 
         try:
             out = shuffle_list_device(indices, seed, rounds)
+        except PROGRAM_FAULTS:
+            raise  # the device module does not import/trace: loud
         except Exception as exc:  # recover on the host rung
             _ep.record_epoch_fault("shuffle", type(exc).__name__)
             # shuffle shares the epoch circuit breaker: a flapping

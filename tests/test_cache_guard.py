@@ -1,55 +1,31 @@
-"""ops/cache_guard: env opt-out + jax._src version guard degrade paths."""
+"""ops/cache_guard: the vm.max_map_count raise and its opt-out."""
+
+import builtins
 
 from lighthouse_tpu.ops import cache_guard
 
 
-def test_env_opt_out_skips_everything(monkeypatch):
+def test_env_opt_out_never_touches_the_sysctl(monkeypatch):
     monkeypatch.setenv("LHTPU_NO_CACHE_GUARD", "1")
 
-    def boom():  # pragma: no cover - must not be reached
-        raise AssertionError("ensure_map_headroom called despite opt-out")
+    def boom(*a, **k):  # pragma: no cover - must not be reached
+        raise AssertionError("sysctl opened despite opt-out")
 
-    monkeypatch.setattr(cache_guard, "ensure_map_headroom", boom)
-    cache_guard.install()  # returns before touching the sysctl or jax
-
-
-def test_version_guard_degrades_to_noop(monkeypatch):
-    """A jax upgrade that resignatures the private compile-cache hooks
-    must leave them unpatched (logged no-op), not wrap them blindly."""
-    from jax._src import compilation_cache as cc
-    from jax._src import compiler as jc
-
-    monkeypatch.setattr(cache_guard, "ensure_map_headroom", lambda: False)
-
-    def moved_api(a, b, c):  # wrong arity vs the signatures we replicate
-        return None
-
-    monkeypatch.setattr(cc, "put_executable_and_time", moved_api)
-    monkeypatch.setattr(cc, "_lhtpu_write_guard", False, raising=False)
-    monkeypatch.setattr(jc, "_lhtpu_read_guard", False, raising=False)
-    orig_read = jc._cache_read
-    cache_guard.install()
-    assert cc.put_executable_and_time is moved_api  # NOT wrapped
-    assert jc._cache_read is orig_read
-    assert not cc._lhtpu_write_guard
-    assert not jc._lhtpu_read_guard
+    monkeypatch.setattr(builtins, "open", boom)
+    assert cache_guard.ensure_map_headroom() is False
 
 
-def test_guard_installs_on_current_jax(monkeypatch):
-    """On the pinned jax the signatures still match: the fallback guard
-    must install (this is the canary that fails when jax moves the API
-    and the version guard starts eating the fallback silently)."""
-    from jax._src import compilation_cache as cc
-    from jax._src import compiler as jc
+def test_unreadable_sysctl_reports_false(monkeypatch, tmp_path):
+    monkeypatch.delenv("LHTPU_NO_CACHE_GUARD", raising=False)
+    monkeypatch.setattr(cache_guard, "_MAP_PATH", str(tmp_path / "absent"))
+    assert cache_guard.ensure_map_headroom() is False
 
-    monkeypatch.setattr(cache_guard, "ensure_map_headroom", lambda: False)
-    orig_put, orig_read = cc.put_executable_and_time, jc._cache_read
-    monkeypatch.setattr(cc, "_lhtpu_write_guard", False, raising=False)
-    monkeypatch.setattr(jc, "_lhtpu_read_guard", False, raising=False)
-    try:
-        cache_guard.install()
-        assert cc.put_executable_and_time is not orig_put
-        assert jc._cache_read is not orig_read
-    finally:
-        cc.put_executable_and_time = orig_put
-        jc._cache_read = orig_read
+
+def test_ceiling_already_at_target_is_not_rewritten(monkeypatch, tmp_path):
+    monkeypatch.delenv("LHTPU_NO_CACHE_GUARD", raising=False)
+    path = tmp_path / "max_map_count"
+    path.write_text(str(cache_guard._MAP_TARGET))
+    path.chmod(0o444)
+    monkeypatch.setattr(cache_guard, "_MAP_PATH", str(path))
+    assert cache_guard.ensure_map_headroom() is True
+    assert path.read_text() == str(cache_guard._MAP_TARGET)

@@ -252,6 +252,29 @@ class TestDevicePubkeyAggregation:
             got = (int(bi.from_mont(xa[i])), int(bi.from_mont(ya[i])))
             assert got == want, i
 
+    def test_lane_cap_slices_match_host_oracle(self, monkeypatch):
+        """Over the lane cap the batch folds in equal-shaped slices of
+        sets (the mainnet block's 131 x 512 keys does not fit one
+        dispatch on a 16 GB chip); rows come back in set order."""
+        from lighthouse_tpu.crypto import bls
+        from lighthouse_tpu.ops import bigint as bi
+        from lighthouse_tpu.ops import bls_backend as bb
+
+        sks, pks = self._keys()
+        msg = b"\x12" * 32
+        sig = sks[0].sign(msg)
+        sets = [bls.SignatureSet(sig, pks[:k], msg)
+                for k in (1, 5, 12, 3, 7)]
+        # seg = 2 * 16 lanes per set: cap at two sets per dispatch, the
+        # shape test_matches_host_oracle_ragged's program does not have
+        monkeypatch.setattr(bb, "_AGG_MAX_LANES", 64)
+        xa, ya, inf = bb.aggregate_pubkeys_device(sets)
+        assert xa.shape[0] == len(sets) and not inf.any()
+        for i, s in enumerate(sets):
+            want = s.aggregate_pubkey()
+            got = (int(bi.from_mont(xa[i])), int(bi.from_mont(ya[i])))
+            assert got == want, i
+
     def test_identity_aggregate_flagged(self):
         from lighthouse_tpu.crypto import bls
         from lighthouse_tpu.crypto.bls import curve as cv

@@ -97,6 +97,36 @@ def test_breaker_opens_and_auto_falls_back(monkeypatch):
     assert ep._BREAKER["open_until"] == 0.0
 
 
+@pytest.mark.parametrize("exc", [
+    ImportError("cannot import name 'enable_x64' from 'jax.experimental'"),
+    AttributeError("module 'jax' has no attribute 'moved'"),
+    NameError("name 'enable_x64' is not defined"),
+    TypeError("fn() got an unexpected keyword argument"),
+])
+def test_program_fault_is_loud_in_epoch_and_shuffle(monkeypatch, exc):
+    """A device module that does not import or trace is re-raised, not
+    filed as a device fault: on jax 0.9.0 exactly this ImportError was
+    swallowed every epoch, the breaker opened and numpy served — with
+    exit code 0."""
+    import numpy as np
+
+    from lighthouse_tpu.state_transition import epoch_device, shuffle
+
+    def boom(*a, **k):
+        raise exc
+
+    st, spec = randomized_state(64, "altair", seed=7)
+    monkeypatch.setattr(epoch_device, "prepare_and_run", boom)
+    monkeypatch.setenv("LHTPU_EPOCH_BACKEND", "device")
+    with pytest.raises(type(exc)):
+        ep.process_epoch(st, spec)
+    monkeypatch.setattr(shuffle, "shuffle_list_device", boom)
+    with pytest.raises(type(exc)):
+        shuffle.shuffle_list(np.arange(512, dtype=np.uint64), b"\x01" * 32,
+                             10, device=True)
+    assert ep._BREAKER["open_until"] == 0.0
+
+
 def test_fault_leaves_state_untouched_for_reference_rerun(monkeypatch):
     """A fault AFTER partial prep must not leave a torn state: the
     bridge applies columns only after every fetch completed."""

@@ -1225,11 +1225,11 @@ def test_numeric_pass_flags_host_int64_lane(tmp_path):
 
 def test_numeric_pass_x64_scope_negative(tmp_path):
     pkg, _ = make_pkg(tmp_path, {"chain/epoch_bridge.py": """
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         def good(epochs):
-            with enable_x64():
+            with jax.enable_x64():
                 return jnp.asarray(epochs, dtype=jnp.int64)
     """})
     assert analyze(pkg) == []
@@ -1258,14 +1258,13 @@ def test_numeric_pass_scoped_dispatch_negative(tmp_path):
     pkg, _ = make_pkg(tmp_path, {"chain/epoch_bridge.py": """
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         @jax.jit
         def kernel(cols):
             return cols.astype(jnp.int64) + 1
 
         def good_dispatch(cols):
-            with enable_x64():
+            with jax.enable_x64():
                 return kernel(cols)
     """})
     assert sans_aot(analyze(pkg)) == []

@@ -157,6 +157,8 @@ def stub_tpu():
 
     def stub(sets_, **kw):
         calls["n"] += 1
+        if isinstance(calls["fail"], BaseException):
+            raise calls["fail"]
         if calls["fail"]:
             raise faults.InjectedFault("stub fault")
         return True  # O(1): must finish far inside the tuned watchdog
@@ -202,6 +204,48 @@ def test_circuit_transition_table(sets, stub_tpu):
         assert bls.verify_signature_sets(valid, backend="tpu") is True
         assert stub_tpu["n"] == n + 1
         assert bls.backend_health()["tpu"] == "closed"
+
+
+@pytest.mark.parametrize("exc", [
+    ImportError("cannot import name 'enable_x64'"),
+    AttributeError("module 'jax' has no attribute 'moved'"),
+    NameError("name 'cache_guard' is not defined"),
+    TypeError("shard_map() got an unexpected keyword 'check_rep'"),
+])
+def test_program_fault_is_loud_not_a_breaker_fault(sets, stub_tpu, exc):
+    """A device module that does not import or trace is a fault of the
+    PROGRAM: the seam re-raises it instead of serving from the reference
+    rung with a counted 'device fault' (the silent step-down that hid
+    the jax 0.9.0 breakage) — and leaves no half-open probe wedged."""
+    valid, _ = sets
+    with supervised_bls(**dict(TUNED, LHTPU_SUPERVISOR_AUDIT="0")):
+        before = _fault_count("tpu", "raise")
+        stub_tpu["fail"] = exc
+        with pytest.raises(type(exc)):
+            bls.verify_signature_sets(valid, backend="tpu")
+        assert _fault_count("tpu", "raise") == before
+        # the breaker still benched the rung; after the backoff a healthy
+        # program is probed and re-promoted as after any fault
+        stub_tpu["fail"] = False
+        _expire_backoff("tpu")
+        assert bls.verify_signature_sets(valid, backend="tpu") is True
+        assert bls.backend_health()["tpu"] == "closed"
+
+
+def test_auto_backend_probe_failure_propagates(monkeypatch):
+    """A device probe that RAISES fails node start-up with that error;
+    a platform that merely is not a TPU still resolves to reference."""
+    import jax
+
+    monkeypatch.delenv("LHTPU_BLS_BACKEND", raising=False)
+    assert api.resolve_auto_backend() == "reference"   # the CPU suite
+
+    def boom():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", boom)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        api.resolve_auto_backend()
 
 
 def test_failed_probe_doubles_backoff(sets, stub_tpu):
@@ -324,7 +368,7 @@ def test_fault_indices_select_chunks():
             faults.fire("chunk", index=2)
 
 
-def test_classify_taxonomy():
+def test_classify_fault_kinds():
     assert faults.classify(faults.WatchdogTimeout("x")) == "hang"
     assert faults.classify(faults.InjectedCompileFault("x")) == "compile"
     assert faults.classify(RuntimeError("XLA compilation failure")) \

@@ -509,6 +509,29 @@ def test_prewarm_gate_env(store, monkeypatch):
     assert prewarm.should_run() is True
 
 
+def _swallowed(site: str) -> float:
+    from lighthouse_tpu.common.metrics import REGISTRY
+
+    return REGISTRY.counter("offload_swallowed_errors_total").labels(
+        site=site).value
+
+
+def test_prewarm_reports_an_owner_that_does_not_import(store, monkeypatch):
+    """A device module that no longer imports must not kill the prewarm
+    thread in silence: the failure is in the report and counted."""
+    from lighthouse_tpu.ops import prewarm
+
+    def boom():
+        raise ImportError("cannot import name 'enable_x64'")
+
+    monkeypatch.setattr(prewarm, "_import_owners", boom)
+    before = _swallowed("prewarm.import_owners")
+    rep = prewarm.run(force=True)
+    assert rep["ran"] is False
+    assert "enable_x64" in rep["import_error"]
+    assert _swallowed("prewarm.import_owners") == before + 1
+
+
 def test_prewarm_accounts_unknown_driver_tags(store, monkeypatch):
     """A typo'd register_entry driver tag must surface as a missing
     outcome + unknown_drivers report, never a silent skip."""
@@ -521,9 +544,6 @@ def test_prewarm_accounts_unknown_driver_tags(store, monkeypatch):
         "source": "env"})
     monkeypatch.setattr(prewarm, "msm_calibration_step", lambda: {
         "source": "env"})
-    import lighthouse_tpu.ops.cache_guard as cg
-
-    monkeypatch.setattr(cg, "install", lambda: None)
     rep = prewarm.run(force=True)
     assert rep["unknown_drivers"] == {"sha265": ["test::entry@f"]}
     assert rep["outcomes"] == {"test::entry@f": "missing"}

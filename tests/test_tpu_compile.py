@@ -1,0 +1,323 @@
+"""Compile-only guard: the main-path device programs at chip_smoke.py's
+shapes, lowered and compiled for a DESCRIBED TPU v5e (no chip attached).
+
+What the TPU compiler refuses here costs no chip time (guide
+on-chip-measurement section 2.3).  Nothing runs, so these tests say
+nothing about results or speed; chip_smoke.py is the run.
+
+Rules this file keeps: the topology is described inside a module-scoped
+fixture (never at import, in a skipif, in parametrize or in conftest),
+everything compiles in the test's own process, the persistent compile
+cache is off around the compiles (a described-device executable cannot
+be read back without a chip), and all such tests live in this one file
+so one xdist worker owns libtpu.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+LANES = 256            # the bucket a 131-set mainnet block pads to
+LEAVES = 1 << 20       # merkle leaves / epoch registry bucket
+BLOCK_SEG = 2 * 512    # blinded fold: 512 key lanes + 512 blinding lanes
+TABLE_ROWS = 1 << 14   # the smoke's 16,384 interop validators
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(topo.devices), axis_names=("data",))
+
+
+@pytest.fixture(scope="module")
+def tpu_branches():
+    """Steer the code to the branches a TPU node takes, and keep the
+    described-device executables out of the persistent cache.
+
+    ``bigint._use_mxu_redc()`` probes ``jax.default_backend()``, which is
+    the CPU here, so the switch is set in the test; the tracing caches
+    are dropped on both sides so no program traced for the other branch
+    is reused."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from lighthouse_tpu.ops import bigint as bi
+
+    was_cache = jax.config.jax_enable_compilation_cache
+    was_mxu = bi._MXU_REDC
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    bi._MXU_REDC = True
+    jax.clear_caches()
+    yield
+    bi._MXU_REDC = was_mxu
+    jax.clear_caches()
+    jax.config.update("jax_enable_compilation_cache", was_cache)
+    cc.reset_cache()
+
+
+def _compile(name, fn, *args, **kwargs):
+    """Lower + compile ``fn`` (an instrumented entry's ``._fn`` or a
+    plain jit) and print one JSON line of what the compiler reported."""
+    t0 = time.perf_counter()
+    lowered = fn.lower(*args, **kwargs)
+    t1 = time.perf_counter()
+    compiled = lowered.compile()
+    t2 = time.perf_counter()
+    ma = compiled.memory_analysis()
+    print("TPU_COMPILE " + json.dumps({
+        "program": name, "trace_s": round(t1 - t0, 1),
+        "compile_s": round(t2 - t1, 1),
+        "temp_bytes": int(ma.temp_size_in_bytes),
+        "code_bytes": int(ma.generated_code_size_in_bytes),
+        "arg_bytes": int(ma.argument_size_in_bytes),
+        "out_bytes": int(ma.output_size_in_bytes)}), flush=True)
+    # one 16 GB chip: a program whose temporaries alone do not fit is a
+    # refusal the compiler does not always raise by itself
+    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < 14 << 30
+    return compiled
+
+
+def _limbs(sh, n=LANES):
+    from lighthouse_tpu.ops import bigint as bi
+
+    return jax.ShapeDtypeStruct((n, bi.L), jnp.uint32, sharding=sh)
+
+
+def _fq12(sh):
+    fq2 = (_limbs(sh, 1), _limbs(sh, 1))
+    fq6 = (fq2, fq2, fq2)
+    return (fq6, fq6)
+
+
+def test_mont_mul_with_mxu_redc(one_chip, tpu_branches):
+    from lighthouse_tpu.ops import bigint as bi
+
+    assert bi._use_mxu_redc()
+    c = _compile("bigint.mont_mul[mxu]", jax.jit(bi.mont_mul),
+                 _limbs(one_chip), _limbs(one_chip))
+    # the MXU branch is a matrix product; the other branch has none
+    assert "convolution" in c.as_text() or "dot" in c.as_text()
+
+
+def test_g2_subgroup_kernel(one_chip, tpu_branches):
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    _compile("_g2_subgroup_kernel@256", bb._g2_subgroup_kernel._fn,
+             *[_limbs(one_chip)] * 4)
+
+
+def test_g1_subgroup_kernel(one_chip, tpu_branches):
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    _compile("_g1_subgroup_kernel@256", bb._g1_subgroup_kernel._fn,
+             *[_limbs(one_chip)] * 2)
+
+
+def test_fp12_mul_q(one_chip, tpu_branches):
+    from lighthouse_tpu.ops import dispatch_pipeline as dp
+
+    _compile("_fp12_mul_q", dp._fq12_mul_pair._fn,
+             _fq12(one_chip), _fq12(one_chip))
+
+
+@pytest.mark.slow  # ~1.5 min: ten unrolled levels of Jacobian adds
+def test_blinded_fold_block_layout(one_chip, tpu_branches):
+    """131 sets x 512 keys fold in slices of bls_backend._AGG_MAX_LANES
+    lanes: 32 sets x (512 key + 512 blinding lanes) per dispatch.  The
+    whole block in ONE dispatch (262,144 lanes) compiles to 15.5 GB of
+    temporaries — all of a 16 GB chip — which is why the cap exists."""
+    from lighthouse_tpu.ops import bls_backend as bb
+    from lighthouse_tpu.ops import msm
+
+    n_pad = bb._AGG_MAX_LANES // BLOCK_SEG
+    assert n_pad * BLOCK_SEG == 1 << 15
+    rows = _limbs(one_chip, BLOCK_SEG * n_pad)
+    c = _compile("_blinded_fold@32x(512+512)", msm._blinded_fold._fn,
+                 rows, rows, rows, _limbs(one_chip, 1), _limbs(one_chip, 1),
+                 n_pad)
+    assert c.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+@pytest.mark.slow  # ~3 min, 291 MB of code; not on chip_smoke's path
+def test_gather_fold_16_committees(one_chip, tpu_branches):
+    """The pubkey plane's fold over a 16,384-row table at 16 groups x
+    512 keys.  At the 131-set x 512-key layout (131,072 lanes) the TPU
+    compiler refuses it outright: 47.6 GB of HBM wanted, 15.75 GB there
+    (ROADMAP Queue 1 item 7 — the plane needs a lane cap of its own)."""
+    from lighthouse_tpu.ops import msm
+
+    groups, lanes = 16, 16 * 512
+    _compile(
+        "_gather_fold@16x512", msm._gather_fold._fn,
+        _limbs(one_chip, TABLE_ROWS), _limbs(one_chip, TABLE_ROWS),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((16, lanes), jnp.uint32, sharding=one_chip),
+        groups)
+
+
+def test_hash_pairs_device(one_chip, tpu_branches):
+    from lighthouse_tpu.ops import sha256
+
+    _compile("hash_pairs_device@2^19", sha256.hash_pairs_device._fn,
+             jax.ShapeDtypeStruct((LEAVES // 2, 16), jnp.uint32,
+                                  sharding=one_chip))
+
+
+def test_fold_levels_device(one_chip, tpu_branches):
+    from lighthouse_tpu.ops import sha256
+
+    _compile("_fold_levels_device@2^20", sha256._fold_levels_device._fn,
+             jax.ShapeDtypeStruct((LEAVES, 8), jnp.uint32,
+                                  sharding=one_chip))
+
+
+def test_fold_to_root(one_chip, tpu_branches):
+    """What merkleize_words(device=True) dispatches at 2^20 leaves."""
+    from lighthouse_tpu.ops import sha256
+
+    _compile("_fold_to_root_jit@2^20", sha256._fold_to_root_jit._fn,
+             jax.ShapeDtypeStruct((LEAVES, 8), jnp.uint32,
+                                  sharding=one_chip))
+
+
+def test_fused_epoch_pass(one_chip, tpu_branches):
+    """int64 lanes are emulated on a TPU: the pass must still compile,
+    under the scoped x64 context it is dispatched in."""
+    from lighthouse_tpu.ops import epoch_kernels as ek
+
+    def col(dt):
+        return jax.ShapeDtypeStruct((LEAVES,), dt, sharding=one_chip)
+
+    with jax.enable_x64():
+        i64 = jnp.int64
+        table = jax.ShapeDtypeStruct((3, 33), i64, sharding=one_chip)
+        _compile(
+            "_fused_epoch_pass@2^20", ek._epoch_pass_jit()._fn,
+            col(jnp.int32), col(i64), col(i64), col(jnp.uint8),
+            col(jnp.bool_), col(i64), col(i64), col(i64),
+            table, table,
+            jax.ShapeDtypeStruct((33,), i64, sharding=one_chip),
+            jax.ShapeDtypeStruct((ek.N_PARAMS,), i64, sharding=one_chip),
+            apply_eb=True)
+
+
+def test_fused_epoch_pass_over_four_chips(mesh4, tpu_branches):
+    """parallel/epoch_sharded's placement: columns over the mesh, tables
+    replicated — pure lane parallelism, so no collective may appear."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from lighthouse_tpu.ops import epoch_kernels as ek
+
+    def arr(shape, dt, spec):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh4, spec))
+
+    with jax.enable_x64():
+        i64 = jnp.int64
+        col = lambda dt: arr((LEAVES,), dt, P("data"))  # noqa: E731
+        table = arr((3, 33), i64, P())
+        c = _compile(
+            "_fused_epoch_pass@2^20/4chips", ek._epoch_pass_jit()._fn,
+            col(jnp.int32), col(i64), col(i64), col(jnp.uint8),
+            col(jnp.bool_), col(i64), col(i64), col(i64), table, table,
+            arr((33,), i64, P()), arr((ek.N_PARAMS,), i64, P()),
+            apply_eb=True)
+    text = c.as_text()
+    assert not any(op in text for op in (
+        "all-gather", "all-reduce", "collective-permute", "all-to-all"))
+
+
+def test_shuffle_rounds(one_chip, tpu_branches):
+    from lighthouse_tpu.ops import epoch_kernels as ek
+
+    rounds = 90
+    _compile(
+        "_shuffle_rounds@2^20", ek._shuffle_jit(rounds)._fn,
+        jax.ShapeDtypeStruct((LEAVES,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((rounds,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((rounds, LEAVES // 8), jnp.uint8,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+
+
+@pytest.mark.slow  # ~7 min; the native C++ final exponentiation serves
+def test_final_exp_hard_device(one_chip, tpu_branches):
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    _compile("final_exp_hard_device", bb._final_exp_hard_jit._fn,
+             _fq12(one_chip))
+
+
+def _pipeline_args(sh, n):
+    rows = [_limbs(sh, n)] * 10
+    return (*rows,
+            jax.ShapeDtypeStruct((16, n), jnp.uint32, sharding=sh),
+            jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=sh),
+            _limbs(sh, 1), _limbs(sh, 1), 0)
+
+
+@pytest.mark.slow  # ~8 min of XLA:TPU compile on 8 cores, one thread
+def test_pipeline_fused_flat_256(one_chip, tpu_branches):
+    """THE program: the whole batch-verify data plane, flat layout, at
+    the 256-lane bucket of a 131-set mainnet block."""
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    _compile("_pipeline_fused@256", bb._pipeline_fused._fn,
+             *_pipeline_args(one_chip, LANES))
+
+
+@pytest.mark.slow  # the node's 1-set proposer check: a second program
+def test_pipeline_fused_flat_4(one_chip, tpu_branches):
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    _compile("_pipeline_fused@4", bb._pipeline_fused._fn,
+             *_pipeline_args(one_chip, 4))
+
+
+@pytest.mark.slow  # ~5 min: the Miller loop once more, as a mesh program
+def test_sharded_miller_reduce_over_four_chips(mesh4, tpu_branches):
+    """parallel/bls_sharded at chip_smoke --chips 4's shape: 257 pairs
+    pad to 128 lanes a device; one all-gather of the partial products."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from lighthouse_tpu.ops import bigint as bi
+    from lighthouse_tpu.parallel import bls_sharded
+
+    per_dev = 128
+    n = per_dev * mesh4.devices.size
+    cols = [jax.ShapeDtypeStruct(
+        (n, bi.L), jnp.uint32,
+        sharding=NamedSharding(mesh4, P("data", None)))] * 6
+    mask = jax.ShapeDtypeStruct(
+        (n,), jnp.bool_, sharding=NamedSharding(mesh4, P("data")))
+    c = _compile("_sharded_miller_reduce@128x4chips",
+                 bls_sharded._sharded_miller_reduce(mesh4, per_dev)._fn,
+                 *cols, mask)
+    assert "all-gather" in c.as_text()
