@@ -104,7 +104,7 @@ def _bench_bls_1k() -> dict:
         # timed emit overwrites them together — a child killed during
         # the main warm-up still reports honest batch-size provenance
 
-    # warm-up compiles every kernel the ledger pass meets (incl. the
+    # warm-up compiles every kernel the stage pass meets (incl. the
     # batched subgroup check, which only fresh signature objects hit);
     # the persistent .jax_cache turns this into a load on later runs
     t0 = time.perf_counter()
@@ -135,14 +135,24 @@ def _bench_bls_1k() -> dict:
     result["stage"] = "tamper_checked"
     _emit_partial(result)
 
-    # per-stage ledger (VERDICT r2 #2): one profiled pass over FRESH
-    # signature objects so the batched device subgroup check is costed
-    from lighthouse_tpu.ops import bls_backend as _bb
+    # per-stage breakdown: one more pass over FRESH signature objects (so
+    # the batched device subgroup check is costed), read as the growth of
+    # the stage spans' histogram — the program that runs, nothing synced
+    from lighthouse_tpu.common import promtext
+    from lighthouse_tpu.common.metrics import REGISTRY
 
-    ledger: dict = {}
-    ledger_ok = _bb.verify_sets_pipeline(_fresh(sets), ledger=ledger)
-    assert ledger_ok, "profiled ledger pass failed to verify"
-    result["stage_ms"] = {k: round(v * 1000, 2) for k, v in ledger.items()}
+    def _stage_sums():
+        fam = promtext.parse(REGISTRY.render()).get("bls_verify_stage_seconds")
+        return {} if fam is None else {
+            dict(s.labels)["stage"]: s.value for s in fam.samples
+            if s.name.endswith("_sum")
+            and dict(s.labels).get("backend") == "tpu"}
+
+    before = _stage_sums()
+    assert bls.verify_signature_sets(_fresh(sets), backend="tpu"), \
+        "stage-breakdown pass failed to verify"
+    result["stage_ms"] = {k: round((v - before.get(k, 0.0)) * 1000, 2)
+                          for k, v in _stage_sums().items()}
     # the cross-bench stage breakdown object (BENCH_*.json consumers read
     # result["stages"][<bench>][<stage>] in ms); per-bench children merge
     # their own sub-dicts in main()

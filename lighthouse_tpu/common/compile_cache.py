@@ -7,6 +7,12 @@ the cache key, so it is never derived from a temp name, a pid or the
 clock.  Every entry point that compiles device programs (``cli.py bn``,
 ``chip_smoke.py``, the test suite, the dry-run worker) calls
 :func:`configure` once before its first compile.
+
+It is also the one place where the data plane meets jax before anything
+runs, so it installs ``jax.profiler.TraceAnnotation`` as the annotator of
+``common.tracing``: from then on every program span lands on the
+``/host:CPU`` plane of any live profiler trace (a no-op while no profiler
+session is live).
 """
 
 from __future__ import annotations
@@ -23,6 +29,9 @@ def configure() -> str:
     """Apply the rule; returns the directory the cache uses."""
     import jax
 
+    from lighthouse_tpu.common import tracing
+
+    tracing.set_annotator(jax.profiler.TraceAnnotation)
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = os.path.join(_CHECKOUT, ".jax_cache")

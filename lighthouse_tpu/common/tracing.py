@@ -17,6 +17,16 @@ OLDEST root out (newest-wins), so a long-lived process's UNSLOTTED
 timeline shows recent device-plane activity, not frozen startup content.
 Tracing is always on — per-span cost is far below a single host<->device
 crossing, the thing being measured.
+
+Two hooks make a span the one timing primitive of the data plane:
+``span(..., observe=fn)`` hands the span's duration (seconds) to ``fn`` on
+exit, so a stage histogram is fed by the same ``with`` that draws the span;
+and :func:`set_annotator` installs a factory (the data plane passes
+``jax.profiler.TraceAnnotation``, from ``compile_cache.configure()``) whose
+context is entered and exited with every span, which puts the program's
+spans on the ``/host:CPU`` plane of any live profiler trace, on the device
+trace's clock.  This module imports no jax: a host-only process never
+installs one and pays nothing.
 """
 
 from __future__ import annotations
@@ -42,6 +52,19 @@ _current: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
 _slot_ctx: contextvars.ContextVar[int | None] = contextvars.ContextVar(
     "lhtpu_current_slot", default=None)
 
+# factory(name, **scalar_attrs) -> context manager, entered/exited with
+# every span while set (see set_annotator)
+_annotator = None
+
+
+def set_annotator(factory) -> None:
+    """Install (or, with None, remove) the annotator: ``factory(name,
+    **scalar_attrs)`` must return a context manager.  It is entered right
+    after a span starts and exited right before it ends, so annotations
+    nest exactly as the spans do."""
+    global _annotator
+    _annotator = factory
+
 
 def _jsonable(v):
     if isinstance(v, (bytes, bytearray, memoryview)):
@@ -63,9 +86,12 @@ class Span:
     wall_start: float = 0.0
     children: list["Span"] = field(default_factory=list)
 
-    def duration_ms(self) -> float:
+    def duration_s(self) -> float:
         end = self.end if self.end is not None else time.perf_counter()
-        return (end - self.start) * 1000.0
+        return end - self.start
+
+    def duration_ms(self) -> float:
+        return self.duration_s() * 1000.0
 
     def to_dict(self, base: float | None = None) -> dict:
         base = self.start if base is None else base
@@ -193,13 +219,19 @@ class span:
     A root span (no enclosing span in this context) is filed into the
     tracer's ring under its `slot` (explicit, else inherited from the
     nearest enclosing span that set one, else UNSLOTTED).
+
+    ``observe`` is called once on exit with the span's duration in
+    seconds (also when the body raised): call sites pass their owner
+    module's stage-histogram helper, so one ``with`` both draws the span
+    and feeds the metric.
     """
 
     def __init__(self, name: str, slot: int | None = None,
-                 tracer: Tracer | None = None, **attrs):
+                 tracer: Tracer | None = None, observe=None, **attrs):
         self.name = name
         self.slot = slot
         self.attrs = attrs
+        self.observe = observe
         self.tracer = tracer if tracer is not None else TRACER
 
     def __enter__(self) -> Span:
@@ -212,10 +244,25 @@ class span:
         self._token = _current.set(self._span)
         self._slot_token = (_slot_ctx.set(int(self.slot))
                             if self.slot is not None else None)
+        self._annotation = None
+        if _annotator is not None:
+            try:
+                self._annotation = _annotator(self.name, **{
+                    k: v for k, v in attrs.items()
+                    if isinstance(v, (str, int, float, bool))})
+                self._annotation.__enter__()
+            except Exception as e:
+                self._annotation = None
+                record_swallowed("tracing.annotator", e)
         return self._span
 
     def __exit__(self, exc_type, exc, tb):
         sp = self._span
+        if self._annotation is not None:
+            try:
+                self._annotation.__exit__(exc_type, exc, tb)
+            except Exception as e:
+                record_swallowed("tracing.annotator", e)
         sp.end = time.perf_counter()
         if exc_type is not None:
             sp.attrs.setdefault("error", exc_type.__name__)
@@ -232,6 +279,11 @@ class span:
             self._parent.children.append(sp)
         else:
             self.tracer.record_root(sp, slot)
+        if self.observe is not None:
+            try:
+                self.observe(sp.duration_s())
+            except Exception as e:
+                record_swallowed("tracing.observe", e)
         return False
 
     def __call__(self, fn):
@@ -239,14 +291,14 @@ class span:
             @functools.wraps(fn)
             async def awrapped(*args, **kwargs):
                 with span(self.name, slot=self.slot, tracer=self.tracer,
-                          **self.attrs):
+                          observe=self.observe, **self.attrs):
                     return await fn(*args, **kwargs)
             return awrapped
 
         @functools.wraps(fn)
         def wrapped(*args, **kwargs):
             with span(self.name, slot=self.slot, tracer=self.tracer,
-                      **self.attrs):
+                      observe=self.observe, **self.attrs):
                 return fn(*args, **kwargs)
         return wrapped
 

@@ -28,6 +28,7 @@ _resolve_backend's lazy hook).
 from __future__ import annotations
 
 import secrets
+from functools import partial
 from typing import Sequence
 
 import numpy as np
@@ -36,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from lighthouse_tpu.common import device_telemetry as _dtel
+from lighthouse_tpu.common import tracing
 from lighthouse_tpu.crypto.bls import api, curve as cv
 from lighthouse_tpu.ops import program_store as _pstore
 
@@ -287,6 +289,14 @@ def _blinding_locked(max_k: int):
 _AGG_MAX_LANES = 1 << 15
 
 
+def _stage_span(name: str, stage: str, **attrs):
+    """One stage of the verify pipeline: a span (on the profiler's clock
+    while a trace is live) whose duration also feeds
+    ``bls_verify_stage_seconds{backend="tpu",stage=<stage>}``."""
+    return tracing.span(name, observe=partial(api.record_stage, "tpu", stage),
+                        **attrs)
+
+
 def aggregate_pubkeys_device(sets):
     """Per-set pubkey aggregation as device segment-sums.
 
@@ -319,20 +329,24 @@ def aggregate_pubkeys_device(sets):
         X0[lanes] = bx
         Y0[lanes] = by
         Z0[lanes] = one
+    tracing.add_attrs(slices=-(-n // n_pad), lanes=seg * n_pad)
     outs = []
     for lo in range(0, n, n_pad):
-        X, Y, Z = X0.copy(), Y0.copy(), Z0.copy()
-        for i, s in enumerate(sets[lo:lo + n_pad]):
-            for j, pk in enumerate(s.pubkeys):
-                xl, yl = pk.mont_limbs()
-                lane = j * n_pad + i   # s-major layout for g1_segment_sum
-                X[lane] = xl
-                Y[lane] = yl
-                Z[lane] = one
-        outs.append(_msm.blinded_fold_device(
-            X, Y, Z, neg_total[0], neg_total[1], n_pad))
-    xa, ya, inf = (np.concatenate(cols)[:n]
-                   for cols in zip(*jax.device_get(outs)))
+        with _stage_span("bls.aggregate.layout", "aggregate_layout"):
+            X, Y, Z = X0.copy(), Y0.copy(), Z0.copy()
+            for i, s in enumerate(sets[lo:lo + n_pad]):
+                for j, pk in enumerate(s.pubkeys):
+                    xl, yl = pk.mont_limbs()
+                    lane = j * n_pad + i   # s-major layout for g1_segment_sum
+                    X[lane] = xl
+                    Y[lane] = yl
+                    Z[lane] = one
+        with _stage_span("bls.aggregate.dispatch", "aggregate_dispatch"):
+            outs.append(_msm.blinded_fold_device(
+                X, Y, Z, neg_total[0], neg_total[1], n_pad))
+    with _stage_span("bls.aggregate.fetch", "aggregate_fetch"):
+        fetched = jax.device_get(outs)
+    xa, ya, inf = (np.concatenate(cols)[:n] for cols in zip(*fetched))
     return xa, ya, inf
 
 
@@ -461,7 +475,6 @@ def _final_exp_is_one(f_host) -> bool:
 
 
 def verify_sets_pipeline(sets: Sequence[api.SignatureSet],
-                         ledger: dict | None = None,
                          chunk_size: int | None = None) -> bool:
     """Batch verification with the scalar work on device (see module doc).
 
@@ -476,97 +489,80 @@ def verify_sets_pipeline(sets: Sequence[api.SignatureSet],
     and single-shot verdicts are identical by construction (the combined
     check is multiplicative over chunks).
 
-    With ``ledger`` given, per-stage wall times (seconds) are recorded under
-    keys subgroup / aggregate / prep_host / limbs / pipeline / final_exp —
-    device stages are synchronized before timing, so only pass a ledger
-    when profiling (it serializes the pipeline).  Every stage also feeds
-    the labeled ``bls_verify_stage_seconds{backend="tpu"}`` histogram; on
-    the async (no-ledger) path the device ``pipeline`` stage times
-    dispatch, not execution (see api.record_stage help)."""
-    from lighthouse_tpu.common import tracing
-
-    with tracing.span("bls.verify_pipeline", sets=len(sets),
-                      profiled=ledger is not None):
-        return _verify_sets_pipeline(sets, ledger, chunk_size)
+    Every stage is a child span of ``bls.verify_pipeline`` that also
+    feeds ``bls_verify_stage_seconds{backend="tpu",stage}``: ``subgroup``,
+    ``aggregate``, ``prep_host``, ``limbs`` and ``pipeline`` (a dispatch
+    time: nothing syncs there) per chunk, ``final_exp``; and as parts of
+    them ``aggregate_layout`` / ``aggregate_dispatch`` / ``aggregate_fetch``
+    and ``subgroup_wait`` / ``pipeline_wait`` / ``final_exp_host``.  A stage
+    boundary sits only where the code blocks anyway or between two pieces
+    of host code, so the spans measure the program that runs."""
+    with tracing.span("bls.verify_pipeline", sets=len(sets)):
+        return _verify_sets_pipeline(sets, chunk_size)
 
 
 def _verify_sets_pipeline(sets: Sequence[api.SignatureSet],
-                          ledger: dict | None = None,
                           chunk_size: int | None = None) -> bool:
-    import time as _time
-
     from lighthouse_tpu.ops import dispatch_pipeline as dp
 
-    def _mark(key, t0):
-        now = _time.perf_counter()
-        if ledger is not None:
-            ledger[key] = ledger.get(key, 0.0) + (now - t0)
-        api.record_stage("tpu", key, now - t0)
-        return _time.perf_counter()
-
-    t0 = _time.perf_counter()
     n = len(sets)
     if n == 0:
         return False
-    # one native batch call decompresses every fresh signature (vs one
-    # ctypes crossing + C++ setup per signature)
-    if not api.Signature.decompress_batch([s.signature for s in sets]):
-        return False
-    sig_pts = []
-    h2cs = []
-    for s in sets:
-        if not s.pubkeys:
+    with _stage_span("bls.subgroup", "subgroup"):
+        # one native batch call decompresses every fresh signature (vs one
+        # ctypes crossing + C++ setup per signature)
+        if not api.Signature.decompress_batch([s.signature for s in sets]):
             return False
-        try:
-            sig_pt = s.signature.point_unchecked()
-        except (api.BlsError, ValueError):
-            return False
-        if sig_pt is cv.INF:
-            return False
-        sig_pts.append(sig_pt)
-        h2cs.append(_hash_to_g2_cached(s.message))
+        sig_pts = []
+        h2cs = []
+        for s in sets:
+            if not s.pubkeys:
+                return False
+            try:
+                sig_pt = s.signature.point_unchecked()
+            except (api.BlsError, ValueError):
+                return False
+            if sig_pt is cv.INF:
+                return False
+            sig_pts.append(sig_pt)
+            h2cs.append(_hash_to_g2_cached(s.message))
 
-    # G2 membership for fresh signatures: one batched device ψ kernel,
-    # DISPATCHED here but not synced — the verdict row is read at the
-    # commit point below, after the Miller chunks are in flight, so the
-    # aggregate/limb host work runs concurrently with the membership
-    # test.  Profiled (ledger) runs commit immediately: the ledger's
-    # whole point is serialized per-stage attribution.
-    verdict = _dispatch_subgroup_check([s.signature for s in sets])
-    if verdict is None:
-        return False
-    if ledger is not None and not verdict.commit(
-            timeout=dp.watchdog_deadline_s()):
-        return False
-    t0 = _mark("subgroup", t0)
+        # G2 membership for fresh signatures: one batched device ψ kernel,
+        # DISPATCHED here but not synced — the verdict row is read at the
+        # commit point below, after the Miller chunks are in flight, so
+        # the aggregate/limb host work runs concurrently with the
+        # membership test.
+        verdict = _dispatch_subgroup_check([s.signature for s in sets])
+        if verdict is None:
+            return False
 
     # per-set pubkey aggregation: one device segment-sum when sets carry
     # real member lists (attestation shape); trivial 1-key batches keep
     # the free host path.  An identity aggregate (opposing keys) can
     # never verify — fail the batch, callers bisect to attribute.
-    try:
-        n_members = sum(len(s.pubkeys) for s in sets)
-        if n_members - n >= 16:
-            pk_rows_x, pk_rows_y, agg_inf = aggregate_pubkeys_device(sets)
-            if agg_inf.any():
-                return False
-        else:
-            agg_pks = [s.aggregate_pubkey() for s in sets]
-            if any(p is cv.INF for p in agg_pks):
-                return False
-            pk_rows_x = ec.ints_to_mont_limbs([p[0] for p in agg_pks])
-            pk_rows_y = ec.ints_to_mont_limbs([p[1] for p in agg_pks])
-    except (api.BlsError, ValueError):
-        return False
-    t0 = _mark("aggregate", t0)
+    with _stage_span("bls.aggregate", "aggregate"):
+        try:
+            n_members = sum(len(s.pubkeys) for s in sets)
+            if n_members - n >= 16:
+                pk_rows_x, pk_rows_y, agg_inf = aggregate_pubkeys_device(sets)
+                if agg_inf.any():
+                    return False
+            else:
+                agg_pks = [s.aggregate_pubkey() for s in sets]
+                if any(p is cv.INF for p in agg_pks):
+                    return False
+                pk_rows_x = ec.ints_to_mont_limbs([p[0] for p in agg_pks])
+                pk_rows_y = ec.ints_to_mont_limbs([p[1] for p in agg_pks])
+        except (api.BlsError, ValueError):
+            return False
 
-    scalars = []
-    for _ in range(n):
-        r = 0
-        while r == 0:
-            r = secrets.randbits(RAND_BITS)
-        scalars.append(r)
-    t0 = _mark("prep_host", t0)
+    with _stage_span("bls.prep_host", "prep_host"):
+        scalars = []
+        for _ in range(n):
+            r = 0
+            while r == 0:
+                r = secrets.randbits(RAND_BITS)
+            scalars.append(r)
 
     # --- chunked double-buffered dispatch: each chunk's host layout runs
     # while the previous chunk's fused kernel is in flight (async JAX
@@ -576,48 +572,37 @@ def _verify_sets_pipeline(sets: Sequence[api.SignatureSet],
     # single-shot path.
     chunks = dp.plan_chunks(n, dp.chunk_size(chunk_size))
     partials = []
-    limbs_s = 0.0
-    pipeline_s = 0.0
     overlap_s = 0.0
-    inflight = False
     for ci, (lo, hi) in enumerate(chunks):
         faults.fire("chunk", index=ci)
-        tc = _time.perf_counter()
-        args = _chunk_layout(sets[lo:hi], sig_pts[lo:hi], h2cs[lo:hi],
-                             pk_rows_x[lo:hi], pk_rows_y[lo:hi],
-                             scalars[lo:hi])
-        td = _time.perf_counter()
-        limbs_s += td - tc
-        f = _pipeline_fused(*args)
-        if ledger is not None:
-            jax.block_until_ready(f)
-        now = _time.perf_counter()
-        pipeline_s += now - td
-        if inflight and ledger is None:
-            # host work done while a dispatched chunk was executing —
-            # meaningless on the profiled path, whose per-chunk sync
-            # serializes everything
-            overlap_s += now - tc
-        inflight = True
-        partials.append(f)
-    if ledger is not None:
-        ledger["limbs"] = ledger.get("limbs", 0.0) + limbs_s
-        ledger["pipeline"] = ledger.get("pipeline", 0.0) + pipeline_s
-    api.record_stage("tpu", "limbs", limbs_s)
-    api.record_stage("tpu", "pipeline", pipeline_s)
+        with _stage_span("bls.limbs", "limbs", chunk=ci) as limbs:
+            args = _chunk_layout(sets[lo:hi], sig_pts[lo:hi], h2cs[lo:hi],
+                                 pk_rows_x[lo:hi], pk_rows_y[lo:hi],
+                                 scalars[lo:hi])
+        # an unsynced dispatch: operand copies and the enqueue, not the
+        # program's execution (pipeline_wait below holds that)
+        with _stage_span("bls.pipeline.dispatch", "pipeline",
+                         chunk=ci) as dispatch:
+            partials.append(_pipeline_fused(*args))
+        if ci:
+            # host work done while a dispatched chunk was executing
+            overlap_s += dispatch.end - limbs.start
     dp.record_pipeline(len(chunks), overlap_s, n)
-    t0 = _time.perf_counter()
 
-    # commit point: the subgroup verdict row is read only now, with the
-    # Miller chunks already in flight behind it in the device queue (a
-    # wedged kernel surfaces as WatchdogTimeout for the supervisor)
-    if not verdict.commit(timeout=dp.watchdog_deadline_s()):
-        return False
-    f = dp.combine_partials(partials)
-    f_host = fq12_from_device(jax.device_get(f))
-    ok = _final_exp_is_one(f_host)
-    _mark("final_exp", t0)
-    return ok
+    with _stage_span("bls.final_exp", "final_exp"):
+        # commit point: the subgroup verdict row is read only now, with
+        # the Miller chunks already in flight behind it in the device
+        # queue (a wedged kernel surfaces as WatchdogTimeout for the
+        # supervisor)
+        with _stage_span("bls.subgroup.wait", "subgroup_wait"):
+            passed = verdict.commit(timeout=dp.watchdog_deadline_s())
+        if not passed:
+            return False
+        with _stage_span("bls.pipeline.wait", "pipeline_wait"):
+            f = dp.combine_partials(partials)
+            f_host = fq12_from_device(jax.device_get(f))
+        with _stage_span("bls.final_exp.host", "final_exp_host"):
+            return _final_exp_is_one(f_host)
 
 
 def _chunk_layout(sets, sig_pts, h2cs, pk_rows_x, pk_rows_y, scalars):

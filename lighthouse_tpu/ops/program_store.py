@@ -67,9 +67,11 @@ import os
 import pathlib
 import pickle
 import threading
+from functools import partial
 
 from lighthouse_tpu.common import env as envreg
 from lighthouse_tpu.common import flight_recorder as _flight
+from lighthouse_tpu.common import tracing
 from lighthouse_tpu.common.metrics import REGISTRY, record_swallowed
 
 
@@ -203,11 +205,13 @@ def _serialize_compiled(compiled) -> bytes:
     return pickle.dumps(se.serialize(compiled))
 
 
-def _deserialize_payload(data: bytes):
+def _deserialize_payload(data: bytes, entry: str = "-"):
     from jax.experimental import serialize_executable as se
 
-    payload, in_tree, out_tree = pickle.loads(data)
-    return se.deserialize_and_load(payload, in_tree, out_tree)
+    with _load_span("aot.load.unpickle", entry, "unpickle"):
+        payload, in_tree, out_tree = pickle.loads(data)
+    with _load_span("aot.load.deserialize", entry, "deserialize"):
+        return se.deserialize_and_load(payload, in_tree, out_tree)
 
 
 def _fingerprint() -> dict:
@@ -253,6 +257,29 @@ def _record_miss(reason: str) -> None:
             "recompile, never a crash)").labels(reason=reason).inc()
     except Exception as e:
         record_swallowed("program_store.metric", e)
+
+
+def _record_load_stage(entry: str, stage: str, seconds: float) -> None:
+    try:
+        REGISTRY.histogram(
+            "aot_store_load_seconds",
+            "wall time of bringing one stored program back, by entry and "
+            "stage: read (file to record), unpickle, deserialize "
+            "(deserialize_and_load), first_call (first execution of a "
+            "store_hit program)",
+            buckets=(0.001, 0.01, 0.1, 0.5, 1.0, 5.0, 15.0, 60.0, 180.0,
+                     600.0),
+        ).labels(entry=entry, stage=stage).observe(seconds)
+    except Exception as e:
+        record_swallowed("program_store.metric", e)
+
+
+def _load_span(name: str, entry: str, stage: str):
+    """A span of the load path whose duration also feeds
+    ``aot_store_load_seconds{entry,stage}``."""
+    return tracing.span(
+        name, entry=entry,
+        observe=partial(_record_load_stage, entry, stage))
 
 
 def _record_commit(outcome: str) -> None:
@@ -483,13 +510,17 @@ class _LoadedProgram:
     """One deserialized/compiled executable plus the calling convention
     (the ``jax.stages.Compiled`` signature drops static args)."""
 
-    __slots__ = ("compiled", "static_argnums", "static_argnames", "source")
+    __slots__ = ("compiled", "static_argnums", "static_argnames", "source",
+                 "first_call_pending")
 
     def __init__(self, compiled, info: dict, source: str):
         self.compiled = compiled
         self.static_argnums = frozenset(info.get("static_argnums") or ())
         self.static_argnames = frozenset(info.get("static_argnames") or ())
         self.source = source
+        # a reloaded executable may finish loading on its first execution:
+        # that call is timed as the load path's last stage
+        self.first_call_pending = source == "store_hit"
 
     def call(self, args, kwargs):
         if self.static_argnums:
@@ -624,7 +655,12 @@ def _dispatch(entry: str, fn, args, kwargs):
         if prog is None:
             return None
     try:
-        out = prog.call(args, kwargs)
+        if prog.first_call_pending:
+            prog.first_call_pending = False
+            with _load_span("aot.first_call", entry, "first_call"):
+                out = prog.call(args, kwargs)
+        else:
+            out = prog.call(args, kwargs)
     except Exception as e:
         # an aval/pytree mismatch or a runtime failure: evict so the
         # next call goes straight to jax.jit instead of failing again
@@ -652,7 +688,8 @@ def _load_or_compile(st: _State, entry: str, fn, args, kwargs, sig: str,
         info = manifest_info().get(entry, {})
         key = store_key(entry, info.get("backend", "-"), sig)
         try:
-            rec = st.store.get(key)
+            with _load_span("aot.load.read", entry, "read"):
+                rec = st.store.get(key)
         except OSError as e:
             # the directory itself is unusable (read-only fs, wrong
             # perms): deactivate rather than pay a failing mkdir +
@@ -664,7 +701,7 @@ def _load_or_compile(st: _State, entry: str, fn, args, kwargs, sig: str,
             return None, False
         if rec is not None:
             try:
-                compiled = _deserialize_payload(rec["data"])
+                compiled = _deserialize_payload(rec["data"], entry)
             except Exception as e:
                 record_swallowed("program_store.load", e)
                 st.store._bump("misses")
@@ -737,7 +774,7 @@ def load_records(recs, stop=None) -> dict:
                     continue
                 info = manifest_info().get(entry, {})
             try:
-                compiled = _deserialize_payload(data)
+                compiled = _deserialize_payload(data, entry)
             except Exception as e:
                 record_swallowed("program_store.load", e)
                 st.store._bump("misses")

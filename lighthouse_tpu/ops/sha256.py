@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from lighthouse_tpu.common import device_telemetry as _dtel
+from lighthouse_tpu.common import tracing
 from lighthouse_tpu.common.metrics import REGISTRY
 from lighthouse_tpu.ops import program_store as _pstore
 
@@ -68,6 +69,33 @@ def _record_fold_dispatch(shape_key, seconds: float) -> None:
         from lighthouse_tpu.common.metrics import record_swallowed
 
         record_swallowed("sha256.record_fold", e)
+
+
+def record_merkle_stage(stage: str, seconds: float) -> None:
+    """One stage of an incremental merkle update (sole registration site
+    of the merkle_stage_* family — lhlint LH501 FAMILY_OWNERS).  The tree
+    cache's stages (leaves, diff, slice, snapshot, gather, scatter) and
+    this module's (pad, h2d, execute, d2h, hash_host) share the family so
+    a state root's time adds up in one place."""
+    try:
+        REGISTRY.histogram(
+            "merkle_stage_seconds",
+            "incremental merkle update wall time by stage (device stages: "
+            "h2d is the host side of the copy-in, execute the enqueue, "
+            "d2h the blocked fetch, which waits for both)",
+            buckets=(0.00001, 0.0001, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5,
+                     1.0, 5.0),
+        ).labels(stage=stage).observe(seconds)
+    except Exception as e:
+        from lighthouse_tpu.common.metrics import record_swallowed
+
+        record_swallowed("sha256.record_merkle_stage", e)
+
+
+def merkle_stage_span(name: str, stage: str, **attrs):
+    """A span whose duration also feeds ``merkle_stage_seconds{stage}``."""
+    return tracing.span(name, observe=partial(record_merkle_stage, stage),
+                        **attrs)
 
 # FIPS 180-4 round constants.
 _K = np.array(
@@ -384,24 +412,51 @@ _DEVICE_MIN_PAIRS = 2048
 
 
 def batch_hash_pairs(pairs: np.ndarray, *, device: bool | None = None) -> np.ndarray:
-    """Public batched pair-hash: uint32[N,16] -> uint32[N,8], device-routed."""
-    return _hash_level(pairs, device=device)
+    """Public batched pair-hash: uint32[N,16] -> uint32[N,8], device-routed.
+
+    The routing point of the tree cache's dirty levels and of the registry's
+    element roots: counts its chunks under ``levels_device``/``levels_host``
+    from the same boolean that routes the call."""
+    use_device = (device if device is not None
+                  else pairs.shape[0] >= _DEVICE_MIN_PAIRS)
+    REGISTRY.counter(
+        "sha256_merkle_chunks_total",
+        "leaf chunks merkleized, by fold path").labels(
+        path="levels_device" if use_device else "levels_host").inc(
+        2 * pairs.shape[0])
+    return _hash_level(pairs, device=use_device)
 
 
 def _hash_level(pairs: np.ndarray, *, device: bool | None = None) -> np.ndarray:
     use_device = device if device is not None else pairs.shape[0] >= _DEVICE_MIN_PAIRS
-    if use_device:
-        # Pad the lane count to a power of two so the jit compile cache is
-        # bounded at ~log2(max_pairs) programs shared by every tree size
-        # (padded lanes hash garbage and are discarded).
-        n = pairs.shape[0]
-        padded = 1 << max(n - 1, 0).bit_length()
+    n = pairs.shape[0]
+    if not use_device:
+        with merkle_stage_span("sha.host", "hash_host", pairs=n):
+            return hash_pairs_np(pairs)
+    # Pad the lane count to a power of two so the jit compile cache is
+    # bounded at ~log2(max_pairs) programs shared by every tree size
+    # (padded lanes hash garbage and are discarded).
+    padded = 1 << max(n - 1, 0).bit_length()
+    lanes = REGISTRY.counter(
+        "sha256_device_lanes_total",
+        "lanes of hash_pairs_device dispatches: live pairs, and the "
+        "padding up to the power-of-two program shape")
+    lanes.labels(kind="live").inc(n)
+    lanes.labels(kind="padding").inc(padded - n)
+    with merkle_stage_span("sha.pad", "pad", pairs=n, lanes=padded):
         if padded != n:
             pairs = np.concatenate(
                 [pairs, np.zeros((padded - n, 16), np.uint32)], axis=0
             )
-        return np.asarray(hash_pairs_device(jnp.asarray(pairs)))[:n]
-    return hash_pairs_np(pairs)
+    # three pieces of host code, no sync added: h2d is the host side of
+    # the copy-in, execute the enqueue, and d2h — the one call that
+    # blocks — waits for the copy, the program and the fetch back
+    with merkle_stage_span("sha.h2d", "h2d", pairs=n, lanes=padded):
+        operand = jnp.asarray(pairs)
+    with merkle_stage_span("sha.execute", "execute", pairs=n, lanes=padded):
+        hashed = hash_pairs_device(operand)
+    with merkle_stage_span("sha.d2h", "d2h", pairs=n, lanes=padded):
+        return np.asarray(hashed)[:n]
 
 
 # whole-fold one-dispatch threshold: pow2 leaf counts keep the jit

@@ -26,8 +26,12 @@ from __future__ import annotations
 
 import numpy as np
 
+from lighthouse_tpu.common import tracing
 from lighthouse_tpu.ops import sha256 as sha_ops
 from lighthouse_tpu.ssz.core import _next_pow2
+
+# a span whose duration feeds merkle_stage_seconds{stage}
+_stage = sha_ops.merkle_stage_span
 
 _ZERO = sha_ops.ZERO_HASH_WORDS  # uint32[depth+1, 8] ladder
 
@@ -85,31 +89,39 @@ class IncrementalTree:
         if pow2 != self.leaves.shape[0]:
             self._grow(pow2)
 
-        if dirty is None:
-            same = (self.leaves[: self.n] == new_leaves[: self.n]).all(axis=1)
-            dirty = np.nonzero(~same)[0]
-        else:
-            dirty = np.asarray(dirty, dtype=np.int64)
-            dirty = dirty[dirty < self.n]
-        if n_new > self.n:
-            appended = np.arange(self.n, n_new, dtype=np.int64)
-            dirty = np.concatenate([dirty, appended])
+        with _stage("tree.diff", "diff"):
+            if dirty is None:
+                same = (self.leaves[: self.n]
+                        == new_leaves[: self.n]).all(axis=1)
+                dirty = np.nonzero(~same)[0]
+            else:
+                dirty = np.asarray(dirty, dtype=np.int64)
+                dirty = dirty[dirty < self.n]
+            if n_new > self.n:
+                appended = np.arange(self.n, n_new, dtype=np.int64)
+                dirty = np.concatenate([dirty, appended])
         if dirty.size == 0:
             self.n = n_new
             return
 
-        self.leaves[: n_new][dirty] = new_leaves[dirty]
-        self.n = n_new
+        with tracing.span("tree.update", dirty=int(dirty.size),
+                          levels=len(self.levels)):
+            with _stage("tree.level.scatter", "scatter", level=0):
+                self.leaves[: n_new][dirty] = new_leaves[dirty]
+            self.n = n_new
 
-        level = self.leaves
-        idx = np.unique(dirty >> 1)
-        for k, nxt in enumerate(self.levels):
-            pairs = np.empty((idx.shape[0], 16), dtype=np.uint32)
-            pairs[:, :8] = level[2 * idx]
-            pairs[:, 8:] = level[2 * idx + 1]
-            nxt[idx] = sha_ops.batch_hash_pairs(pairs)
-            level = nxt
-            idx = np.unique(idx >> 1)
+            level = self.leaves
+            idx = dirty
+            for k, nxt in enumerate(self.levels, start=1):
+                with _stage("tree.level.gather", "gather", level=k):
+                    idx = np.unique(idx >> 1)
+                    pairs = np.empty((idx.shape[0], 16), dtype=np.uint32)
+                    pairs[:, :8] = level[2 * idx]
+                    pairs[:, 8:] = level[2 * idx + 1]
+                hashed = sha_ops.batch_hash_pairs(pairs)
+                with _stage("tree.level.scatter", "scatter", level=k):
+                    nxt[idx] = hashed
+                level = nxt
 
     def _grow(self, pow2: int) -> None:
         """Extend padded storage to a larger power of two; new regions are
@@ -236,23 +248,31 @@ class ValidatorsCache:
         if n_new < n_old:
             self.__init__(typ, validators)  # shrink: rebuild (never in spec)
         else:
-            dirty = self._dirty_rows(validators)
-            appended = np.arange(n_old, n_new, dtype=np.int64)
-            rows = np.concatenate([dirty, appended])
+            with _stage("tree.validators.diff", "diff"):
+                dirty = self._dirty_rows(validators)
+                appended = np.arange(n_old, n_new, dtype=np.int64)
+                rows = np.concatenate([dirty, appended])
             if rows.size:
-                sub = _slice_validators(validators, rows)
-                new_roots = typ.batch_roots(sub)
-                if n_new > n_old:
-                    grown = np.zeros((n_new, 8), dtype=np.uint32)
-                    grown[:n_old] = self.element_roots
-                    self.element_roots = grown
+                with _stage("tree.validators.slice", "slice",
+                            rows=int(rows.size)):
+                    sub = _slice_validators(validators, rows)
+                # a parent only: its time is its children's (tree.leaves
+                # and the sha.* stages of the four element-root levels)
+                with tracing.span("tree.validators.element_roots",
+                                  rows=int(rows.size)):
+                    new_roots = typ.batch_roots(sub)
+                with _stage("tree.validators.snapshot", "snapshot"):
+                    if n_new > n_old:
+                        grown = np.zeros((n_new, 8), dtype=np.uint32)
+                        grown[:n_old] = self.element_roots
+                        self.element_roots = grown
+                        for c in _COLS():
+                            col = getattr(validators, c)
+                            self.snap[c] = np.concatenate(
+                                [self.snap[c], col[n_old:n_new].copy()])
+                    self.element_roots[rows] = new_roots
                     for c in _COLS():
-                        col = getattr(validators, c)
-                        self.snap[c] = np.concatenate(
-                            [self.snap[c], col[n_old:n_new].copy()])
-                self.element_roots[rows] = new_roots
-                for c in _COLS():
-                    self.snap[c][dirty] = getattr(validators, c)[dirty]
+                        self.snap[c][dirty] = getattr(validators, c)[dirty]
                 self.tree.update(self.element_roots, dirty=rows)
         r = self.tree.root()
         return sha_ops.mix_in_length(r, n_new)
@@ -281,6 +301,10 @@ class StateTreeCache:
         self.fields: dict[str, object] = {}
 
     def field_root(self, fname: str, ftype, value) -> bytes:
+        with tracing.span("tree.field", field=fname):
+            return self._field_root(fname, ftype, value)
+
+    def _field_root(self, fname: str, ftype, value) -> bytes:
         from lighthouse_tpu.types import registry as reg
 
         if isinstance(ftype, reg.ValidatorRegistryType):
@@ -311,7 +335,8 @@ class StateTreeCache:
         else:
             return ftype.hash_tree_root(value)
 
-        leaves = build(value)
+        with _stage("tree.leaves", "leaves"):
+            leaves = build(value)
         c = self.fields.get(fname)
         if c is None:
             c = self.fields[fname] = _FieldCache(leaves, limit, mixin)

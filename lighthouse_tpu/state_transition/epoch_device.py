@@ -24,8 +24,6 @@ was actually selected (fast tests stay zero-XLA).
 
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from lighthouse_tpu import types as T
@@ -187,35 +185,34 @@ def prepare_and_run(state, spec, fork: str, backend: str):
     n = len(state.validators)
     if n == 0 or cur == T.GENESIS_EPOCH:
         return None  # genesis epoch skips inactivity/rewards entirely
-    t0 = time.perf_counter()
-    leak = ep.is_in_inactivity_leak(state, spec)
-    tables = build_tables(state, spec, fork, leak=leak)
-    if tables is None:
-        return None
-    from lighthouse_tpu.ops import epoch_kernels as ek
+    with ep.epoch_stage_span("prep_host") as prep:
+        leak = ep.is_in_inactivity_leak(state, spec)
+        tables = build_tables(state, spec, fork, leak=leak)
+        if tables is None:
+            return None
+        from lighthouse_tpu.ops import epoch_kernels as ek
 
-    bucket = ek.bucket_size(n, bucket_floor())
-    columns = build_columns(state, spec, bucket)
-    params = build_params(state, spec, fork, leak=leak)
-    apply_eb = fork != "electra"
-    t1 = time.perf_counter()
-    ep.record_epoch_stage("prep_host", t1 - t0)
-    if backend == "sharded":
-        from lighthouse_tpu.parallel.epoch_sharded import epoch_pass_sharded
+        bucket = ek.bucket_size(n, bucket_floor())
+        columns = build_columns(state, spec, bucket)
+        params = build_params(state, spec, fork, leak=leak)
+        apply_eb = fork != "electra"
+    with ep.epoch_stage_span("dispatch") as dispatch:
+        if backend == "sharded":
+            from lighthouse_tpu.parallel.epoch_sharded import (
+                epoch_pass_sharded,
+            )
 
-        sc, bal, eff = epoch_pass_sharded(
-            columns, tables, params, apply_eb=apply_eb)
-    else:
-        sc, bal, eff = ek.epoch_pass_device(
-            columns, tables, params, apply_eb=apply_eb)
-    t2 = time.perf_counter()
-    ep.record_epoch_stage("dispatch", t2 - t1)
+            sc, bal, eff = epoch_pass_sharded(
+                columns, tables, params, apply_eb=apply_eb)
+        else:
+            sc, bal, eff = ek.epoch_pass_device(
+                columns, tables, params, apply_eb=apply_eb)
     # all-or-nothing apply (every fetch is done; nothing below can raise)
-    state.inactivity_scores = sc[:n].astype(np.uint64)
-    state.balances = bal[:n].astype(np.uint64)
-    deferred = eff[:n].astype(np.uint64) if apply_eb else None
-    ep.record_epoch_stage("apply", time.perf_counter() - t2)
+    with ep.epoch_stage_span("apply"):
+        state.inactivity_scores = sc[:n].astype(np.uint64)
+        state.balances = bal[:n].astype(np.uint64)
+        deferred = eff[:n].astype(np.uint64) if apply_eb else None
     return DeviceEpochOutcome(deferred, {
-        "prep_host_ms": (t1 - t0) * 1000,
-        "dispatch_ms": (t2 - t1) * 1000,
+        "prep_host_ms": prep.duration_ms(),
+        "dispatch_ms": dispatch.duration_ms(),
     })

@@ -39,11 +39,13 @@ from __future__ import annotations
 import sys
 import threading
 import time
+from functools import partial
 
 import numpy as np
 
 from lighthouse_tpu import types as T
 from lighthouse_tpu.common import env as envreg
+from lighthouse_tpu.common import tracing
 from lighthouse_tpu.common.metrics import REGISTRY, record_swallowed
 from lighthouse_tpu.ops.faults import PROGRAM_FAULTS as _PROGRAM_FAULTS
 from lighthouse_tpu.state_transition import misc
@@ -125,15 +127,24 @@ _AUTO_RUNG: str | None = None
 
 
 def record_epoch_stage(stage: str, seconds: float) -> None:
-    """Per-stage wall time of the device epoch pass (sole registration
-    site of the epoch_* metric family — lhlint LH501 FAMILY_OWNERS)."""
+    """Per-stage wall time of the epoch transition: the sub-transitions
+    of process_epoch and, inside the device pass, prep_host / dispatch /
+    apply (sole registration site of the epoch_* metric family — lhlint
+    LH501 FAMILY_OWNERS)."""
     try:
         REGISTRY.histogram(
             "epoch_stage_seconds",
-            "device epoch-pass stage wall time",
+            "epoch transition stage wall time",
         ).labels(stage=stage).observe(seconds)
     except Exception as e:
         record_swallowed("epoch.record_stage", e)
+
+
+def epoch_stage_span(stage: str, **attrs):
+    """The span ``epoch.<stage>``, whose duration also feeds
+    ``epoch_stage_seconds{stage}``."""
+    return tracing.span("epoch." + stage,
+                        observe=partial(record_epoch_stage, stage), **attrs)
 
 
 def record_epoch_fault(backend: str, kind: str) -> None:
@@ -258,12 +269,11 @@ def _maybe_device_epoch(state, spec: T.ChainSpec, fork: str):
     backend = resolve_epoch_backend(n)
     if backend == "reference":
         return None
-    from lighthouse_tpu.common import tracing
     from lighthouse_tpu.state_transition import epoch_device
 
-    t0 = time.perf_counter()
     try:
-        with tracing.span("epoch.device_pass", backend=backend, n=n):
+        with tracing.span("epoch.device_pass", backend=backend,
+                          n=n) as device_pass:
             out = epoch_device.prepare_and_run(state, spec, fork, backend)
     except _PROGRAM_FAULTS:
         # the device module failed to import or trace — a fault of the
@@ -276,7 +286,7 @@ def _maybe_device_epoch(state, spec: T.ChainSpec, fork: str):
     if out is None:
         return None
     _breaker_ok()
-    _record_epoch_batch(backend, time.perf_counter() - t0)
+    _record_epoch_batch(backend, device_pass.duration_s())
     return out
 
 
@@ -290,24 +300,27 @@ def process_epoch(state, spec: T.ChainSpec) -> None:
 
         process_epoch_phase0(state, spec)
         return
-    process_justification_and_finalization(state, spec)
+    with epoch_stage_span("justification"):
+        process_justification_and_finalization(state, spec)
     dev = _maybe_device_epoch(state, spec, fork)
     if dev is None:
-        t0 = time.perf_counter()
-        process_inactivity_updates(state, spec)
-        process_rewards_and_penalties(state, spec, fork)
-        core_s = time.perf_counter() - t0
-    process_registry_updates(state, spec, fork)
+        with epoch_stage_span("inactivity") as inactivity:
+            process_inactivity_updates(state, spec)
+        with epoch_stage_span("rewards") as rewards:
+            process_rewards_and_penalties(state, spec, fork)
+    with epoch_stage_span("registry_updates"):
+        process_registry_updates(state, spec, fork)
     if dev is None:
         # epoch_transition_seconds{backend=reference} spans exactly the
         # stages the device pass covers (inactivity, rewards/penalties,
         # slashings) — registry updates run on the host under EVERY
         # backend and are excluded, so the two series are comparable
-        t0 = time.perf_counter()
-        process_slashings(state, spec, fork)
-        _record_epoch_batch("reference",
-                            core_s + (time.perf_counter() - t0))
-    process_eth1_data_reset(state, spec)
+        with epoch_stage_span("slashings") as slashings:
+            process_slashings(state, spec, fork)
+        _record_epoch_batch("reference", sum(
+            sp.duration_s() for sp in (inactivity, rewards, slashings)))
+    with epoch_stage_span("resets"):
+        process_eth1_data_reset(state, spec)
     if fork == "electra":
         from lighthouse_tpu.state_transition.electra import (
             process_effective_balance_updates_electra,
@@ -315,20 +328,29 @@ def process_epoch(state, spec: T.ChainSpec) -> None:
             process_pending_consolidations,
         )
 
-        process_pending_balance_deposits(state, spec)
-        process_pending_consolidations(state, spec)
-        process_effective_balance_updates_electra(state, spec)
-    elif dev is not None and dev.deferred_eff is not None:
-        # the fused pass's hysteresis output, applied at the spec's
-        # effective-balance-update point (after registry updates)
-        state.validators.effective_balance = dev.deferred_eff
+        with epoch_stage_span("pending_balance"):
+            process_pending_balance_deposits(state, spec)
+            process_pending_consolidations(state, spec)
+        with epoch_stage_span("effective_balance"):
+            process_effective_balance_updates_electra(state, spec)
     else:
-        process_effective_balance_updates(state, spec)
-    process_slashings_reset(state, spec)
-    process_randao_mixes_reset(state, spec)
-    process_historical_update(state, spec, fork)
-    process_participation_flag_updates(state)
-    process_sync_committee_updates(state, spec)
+        with epoch_stage_span("effective_balance"):
+            if dev is not None and dev.deferred_eff is not None:
+                # the fused pass's hysteresis output, applied at the
+                # spec's effective-balance-update point (after registry
+                # updates)
+                state.validators.effective_balance = dev.deferred_eff
+            else:
+                process_effective_balance_updates(state, spec)
+    # the four cheap resets and the flag rotation, one stage with the
+    # eth1 reset above
+    with epoch_stage_span("resets"):
+        process_slashings_reset(state, spec)
+        process_randao_mixes_reset(state, spec)
+        process_historical_update(state, spec, fork)
+        process_participation_flag_updates(state)
+    with epoch_stage_span("sync_committee"):
+        process_sync_committee_updates(state, spec)
     # registry write-back hook: the epoch boundary is where the prior
     # epoch's deposits have settled into the registry — refresh the
     # device-resident pubkey table eagerly (all-or-nothing swap inside

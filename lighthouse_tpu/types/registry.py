@@ -456,21 +456,23 @@ class ValidatorRegistryType(SSZType):
         if n == 0:
             return np.zeros((0, 8), dtype=np.uint32)
         # pubkey (48B) root needs one pre-hash of its 2 chunks
-        pk = np.zeros((n, 64), dtype=np.uint8)
-        pk[:, :48] = value.pubkeys
-        pk_pairs = np.frombuffer(pk.tobytes(), dtype=">u4").astype(np.uint32).reshape(n, 16)
+        with sha_ops.merkle_stage_span("tree.leaves", "leaves", rows=n):
+            pk = np.zeros((n, 64), dtype=np.uint8)
+            pk[:, :48] = value.pubkeys
+            pk_pairs = np.frombuffer(pk.tobytes(), dtype=">u4").astype(np.uint32).reshape(n, 16)
         pk_roots = sha_ops.batch_hash_pairs(pk_pairs)
-        leaves = np.zeros((n, 8, 8), dtype=np.uint32)
-        leaves[:, 0] = pk_roots
-        leaves[:, 1] = _bytes_col_chunks(value.withdrawal_credentials, 32)
-        leaves[:, 2] = _u64_chunks(value.effective_balance)
-        leaves[:, 3] = _bytes_col_chunks(
-            value.slashed.astype(np.uint8).reshape(n, 1), 1
-        )
-        leaves[:, 4] = _u64_chunks(value.activation_eligibility_epoch)
-        leaves[:, 5] = _u64_chunks(value.activation_epoch)
-        leaves[:, 6] = _u64_chunks(value.exit_epoch)
-        leaves[:, 7] = _u64_chunks(value.withdrawable_epoch)
+        with sha_ops.merkle_stage_span("tree.leaves", "leaves", rows=n):
+            leaves = np.zeros((n, 8, 8), dtype=np.uint32)
+            leaves[:, 0] = pk_roots
+            leaves[:, 1] = _bytes_col_chunks(value.withdrawal_credentials, 32)
+            leaves[:, 2] = _u64_chunks(value.effective_balance)
+            leaves[:, 3] = _bytes_col_chunks(
+                value.slashed.astype(np.uint8).reshape(n, 1), 1
+            )
+            leaves[:, 4] = _u64_chunks(value.activation_eligibility_epoch)
+            leaves[:, 5] = _u64_chunks(value.activation_epoch)
+            leaves[:, 6] = _u64_chunks(value.exit_epoch)
+            leaves[:, 7] = _u64_chunks(value.withdrawable_epoch)
         return _batch_merkleize_subtrees(leaves)
 
     def hash_tree_root(self, value: Validators) -> bytes:

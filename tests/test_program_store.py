@@ -99,7 +99,7 @@ def store(tmp_path, monkeypatch):
         ps, "_serialize_compiled",
         lambda compiled: pickle.dumps(("fake-exe", compiled.tag)))
 
-    def fake_deserialize(data):
+    def fake_deserialize(data, entry="-"):
         kind, tag = pickle.loads(data)
         assert kind == "fake-exe"
         return FakeCompiled(tag)
@@ -288,7 +288,7 @@ def test_failing_loaded_program_evicted_to_jit_path(store, tmp_path,
     f = dtel.instrument("test::entry@f", FakeJit())
     f(Arr(4))
 
-    def deserialize_broken(data):
+    def deserialize_broken(data, entry="-"):
         return FakeCompiled("broken", fail_call=True)
 
     monkeypatch.setattr(ps, "_deserialize_payload", deserialize_broken)
@@ -426,7 +426,7 @@ def test_load_phase_quarantines_undeserializable_payload(store, tmp_path,
     f(Arr(4))
     st2 = restart(tmp_path)
 
-    def always_fails(data):
+    def always_fails(data, entry="-"):
         raise ValueError("runtime rejects this executable")
 
     monkeypatch.setattr(ps, "_deserialize_payload", always_fails)

@@ -1,0 +1,301 @@
+"""Stage spans inside the BLS seam, the tree cache and the epoch step.
+
+One small batch through ``verify_sets_pipeline`` and one ``state_advance``
+across an epoch boundary at the minimal preset: every stage span sits under
+the right parent, feeds its ``stage`` value, and the children of a request's
+pipeline/slot span cover their parent.  The counters that sit where the
+merkle work happens move by exactly the work done.  And every per-layer
+metric file this PR added to the benchmark names a reader that imports and
+reads a synthetic context.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+from lighthouse_tpu.common import tracing
+from lighthouse_tpu.common.metrics import REGISTRY
+from lighthouse_tpu.ops import sha256 as sha_ops
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+class _Roots:
+    """Sink: every finished root span, as a dict tree."""
+
+    def __init__(self):
+        self.roots = []
+
+    def _sink(self, root, _slot):
+        self.roots.append(root.to_dict())
+
+    def __enter__(self):
+        tracing.TRACER.add_sink(self._sink)
+        return self
+
+    def __exit__(self, *exc):
+        tracing.TRACER.remove_sink(self._sink)
+
+    def parents(self):
+        """{span name: set of the names it appeared under (None = root)}."""
+        out = {}
+
+        def walk(d, parent):
+            out.setdefault(d["name"], set()).add(parent)
+            for child in d.get("children", ()):
+                walk(child, d["name"])
+
+        for root in self.roots:
+            walk(root, None)
+        return out
+
+    def find(self, name):
+        def walk(d):
+            if d["name"] == name:
+                yield d
+            for child in d.get("children", ()):
+                yield from walk(child)
+
+        return [d for root in self.roots for d in walk(root)]
+
+
+def _closure(span_dict):
+    """Share of a span's duration that its direct children cover."""
+    return (sum(c["duration_ms"] for c in span_dict.get("children", ()))
+            / span_dict["duration_ms"])
+
+
+def _stage_values(family):
+    return {line.split('stage="')[1].split('"')[0]
+            for line in REGISTRY.render().splitlines()
+            if line.startswith(family + "_count{")}
+
+
+# -- the BLS seam --------------------------------------------------------------
+
+BLS_PARENTS = {
+    "bls.subgroup": "bls.verify_pipeline",
+    "bls.aggregate": "bls.verify_pipeline",
+    "bls.prep_host": "bls.verify_pipeline",
+    "bls.limbs": "bls.verify_pipeline",
+    "bls.pipeline.dispatch": "bls.verify_pipeline",
+    "bls.final_exp": "bls.verify_pipeline",
+    "bls.aggregate.layout": "bls.aggregate",
+    "bls.aggregate.dispatch": "bls.aggregate",
+    "bls.aggregate.fetch": "bls.aggregate",
+    "bls.subgroup.wait": "bls.final_exp",
+    "bls.pipeline.wait": "bls.final_exp",
+    "bls.final_exp.host": "bls.final_exp",
+}
+BLS_STAGES_KEPT = {"subgroup", "aggregate", "prep_host", "limbs", "pipeline",
+                   "final_exp"}
+BLS_STAGES_NEW = {"aggregate_layout", "aggregate_dispatch", "aggregate_fetch",
+                  "subgroup_wait", "pipeline_wait", "final_exp_host"}
+
+
+def test_bls_pipeline_stage_spans():
+    """The shapes of test_device_pairing's aggregation batch (3 sets of
+    8, 11 and 7 keys, one message): the fold and the fused program are in
+    the compile cache of any tree that ran that test."""
+    from lighthouse_tpu.crypto import bls
+    from lighthouse_tpu.ops.bls_backend import verify_sets_pipeline
+
+    sks = [bls.SecretKey.from_bytes(int(500 + i).to_bytes(32, "big"))
+           for i in range(12)]
+    pks = [sk.public_key() for sk in sks]
+    msg = b"\x33" * 32
+    sets = []
+    for lo, hi in ((0, 8), (1, 12), (2, 9)):
+        sig = bls.Signature.aggregate([sks[k].sign(msg) for k in range(lo, hi)])
+        sets.append(bls.SignatureSet(bls.Signature(sig.to_bytes()),
+                                     pks[lo:hi], msg))
+    with _Roots() as sink:
+        assert verify_sets_pipeline(sets)
+    parents = sink.parents()
+    assert parents["bls.verify_pipeline"] == {None}
+    for name, parent in BLS_PARENTS.items():
+        assert parents.get(name) == {parent}, name
+    (pipeline,) = sink.find("bls.verify_pipeline")
+    assert "profiled" not in pipeline["attrs"]
+    (aggregate,) = sink.find("bls.aggregate")
+    assert aggregate["attrs"]["slices"] == 1
+    assert aggregate["attrs"]["lanes"] == 2 * 16 * 4   # seg x n_pad
+    stages = _stage_values("bls_verify_stage_seconds")
+    assert BLS_STAGES_KEPT <= stages and BLS_STAGES_NEW <= stages
+    # closure: the stages are the request, and the parts are their stage
+    assert _closure(pipeline) >= 0.90
+    assert _closure(aggregate) >= 0.90
+    assert _closure(sink.find("bls.final_exp")[0]) >= 0.90
+
+
+def test_verify_sets_pipeline_has_no_ledger_mode():
+    import inspect
+
+    from lighthouse_tpu.ops import bls_backend
+
+    for fn in (bls_backend.verify_sets_pipeline,
+               bls_backend._verify_sets_pipeline):
+        assert "ledger" not in inspect.signature(fn).parameters
+    assert not os.path.exists(os.path.join(ROOT, "tools", "bls_ledger.py"))
+
+
+# -- the state plane -----------------------------------------------------------
+
+STATE_PARENTS = {
+    "state.root": {"state.slot"},
+    "epoch.transition": {"state.slot"},
+    "tree.field": {"state.root"},
+    "tree.leaves": {"tree.field", "tree.validators.element_roots"},
+    "tree.diff": {"tree.field"},
+    "tree.update": {"tree.field"},
+    "tree.validators.diff": {"tree.field"},
+    "tree.validators.slice": {"tree.field"},
+    "tree.validators.element_roots": {"tree.field"},
+    "tree.validators.snapshot": {"tree.field"},
+    "tree.level.gather": {"tree.update"},
+    "tree.level.scatter": {"tree.update"},
+    # (a field with no cache of its own merkleizes whole, under tree.field;
+    # the container's own few chunks fold under state.root)
+    **{name: {"tree.update", "tree.validators.element_roots", "tree.field",
+              "state.root"}
+       for name in ("sha.pad", "sha.h2d", "sha.execute", "sha.d2h",
+                    "sha.host")},
+    "epoch.justification": {"epoch.transition"},
+    "epoch.registry_updates": {"epoch.transition"},
+    "epoch.effective_balance": {"epoch.transition"},
+    "epoch.resets": {"epoch.transition"},
+    "epoch.sync_committee": {"epoch.transition"},
+}
+RUNG_PARENTS = {
+    "reference": {
+        "epoch.inactivity": {"epoch.transition"},
+        "epoch.rewards": {"epoch.transition"},
+        "epoch.slashings": {"epoch.transition"},
+    },
+    "device": {
+        "epoch.device_pass": {"epoch.transition"},
+        "epoch.prep_host": {"epoch.device_pass"},
+        "epoch.dispatch": {"epoch.device_pass"},
+        "epoch.apply": {"epoch.device_pass"},
+    },
+}
+MERKLE_STAGES = {"leaves", "diff", "slice", "snapshot", "gather", "scatter",
+                 "pad", "h2d", "execute", "d2h", "hash_host"}
+EPOCH_STAGES = {"justification", "registry_updates", "effective_balance",
+                "resets", "sync_committee"}
+
+
+def _fake_epoch_pass(columns, tables, params, apply_eb):
+    """The fused epoch program's interface without its compile: scores and
+    balances pass through, effective balances from their increments."""
+    return (columns["scores"], columns["balances"],
+            columns["eff_incr"].astype(np.int64) * 10**9)
+
+
+@pytest.mark.parametrize("rung", ["reference", "device"])
+def test_state_advance_stage_spans(monkeypatch, rung):
+    from lighthouse_tpu.ops import epoch_kernels
+    from lighthouse_tpu.state_transition import epoch_processing as ep
+    from lighthouse_tpu.state_transition import state_advance
+    from lighthouse_tpu.testing import Harness
+
+    monkeypatch.setenv("LHTPU_EPOCH_BACKEND", rung)
+    monkeypatch.setattr(epoch_kernels, "epoch_pass_device", _fake_epoch_pass)
+    # levels of 16 pairs and more take the device path (a handful of small
+    # shapes to compile), the ones under it the host's
+    monkeypatch.setattr(sha_ops, "_DEVICE_MIN_PAIRS", 16)
+    h = Harness(n_validators=64, fork="altair", real_crypto=False)
+    spe = h.spec.preset.slots_per_epoch
+    state_advance(h.state, h.spec, 2 * spe - 1)   # cache warm, epoch 1
+    # what an epoch of blocks leaves behind: every record and balance dirty
+    h.state.validators.effective_balance[:] -= np.uint64(10**9)
+    h.state.balances[:] += np.uint64(12345)
+    try:
+        with _Roots() as sink:
+            state_advance(h.state, h.spec, 2 * spe + 1)
+    finally:
+        ep.reset_epoch_supervisor()
+    parents = sink.parents()
+    assert parents["state.slot"] == {None}
+    for name, under in {**STATE_PARENTS, **RUNG_PARENTS[rung]}.items():
+        assert parents.get(name) and parents[name] <= under, (name, parents.get(name))
+    other = "device" if rung == "reference" else "reference"
+    assert not set(RUNG_PARENTS[other]) & set(parents)
+    first, second = sink.find("state.slot")
+    assert first["attrs"]["slot"] == 2 * spe - 1
+    assert [c["name"] for c in first["children"]] == ["state.root",
+                                                      "epoch.transition"]
+    assert [c["name"] for c in second["children"]] == ["state.root"]
+    (update,) = [u for u in sink.find("tree.update")
+                 if u["attrs"]["dirty"] == 64]
+    assert update["attrs"]["levels"] == 6
+    assert {f["attrs"]["field"] for f in sink.find("tree.field")} >= {
+        "validators", "balances", "slot"}
+    assert MERKLE_STAGES <= _stage_values("merkle_stage_seconds")
+    assert EPOCH_STAGES | {s.split(".")[1] for s in RUNG_PARENTS[rung]
+                           if s != "epoch.device_pass"} <= _stage_values(
+        "epoch_stage_seconds")
+    assert "state_root_seconds_count" in REGISTRY.render()
+    # closure: the two children are the slot
+    assert _closure(first) >= 0.90 and _closure(second) >= 0.90
+
+
+# -- counters where the merkle work happens ------------------------------------
+
+def _counter(name, **labels):
+    want = ",".join(f'{k}="{v}"' for k, v in labels.items())
+    for line in REGISTRY.render().splitlines():
+        if line.startswith(f"{name}{{{want}}}"):
+            return float(line.rsplit(" ", 1)[1])
+    return 0.0
+
+
+@pytest.mark.parametrize("min_pairs, path", [(1, "levels_device"),
+                                             (1 << 30, "levels_host")])
+def test_tree_update_counts_its_chunks_and_lanes(monkeypatch, min_pairs, path):
+    from lighthouse_tpu.ssz.tree_cache import IncrementalTree
+
+    rng = np.random.default_rng(5)
+    leaves = rng.integers(0, 2**32, (64, 8), dtype=np.uint32)
+    tree = IncrementalTree(leaves, 64)
+    monkeypatch.setattr(sha_ops, "_DEVICE_MIN_PAIRS", min_pairs)
+    new = leaves.copy()
+    dirty = np.array([0, 1, 2, 9, 40, 41, 42, 43, 44, 63])
+    new[dirty] ^= np.uint32(1)
+    # pairs per level: the distinct parents of the dirty leaves, level by level
+    pairs, idx = [], dirty
+    for _ in range(6):
+        idx = np.unique(idx >> 1)
+        pairs.append(len(idx))
+    assert pairs == [7, 5, 4, 3, 2, 1]
+    padded = [1 << max(p - 1, 0).bit_length() for p in pairs]
+    before = {p: _counter("sha256_merkle_chunks_total", path=p)
+              for p in ("levels_device", "levels_host")}
+    lanes = {k: _counter("sha256_device_lanes_total", kind=k)
+             for k in ("live", "padding")}
+    tree.update(new)
+    other = "levels_host" if path == "levels_device" else "levels_device"
+    assert (_counter("sha256_merkle_chunks_total", path=path) - before[path]
+            == 2 * sum(pairs))
+    assert _counter("sha256_merkle_chunks_total", path=other) == before[other]
+    on_device = path == "levels_device"
+    assert (_counter("sha256_device_lanes_total", kind="live") - lanes["live"]
+            == (sum(pairs) if on_device else 0))
+    assert (_counter("sha256_device_lanes_total", kind="padding")
+            - lanes["padding"]
+            == (sum(padded) - sum(pairs) if on_device else 0))
+    # and the root is the plain one
+    fresh = IncrementalTree(new, 64)
+    assert tree.root() == fresh.root()
+
+
+# -- the benchmark's new per-layer metrics and host_gaps.py's attribution ------
+# (the benchmark's own tests, mirrored here so that tier-1 sees them)
+
+from benchmarks.tests.test_stage_metrics import (  # noqa: E402,F401
+    test_every_per_layer_metric_of_benchmark_json_resolves,
+    test_idle_gaps_go_to_the_innermost_span_open,
+    test_merkle_pad_waste_reads_a_synthetic_window,
+    test_new_histogram_metric_reads_a_synthetic_window,
+)

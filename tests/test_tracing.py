@@ -2,8 +2,13 @@
 
 import asyncio
 import json
+import subprocess
+import sys
 import threading
 
+import pytest
+
+from lighthouse_tpu.common import tracing
 from lighthouse_tpu.common.tracing import (
     UNSLOTTED,
     Tracer,
@@ -192,3 +197,119 @@ class TestTimelineJson:
         assert root["attrs"]["n"] == 3
         assert root["wall_start"] > 0
         assert json.loads(t.to_json(999)) == {"slot": 999, "spans": []}
+
+
+class TestObserve:
+    def test_observe_called_once_with_the_duration(self):
+        t = Tracer()
+        seen = []
+        with t.span("stage", observe=seen.append) as sp:
+            pass
+        assert seen == [sp.duration_s()]
+        assert seen[0] == sp.end - sp.start >= 0.0
+
+    def test_observe_called_when_the_body_raises(self):
+        t = Tracer()
+        seen = []
+        with pytest.raises(ValueError):
+            with t.span("stage", observe=seen.append):
+                raise ValueError("boom")
+        assert len(seen) == 1 and seen[0] >= 0.0
+
+    def test_observe_rides_the_decorator_and_is_no_attr(self):
+        t = Tracer()
+        seen = []
+
+        @t.span("decorated", observe=seen.append)
+        def f():
+            return current_span().attrs
+
+        assert f() == {} and f() == {}
+        assert len(seen) == 2
+
+    def test_a_broken_observer_does_not_break_the_span(self):
+        t = Tracer()
+
+        def broken(seconds):
+            raise RuntimeError("observer")
+
+        with t.span("stage", observe=broken):
+            pass
+        assert t.timeline(UNSLOTTED)["spans"][0]["name"] == "stage"
+
+
+class _Recorder:
+    """An annotator factory that records enter/exit in order."""
+
+    def __init__(self):
+        self.events = []
+
+    def __call__(self, name, **attrs):
+        rec = self
+
+        class _Ctx:
+            def __enter__(self):
+                rec.events.append(("enter", name, attrs))
+
+            def __exit__(self, *exc):
+                rec.events.append(("exit", name, attrs))
+
+        return _Ctx()
+
+
+@pytest.fixture
+def annotator():
+    before = tracing._annotator
+    rec = _Recorder()
+    tracing.set_annotator(rec)
+    yield rec
+    tracing.set_annotator(before)
+
+
+class TestAnnotator:
+    def test_entered_and_exited_in_order_with_the_spans_name(self, annotator):
+        t = Tracer()
+        with t.span("outer", slot=3, n=2, blob=b"\x00", label="x"):
+            with t.span("inner"):
+                pass
+        # scalar attrs only reach the annotator (bytes stay in the tracer)
+        outer = {"slot": 3, "n": 2, "label": "x"}
+        assert annotator.events == [
+            ("enter", "outer", outer), ("enter", "inner", {}),
+            ("exit", "inner", {}), ("exit", "outer", outer)]
+
+    def test_exited_when_the_body_raises(self, annotator):
+        with pytest.raises(KeyError):
+            with Tracer().span("stage"):
+                raise KeyError("k")
+        assert [e[0] for e in annotator.events] == ["enter", "exit"]
+
+    def test_never_called_when_unset(self, annotator):
+        tracing.set_annotator(None)
+        with Tracer().span("stage"):
+            pass
+        assert annotator.events == []
+
+    def test_a_broken_annotator_does_not_break_the_span(self):
+        before = tracing._annotator
+
+        def broken(name, **attrs):
+            raise RuntimeError("annotator")
+
+        tracing.set_annotator(broken)
+        try:
+            t = Tracer()
+            with t.span("stage"):
+                pass
+            assert t.timeline(UNSLOTTED)["spans"][0]["name"] == "stage"
+        finally:
+            tracing.set_annotator(before)
+
+
+def test_tracing_imports_no_jax():
+    """Spans cost a host-only process no jax import: the annotator is
+    installed by the data plane (compile_cache.configure), never here."""
+    code = ("import sys; from lighthouse_tpu.common import tracing; "
+            "assert tracing._annotator is None; "
+            "sys.exit(1 if 'jax' in sys.modules else 0)")
+    assert subprocess.run([sys.executable, "-c", code]).returncode == 0

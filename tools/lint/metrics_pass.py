@@ -57,6 +57,11 @@ FAMILY_OWNERS = {
     # device epoch pass: the backend seam owns the family; epoch_device /
     # phase0_epoch / shuffle record through its helpers
     "epoch_": "lighthouse_tpu/state_transition/epoch_processing.py",
+    # the stage spans of the state plane (PR 26): the tree cache, the
+    # registry's element roots and the hashers all record through
+    # sha256.record_merkle_stage; the slot's state root has one writer
+    "merkle_stage_": "lighthouse_tpu/ops/sha256.py",
+    "state_root_": "lighthouse_tpu/state_transition/slot_processing.py",
     # the observatory plane (PR 11): each subsystem owns its families —
     # flight events/trips, manifest-keyed jit telemetry + the cold-start
     # headline, SLO scoring, invariant breaches, and the shared
