@@ -1470,7 +1470,7 @@ def _bench_observatory() -> dict:
        observatory disarmed/armed (flight recorder + slow-span capture
        + SLO scoring + invariant sweeper); armed throughput must hold
        >= 95% of unarmed.
-    2. **manifest telemetry tour** — dispatch every one of the 20
+    2. **manifest telemetry tour** — dispatch every one of the 21
        shape-manifest jit entry points at tiny shapes; every entry must
        report compile/dispatch telemetry, and the BLS verifies record
        time_to_first_verify_seconds per backend (reference + tpu).
@@ -1561,6 +1561,7 @@ def _bench_observatory() -> dict:
     from lighthouse_tpu.state_transition import epoch_processing as ep
     from lighthouse_tpu.state_transition import shuffle as shuffle_mod
     from lighthouse_tpu.testing import randomized_registry_state
+    from lighthouse_tpu.types.registry import Validators
     import hashlib
 
     import jax.numpy as jnp
@@ -1611,6 +1612,7 @@ def _bench_observatory() -> dict:
                              jnp.zeros((1, 16), jnp.uint32)),
         sha_ops.hash_pairs_device(jnp.zeros((2, 16), jnp.uint32)),
         sha_ops._fold_levels_device(jnp.zeros((4, 8), jnp.uint32)),
+        sha_ops.validator_roots(Validators(2).columns()),
         sha_ops._fold_to_root_jit(jnp.zeros((4, 8), jnp.uint32))))
 
     def fr_tour():
@@ -2042,7 +2044,7 @@ def _bench_coldstart() -> dict:
     a second fresh interpreter against the now-populated store (warm:
     every entry deserializes straight into the dispatch memo).  Gates:
     warm ``time_to_first_verify_seconds{tpu}`` >= 5x lower than cold,
-    all 20 manifest entries served as ``store_hit`` on the warm run,
+    all 21 manifest entries served as ``store_hit`` on the warm run,
     zero store failures beyond accounted misses, and the sha256
     calibration loaded from the store instead of re-measured."""
     import shutil

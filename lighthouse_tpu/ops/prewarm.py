@@ -234,11 +234,13 @@ def _drv_msm(scale: str) -> None:
 
 def _drv_sha256(scale: str) -> None:
     """The merkle hashers at their serving buckets: the pair hash, the
-    single-block message sweep, and both whole-fold programs."""
+    single-block message sweep, both whole-fold programs and the
+    validator element roots."""
     import jax.numpy as jnp
     import numpy as np
 
     from lighthouse_tpu.ops import sha256 as sha_ops
+    from lighthouse_tpu.types.registry import Validators
 
     if scale == "production":
         pairs = min(max(sha_ops._DEVICE_MIN_PAIRS, 2048), 1 << 15)
@@ -250,6 +252,8 @@ def _drv_sha256(scale: str) -> None:
     sha_ops.hash_pairs_device(jnp.zeros((pairs, 16), jnp.uint32))
     sha_ops._fold_levels_device(jnp.zeros((leaves, 8), jnp.uint32))
     sha_ops._fold_to_root_jit(jnp.zeros((leaves, 8), jnp.uint32))
+    # the registry's element roots at the row count their routing starts at
+    sha_ops.validator_roots(Validators(pairs).columns())
     # host-path sanity so a mis-prewarmed program can never serve: the
     # device fold of a known tree must match hashlib
     probe = np.arange(4 * 8, dtype=np.uint32).reshape(4, 8)

@@ -45,6 +45,9 @@ _pstore.register_entry("ops/sha256.py::hash_pairs_device@hash_pairs_device",
 _pstore.register_entry(
     "ops/sha256.py::_fold_levels_device@_fold_levels_device",
     driver="sha256")
+_pstore.register_entry(
+    "ops/sha256.py::validator_roots_device@validator_roots_device",
+    driver="sha256")
 _pstore.register_entry("ops/sha256.py::<module>@<lambda>", driver="sha256")
 
 # shapes whose whole-fold device program has already been dispatched in
@@ -244,6 +247,59 @@ _fold_levels_device = _dtel.instrument(
     _fold_levels_device)
 
 
+def _be_words(x: jax.Array) -> jax.Array:
+    """Little-endian uint32 views of a byte column -> SHA-256's
+    big-endian words (a byte swap)."""
+    return ((x << np.uint32(24)) | ((x & np.uint32(0xFF00)) << np.uint32(8))
+            | ((x >> np.uint32(8)) & np.uint32(0xFF00)) | (x >> np.uint32(24)))
+
+
+@jax.jit
+def validator_roots_device(pubkeys, credentials, effective_balance, slashed,
+                           eligibility_epoch, activation_epoch, exit_epoch,
+                           withdrawable_epoch) -> jax.Array:
+    """``hash_tree_root`` of N ``Validator`` records from the registry's
+    columns, in ONE device program: the SSZ chunk packing, the pubkey
+    pre-hash and the three subtree levels 8 -> 4 -> 2 -> 1.
+
+    The columns arrive as the host's bytes, viewed as little-endian
+    uint32: pubkeys uint32[N, 12], credentials uint32[N, 8], each uint64
+    column uint32[N, 2] (low word first), slashed uint8[N].  -> uint32[N, 8].
+    Every level is ``hash_pairs_device``'s compression: the hashes are the
+    ones ``_batch_merkleize_subtrees`` computes from ``leaves[N, 8, 8]``,
+    which here never exists on the host.  A level's lanes run pair by pair
+    over all records, not record by record (rows [k N, (k+1) N) hold pair k
+    of every record), so the next level is made of whole slices of the last
+    and no level shuffles lanes.
+    """
+    n = pubkeys.shape[0]
+    zeros = jnp.zeros((n, 8), jnp.uint32)
+
+    def uint64_chunk(col):
+        return jnp.concatenate([_be_words(col), zeros[:, :6]], axis=1)
+
+    pubkey_root = hash_pairs_device(jnp.concatenate(
+        [_be_words(pubkeys), zeros[:, :4]], axis=1))
+    slashed_chunk = jnp.concatenate(
+        [slashed.astype(jnp.uint32)[:, None] << np.uint32(24), zeros[:, :7]],
+        axis=1)
+    level = [pubkey_root, _be_words(credentials),
+             uint64_chunk(effective_balance), slashed_chunk,
+             uint64_chunk(eligibility_epoch), uint64_chunk(activation_epoch),
+             uint64_chunk(exit_epoch), uint64_chunk(withdrawable_epoch)]
+    while len(level) > 1:
+        hashed = hash_pairs_device(jnp.concatenate(
+            [jnp.concatenate(level[k: k + 2], axis=1)
+             for k in range(0, len(level), 2)], axis=0))
+        level = jnp.split(hashed, len(level) // 2, axis=0)
+    return level[0]
+
+
+validator_roots_device = _dtel.instrument(
+    "ops/sha256.py::validator_roots_device@validator_roots_device",
+    validator_roots_device)
+
+
 def fold_levels(leaves: np.ndarray, *, device: bool | None = None) -> list[np.ndarray]:
     """Build every interior level of a power-of-two-leaf merkle tree.
 
@@ -427,6 +483,15 @@ def batch_hash_pairs(pairs: np.ndarray, *, device: bool | None = None) -> np.nda
     return _hash_level(pairs, device=use_device)
 
 
+def _count_device_lanes(live: int, padding: int) -> None:
+    lanes = REGISTRY.counter(
+        "sha256_device_lanes_total",
+        "lanes of hash_pairs_device's compression dispatched: live pairs, "
+        "and the padding up to the power-of-two program shape")
+    lanes.labels(kind="live").inc(live)
+    lanes.labels(kind="padding").inc(padding)
+
+
 def _hash_level(pairs: np.ndarray, *, device: bool | None = None) -> np.ndarray:
     use_device = device if device is not None else pairs.shape[0] >= _DEVICE_MIN_PAIRS
     n = pairs.shape[0]
@@ -437,12 +502,7 @@ def _hash_level(pairs: np.ndarray, *, device: bool | None = None) -> np.ndarray:
     # bounded at ~log2(max_pairs) programs shared by every tree size
     # (padded lanes hash garbage and are discarded).
     padded = 1 << max(n - 1, 0).bit_length()
-    lanes = REGISTRY.counter(
-        "sha256_device_lanes_total",
-        "lanes of hash_pairs_device dispatches: live pairs, and the "
-        "padding up to the power-of-two program shape")
-    lanes.labels(kind="live").inc(n)
-    lanes.labels(kind="padding").inc(padded - n)
+    _count_device_lanes(n, padded - n)
     with merkle_stage_span("sha.pad", "pad", pairs=n, lanes=padded):
         if padded != n:
             pairs = np.concatenate(
@@ -457,6 +517,48 @@ def _hash_level(pairs: np.ndarray, *, device: bool | None = None) -> np.ndarray:
         hashed = hash_pairs_device(operand)
     with merkle_stage_span("sha.d2h", "d2h", pairs=n, lanes=padded):
         return np.asarray(hashed)[:n]
+
+
+def _stage_column(col: np.ndarray, rows: int) -> np.ndarray:
+    """One registry column at the program's bucket size, as the view of its
+    bytes ``validator_roots_device`` takes.  The rows past the column's own
+    are left as allocated: they hash garbage that the caller slices off."""
+    dtype = col.dtype.newbyteorder("<")
+    if col.shape[0] == rows:
+        staged = np.ascontiguousarray(col, dtype=dtype)
+    else:
+        staged = np.empty((rows,) + col.shape[1:], dtype)
+        staged[: col.shape[0]] = col
+    if staged.dtype.itemsize == 1 and staged.ndim == 1:
+        return staged.view(np.uint8)
+    return staged.view("<u4").reshape(rows, -1)
+
+
+def validator_roots(columns) -> np.ndarray:
+    """Element roots of n validator records, uint32[n, 8], through ONE
+    dispatch of ``validator_roots_device``: only the raw columns
+    (``validator_roots_device``'s arguments, n rows each) go up and only the
+    roots come back.  The rows are padded to a power of two, as
+    ``_hash_level`` pads its lanes (one program per bucket); the counters
+    move by what the four levels of the per-level path would count — 16
+    chunks and 8 lanes a record."""
+    n = columns[0].shape[0]
+    padded = 1 << max(n - 1, 0).bit_length()
+    REGISTRY.counter(
+        "sha256_merkle_chunks_total",
+        "leaf chunks merkleized, by fold path").labels(
+        path="levels_device").inc(16 * n)
+    _count_device_lanes(8 * n, 8 * (padded - n))
+    with merkle_stage_span("sha.pad", "pad", rows=n, lanes=8 * padded):
+        staged = [_stage_column(col, padded) for col in columns]
+    # the same three pieces of host code as _hash_level's device branch
+    with merkle_stage_span("sha.h2d", "h2d", rows=n, lanes=8 * padded):
+        operands = [jnp.asarray(col) for col in staged]
+    with merkle_stage_span("sha.execute", "execute", rows=n,
+                           lanes=8 * padded):
+        roots = validator_roots_device(*operands)
+    with merkle_stage_span("sha.d2h", "d2h", rows=n, lanes=8 * padded):
+        return np.asarray(roots)[:n]
 
 
 # whole-fold one-dispatch threshold: pow2 leaf counts keep the jit

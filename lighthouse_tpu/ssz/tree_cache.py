@@ -256,8 +256,9 @@ class ValidatorsCache:
                 with _stage("tree.validators.slice", "slice",
                             rows=int(rows.size)):
                     sub = _slice_validators(validators, rows)
-                # a parent only: its time is its children's (tree.leaves
-                # and the sha.* stages of the four element-root levels)
+                # a parent only: its time is its children's (the sha.*
+                # stages of the one fused call; under the device-routing
+                # row count tree.leaves and the four levels' sha.* stages)
                 with tracing.span("tree.validators.element_roots",
                                   rows=int(rows.size)):
                     new_roots = typ.batch_roots(sub)

@@ -21,6 +21,7 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from lighthouse_tpu.common.metrics import REGISTRY
 from lighthouse_tpu.ops import sha256 as sha_ops
 from lighthouse_tpu.ssz import core as ssz_core
 from lighthouse_tpu.ssz.core import SSZType, _batch_merkleize_subtrees
@@ -300,6 +301,11 @@ class Validators:
     def __len__(self) -> int:
         return self._n
 
+    def columns(self) -> list[np.ndarray]:
+        """The column views in ``_COLUMNS`` order — SSZ field order, the
+        argument order of ``sha256.validator_roots``."""
+        return [getattr(self, c) for c in self._COLUMNS]
+
     def _grow_to(self, cap: int) -> None:
         for c in self._COLUMNS:
             backing = getattr(self, "_" + c)
@@ -451,10 +457,21 @@ class ValidatorRegistryType(SSZType):
         return self.limit
 
     def batch_roots(self, value: Validators) -> np.ndarray:
-        """All validator roots as one lockstep device merkleization."""
+        """All validator roots as one lockstep merkleization: from the
+        device-routing row count up, one device program fed the raw columns
+        (``sha256.validator_roots``); under it, chunk words built on the
+        host and hashed level by level."""
         n = len(value)
         if n == 0:
             return np.zeros((0, 8), dtype=np.uint32)
+        fused = n >= sha_ops._DEVICE_MIN_PAIRS
+        REGISTRY.counter(
+            "validator_roots_total",
+            "validator element roots computed, by path: fused = one device "
+            "program from the columns, host = chunk words built on the host",
+        ).labels(path="fused" if fused else "host").inc(n)
+        if fused:
+            return sha_ops.validator_roots(value.columns())
         # pubkey (48B) root needs one pre-hash of its 2 chunks
         with sha_ops.merkle_stage_span("tree.leaves", "leaves", rows=n):
             pk = np.zeros((n, 64), dtype=np.uint8)

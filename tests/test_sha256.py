@@ -118,3 +118,120 @@ class TestDeviceThresholdCalibration:
                 s.batch_hash_pairs(pairs), _ref_hash_pairs(pairs))
         finally:
             s._DEVICE_MIN_PAIRS, s._CALIBRATED = saved
+
+
+# -- validator element roots: one device program from the registry's columns ---
+
+def _registry(n, seed):
+    """Random columns: far-future epochs mixed in, slashed both ways, the
+    first pubkey all zeros and the last all ones."""
+    from lighthouse_tpu.types.registry import Validators
+    from lighthouse_tpu.types.spec import FAR_FUTURE_EPOCH
+
+    rng = np.random.default_rng(seed)
+    v = Validators(n)
+    v.pubkeys[...] = rng.integers(0, 256, (n, 48), dtype=np.uint8)
+    v.pubkeys[0] = 0
+    v.pubkeys[-1] = 0xFF
+    v.withdrawal_credentials[...] = rng.integers(0, 256, (n, 32), np.uint8)
+    v.effective_balance[...] = rng.integers(0, 2**63, n).astype(np.uint64)
+    v.slashed[...] = rng.random(n) < 0.5
+    v.slashed[0], v.slashed[-1] = n % 2 == 0, n % 2 == 1
+    for c in ("activation_eligibility_epoch", "activation_epoch",
+              "exit_epoch", "withdrawable_epoch"):
+        getattr(v, c)[...] = np.where(
+            rng.random(n) < 0.3, np.uint64(FAR_FUTURE_EPOCH),
+            rng.integers(0, 2**40, n).astype(np.uint64))
+    return v
+
+
+def _record_chunks(v, i):
+    def u64(x):
+        return int(x).to_bytes(32, "little")
+
+    pk = bytes(v.pubkeys[i])
+    return [hashlib.sha256(pk.ljust(64, b"\x00")).digest(),
+            bytes(v.withdrawal_credentials[i]), u64(v.effective_balance[i]),
+            u64(v.slashed[i]), u64(v.activation_eligibility_epoch[i]),
+            u64(v.activation_epoch[i]), u64(v.exit_epoch[i]),
+            u64(v.withdrawable_epoch[i])]
+
+
+def _counter(name, **labels):
+    from lighthouse_tpu.common.metrics import REGISTRY
+
+    want = ",".join(f'{k}="{v}"' for k, v in labels.items())
+    for line in REGISTRY.render().splitlines():
+        if line.startswith(f"{name}{{{want}}}"):
+            return float(line.rsplit(" ", 1)[1])
+    return 0.0
+
+
+@pytest.mark.parametrize("n", [1, 2047, 2048, 2049, 5000])
+def test_validator_roots_fused_matches_host_path_and_hashlib(monkeypatch, n):
+    """Both sides of the routing threshold, a bucket's last row (2048 of
+    2048) and first (2049 of 4096): the fused program, whatever
+    ``batch_roots`` routes to, the host's chunk words through
+    ``_batch_merkleize_subtrees`` and hashlib per record all agree."""
+    from lighthouse_tpu.ssz.core import _batch_merkleize_subtrees
+    from lighthouse_tpu.types.registry import ValidatorRegistryType
+
+    assert s._DEVICE_MIN_PAIRS == 2048
+    v = _registry(n, seed=n)
+    fused = s.validator_roots(v.columns())
+    assert fused.shape == (n, 8) and fused.dtype == np.uint32
+    want = b"".join(_naive_merkleize(_record_chunks(v, i)) for i in range(n))
+    assert s.words_to_bytes(fused) == want
+    leaves = np.stack([s.chunks_to_words(b"".join(_record_chunks(v, i)))
+                       for i in range(n)])
+    with monkeypatch.context() as host_only:
+        host_only.setattr(s, "_DEVICE_MIN_PAIRS", 1 << 30)
+        np.testing.assert_array_equal(
+            fused, _batch_merkleize_subtrees(leaves))
+    before = {p: _counter("validator_roots_total", path=p)
+              for p in ("fused", "host")}
+    routed = ValidatorRegistryType(2**40).batch_roots(v)
+    np.testing.assert_array_equal(routed, fused)
+    path = "fused" if n >= 2048 else "host"
+    other = "host" if path == "fused" else "fused"
+    assert _counter("validator_roots_total", path=path) - before[path] == n
+    assert _counter("validator_roots_total", path=other) == before[other]
+
+
+def test_validator_roots_fused_counts_chunks_lanes_and_rows():
+    """16 chunks and 8 lanes a record, as the four per-level calls count
+    them; the padding is the bucket's."""
+    from lighthouse_tpu.types.registry import ValidatorRegistryType
+
+    n, bucket = 2049, 4096
+    v = _registry(n, seed=3)
+    names = [("sha256_merkle_chunks_total", {"path": "levels_device"}),
+             ("sha256_merkle_chunks_total", {"path": "levels_host"}),
+             ("sha256_device_lanes_total", {"kind": "live"}),
+             ("sha256_device_lanes_total", {"kind": "padding"}),
+             ("validator_roots_total", {"path": "fused"}),
+             ("validator_roots_total", {"path": "host"})]
+    before = [_counter(name, **labels) for name, labels in names]
+    ValidatorRegistryType(2**40).batch_roots(v)
+    moved = [_counter(name, **labels) - b
+             for (name, labels), b in zip(names, before)]
+    assert moved == [16 * n, 0, 8 * n, 8 * (bucket - n), n, 0]
+
+
+def test_validators_cache_root_after_mass_balance_change():
+    """Every second effective balance moves (the epoch's own write): the
+    cache re-roots those rows through the fused program and lands on a cold
+    ``hash_tree_root``."""
+    from lighthouse_tpu.ssz.tree_cache import ValidatorsCache
+    from lighthouse_tpu.types.registry import ValidatorRegistryType
+
+    typ = ValidatorRegistryType(2**40)
+    v = _registry(6000, seed=11)
+    cache = ValidatorsCache(typ, v)
+    assert cache.root(typ, v) == typ.hash_tree_root(v)
+    before = _counter("validator_roots_total", path="fused")
+    v.effective_balance[::2] += np.uint64(10**9)
+    v.exit_epoch[5] = np.uint64(77)
+    assert cache.root(typ, v) == typ.hash_tree_root(v)
+    # the cache's 3,001 dirty rows and the cold root's 6,000
+    assert _counter("validator_roots_total", path="fused") - before == 9001

@@ -198,6 +198,20 @@ def test_fold_levels_device(one_chip, tpu_branches):
                                   sharding=one_chip))
 
 
+def test_validator_roots_device(one_chip, tpu_branches):
+    """The registry's element roots at the 2^20 bucket: a 644k-row update
+    and the first full build both dispatch this shape."""
+    from lighthouse_tpu.ops import sha256
+    from lighthouse_tpu.types.registry import Validators
+
+    views = [sha256._stage_column(col, LEAVES)
+             for col in Validators(LEAVES).columns()]
+    _compile("validator_roots_device@2^20",
+             sha256.validator_roots_device._fn,
+             *(jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+               for v in views))
+
+
 def test_fold_to_root(one_chip, tpu_branches):
     """What merkleize_words(device=True) dispatches at 2^20 leaves."""
     from lighthouse_tpu.ops import sha256
