@@ -4,13 +4,34 @@ Rebuild of /root/reference/beacon_node/beacon_chain/src/
 data_availability_checker.rs (:32,:61) + its overflow LRU cache: pending
 block/blob components are held per block root until every commitment the
 block carries has a verified sidecar — only then does import proceed.
-Capacity-bounded; finalization prunes.
+Capacity-bounded; finalization prunes.  `verify_kzg_for_rpc_blocks` is the
+checker's segment entry (:verify_kzg_for_rpc_blocks -> kzg_utils.rs
+validate_blobs): the sidecars of a whole chain segment in ONE batch.
 """
 
 from __future__ import annotations
 
 from collections import OrderedDict
 from dataclasses import dataclass, field
+
+
+def verify_kzg_for_rpc_blocks(settings, blocks_sidecars) -> bool:
+    """KZG verification for a chain segment that arrived over RPC (range
+    sync, backfill): ``blocks_sidecars`` holds, block by block, the blob
+    sidecars that came with it (anything with ``blob``,
+    ``kzg_commitment`` and ``kzg_proof``).  Every sidecar of the segment
+    goes through `validate_blobs` in one call — up to
+    MAX_REQUEST_BLOB_SIDECARS (768) of them, one
+    `verify_blob_kzg_proof_batch` — and one invalid proof anywhere fails
+    the segment, as in the reference."""
+    from lighthouse_tpu.chain.blob_verification import validate_blobs
+
+    sidecars = [s for block in blocks_sidecars for s in block]
+    return validate_blobs(
+        settings,
+        [s.kzg_commitment for s in sidecars],
+        [s.blob for s in sidecars],
+        [s.kzg_proof for s in sidecars])
 
 
 @dataclass

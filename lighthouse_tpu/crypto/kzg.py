@@ -14,30 +14,35 @@ this repo's own BLS12-381 core:
 - `verify_blob_kzg_proof_batch` folds n proofs into a single 2-pairing
   check by a random linear combination (the verifier-local scalar r),
   and for production batch sizes rides the FUSED device plane: one
-  dispatch evaluates every blob barycentrically (product-tree
-  denominator inversion, ops/fr.py) and one dispatch runs both RLC MSMs
-  + the pairing, with the folded points entering the Miller loop in
-  Jacobian form (zp path) so no affine conversion or host crossing sits
-  between MSM and pairing.  Host work: challenges, r-powers, limb
-  packing, and the native final exponentiation.
+  membership dispatch for every decoded point, the blobs evaluated
+  barycentrically in slices of ops/fr._EVAL_MAX_BLOBS (product-tree
+  denominator inversion) and one dispatch for both RLC MSMs + the
+  pairing, with the folded points entering the Miller loop in Jacobian
+  form (zp path) so no affine conversion or host crossing sits between
+  MSM and pairing.  Host work: decompression, challenges, r-powers,
+  limb packing, and the native final exponentiation.  Every stage is a
+  span (`kzg.verify_batch` and its children) feeding
+  ``kzg_verify_stage_seconds{stage}``.
 """
 
 from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass
-from functools import lru_cache
+from functools import lru_cache, partial
 
 import numpy as np
 
 from lighthouse_tpu.common import device_telemetry as _dtel
+from lighthouse_tpu.common import tracing
+from lighthouse_tpu.common.metrics import REGISTRY, record_swallowed
 from lighthouse_tpu.crypto.bls import curve as cv
 from lighthouse_tpu.ops import program_store as _pstore
 
 # AOT program-store coverage (lhlint LH606): the fused verification
 # program is prewarmed by the "kzg" driver in ops/prewarm; the plain
 # MSM rides the unified plane's entry (ops/msm.py, "msm" driver)
-_pstore.register_entry("crypto/kzg.py::_kzg_fused_check@_kzg_fused",
+_pstore.register_entry("crypto/kzg.py::_kzg_fused_program@_kzg_fused",
                        driver="kzg")
 from lighthouse_tpu.crypto.bls.fields import R as BLS_MODULUS
 
@@ -365,22 +370,38 @@ def verify_blob_kzg_proof(blob: bytes, commitment_bytes: bytes,
 # below this many blobs the device round-trip is not worth it
 _DEVICE_EVAL_MIN = 8
 
+_STAGE_BUCKETS = (0.0001, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 30.0)
 
-def _evaluate_polynomials(polys, zs, blobs, settings) -> list[int]:
-    """All blobs' barycentric evaluations; large batches run as one
-    device dispatch over every (blob, root) lane (ops/fr.py), small ones
-    on host."""
-    if len(polys) < _DEVICE_EVAL_MIN:
-        return [evaluate_polynomial_in_evaluation_form(p, z, settings)
-                for p, z in zip(polys, zs)]
-    import numpy as np
 
-    from lighthouse_tpu.ops import fr
+def record_stage(stage: str, seconds: float) -> None:
+    """One stage of a blob batch verification (sole registration site of
+    the kzg_* families — lhlint LH501 FAMILY_OWNERS)."""
+    try:
+        REGISTRY.histogram(
+            "kzg_verify_stage_seconds",
+            "blob batch verification wall time by stage (eval_dispatch "
+            "and fused_dispatch time the enqueue, eval_fetch and "
+            "fused_wait the host blocked on the device)",
+            buckets=_STAGE_BUCKETS,
+        ).labels(stage=stage).observe(seconds)
+    except Exception as e:
+        record_swallowed("kzg.record_stage", e)
 
-    raw = np.frombuffer(b"".join(blobs), np.uint8).reshape(
-        len(blobs), settings.width, 32)
-    limbs = fr.be32_bytes_to_limbs(raw)
-    return fr.evaluate_polynomials_batch(limbs, zs, settings.roots_brp)
+
+def stage_span(name: str, stage: str, **attrs):
+    """A span whose duration also feeds
+    ``kzg_verify_stage_seconds{stage}``."""
+    return tracing.span(name, observe=partial(record_stage, stage), **attrs)
+
+
+def count_eval_lanes(live: int, padding: int) -> None:
+    """(blob, root) lanes the evaluation slices carried (ops/fr.py): the
+    batch's own and the last slice's fill."""
+    lanes = REGISTRY.counter(
+        "kzg_eval_lanes_total",
+        "barycentric evaluation lanes dispatched, by kind")
+    lanes.labels(kind="live").inc(live)
+    lanes.labels(kind="padding").inc(padding)
 
 
 def _blob_fields_canonical(raw: "np.ndarray") -> bool:
@@ -396,7 +417,48 @@ def _blob_fields_canonical(raw: "np.ndarray") -> bool:
     return bool(ok.all())
 
 
+def _decode_g1_batch(encodings: list[bytes]) -> list:
+    """Decompress every point, then ONE device membership dispatch for all
+    of them (ops/bls_backend.batch_subgroup_check_g1) instead of a
+    pure-Python [r]P a point: 4.1 ms each, 6.4 s for the 1,536 points of
+    a 768-sidecar batch.  Raises ValueError as cv.g1_from_bytes does."""
+    pts = [cv.g1_from_bytes(b, subgroup_check=False) for b in encodings]
+    finite = [p for p in pts if p is not cv.INF]
+    if finite:
+        from lighthouse_tpu.ops.bls_backend import batch_subgroup_check_g1
+
+        if not bool(batch_subgroup_check_g1(finite).all()):
+            raise ValueError("G1 point not in subgroup")
+    return pts
+
+
 _KZG_FUSED_JIT = None
+
+
+def _kzg_fused_program():
+    """The fused verification program (built on first use: importing this
+    module touches no jax)."""
+    import jax
+
+    from lighthouse_tpu.ops import bigint as bi
+    from lighthouse_tpu.ops import msm as _msm
+    from lighthouse_tpu.ops.bls12_381 import (
+        batch_miller_loop,
+        reduce_product,
+    )
+
+    global _KZG_FUSED_JIT
+    if _KZG_FUSED_JIT is None:
+        def _kzg_fused(xs, ys, digits, xqa, xqb, yqa, yqb):
+            Xg, Yg, Zg = _msm.fold_segments_g1(xs, ys, digits, 2)
+            ok = ~bi.is_zero_mod_p_device(Zg)
+            f = batch_miller_loop(Xg, Yg, xqa, xqb, yqa, yqb, zp=Zg)
+            return reduce_product(f, ok)
+
+        _KZG_FUSED_JIT = jax.jit(_kzg_fused)
+        _KZG_FUSED_JIT = _dtel.instrument(
+            "crypto/kzg.py::_kzg_fused_program@_kzg_fused", _KZG_FUSED_JIT)
+    return _KZG_FUSED_JIT
 
 
 def _kzg_fused_check(lhs_points, lhs_scalars, pis, r_pows,
@@ -409,32 +471,21 @@ def _kzg_fused_check(lhs_points, lhs_scalars, pis, r_pows,
     points feed the Miller loop DIRECTLY in Jacobian form (zp path), so
     no affine conversion — and no host crossing — exists between MSM
     and pairing.  Σ-lanes that legally fold to infinity (zero quotient
-    polynomials) are masked on device: e(INF, ·) = 1."""
+    polynomials) are masked on device: e(INF, ·) = 1.
+
+    One dispatch at every batch size a node sees: at the 768 sidecars of
+    a full blob_sidecars_by_range response (2n+1 = 1,537 -> 2,048 lanes
+    an MSM, 4,096 in all) the TPU compiler reports 2.16 GB of
+    temporaries for a described v5e, so no lane cap is needed."""
     import jax
     import jax.numpy as jnp
 
-    from lighthouse_tpu.ops import bigint as bi
     from lighthouse_tpu.ops import ec
     from lighthouse_tpu.ops import msm as _msm
-    from lighthouse_tpu.ops.bls12_381 import (
-        batch_miller_loop,
-        fq12_from_device,
-        reduce_product,
-    )
+    from lighthouse_tpu.ops.bls12_381 import fq12_from_device
     from lighthouse_tpu.ops.bls_backend import _final_exp_is_one
 
-    global _KZG_FUSED_JIT
-    if _KZG_FUSED_JIT is None:
-        def _kzg_fused(xs, ys, digits, xqa, xqb, yqa, yqb):
-            Xg, Yg, Zg = _msm.fold_segments_g1(xs, ys, digits, 2)
-            ok = ~bi.is_zero_mod_p_device(Zg)
-            f = batch_miller_loop(Xg, Yg, xqa, xqb, yqa, yqb, zp=Zg)
-            return reduce_product(f, ok)
-
-        _KZG_FUSED_JIT = jax.jit(_kzg_fused)
-        _KZG_FUSED_JIT = _dtel.instrument(
-            "crypto/kzg.py::_kzg_fused_check@_kzg_fused", _KZG_FUSED_JIT)
-
+    program = _kzg_fused_program()
     m = _msm.bucket(len(lhs_points))
 
     def lane_arrays(points, scalars):
@@ -452,29 +503,33 @@ def _kzg_fused_check(lhs_points, lhs_scalars, pis, r_pows,
                 ec.ints_to_mont_limbs(ys + [0] * pad),
                 ec.scalars_to_digits(ks + [0] * pad, n_bits=256))
 
-    lx, ly, ld = lane_arrays(lhs_points, lhs_scalars)
-    px_, py_, pd = lane_arrays(pis, r_pows)
-    xs = np.empty((2 * m, lx.shape[-1]), np.uint32)
-    ys = np.empty_like(xs)
-    xs[0::2], xs[1::2] = lx, px_
-    ys[0::2], ys[1::2] = ly, py_
-    digits = np.empty((ld.shape[0], 2 * m), np.uint32)
-    digits[:, 0::2], digits[:, 1::2] = ld, pd
+    with stage_span("kzg.pack", "pack", lanes=2 * m):
+        lx, ly, ld = lane_arrays(lhs_points, lhs_scalars)
+        px_, py_, pd = lane_arrays(pis, r_pows)
+        xs = np.empty((2 * m, lx.shape[-1]), np.uint32)
+        ys = np.empty_like(xs)
+        xs[0::2], xs[1::2] = lx, px_
+        ys[0::2], ys[1::2] = ly, py_
+        digits = np.empty((ld.shape[0], 2 * m), np.uint32)
+        digits[:, 0::2], digits[:, 1::2] = ld, pd
 
-    g2rows = getattr(settings, cache_attr, None)
-    if g2rows is None:  # constants per settings: pack once, reuse per call
-        neg_g2 = cv.g2_neg(cv.g2_generator())
-        if tau_g2 is None:
-            tau_g2 = settings.g2_tau
-        g2rows = [jnp.asarray(ec.ints_to_mont_limbs(v)) for v in (
-            [neg_g2[0].a, tau_g2[0].a], [neg_g2[0].b, tau_g2[0].b],
-            [neg_g2[1].a, tau_g2[1].a], [neg_g2[1].b, tau_g2[1].b])]
-        setattr(settings, cache_attr, g2rows)
+        g2rows = getattr(settings, cache_attr, None)
+        if g2rows is None:  # constants per settings: pack once, reuse
+            neg_g2 = cv.g2_neg(cv.g2_generator())
+            if tau_g2 is None:
+                tau_g2 = settings.g2_tau
+            g2rows = [jnp.asarray(ec.ints_to_mont_limbs(v)) for v in (
+                [neg_g2[0].a, tau_g2[0].a], [neg_g2[0].b, tau_g2[0].b],
+                [neg_g2[1].a, tau_g2[1].a], [neg_g2[1].b, tau_g2[1].b])]
+            setattr(settings, cache_attr, g2rows)
 
-    f = _KZG_FUSED_JIT(jnp.asarray(xs), jnp.asarray(ys),
-                       jnp.asarray(digits), *g2rows)
-    f_host = fq12_from_device(jax.device_get(f))
-    return _final_exp_is_one(f_host)
+    with stage_span("kzg.fused.dispatch", "fused_dispatch"):
+        f = program(jnp.asarray(xs), jnp.asarray(ys),
+                    jnp.asarray(digits), *g2rows)
+    with stage_span("kzg.fused.wait", "fused_wait"):
+        f_host = fq12_from_device(jax.device_get(f))
+    with stage_span("kzg.final_exp", "final_exp"):
+        return _final_exp_is_one(f_host)
 
 
 def verify_blob_kzg_proof_batch(
@@ -488,47 +543,39 @@ def verify_blob_kzg_proof_batch(
       e(Σ r^i(C_i − y_i·G1 + z_i·π_i), −G2) · e(Σ r^i·π_i, τ·G2) == 1.
 
     Batches of >= _DEVICE_EVAL_MIN blobs ride the fused device plane:
-    vectorized canonicity validation, one dispatch for every
-    barycentric evaluation (product-tree denominator inversion), and
-    one dispatch for both MSMs + the pairing (_kzg_fused_check) —
-    host work shrinks to challenges, r-powers and limb packing."""
+    one membership dispatch for every commitment and proof, vectorized
+    canonicity validation, the barycentric evaluations in slices of
+    blobs (product-tree denominator inversion, ops/fr.py), and one
+    dispatch for both MSMs + the pairing (_kzg_fused_check) — host work
+    shrinks to challenges, r-powers and limb packing.  Smaller batches
+    stay on the host.  Which of the two served is the ``path`` of the
+    ``kzg.verify_batch`` span and of ``kzg_blobs_verified_total``."""
     n = len(blobs)
     if not (n == len(commitment_bytes_list) == len(proof_bytes_list)):
         return False
     if n == 0:
         return True
     fused = n >= _DEVICE_EVAL_MIN
-    try:
-        cs = [cv.g1_from_bytes(b) for b in commitment_bytes_list]
-        pis = [cv.g1_from_bytes(b) for b in proof_bytes_list]
-        if fused:
-            width = settings.width
-            if any(len(b) != width * BYTES_PER_FIELD_ELEMENT
-                   for b in blobs):
-                return False
-            raw = np.frombuffer(b"".join(blobs), np.uint8).reshape(
-                n, width, 32)
-            if not _blob_fields_canonical(raw):
-                return False
-            polys = None
-        else:
-            polys = [blob_to_polynomial(b, settings) for b in blobs]
-    except (ValueError, KzgError):
-        return False
-    zs = [compute_challenge(blob, cb, settings)
-          for blob, cb in zip(blobs, commitment_bytes_list)]
-    if fused:
-        from lighthouse_tpu.ops import fr
+    path = "fused" if fused else "host"
+    with stage_span("kzg.verify_batch", "verify_batch", blobs=n, path=path):
+        verdict = (_verify_batch_fused if fused else _verify_batch_host)(
+            blobs, commitment_bytes_list, proof_bytes_list, settings)
+    REGISTRY.counter(
+        "kzg_blobs_verified_total",
+        "blobs through verify_blob_kzg_proof_batch, by the path that "
+        "served the batch").labels(path=path).inc(n)
+    return verdict
 
-        ys = fr.evaluate_polynomials_batch(
-            fr.be32_bytes_to_limbs(raw), zs, settings.roots_brp)
-    else:
-        ys = _evaluate_polynomials(polys, zs, blobs, settings)
 
-    # verifier-local random linear combination (domain-separated hash seed
-    # + per-run entropy: r need only be unpredictable to the prover)
+def _rlc(zs, ys, cs, pis, commitment_bytes_list, proof_bytes_list,
+         settings):
+    """The verifier-local random linear combination: (r_pows, lhs_points,
+    lhs_scalars) of Σ r^i·π_i and Σ r^i·(C_i − y_i·G1 + z_i·π_i).  r is a
+    domain-separated hash of the batch plus per-run entropy: it need only
+    be unpredictable to the prover."""
     import secrets
 
+    n = len(zs)
     seed = hashlib.sha256(
         RANDOM_CHALLENGE_KZG_BATCH_DOMAIN
         + settings.width.to_bytes(16, KZG_ENDIANNESS)
@@ -537,21 +584,66 @@ def verify_blob_kzg_proof_batch(
         + secrets.token_bytes(32)).digest()
     r = int.from_bytes(seed, "big") % BLS_MODULUS
     r_pows = [pow(r, i, BLS_MODULUS) for i in range(n)]
-
-    g1 = cv.g1_generator()
-    # Σ r^i·π_i  and  Σ r^i·(C_i − y_i·G1 + z_i·π_i); both MSMs padded to
-    # one lane count so the device compiles a single program shape
-    lhs_points = cs + pis + [g1]
+    lhs_points = cs + pis + [cv.g1_generator()]
     lhs_scalars = list(r_pows) + [ri * z % BLS_MODULUS
                                   for ri, z in zip(r_pows, zs)]
     y_comb = sum(ri * y % BLS_MODULUS for ri, y in zip(r_pows, ys)) % BLS_MODULUS
     lhs_scalars.append((-y_comb) % BLS_MODULUS)
-    if fused:
+    return r_pows, lhs_points, lhs_scalars
+
+
+def _verify_batch_fused(blobs, commitment_bytes_list, proof_bytes_list,
+                        settings) -> bool:
+    from lighthouse_tpu.ops import fr
+
+    n, width = len(blobs), settings.width
+    with stage_span("kzg.decode", "decode", points=2 * n):
         try:
-            return _kzg_fused_check(lhs_points, lhs_scalars, pis, r_pows,
-                                    settings)
-        except KzgError:  # defensive lane-overflow guard: bad input -> False
+            pts = _decode_g1_batch(
+                list(commitment_bytes_list) + list(proof_bytes_list))
+        except ValueError:
             return False
+        cs, pis = pts[:n], pts[n:]
+    with stage_span("kzg.canonical", "canonical"):
+        if any(len(b) != width * BYTES_PER_FIELD_ELEMENT for b in blobs):
+            return False
+        raw = np.frombuffer(b"".join(blobs), np.uint8).reshape(n, width, 32)
+        if not _blob_fields_canonical(raw):
+            return False
+    with stage_span("kzg.challenge", "challenge"):
+        zs = [compute_challenge(blob, cb, settings)
+              for blob, cb in zip(blobs, commitment_bytes_list)]
+    with stage_span("kzg.limbs", "limbs"):
+        limbs = fr.be32_bytes_to_limbs(raw)
+    # span kzg.eval, with its slices, is the evaluation's own (ops/fr.py)
+    ys = fr.evaluate_polynomials_batch(limbs, zs, settings.roots_brp)
+    with stage_span("kzg.rlc", "rlc"):
+        r_pows, lhs_points, lhs_scalars = _rlc(
+            zs, ys, cs, pis, commitment_bytes_list, proof_bytes_list,
+            settings)
+    try:
+        return _kzg_fused_check(lhs_points, lhs_scalars, pis, r_pows,
+                                settings)
+    except KzgError:  # defensive lane-overflow guard: bad input -> False
+        return False
+
+
+def _verify_batch_host(blobs, commitment_bytes_list, proof_bytes_list,
+                       settings) -> bool:
+    try:
+        cs = [cv.g1_from_bytes(b) for b in commitment_bytes_list]
+        pis = [cv.g1_from_bytes(b) for b in proof_bytes_list]
+        polys = [blob_to_polynomial(b, settings) for b in blobs]
+    except (ValueError, KzgError):
+        return False
+    zs = [compute_challenge(blob, cb, settings)
+          for blob, cb in zip(blobs, commitment_bytes_list)]
+    ys = [evaluate_polynomial_in_evaluation_form(p, z, settings)
+          for p, z in zip(polys, zs)]
+    r_pows, lhs_points, lhs_scalars = _rlc(
+        zs, ys, cs, pis, commitment_bytes_list, proof_bytes_list, settings)
+    # both MSMs padded to one lane count so the device compiles a single
+    # program shape
     shared_pad = 1 << max(len(lhs_points) - 1, 0).bit_length()
     proof_comb = g1_lincomb(pis, r_pows, pad_to=shared_pad)
     lhs = g1_lincomb(lhs_points, lhs_scalars, pad_to=shared_pad)

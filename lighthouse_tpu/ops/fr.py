@@ -33,7 +33,8 @@ from lighthouse_tpu.ops import program_store as _pstore
 # AOT program-store coverage (lhlint LH606): the barycentric-eval plane
 # is prewarmed by the "fr" driver in ops/prewarm
 _pstore.register_entry("ops/fr.py::_eval_kernel@_eval_kernel", driver="fr")
-_pstore.register_entry("ops/fr.py::<module>@<lambda>", driver="fr")
+_pstore.register_entry("ops/fr.py::_to_mont_kernel@_to_mont_kernel",
+                       driver="fr")
 
 R_INT = 0x73EDA753299D7D483339D80809A1D80553BDA402FFFE5BFEFFFFFFFF00000001
 
@@ -231,18 +232,34 @@ def from_mont_host(limbs) -> np.ndarray:
     return vals.reshape(arr.shape[:-1])
 
 
+# field elements one pass of be32_bytes_to_limbs converts: the word
+# columns and limb rows of 2^18 values (one 64-blob slice at width 4096)
+# stay a few MB each, so the pass is not a first touch of fresh pages
+_LIMB_BLOCK = 1 << 18
+
+
 def be32_bytes_to_limbs(raw: np.ndarray) -> np.ndarray:
     """Vectorized 32-byte big-endian values -> raw (non-Montgomery) limb
-    rows uint32[..., 18].  Avoids the per-int Python loop for the
-    millions of field elements a blob batch carries."""
-    u8 = np.asarray(raw, np.uint8)
-    bits = np.unpackbits(u8, axis=-1, bitorder="big")  # [..., 256] MSB first
-    bits = bits[..., ::-1]                              # LSB first
-    pad = np.zeros(bits.shape[:-1] + (RADIX_BITS - 256,), np.uint8)
-    bits = np.concatenate([bits, pad], axis=-1)
-    groups = bits.reshape(bits.shape[:-1] + (L, B))
-    weights = (1 << np.arange(B, dtype=np.uint32))
-    return (groups.astype(np.uint32) * weights).sum(axis=-1, dtype=np.uint32)
+    rows uint32[..., 18], by shifts over the four 64-bit words of a value
+    (a limb straddles at most two).  A blob batch carries millions of
+    field elements: no per-int Python loop, no per-bit temporaries."""
+    u8 = np.ascontiguousarray(raw, np.uint8)
+    be = u8.reshape(-1, 32).view(">u8")
+    out = np.empty((be.shape[0], L), np.uint32)
+    mask = np.uint64(MASK)
+    for lo in range(0, be.shape[0], _LIMB_BLOCK):
+        blk = be[lo:lo + _LIMB_BLOCK]
+        # native words, least significant first
+        w = [blk[:, 3 - j].astype(np.uint64) for j in range(4)]
+        rows = np.empty((L, blk.shape[0]), np.uint32)
+        for i in range(L):
+            j, s = divmod(B * i, 64)
+            limb = w[j] >> np.uint64(s)
+            if s > 64 - B and j < 3:
+                limb |= w[j + 1] << np.uint64(64 - s)
+            rows[i] = limb & mask
+        out[lo:lo + _LIMB_BLOCK] = rows.T
+    return out.reshape(u8.shape[:-1] + (L,))
 
 
 # --- inversion + fixed-exponent power ---------------------------------------
@@ -329,35 +346,75 @@ _eval_kernel = _dtel.instrument(
     "ops/fr.py::_eval_kernel@_eval_kernel", _eval_kernel)
 
 
-_TO_MONT_JIT = jax.jit(lambda x: mont_mul(x, _jconst("r2")))
-_TO_MONT_JIT = _dtel.instrument("ops/fr.py::<module>@<lambda>", _TO_MONT_JIT)
+@jax.jit
+def _to_mont_kernel(x):
+    """Raw limb rows -> Montgomery form (one multiply by RADIX² mod R).
+    A named program: the device trace and its readers find it by name."""
+    return mont_mul(x, _jconst("r2"))
+
+
+_to_mont_kernel = _dtel.instrument(
+    "ops/fr.py::_to_mont_kernel@_to_mont_kernel", _to_mont_kernel)
+
+
+# blobs one evaluation dispatch may carry.  _eval_kernel's temporaries
+# grow with the lane count (blobs x width; the [.., 18, 36] partial
+# products of a multiply tile to (8, 128)): for a described v5e the TPU
+# compiler reports 2.74 GB of temporaries at 64 blobs of 4,096 field
+# elements, 5.46 GB at 128, and refuses the 768 blobs of a full
+# blob_sidecars_by_range response outright (22.79 GB wanted of 15.75 GB;
+# the to-Montgomery program alone 16.08 GB).  Wider batches evaluate in
+# equal-shaped slices of blobs; one compiled program serves all of them.
+_EVAL_MAX_BLOBS = 64
 
 
 def evaluate_polynomials_batch(polys_raw_limbs: np.ndarray,
                                zs: list[int],
-                               roots: list[int]) -> list[int]:
+                               roots: list[int], *,
+                               max_blobs: int = _EVAL_MAX_BLOBS) -> list[int]:
     """y_i = p_i(z_i) for every blob polynomial, on device.
 
     polys_raw_limbs: uint32[N, W, L] NON-Montgomery limb rows (from
     be32_bytes_to_limbs); zs: N challenge ints; roots: the W
-    bit-reversed roots of unity."""
+    bit-reversed roots of unity.  Batches over ``max_blobs`` blobs run
+    in slices of that many (the last one padded with zero polynomials
+    at z = 0, which is no root), all dispatched before the one fetch."""
+    from lighthouse_tpu.crypto.kzg import count_eval_lanes, stage_span
+
     N, W, _ = polys_raw_limbs.shape
-    width_inv = pow(W, -1, R_INT)
-    f_m = _TO_MONT_JIT(jnp.asarray(polys_raw_limbs))  # raw -> Montgomery
-    roots_m = jnp.asarray(to_mont_host(roots))
-    zs_m = jnp.asarray(to_mont_host(zs))
-    invw_m = jnp.asarray(to_mont_host(width_inv))
-    y_m = _eval_kernel(f_m, zs_m, roots_m, invw_m)
-    ys = from_mont_host(np.asarray(y_m))
-    root_pos = {int(w): k for k, w in enumerate(roots)}
-    out = []
-    for i in range(N):
-        hit = root_pos.get(int(zs[i]))
-        if hit is not None:
-            # degenerate barycentric case: y = f at that root
-            out.append(int(_limbs_to_int(polys_raw_limbs[i, hit]) % R_INT))
-        else:
-            out.append(int(ys[i]))
+    per = min(N, max_blobs)
+    slices = -(-N // per)
+    with stage_span("kzg.eval", "eval", slices=slices):
+        with stage_span("kzg.eval.dispatch", "eval_dispatch"):
+            roots_m = jnp.asarray(to_mont_host(roots))
+            invw_m = jnp.asarray(to_mont_host(pow(W, -1, R_INT)))
+            zs_m = np.zeros((slices * per, L), np.uint32)
+            zs_m[:N] = to_mont_host(zs)
+            y_slices = []
+            for lo in range(0, N, per):
+                f = polys_raw_limbs[lo:lo + per]
+                if f.shape[0] < per:
+                    f = np.concatenate(
+                        [f, np.zeros((per - f.shape[0], W, L), np.uint32)])
+                y_slices.append(_eval_kernel(
+                    _to_mont_kernel(jnp.asarray(f)),
+                    jnp.asarray(zs_m[lo:lo + per]), roots_m, invw_m))
+        count_eval_lanes(N * W, (slices * per - N) * W)
+        with stage_span("kzg.eval.fetch", "eval_fetch"):
+            y_m = np.concatenate(jax.device_get(y_slices))[:N]
+        ys = from_mont_host(y_m)
+        root_pos = {int(w): k for k, w in enumerate(roots)}
+        out = []
+        for i in range(N):
+            hit = root_pos.get(int(zs[i]))
+            if hit is not None:
+                # degenerate barycentric case: y = f at that root (the
+                # zero denominator spoils that blob's own product tree,
+                # no other)
+                out.append(
+                    int(_limbs_to_int(polys_raw_limbs[i, hit]) % R_INT))
+            else:
+                out.append(int(ys[i]))
     return out
 
 

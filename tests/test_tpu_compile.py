@@ -182,6 +182,61 @@ def test_gather_fold_16_committees(one_chip, tpu_branches):
         groups)
 
 
+# -- the blob plane at a full blob_sidecars_by_range response (768 blobs) ----
+
+BLOB_WIDTH = 4096      # FIELD_ELEMENTS_PER_BLOB
+BLOB_POINTS = 2048     # 2 x 768 commitments and proofs, padded
+FUSED_LANES = 4096     # two MSMs of bucket(2 * 768 + 1) = 2,048 lanes
+
+
+def _fr_rows(sh, *lead):
+    from lighthouse_tpu.ops import fr
+
+    return jax.ShapeDtypeStruct((*lead, fr.L), jnp.uint32, sharding=sh)
+
+
+def test_kzg_eval_slice(one_chip, tpu_branches):
+    """One evaluation slice: fr._EVAL_MAX_BLOBS blobs of 4,096 field
+    elements through the to-Montgomery program and _eval_kernel.  All 768
+    blobs in ONE dispatch are refused (22.79 GB wanted of 15.75 GB; the
+    to-Montgomery program alone 16.08 GB of temporaries), 128 compile to
+    5.46 GB, the cap's 64 to 2.74 GB — which is why the cap exists."""
+    from lighthouse_tpu.ops import fr
+
+    n = fr._EVAL_MAX_BLOBS
+    assert 768 % n == 0  # a full response is whole slices: no fill
+    _compile(f"_to_mont_kernel@{n}x4096", fr._to_mont_kernel._fn,
+             _fr_rows(one_chip, n, BLOB_WIDTH))
+    c = _compile(f"_eval_kernel@{n}x4096", fr._eval_kernel._fn,
+                 _fr_rows(one_chip, n, BLOB_WIDTH), _fr_rows(one_chip, n),
+                 _fr_rows(one_chip, BLOB_WIDTH), _fr_rows(one_chip))
+    assert c.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_g1_subgroup_kernel_blob_batch(one_chip, tpu_branches):
+    """The membership dispatch of a 768-sidecar batch: 1,536 points."""
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    _compile("_g1_subgroup_kernel@2048", bb._g1_subgroup_kernel._fn,
+             *[_limbs(one_chip, BLOB_POINTS)] * 2)
+
+
+@pytest.mark.slow  # ~3.5 min: 4,096 lanes of 256-bit windowed scalar-mul
+def test_kzg_fused_768_blobs(one_chip, tpu_branches):
+    """Both RLC MSMs and the two-lane Jacobian Miller loop in one dispatch
+    at 768 blobs: 2.16 GB of temporaries, so _kzg_fused_check needs no
+    lane cap."""
+    from lighthouse_tpu.crypto import kzg
+
+    c = _compile(
+        "_kzg_fused@4096", kzg._kzg_fused_program()._fn,
+        _limbs(one_chip, FUSED_LANES), _limbs(one_chip, FUSED_LANES),
+        jax.ShapeDtypeStruct((64, FUSED_LANES), jnp.uint32,
+                             sharding=one_chip),
+        *[_limbs(one_chip, 2)] * 4)
+    assert c.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
 def test_hash_pairs_device(one_chip, tpu_branches):
     from lighthouse_tpu.ops import sha256
 

@@ -62,6 +62,9 @@ FAMILY_OWNERS = {
     # sha256.record_merkle_stage; the slot's state root has one writer
     "merkle_stage_": "lighthouse_tpu/ops/sha256.py",
     "state_root_": "lighthouse_tpu/state_transition/slot_processing.py",
+    # the stage spans of the blob plane (PR 29): the batch verifier and
+    # the sliced evaluation (ops/fr.py) record through kzg's helpers
+    "kzg_": "lighthouse_tpu/crypto/kzg.py",
     # the observatory plane (PR 11): each subsystem owns its families —
     # flight events/trips, manifest-keyed jit telemetry + the cold-start
     # headline, SLO scoring, invariant breaches, and the shared
