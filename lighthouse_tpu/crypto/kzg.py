@@ -14,14 +14,17 @@ this repo's own BLS12-381 core:
 - `verify_blob_kzg_proof_batch` folds n proofs into a single 2-pairing
   check by a random linear combination (the verifier-local scalar r),
   and for production batch sizes rides the FUSED device plane: one
-  membership dispatch for every decoded point, the blobs evaluated
+  membership dispatch for every decoded point (its verdict read when
+  the fold needs it, not when it is dispatched), the blobs evaluated
   barycentrically in slices of ops/fr._EVAL_MAX_BLOBS (product-tree
-  denominator inversion) and one dispatch for both RLC MSMs + the
-  pairing, with the folded points entering the Miller loop in Jacobian
-  form (zp path) so no affine conversion or host crossing sits between
-  MSM and pairing.  Host work: decompression, challenges, r-powers,
-  limb packing, and the native final exponentiation.  Every stage is a
-  span (`kzg.verify_batch` and its children) feeding
+  denominator inversion), each slice's canonicity check, challenges
+  and limbs made while the device evaluates the slice before it, and
+  one dispatch for both RLC MSMs + the pairing, with the folded points
+  entering the Miller loop in Jacobian form (zp path) so no affine
+  conversion or host crossing sits between MSM and pairing.  Host
+  work: decompression, challenges, r-powers, limb packing, and the
+  native final exponentiation.  Every stage is a span
+  (`kzg.verify_batch` and its children) feeding
   ``kzg_verify_stage_seconds{stage}``.
 """
 
@@ -404,6 +407,18 @@ def count_eval_lanes(live: int, padding: int) -> None:
     lanes.labels(kind="padding").inc(padding)
 
 
+def count_eval_slice(overlapped: bool) -> None:
+    """One evaluation slice dispatched (ops/fr.py): ``exposed`` when the
+    host prepared it with no evaluation slice of its batch in flight
+    (the first), ``overlapped`` when the device had the slice before it
+    to run meanwhile."""
+    REGISTRY.counter(
+        "kzg_eval_slices_total",
+        "evaluation slices dispatched, by whether their host preparation "
+        "ran under an earlier slice of the batch").labels(
+            prep="overlapped" if overlapped else "exposed").inc()
+
+
 def _blob_fields_canonical(raw: "np.ndarray") -> bool:
     """Vectorized canonicity check of [N, W, 32] big-endian field bytes
     (< BLS_MODULUS) — replaces per-element python parsing on the batch
@@ -417,19 +432,20 @@ def _blob_fields_canonical(raw: "np.ndarray") -> bool:
     return bool(ok.all())
 
 
-def _decode_g1_batch(encodings: list[bytes]) -> list:
-    """Decompress every point, then ONE device membership dispatch for all
-    of them (ops/bls_backend.batch_subgroup_check_g1) instead of a
+def _decode_g1_batch(encodings: list[bytes]):
+    """(points, membership): decompress every point, then ONE device
+    membership dispatch for all of them
+    (ops/bls_backend.dispatch_subgroup_check_g1) instead of a
     pure-Python [r]P a point: 4.1 ms each, 6.4 s for the 1,536 points of
-    a 768-sidecar batch.  Raises ValueError as cv.g1_from_bytes does."""
-    pts = [cv.g1_from_bytes(b, subgroup_check=False) for b in encodings]
-    finite = [p for p in pts if p is not cv.INF]
-    if finite:
-        from lighthouse_tpu.ops.bls_backend import batch_subgroup_check_g1
+    a 768-sidecar batch.  The dispatch is not waited for: ``membership``
+    is an AsyncVerdict, and the caller commits it before any point
+    enters a fold.  Raises ValueError as cv.g1_from_bytes does, before
+    anything is dispatched."""
+    from lighthouse_tpu.ops.bls_backend import dispatch_subgroup_check_g1
 
-        if not bool(batch_subgroup_check_g1(finite).all()):
-            raise ValueError("G1 point not in subgroup")
-    return pts
+    pts = [cv.g1_from_bytes(b, subgroup_check=False) for b in encodings]
+    return pts, dispatch_subgroup_check_g1(
+        [p for p in pts if p is not cv.INF])
 
 
 _KZG_FUSED_JIT = None
@@ -542,14 +558,20 @@ def verify_blob_kzg_proof_batch(
     With challenges z_i, evaluations y_i and verifier powers r^i:
       e(Σ r^i(C_i − y_i·G1 + z_i·π_i), −G2) · e(Σ r^i·π_i, τ·G2) == 1.
 
-    Batches of >= _DEVICE_EVAL_MIN blobs ride the fused device plane:
-    one membership dispatch for every commitment and proof, vectorized
-    canonicity validation, the barycentric evaluations in slices of
-    blobs (product-tree denominator inversion, ops/fr.py), and one
-    dispatch for both MSMs + the pairing (_kzg_fused_check) — host work
-    shrinks to challenges, r-powers and limb packing.  Smaller batches
-    stay on the host.  Which of the two served is the ``path`` of the
-    ``kzg.verify_batch`` span and of ``kzg_blobs_verified_total``."""
+    Batches of >= _DEVICE_EVAL_MIN blobs ride the fused device plane,
+    in this order: every blob's length checked; commitments and proofs
+    decompressed and ONE membership dispatch for all of them, not
+    waited for; the barycentric evaluations in slices of blobs
+    (product-tree denominator inversion, ops/fr.py), the host checking
+    canonicity, hashing the challenges and laying out the limbs of
+    slice k+1 while the device evaluates slice k; one fetch of every
+    slice's evaluations; the membership verdict read (the device runs
+    in order: it was ready before the first slice started), so no point
+    outside the subgroup reaches a fold; and one dispatch for both MSMs
+    + the pairing (_kzg_fused_check).  A batch of one slice goes the
+    same way and overlaps nothing.  Smaller batches stay on the host.
+    Which of the two served is the ``path`` of the ``kzg.verify_batch``
+    span and of ``kzg_blobs_verified_total``."""
     n = len(blobs)
     if not (n == len(commitment_bytes_list) == len(proof_bytes_list)):
         return False
@@ -597,26 +619,40 @@ def _verify_batch_fused(blobs, commitment_bytes_list, proof_bytes_list,
     from lighthouse_tpu.ops import fr
 
     n, width = len(blobs), settings.width
+    if any(len(b) != width * BYTES_PER_FIELD_ELEMENT for b in blobs):
+        return False
     with stage_span("kzg.decode", "decode", points=2 * n):
         try:
-            pts = _decode_g1_batch(
+            pts, membership = _decode_g1_batch(
                 list(commitment_bytes_list) + list(proof_bytes_list))
         except ValueError:
             return False
         cs, pis = pts[:n], pts[n:]
-    with stage_span("kzg.canonical", "canonical"):
-        if any(len(b) != width * BYTES_PER_FIELD_ELEMENT for b in blobs):
+
+    def prepare(lo, hi):
+        """Limb rows and challenges of blobs [lo, hi): called by the
+        evaluation between two dispatches."""
+        with stage_span("kzg.canonical", "canonical"):
+            raw = np.frombuffer(b"".join(blobs[lo:hi]), np.uint8).reshape(
+                hi - lo, width, BYTES_PER_FIELD_ELEMENT)
+            if not _blob_fields_canonical(raw):
+                raise KzgError("field element not canonical")
+        with stage_span("kzg.challenge", "challenge"):
+            challenges = [
+                compute_challenge(blob, cb, settings) for blob, cb in zip(
+                    blobs[lo:hi], commitment_bytes_list[lo:hi])]
+        with stage_span("kzg.limbs", "limbs"):
+            return fr.be32_bytes_to_limbs(raw), challenges
+
+    try:
+        # span kzg.eval, with its slices, is the evaluation's own (ops/fr.py)
+        zs, ys = fr.evaluate_polynomial_slices(n, prepare,
+                                               settings.roots_brp)
+    except KzgError:  # slices in flight are dropped, nothing is fetched
+        return False
+    with stage_span("kzg.decode.verdict", "decode"):
+        if not membership.commit():
             return False
-        raw = np.frombuffer(b"".join(blobs), np.uint8).reshape(n, width, 32)
-        if not _blob_fields_canonical(raw):
-            return False
-    with stage_span("kzg.challenge", "challenge"):
-        zs = [compute_challenge(blob, cb, settings)
-              for blob, cb in zip(blobs, commitment_bytes_list)]
-    with stage_span("kzg.limbs", "limbs"):
-        limbs = fr.be32_bytes_to_limbs(raw)
-    # span kzg.eval, with its slices, is the evaluation's own (ops/fr.py)
-    ys = fr.evaluate_polynomials_batch(limbs, zs, settings.roots_brp)
     with stage_span("kzg.rlc", "rlc"):
         r_pows, lhs_points, lhs_scalars = _rlc(
             zs, ys, cs, pis, commitment_bytes_list, proof_bytes_list,

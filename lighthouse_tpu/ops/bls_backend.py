@@ -350,21 +350,43 @@ def aggregate_pubkeys_device(sets):
     return xa, ya, inf
 
 
+def _dispatch_g1_subgroup_kernel(points):
+    """Dispatch (no host sync) the [r-1]P membership kernel over affine
+    G1 points, generator-padded to a power of two (floor 4).  Returns
+    the device bool row; callers read [:len(points)] when they sync."""
+    padded = _next_pow2(len(points), floor=4)
+    pts = list(points) + [cv.g1_generator()] * (padded - len(points))
+    xp = jnp.asarray(ec.ints_to_mont_limbs([p[0] for p in pts]))
+    yp = jnp.asarray(ec.ints_to_mont_limbs([p[1] for p in pts]))
+    # deliberately outside the supervised verify path: trusted-setup
+    # validation, cold-pubkey checks and the blob batch's membership
+    # test handle errors directly
+    return _g1_subgroup_kernel(xp, yp)  # lhlint: allow(LH601)
+
+
 def batch_subgroup_check_g1(points) -> np.ndarray:
     """Device [r-1]P membership test over affine G1 points -> bool[n]
-    (the trusted-setup validator and cold-pubkey batch path)."""
+    (the trusted-setup validator, which names the failing points, and
+    the cold-pubkey batch path).  Synchronous by contract: it fetches
+    the row it dispatched; dispatch_subgroup_check_g1 is the form that
+    does not."""
     n = len(points)
     if n == 0:
         return np.zeros(0, bool)
-    padded = _next_pow2(n, floor=4)
-    pts = list(points) + [cv.g1_generator()] * (padded - n)
-    xp = jnp.asarray(ec.ints_to_mont_limbs([p[0] for p in pts]))
-    yp = jnp.asarray(ec.ints_to_mont_limbs([p[1] for p in pts]))
-    # deliberately outside the supervised verify path: startup-time
-    # trusted-setup validation and cold-pubkey checks are synchronous by
-    # contract and their callers handle errors directly
-    ok = np.asarray(_g1_subgroup_kernel(xp, yp))  # lhlint: allow(LH601)
-    return ok[:n]
+    return np.asarray(_dispatch_g1_subgroup_kernel(points))[:n]
+
+
+def dispatch_subgroup_check_g1(points):
+    """The same test WITHOUT a host sync: an AsyncVerdict whose commit()
+    reads whether every point passed.  The blob batch verifier
+    (crypto/kzg) dispatches it in front of the evaluation slices and
+    commits it once they are fetched, so the host feeds the slices
+    while the membership program runs."""
+    from lighthouse_tpu.ops import dispatch_pipeline as dp
+
+    if not points:
+        return dp.AsyncVerdict.immediate(True)
+    return dp.AsyncVerdict(_dispatch_g1_subgroup_kernel(points), len(points))
 
 
 def _dispatch_subgroup_check(sigs):

@@ -368,54 +368,83 @@ _to_mont_kernel = _dtel.instrument(
 _EVAL_MAX_BLOBS = 64
 
 
+def evaluate_polynomial_slices(n: int, prepare, roots: list[int], *,
+                               max_blobs: int = _EVAL_MAX_BLOBS
+                               ) -> tuple[list[int], list[int]]:
+    """(zs, ys) with y_i = p_i(z_i) for n blob polynomials, on device,
+    fed slice by slice.
+
+    ``roots`` are the W bit-reversed roots of unity.  ``prepare(lo, hi)``
+    makes blobs [lo, hi): their uint32[hi-lo, W, L] NON-Montgomery limb
+    rows (be32_bytes_to_limbs) and their challenge ints.  It is called
+    inside the dispatch loop, so the host makes slice k+1 while the
+    device evaluates slice k; whatever it raises passes through, with
+    the slices in flight dropped and nothing fetched.  Batches over
+    ``max_blobs`` blobs run in slices of that many (the last one padded
+    with zero polynomials at z = 0, which is no root) and one fetch
+    follows the last dispatch; a smaller batch is one slice, by the same
+    loop.  A slice's limbs are let go once it is dispatched: the
+    z == root patch takes its field element while they are alive."""
+    from lighthouse_tpu.crypto.kzg import (
+        count_eval_lanes,
+        count_eval_slice,
+        stage_span,
+    )
+
+    width = len(roots)
+    per = min(n, max_blobs)
+    slices = -(-n // per)
+    with stage_span("kzg.eval", "eval", slices=slices,
+                    overlapped=slices - 1):
+        root_pos = {int(w): k for k, w in enumerate(roots)}
+        zs, y_slices, at_root = [], [], {}
+        for lo in range(0, n, per):
+            hi = min(lo + per, n)
+            f, zs_k = prepare(lo, hi)
+            for i, z in enumerate(zs_k, lo):
+                hit = root_pos.get(int(z))
+                if hit is not None:
+                    # degenerate barycentric case: y = f at that root
+                    # (the zero denominator spoils that blob's own
+                    # product tree, no other)
+                    at_root[i] = _limbs_to_int(f[i - lo, hit]) % R_INT
+            with stage_span("kzg.eval.dispatch", "eval_dispatch"):
+                if not y_slices:  # the constants go up with the first slice
+                    roots_m = jnp.asarray(to_mont_host(roots))
+                    invw_m = jnp.asarray(to_mont_host(pow(width, -1, R_INT)))
+                zs_m = to_mont_host(zs_k)
+                if hi - lo < per:
+                    fill = per - (hi - lo)
+                    f = np.concatenate(
+                        [f, np.zeros((fill, width, L), np.uint32)])
+                    zs_m = np.concatenate(
+                        [zs_m, np.zeros((fill, L), np.uint32)])
+                y_slices.append(_eval_kernel(
+                    _to_mont_kernel(jnp.asarray(f)), jnp.asarray(zs_m),
+                    roots_m, invw_m))
+            del f
+            count_eval_slice(overlapped=lo > 0)
+            zs.extend(zs_k)
+        count_eval_lanes(n * width, (slices * per - n) * width)
+        with stage_span("kzg.eval.fetch", "eval_fetch"):
+            y_m = np.concatenate(jax.device_get(y_slices))[:n]
+        ys = [int(y) for y in from_mont_host(y_m)]
+        for i, y in at_root.items():
+            ys[i] = y
+    return zs, ys
+
+
 def evaluate_polynomials_batch(polys_raw_limbs: np.ndarray,
                                zs: list[int],
                                roots: list[int], *,
                                max_blobs: int = _EVAL_MAX_BLOBS) -> list[int]:
-    """y_i = p_i(z_i) for every blob polynomial, on device.
-
-    polys_raw_limbs: uint32[N, W, L] NON-Montgomery limb rows (from
-    be32_bytes_to_limbs); zs: N challenge ints; roots: the W
-    bit-reversed roots of unity.  Batches over ``max_blobs`` blobs run
-    in slices of that many (the last one padded with zero polynomials
-    at z = 0, which is no root), all dispatched before the one fetch."""
-    from lighthouse_tpu.crypto.kzg import count_eval_lanes, stage_span
-
-    N, W, _ = polys_raw_limbs.shape
-    per = min(N, max_blobs)
-    slices = -(-N // per)
-    with stage_span("kzg.eval", "eval", slices=slices):
-        with stage_span("kzg.eval.dispatch", "eval_dispatch"):
-            roots_m = jnp.asarray(to_mont_host(roots))
-            invw_m = jnp.asarray(to_mont_host(pow(W, -1, R_INT)))
-            zs_m = np.zeros((slices * per, L), np.uint32)
-            zs_m[:N] = to_mont_host(zs)
-            y_slices = []
-            for lo in range(0, N, per):
-                f = polys_raw_limbs[lo:lo + per]
-                if f.shape[0] < per:
-                    f = np.concatenate(
-                        [f, np.zeros((per - f.shape[0], W, L), np.uint32)])
-                y_slices.append(_eval_kernel(
-                    _to_mont_kernel(jnp.asarray(f)),
-                    jnp.asarray(zs_m[lo:lo + per]), roots_m, invw_m))
-        count_eval_lanes(N * W, (slices * per - N) * W)
-        with stage_span("kzg.eval.fetch", "eval_fetch"):
-            y_m = np.concatenate(jax.device_get(y_slices))[:N]
-        ys = from_mont_host(y_m)
-        root_pos = {int(w): k for k, w in enumerate(roots)}
-        out = []
-        for i in range(N):
-            hit = root_pos.get(int(zs[i]))
-            if hit is not None:
-                # degenerate barycentric case: y = f at that root (the
-                # zero denominator spoils that blob's own product tree,
-                # no other)
-                out.append(
-                    int(_limbs_to_int(polys_raw_limbs[i, hit]) % R_INT))
-            else:
-                out.append(int(ys[i]))
-    return out
+    """evaluate_polynomial_slices over limb rows that are already made:
+    polys_raw_limbs uint32[N, W, L] (from be32_bytes_to_limbs) and N
+    challenge ints."""
+    return evaluate_polynomial_slices(
+        len(polys_raw_limbs),
+        lambda lo, hi: (polys_raw_limbs[lo:hi], zs[lo:hi]), roots,
+        max_blobs=max_blobs)[1]
 
 
 __all__ = [
@@ -424,6 +453,7 @@ __all__ = [
     "R_INT",
     "add",
     "be32_bytes_to_limbs",
+    "evaluate_polynomial_slices",
     "evaluate_polynomials_batch",
     "from_mont_host",
     "inv_mont",

@@ -104,3 +104,46 @@ class TestBarycentricEval:
             [np.stack([fr._int_to_limbs(v) for v in p]) for p in polys])
         got = fr.evaluate_polynomials_batch(raw, zs, settings.roots_brp)
         assert got == want
+
+    def test_slice_fed_form_patches_a_root_hit_from_its_own_slice(self):
+        """Five blobs fed in slices of two from their bytes, z == root in
+        a blob of the second slice: its y is the host oracle's, taken
+        from limbs that no one holds once the slice is dispatched."""
+        from lighthouse_tpu.crypto import kzg
+
+        settings = kzg.KzgSettings.dev(width=8)
+        N = 5
+        polys = [[secrets.randbelow(R) for _ in range(8)] for _ in range(N)]
+        zs = [secrets.randbelow(R) for _ in range(N)]
+        zs[3] = settings.roots_brp[6]
+        want = [kzg.evaluate_polynomial_in_evaluation_form(p, z, settings)
+                for p, z in zip(polys, zs)]
+        assert want[3] == polys[3][6]
+        blobs = [b"".join(v.to_bytes(32, "big") for v in p) for p in polys]
+        asked = []
+
+        def prepare(lo, hi):
+            asked.append((lo, hi))
+            raw = np.frombuffer(b"".join(blobs[lo:hi]), np.uint8)
+            return (fr.be32_bytes_to_limbs(raw.reshape(hi - lo, 8, 32)),
+                    zs[lo:hi])
+
+        got = fr.evaluate_polynomial_slices(N, prepare, settings.roots_brp,
+                                            max_blobs=2)
+        assert got == (zs, want)
+        assert asked == [(0, 2), (2, 4), (4, 5)]
+
+    def test_what_prepare_raises_passes_through(self):
+        from lighthouse_tpu.crypto import kzg
+
+        settings = kzg.KzgSettings.dev(width=8)
+        raw = np.zeros((3, 8, fr.L), np.uint32)
+
+        def prepare(lo, hi):
+            if lo == 2:
+                raise kzg.KzgError("refused")
+            return raw[lo:hi], [5] * (hi - lo)
+
+        with pytest.raises(kzg.KzgError):
+            fr.evaluate_polynomial_slices(3, prepare, settings.roots_brp,
+                                          max_blobs=1)

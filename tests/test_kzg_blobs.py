@@ -6,10 +6,13 @@ Python integers; it imports nothing of the program.  Every variant a
 both.  Batches here are 8 blobs: the fused path's lane bucket
 (``bucket(2n+1) = 32``) and the 16-lane membership program are the ones
 ``tests/test_kzg.py`` already compiles, so this file compiles no further
-Miller program.  The slice cap of the evaluation is passed by argument.
+Miller program.  The slice cap of the evaluation is passed by argument;
+the pipelined tests force it to 2 and 3 blobs, so that the same 8 blobs are
+four whole slices or two and a ragged third.
 """
 
 import random
+from functools import partial
 
 import numpy as np
 import pytest
@@ -241,9 +244,10 @@ def test_segment_entry_equals_per_block_validation(settings, setup, bad_block):
 
 KZG_PARENTS = {
     "kzg.decode": "kzg.verify_batch",
-    "kzg.canonical": "kzg.verify_batch",
-    "kzg.challenge": "kzg.verify_batch",
-    "kzg.limbs": "kzg.verify_batch",
+    "kzg.decode.verdict": "kzg.verify_batch",
+    "kzg.canonical": "kzg.eval",
+    "kzg.challenge": "kzg.eval",
+    "kzg.limbs": "kzg.eval",
     "kzg.eval": "kzg.verify_batch",
     "kzg.rlc": "kzg.verify_batch",
     "kzg.pack": "kzg.verify_batch",
@@ -293,7 +297,7 @@ def test_stage_spans_cover_the_batch_and_stamp_the_path(settings, setup):
     for name, parent in KZG_PARENTS.items():
         assert parents.get(name) == {parent}, name
     (evaluation,) = [c for c in fused["children"] if c["name"] == "kzg.eval"]
-    assert evaluation["attrs"] == {"slices": 1}
+    assert evaluation["attrs"] == {"slices": 1, "overlapped": 0}
     covered = sum(c["duration_ms"] for c in fused["children"])
     assert covered >= 0.95 * fused["duration_ms"]
     stages = {line.split('stage="')[1].split('"')[0]
@@ -303,3 +307,118 @@ def test_stage_spans_cover_the_batch_and_stamp_the_path(settings, setup):
     after = _blobs_verified()
     assert after["fused"] - before.get("fused", 0.0) == N
     assert after["host"] - before.get("host", 0.0) == 2
+
+
+# --- the evaluation fed slice by slice (ISSUE 30) ---------------------------
+
+@pytest.fixture
+def slice_cap(monkeypatch):
+    """Force the evaluation's slice cap on the fused route."""
+    def force(max_blobs):
+        monkeypatch.setattr(
+            fr, "evaluate_polynomial_slices",
+            partial(fr.evaluate_polynomial_slices, max_blobs=max_blobs))
+    return force
+
+
+def _flat(d, out):
+    out.append(d)
+    for child in d.get("children", ()):
+        _flat(child, out)
+    return out
+
+
+def _traced_batch(blobs, cs, proofs, settings):
+    """(verdict, the spans of the one kzg.verify_batch, in closing order)."""
+    roots = []
+
+    def sink(root, _slot):
+        roots.append(root.to_dict())
+
+    tracing.TRACER.add_sink(sink)
+    try:
+        verdict = kzg.verify_blob_kzg_proof_batch(blobs, cs, proofs, settings)
+    finally:
+        tracing.TRACER.remove_sink(sink)
+    (batch,) = [r for r in roots if r["name"] == "kzg.verify_batch"]
+    return verdict, _flat(batch, [])
+
+
+def _named(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+def _slices():
+    return {"exposed": 0.0, "overlapped": 0.0,
+            **_counter("kzg_eval_slices_total", "prep")}
+
+
+@pytest.mark.parametrize("max_blobs", [2, 3], ids=["whole", "ragged"])
+@pytest.mark.parametrize("variant", ["good", "changed_field_element",
+                                     "cancelling_forged_pair"])
+def test_pipelined_verdict_equals_plain_reference(settings, setup, slice_cap,
+                                                  variant, max_blobs):
+    make, expected = VARIANTS[variant]
+    blobs, cs, proofs = make(setup, *_batch(setup, N, seed=59))
+    want = ref.verify_blob_kzg_proof_batch(blobs, cs, proofs, setup)
+    assert want is expected
+    slice_cap(max_blobs)
+    assert kzg.verify_blob_kzg_proof_batch(blobs, cs, proofs, settings) is want
+
+
+def test_non_canonical_in_the_last_slice_drops_the_slices_in_flight(
+        settings, setup, slice_cap):
+    blobs, cs, proofs, _, _ = _batch(setup, N, seed=61)
+    blobs[N - 1] = blobs[N - 1][:-32] + ref.BLS_MODULUS.to_bytes(32, "big")
+    assert ref.verify_blob_kzg_proof_batch(blobs, cs, proofs, setup) is False
+    slice_cap(3)
+    verdict, spans = _traced_batch(blobs, cs, proofs, settings)
+    assert verdict is False
+    # two slices went up, the third's check refused, nothing was fetched
+    assert len(_named(spans, "kzg.eval.dispatch")) == 2
+    assert len(_named(spans, "kzg.canonical")) == 3
+    assert _named(spans, "kzg.canonical")[-1]["attrs"] == {"error": "KzgError"}
+    for never in ("kzg.eval.fetch", "kzg.decode.verdict", "kzg.fused.dispatch"):
+        assert not _named(spans, never), never
+
+
+def test_point_outside_the_subgroup_never_reaches_the_fold(
+        settings, setup, slice_cap, monkeypatch):
+    blobs, cs, proofs = _outside_subgroup(setup, *_batch(setup, N, seed=67))
+    assert ref.verify_blob_kzg_proof_batch(blobs, cs, proofs, setup) is False
+    folded = []
+    monkeypatch.setattr(kzg, "_kzg_fused_check",
+                        lambda *a, **k: folded.append(a) or True)
+    slice_cap(3)
+    verdict, spans = _traced_batch(blobs, cs, proofs, settings)
+    assert verdict is False and not folded
+    # the verdict is read after the evaluation's fetch, not in kzg.decode
+    names = [s["name"] for s in sorted(spans, key=lambda s: s["offset_ms"])]
+    assert names.index("kzg.eval.fetch") < names.index("kzg.decode.verdict")
+    assert not _named(spans, "kzg.rlc")
+
+
+@pytest.mark.parametrize("max_blobs, slices", [(3, 3), (fr._EVAL_MAX_BLOBS, 1)],
+                         ids=["three_slices", "one_slice"])
+def test_host_prepares_a_slice_while_the_one_before_is_dispatched(
+        settings, setup, slice_cap, max_blobs, slices):
+    blobs, cs, proofs, _, _ = _batch(setup, N, seed=71)
+    slice_cap(max_blobs)
+    before = _slices()
+    verdict, spans = _traced_batch(blobs, cs, proofs, settings)
+    after = _slices()
+    assert verdict is True
+    assert after["exposed"] - before["exposed"] == 1
+    assert after["overlapped"] - before["overlapped"] == slices - 1
+    (evaluation,) = _named(spans, "kzg.eval")
+    assert evaluation["attrs"] == {"slices": slices, "overlapped": slices - 1}
+    dispatches = _named(spans, "kzg.eval.dispatch")
+    limbs = _named(spans, "kzg.limbs")
+    assert len(dispatches) == len(limbs) == slices
+    assert len(_named(spans, "kzg.canonical")) == slices
+    assert len(_named(spans, "kzg.challenge")) == slices
+    # slice k is dispatched before slice k+1's limbs are made
+    for k in range(slices - 1):
+        assert (dispatches[k]["offset_ms"] + dispatches[k]["duration_ms"]
+                <= limbs[k + 1]["offset_ms"])
+    assert (dispatches[0]["offset_ms"] < limbs[-1]["offset_ms"]) is (slices > 1)
