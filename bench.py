@@ -1470,7 +1470,7 @@ def _bench_observatory() -> dict:
        observatory disarmed/armed (flight recorder + slow-span capture
        + SLO scoring + invariant sweeper); armed throughput must hold
        >= 95% of unarmed.
-    2. **manifest telemetry tour** — dispatch every one of the 21
+    2. **manifest telemetry tour** — dispatch every one of the 20
        shape-manifest jit entry points at tiny shapes; every entry must
        report compile/dispatch telemetry, and the BLS verifies record
        time_to_first_verify_seconds per backend (reference + tpu).
@@ -1497,9 +1497,6 @@ def _bench_observatory() -> dict:
     from lighthouse_tpu.processor import BeaconProcessor, WorkType
     from lighthouse_tpu.processor.firehose import FirehoseDriver, ledger
 
-    # the final-exp hard part rides the device in this child so the
-    # ops/bls_backend.py::<module>@final_exp_hard_device entry reports
-    os.environ.setdefault("LHTPU_DEVICE_FINAL_EXP", "1")
     platform = jax.devices()[0].platform
     result: dict = {"observatory_platform": platform, "stage": "built"}
     _emit_partial(result)
@@ -1636,20 +1633,6 @@ def _bench_observatory() -> dict:
     step("fq12_mul", lambda: dp.combine_partials(
         [b381.fq12_to_device(pairing_box["f"]),
          b381.fq12_to_device(pairing_box["f"])]))
-
-    def final_exp_tour():
-        # the native C++ final exp normally preempts this program even
-        # with LHTPU_DEVICE_FINAL_EXP=1 — dispatch the device ladder
-        # directly so its manifest entry reports
-        from lighthouse_tpu.crypto.bls.fields import final_exp_easy
-        from lighthouse_tpu.ops import bls_backend as bb
-
-        m = final_exp_easy(pairing_box["f"])
-        import jax as _jax
-
-        _jax.device_get(bb._final_exp_hard_jit(b381.fq12_to_device(m)))
-
-    step("final_exp", final_exp_tour)
 
     def kzg_tour():
         settings = kzg.KzgSettings.dev(width=16)
@@ -2044,7 +2027,7 @@ def _bench_coldstart() -> dict:
     a second fresh interpreter against the now-populated store (warm:
     every entry deserializes straight into the dispatch memo).  Gates:
     warm ``time_to_first_verify_seconds{tpu}`` >= 5x lower than cold,
-    all 21 manifest entries served as ``store_hit`` on the warm run,
+    all 20 manifest entries served as ``store_hit`` on the warm run,
     zero store failures beyond accounted misses, and the sha256
     calibration loaded from the store instead of re-measured."""
     import shutil

@@ -427,7 +427,6 @@ def _phase_bls(args, sz, block_sets, flood_sets, warm, expect, spans):
         verdict_valid=True, verdict_one_bad=False,
         reference_subset=len(subset), reference_s=ref_t.s,
         served=served(2), mxu_redc=bi._use_mxu_redc(),
-        device_final_exp=bb._use_device_final_exp(),
         key_aggregation_rung=("device:_blinded_fold"
                               if fold.get("dispatches") else "host"),
         key_aggregation_dispatches=fold.get("dispatches", 0))

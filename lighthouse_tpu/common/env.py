@@ -43,9 +43,6 @@ _register("LHTPU_BLS_CHUNK", None,
           "Overlapped-pipeline chunk size in signature sets; unset = "
           "512 (dispatch_pipeline.DEFAULT_CHUNK_SETS), 0 disables "
           "chunking (monolithic single-dispatch).")
-_register("LHTPU_DEVICE_FINAL_EXP", None,
-          "1/0 forces the final-exponentiation hard part on/off device; "
-          "unset = on for TPU, host path for XLA-CPU.")
 _register("LHTPU_NO_CACHE_GUARD", None,
           "Any non-empty value disables the vm.max_map_count raise the "
           "test suite makes before XLA:CPU compiles (ops/cache_guard).")

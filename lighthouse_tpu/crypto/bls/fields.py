@@ -326,10 +326,7 @@ def final_exp_easy(f: Fq12) -> Fq12:
 
 
 def final_exp_hard(m: Fq12) -> Fq12:
-    """Hard part (m^((p^4-p^2+1)/r))^3 via the x-ladder (m cyclotomic).
-
-    This is the host oracle for ops/bls12_381.final_exp_hard_device —
-    the device mirror runs the identical ladder."""
+    """Hard part (m^((p^4-p^2+1)/r))^3 via the x-ladder (m cyclotomic)."""
     # x < 0: f^x = conj(f^|x|) (conj inverts in the cyclotomic subgroup)
     px = lambda g: _pow_u_cyc(g).conj()   # noqa: E731  g^x
     t1 = px(m)                            # m^x

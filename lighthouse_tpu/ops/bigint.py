@@ -1,45 +1,61 @@
-"""Batched 381-bit modular arithmetic for BLS12-381 on TPU (jnp, uint32).
+"""Batched modular arithmetic for the BLS12-381 fields on TPU (jnp, uint32).
 
-The machine has no wide integers (SURVEY.md §7 hard part #1), so Fp
-elements are 27 limbs x 15 bits in uint32 lanes (trailing axis), kept in a
-REDUNDANT representation: limbs may slightly exceed 2^15 (bounded by
-~2^15 + 2^11) and values may exceed P (bounded by ~2^394 << 2^405 = R).
-The redundancy is what makes the arithmetic vectorize:
+ONE Montgomery construction, ``MontField``, instantiated twice: the
+381-bit BASE field Fp here (``FP``, whose operations this module binds
+under the module-level names its callers use) and the 255-bit SCALAR
+field Fr in ops/fr.py.  A bound, carry or layout change lands in the
+class, once, for every device program built on it.
+
+The machine has no wide integers (SURVEY.md §7 hard part #1), so an
+element is L limbs x 15 bits in uint32 lanes (trailing axis), kept in a
+REDUNDANT representation: limbs may slightly exceed 2^15 and values may
+exceed the modulus N, inside a capacity of C = 15·L bits.  The
+redundancy is what makes the arithmetic vectorize:
 
 - products of two sub-2^16 limbs fit uint32 exactly;
 - every product is split into 15-bit hi/lo halves before accumulation, so
-  a full 27x27 schoolbook column sum stays < 2^24 — no carry chains in
+  a full LxL schoolbook column sum stays < 2^24 — no carry chains in
   the hot path;
 - ONE data-parallel carry pass (limb_k = (col_k & mask) + (col_{k-1}>>15))
-  restores the limb bound.  The capacity margin (405 representable bits
-  vs < 2^394 values) makes the top limb tiny, so the pass never spills —
-  no sequential ripple exists anywhere.
+  restores the limb bound.  The capacity margin makes the top limb tiny,
+  so the pass never spills — no sequential ripple exists anywhere.
 
 Montgomery multiplication uses the separated REDC (m = T·N' mod R;
-out = (T + m·N)/R with R = 2^405).  The carry out of the low half — the
+out = (T + m·N)/R with R = 2^C).  The carry out of the low half — the
 one place an exact carry chain seems unavoidable — is recovered from the
 divisibility invariant instead: T + mN ≡ 0 (mod R) forces the low-half
-value to be exactly 0 or R, so the carry is (S_26 >> 15) + (1 iff any low
-residue is nonzero), a vectorized reduction.
+value to be exactly 0 or R, so the carry is (S_{L-1} >> 15) + (1 iff any
+low residue is nonzero), a vectorized reduction.
 
-Subtraction adds a precomputed multiple of P whose limbs all dominate the
-redundancy bound (so no borrows), with a tiny top limb (so values stay
+Subtraction adds a precomputed multiple of N whose limbs all dominate the
+redundancy bound (so no borrows), with a small top limb (so values stay
 bounded).  Values re-enter the canonical range only at the host boundary
-(to_mont / from_mont).  Value-bound ledger (worst cases, enforced by the
-asserts in tests/test_bigint.py):
+(to_mont / from_mont).
 
-    mul out   < 2^383      add out < in + 2^393      sub out < in + 2^392
-    limbs     < 2^15 + 2^11 everywhere; top limb < 2^7
+Value-bound ledger.  n = bits of N, C = 15·L, F = C − 11 (bit 4 of the
+top limb: where ``_fold_top`` cuts).  Worst cases, enforced for both
+fields by the asserts in tests/test_bigint.py:
+
+                                             Fp (n 381, L 27)   Fr (n 255, L 18)
+    capacity C, fold bit F                   405, 394           270, 259
+    add / sub / neg out   < 2^F + e·2^n      < 2^395            < 2^260
+        (e = top limb >> 4 before the fold: <= 4 after add, <= 10 after
+        sub and neg), top limb < 2^5
+    scale_small(k <= 16) out, e < 2^5        < 2^395, top < 2^5  < 2^261, top < 2^6
+    mul out   < 2^(2(F+1) − C) + N           < 2^386            < 2^256
+        top limb 0 (Fp), <= 1 (Fr)
+    sub's constant: limbs 0..L-2 in [2^15+2^10, 2^16+2^10), top limb in
+        [2^6, 2^7) — above every top limb this table allows; pre-fold
+        values < 2^(F+4)
+    limbs     < 2^15 + 2^11 everywhere
 
 Reference counterpart: the limb arithmetic inside blst
 (/root/reference/crypto/bls/src/impls/blst.rs's FFI layer).
-
-NOTE: ops/fr.py instantiates this same construction (carry pass, REDC,
-fold, neg-const decomposition) for the 255-bit SCALAR field.  A bound or
-carry fix here almost certainly applies there too — patch both.
 """
 
 from __future__ import annotations
+
+import functools
 
 import numpy as np
 
@@ -49,110 +65,16 @@ import jax.numpy as jnp
 P_INT = 0x1A0111EA397FE69A4B1BA7B6434BACD764774B84F38512BF6730D2A0F6B0F6241EABFFFEB153FFFFB9FEFFFFFFFFAAAB
 
 B = 15                 # bits per limb
-L = 27                 # limbs (405 bits of capacity for 381-bit values)
 MASK = (1 << B) - 1
-R_BITS = B * L         # 405
-R_INT = 1 << R_BITS    # Montgomery R
 
 
-def _int_to_limbs(v: int, n: int = L) -> np.ndarray:
-    out = np.zeros(n, np.uint32)
-    for i in range(n):
-        out[i] = (v >> (B * i)) & MASK
-    assert v >> (B * n) == 0, "value does not fit"
-    return out
-
-
-def _limbs_to_int(limbs) -> int:
-    arr = np.asarray(limbs, dtype=np.uint64)
-    return sum(int(arr[..., i]) << (B * i) for i in range(arr.shape[-1]))
-
-
-# --- module constants (host-computed once) ---------------------------------
-
-P_LIMBS = _int_to_limbs(P_INT)
-# -P^{-1} mod R, for the separated Montgomery reduction
-NPRIME_INT = (-pow(P_INT, -1, R_INT)) % R_INT
-NPRIME_LIMBS = _int_to_limbs(NPRIME_INT)
-
-# Montgomery form of 1
-ONE_M = _int_to_limbs((1 * R_INT) % P_INT)
-ZERO_L = np.zeros(L, np.uint32)
-
-
-# 2^394 mod P: folds excess top-limb bits (>= bit 4 of limb 26) back into
-# range, pinning every value below ~2^395 with a single vectorized pass.
-FOLDQ_INT = (1 << (B * (L - 1) + 4)) % P_INT
-FOLDQ_LIMBS = _int_to_limbs(FOLDQ_INT)
-
-
-def _neg_const() -> np.ndarray:
-    """A multiple of P decomposed so limbs 0..25 sit in
-    [2^15+2^10, 2^16+2^10) — dominating any redundant operand limb, and a
-    full 2^15 wide so the representable set is contiguous — while the top
-    limb sits in [2^6, 2^7): above any folded value's top limb (< 2^5)
-    but small enough that values stay < 2^397 pre-fold."""
-    lo_limb = (1 << B) + (1 << 10)
-    hi_limb = lo_limb + (1 << B)  # width exactly 2^15 → contiguous
-    top_lo, top_hi = 1 << 6, 1 << 7
-    lo = top_lo << (B * (L - 1))
-    hi = (top_hi - 1) << (B * (L - 1))
-    for i in range(L - 1):
-        lo += lo_limb << (B * i)
-        hi += (hi_limb - 1) << (B * i)
-    k = lo // P_INT + 1
-    v = k * P_INT
-    assert lo <= v <= hi, "no representable multiple of P in range"
-    out = np.zeros(L, np.uint32)
-    rem = v
-    for i in range(L - 1, -1, -1):
-        unit = 1 << (B * i)
-        lo_i, hi_i = (top_lo, top_hi - 1) if i == L - 1 else (lo_limb, hi_limb - 1)
-        low_rest = sum(lo_limb << (B * j) for j in range(i))
-        hi_rest = sum((hi_limb - 1) << (B * j) for j in range(i))
-        # keep the remainder representable by the lower limbs' ranges
-        d_max = min(hi_i, (rem - low_rest) // unit)
-        d_min = max(lo_i, -((hi_rest - rem) // unit) if rem > hi_rest else lo_i)
-        d = max(d_min, min(d_max, (rem - low_rest) // unit))
-        assert lo_i <= d <= hi_i and low_rest <= rem - d * unit <= hi_rest or i == 0, (
-            i, hex(d))
-        out[i] = d
-        rem -= d * unit
-    assert rem == 0 and _limbs_to_int(out) == v
-    return out
-
-
-NEG_CONST = _neg_const()
-
-
-# --- device constants (one object per process => one jaxpr constvar) --------
-#
-# jnp.asarray(np_const) at every use site emits a fresh `constant` op per
-# trace reference (tens of thousands of lines in the Miller scan); caching
-# the jnp array gives jaxpr constvar dedup by object identity.
-
-import functools
-
-
-@functools.cache
-def _jconst(name: str) -> jax.Array:
-    # ensure_compile_time_eval: materialize a concrete array even when the
-    # first call happens inside a jit trace (else a tracer leaks into the
-    # cache and escapes its trace)
-    with jax.ensure_compile_time_eval():
-        return jnp.asarray(
-            {"p": P_LIMBS, "nprime": NPRIME_LIMBS, "foldq": FOLDQ_LIMBS,
-             "neg": NEG_CONST, "one_m": ONE_M,
-             "one_plain": _int_to_limbs(1)}[name], jnp.uint32)
-
+# --- limb-level pieces no modulus enters ------------------------------------
 
 def _set_top(x: jax.Array, top: jax.Array) -> jax.Array:
     """Replace the last limb (concat of static slices; `.at[..., -1]`
     lowers to scatter — thousands of them blew up the trace)."""
     return jnp.concatenate([x[..., :-1], top], axis=-1)
 
-
-# --- device primitives ------------------------------------------------------
 
 def _carry(cols: jax.Array) -> jax.Array:
     """One vectorized carry pass; by the value-bound ledger the top limb's
@@ -167,31 +89,9 @@ def _carry(cols: jax.Array) -> jax.Array:
     return _set_top(out, out[..., -1:] + ((cols[..., -1:] >> B) << B))
 
 
-def _fold_top(x: jax.Array) -> jax.Array:
-    """Fold top-limb bits >= 4 down via 2^394 ≡ FOLDQ (mod P): one pass,
-    no iteration — output value < 2^395, top limb < 2^5."""
-    e = x[..., -1:] >> 4
-    x = _set_top(x, x[..., -1:] & 0xF)
-    return _carry(x + e * _jconst("foldq"))
-
-
-def add(a: jax.Array, b: jax.Array) -> jax.Array:
-    return _fold_top(_carry(a + b))
-
-
-def sub(a: jax.Array, b: jax.Array) -> jax.Array:
-    """a - b + kP (NEG_CONST limbs dominate any redundant b limb)."""
-    return _fold_top(_carry(a + (_jconst("neg") - b)))
-
-
-def neg(a: jax.Array) -> jax.Array:
-    return _fold_top(_carry(_jconst("neg") - a))
-
-
-def scale_small(a: jax.Array, k: int) -> jax.Array:
-    """a·k for small positive k (k <= 16 keeps values in fold range)."""
-    assert 0 < k <= 16
-    return _fold_top(_carry(a * np.uint32(k)))
+def _shift_pad(x: jax.Array, off: int, width: int) -> jax.Array:
+    pads = [(0, 0, 0)] * (x.ndim - 1) + [(off, width - off - x.shape[-1], 0)]
+    return jax.lax.pad(x, jnp.uint32(0), pads)
 
 
 def _mul_cols(a: jax.Array, b: jax.Array, out_cols: int) -> jax.Array:
@@ -207,6 +107,7 @@ def _mul_cols(a: jax.Array, b: jax.Array, out_cols: int) -> jax.Array:
     a rewrite loop; and no per-term add chains — a 216-op chain per product
     made the Miller scan trace to ~300k StableHLO lines, VERDICT round-2.)
     """
+    L = b.shape[-1]
     rows = min(L, out_cols)
     b_stack = jnp.stack(
         [_shift_pad(b[..., : min(L, out_cols - i)], i, out_cols)
@@ -219,19 +120,14 @@ def _mul_cols(a: jax.Array, b: jax.Array, out_cols: int) -> jax.Array:
     return (lo + hi).sum(axis=-2, dtype=jnp.uint32)
 
 
-def _shift_pad(x: jax.Array, off: int, width: int) -> jax.Array:
-    pads = [(0, 0, 0)] * (x.ndim - 1) + [(off, width - off - x.shape[-1], 0)]
-    return jax.lax.pad(x, jnp.uint32(0), pads)
-
-
 # --- MXU constant-multiplicand products -------------------------------------
 #
 # Two of mont_mul's three big products have a FIXED multiplicand (N' and
-# P, the separated REDC).  A fixed c turns the schoolbook column sum
+# N, the separated REDC).  A fixed c turns the schoolbook column sum
 # into a matmul:  col_k = Σ_i a_i·c_{k-i}  =  (a @ M_c)_k  with
 # M_c[i, k] = c_{k-i} — which the TPU runs on the MXU instead of
-# materializing the [.., 27, 54] schoolbook intermediate on the VPU
-# (~20 KB of HBM traffic per product-lane; the fused BLS pipeline is
+# materializing the [.., L, 2L] schoolbook intermediate on the VPU
+# (~20 KB of HBM traffic per Fp product-lane; the fused BLS pipeline is
 # memory-bound on exactly this).  Exactness comes from int8 chunking:
 # a limbs (< 2^16) split 6|6|4 bits, c limbs (< 2^15) split 5|5|5, so
 # every dot product is ≤ 27·63·31 < 2^16 in an int32 accumulator.  The
@@ -246,93 +142,6 @@ _A_MASKS = (63, 63, 31)         # the top chunk covers limbs < 2^17 —
 #                                 m's limbs after carrying ~2^31 columns
 #                                 land just above 2^16)
 _C_SHIFTS = (0, 5, 10)          # rhs chunk bit offsets (5|5|5 split)
-
-
-def make_const_mul(limb_count: int, consts: dict[str, np.ndarray]):
-    """Factory for fixed-multiplicand column products as int8 MXU
-    matmuls — ONE copy of the exactness-critical chunk/recombination
-    construction, instantiated by the base field (L=27) and by ops/fr
-    (L=18).  Any bound or chunk-split change lands here for both.
-
-    The returned fn(a, name, out_cols): a uint32[..., limb_count] with
-    limbs < 2^17 -> uint32[..., out_cols] columns < 9·2^28 (callers
-    must _carry before further multiplies; out_cols == limb_count drops
-    the k >= L columns — the mod-radix truncation the separated REDC
-    needs).  Exact because every int8 chunk product is ≤ 63·31 and a
-    dot accumulates ≤ limb_count of them in int32."""
-
-    @functools.cache
-    def rhs(name: str, out_cols: int) -> jax.Array:
-        c = consts[name]
-        m = np.zeros((limb_count, 3 * out_cols), np.int8)
-        for j, sh in enumerate(_C_SHIFTS):
-            for i in range(limb_count):
-                for k in range(i, min(i + limb_count, out_cols)):
-                    m[i, j * out_cols + k] = (int(c[k - i]) >> sh) & 31
-        with jax.ensure_compile_time_eval():
-            return jnp.asarray(m)
-
-    def mul_cols_const(a: jax.Array, name: str,
-                       out_cols: int) -> jax.Array:
-        lhs = jnp.stack(
-            [((a >> sh) & msk).astype(jnp.int8)
-             for sh, msk in zip(_A_SHIFTS, _A_MASKS)],
-            axis=-2)                            # [..., 3, L]
-        out = jax.lax.dot_general(
-            lhs, rhs(name, out_cols),
-            dimension_numbers=(((lhs.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)   # [..., 3, 3·out]
-        out = out.astype(jnp.uint32).reshape(
-            out.shape[:-2] + (3, 3, out_cols))  # [..., i, j, out]
-        cols = jnp.zeros(out.shape[:-3] + (out_cols,), jnp.uint32)
-        for i in range(3):
-            for j in range(3):
-                q, s = divmod(_A_SHIFTS[i] + _C_SHIFTS[j], B)
-                blk = out[..., i, j, :] << s
-                if q:           # one-column shift (2^B per column)
-                    blk = jnp.concatenate(
-                        [jnp.zeros_like(blk[..., :q]), blk[..., :-q]],
-                        axis=-1)
-                cols = cols + blk
-        return cols
-
-    return mul_cols_const
-
-
-_mul_cols_const = make_const_mul(L, {"p": P_LIMBS,
-                                     "nprime": NPRIME_LIMBS})
-
-
-def _redc(t: jax.Array, mxu: bool) -> jax.Array:
-    """Separated Montgomery reduction of carried columns t (54 limbs,
-    < 2^16): out = (t + (t·N' mod R)·P) / R."""
-    if mxu:
-        m_cols = _mul_cols_const(t[..., :L], "nprime", L)
-    else:
-        m_cols = _mul_cols(t[..., :L], _jconst("nprime"), L)
-    m = _carry(m_cols)                         # limbs < 2^16 (redundant)
-    # mod R: mask ONLY the top limb (drops multiples of R = 2^405, legal;
-    # masking other limbs would change m mod R and break divisibility)
-    m = _set_top(m, m[..., -1:] & MASK)
-    if mxu:
-        mn_cols = _mul_cols_const(m, "p", 2 * L)
-        # MXU columns reach ~2^31; one value-preserving carry pass brings
-        # them under 2^17 so the 0-or-R low-half residual argument below
-        # holds (residual < R + 2^392 < 2R)
-        s = _carry(mn_cols + t)
-    else:
-        s = _mul_cols(m, _jconst("p"), 2 * L) + t  # < 2^25 ✓ uint32
-    # low half of s has value ≡ 0 (mod R): carry into the high half is
-    # (s_26 >> B) + (1 iff any low residue bits remain)
-    low_resid = jnp.concatenate(
-        [s[..., :L - 1], (s[..., L - 1:L] & MASK)], axis=-1)
-    delta = jnp.any(low_resid != 0, axis=-1, keepdims=True).astype(jnp.uint32)
-    c = (s[..., L - 1:L] >> B) + delta
-    out_cols = s[..., L:]                      # 27 columns
-    out_cols = jnp.concatenate(
-        [out_cols[..., :1] + c, out_cols[..., 1:]], axis=-1)
-    return _carry(out_cols)
-
 
 _MXU_REDC: bool | None = None
 
@@ -358,15 +167,256 @@ def _use_mxu_redc() -> bool:
     return _MXU_REDC
 
 
-def mont_mul(a: jax.Array, b: jax.Array) -> jax.Array:
-    """Montgomery product a·b·R⁻¹ (mod P, redundant representation)."""
-    t_cols = _mul_cols(a, b, 2 * L)            # 54 columns < 2^24
-    t = _carry(t_cols)                         # 54 limbs < 2^16
-    return _redc(t, _use_mxu_redc())
+class MontField:
+    """The construction of the module header for one modulus and limb
+    count: its constant tables, the device operations on redundant
+    Montgomery limb rows uint32[..., L], and the host boundary."""
+
+    def __init__(self, modulus: int, limbs: int):
+        self.P_INT = modulus
+        self.L = limbs
+        self.R_INT = 1 << (B * limbs)          # Montgomery R
+        self.R_INV = pow(self.R_INT, -1, modulus)
+        # -N^{-1} mod R, for the separated Montgomery reduction
+        self.NPRIME_INT = (-pow(modulus, -1, self.R_INT)) % self.R_INT
+        lim = self.int_to_limbs
+        #: the host limb tables, by the names `jconst` serves them under
+        self.tables: dict[str, np.ndarray] = {
+            "p": lim(modulus),
+            "nprime": lim(self.NPRIME_INT),
+            # 2^F mod N: folds excess top-limb bits (>= bit 4 of the top
+            # limb) back into range, pinning every value below ~2^(F+1)
+            # with a single vectorized pass
+            "foldq": lim((1 << (B * (limbs - 1) + 4)) % modulus),
+            "neg": self._neg_const(),
+            "one_m": lim(self.R_INT % modulus),          # Montgomery 1
+            # R^2 mod N: host -> Montgomery form by one device multiply
+            "r2": lim((self.R_INT * self.R_INT) % modulus),
+            "one_plain": lim(1)}
+        self._jconsts: dict[str, jax.Array] = {}
+        self._const_rhs: dict[tuple[str, int], jax.Array] = {}
+
+    # --- host boundary ------------------------------------------------------
+
+    def int_to_limbs(self, v: int) -> np.ndarray:
+        n = self.L
+        out = np.zeros(n, np.uint32)
+        for i in range(n):
+            out[i] = (v >> (B * i)) & MASK
+        assert v >> (B * n) == 0, "value does not fit"
+        return out
+
+    @staticmethod
+    def limbs_to_int(limbs) -> int:
+        """The value of ONE limb row (redundant limbs allowed)."""
+        return sum(x << (B * i)
+                   for i, x in enumerate(np.asarray(limbs).tolist()))
+
+    def to_mont(self, v) -> np.ndarray:
+        """int (or array / sequence of ints) -> Montgomery limb vector(s)."""
+        if isinstance(v, (int, np.integer)):
+            return self.int_to_limbs((int(v) * self.R_INT) % self.P_INT)
+        flat = [(int(x) * self.R_INT) % self.P_INT
+                for x in np.ravel(np.asarray(v, object))]
+        out = np.stack([self.int_to_limbs(x) for x in flat])
+        return out.reshape(np.shape(v) + (self.L,))
+
+    def from_mont(self, limbs) -> int | np.ndarray:
+        """Montgomery limb vector(s) -> canonical int(s)."""
+        arr = np.asarray(limbs)
+        rinv = self.R_INV
+        if arr.ndim == 1:
+            return (self.limbs_to_int(arr) * rinv) % self.P_INT
+        flat = arr.reshape(-1, arr.shape[-1])
+        vals = np.array(
+            [(self.limbs_to_int(x) * rinv) % self.P_INT for x in flat],
+            dtype=object)
+        return vals.reshape(arr.shape[:-1])
+
+    # --- constants ----------------------------------------------------------
+
+    def _neg_const(self) -> np.ndarray:
+        """A multiple of N decomposed so limbs 0..L-2 sit in
+        [2^15+2^10, 2^16+2^10) — dominating any redundant operand limb, and
+        a full 2^15 wide so the representable set is contiguous — while the
+        top limb sits in [2^6, 2^7): above any top limb the ledger allows
+        but small enough that values stay < 2^(F+4) pre-fold."""
+        L, P = self.L, self.P_INT
+        lo_limb = (1 << B) + (1 << 10)
+        hi_limb = lo_limb + (1 << B)  # width exactly 2^15 → contiguous
+        top_lo, top_hi = 1 << 6, 1 << 7
+        lo = top_lo << (B * (L - 1))
+        hi = (top_hi - 1) << (B * (L - 1))
+        for i in range(L - 1):
+            lo += lo_limb << (B * i)
+            hi += (hi_limb - 1) << (B * i)
+        k = lo // P + 1
+        v = k * P
+        assert lo <= v <= hi, "no representable multiple of N in range"
+        out = np.zeros(L, np.uint32)
+        rem = v
+        for i in range(L - 1, -1, -1):
+            unit = 1 << (B * i)
+            lo_i, hi_i = (top_lo, top_hi - 1) if i == L - 1 else (
+                lo_limb, hi_limb - 1)
+            low_rest = sum(lo_limb << (B * j) for j in range(i))
+            hi_rest = sum((hi_limb - 1) << (B * j) for j in range(i))
+            # keep the remainder representable by the lower limbs' ranges
+            d_max = min(hi_i, (rem - low_rest) // unit)
+            d_min = max(lo_i, -((hi_rest - rem) // unit) if rem > hi_rest
+                        else lo_i)
+            d = max(d_min, min(d_max, (rem - low_rest) // unit))
+            assert (lo_i <= d <= hi_i
+                    and low_rest <= rem - d * unit <= hi_rest) or i == 0, (
+                i, hex(d))
+            out[i] = d
+            rem -= d * unit
+        assert rem == 0 and self.limbs_to_int(out) == v
+        return out
+
+    def jconst(self, name: str) -> jax.Array:
+        """The device constant `name`: ONE object per field and name, so one
+        jaxpr constvar.  jnp.asarray(np_const) at every use site emits a
+        fresh `constant` op per trace reference (tens of thousands of lines
+        in the Miller scan); caching the jnp array gives jaxpr constvar
+        dedup by object identity."""
+        c = self._jconsts.get(name)
+        if c is None:
+            # ensure_compile_time_eval: materialize a concrete array even
+            # when the first call happens inside a jit trace (else a tracer
+            # leaks into the cache and escapes its trace)
+            with jax.ensure_compile_time_eval():
+                c = self._jconsts[name] = jnp.asarray(
+                    self.tables[name], jnp.uint32)
+        return c
+
+    # --- device primitives --------------------------------------------------
+
+    def _fold_top(self, x: jax.Array) -> jax.Array:
+        """Fold top-limb bits >= 4 down via 2^F ≡ `foldq` (mod N): one pass,
+        no iteration — output value < 2^(F+1), top limb < 2^5."""
+        e = x[..., -1:] >> 4
+        x = _set_top(x, x[..., -1:] & 0xF)
+        return _carry(x + e * self.jconst("foldq"))
+
+    def add(self, a: jax.Array, b: jax.Array) -> jax.Array:
+        return self._fold_top(_carry(a + b))
+
+    def sub(self, a: jax.Array, b: jax.Array) -> jax.Array:
+        """a - b + kN (the `neg` table's limbs dominate any redundant b
+        limb)."""
+        return self._fold_top(_carry(a + (self.jconst("neg") - b)))
+
+    def neg(self, a: jax.Array) -> jax.Array:
+        return self._fold_top(_carry(self.jconst("neg") - a))
+
+    def scale_small(self, a: jax.Array, k: int) -> jax.Array:
+        """a·k for small positive k (k <= 16 keeps values in fold range)."""
+        assert 0 < k <= 16
+        return self._fold_top(_carry(a * np.uint32(k)))
+
+    def _const_mul_rhs(self, name: str, out_cols: int) -> jax.Array:
+        key = (name, out_cols)
+        m_dev = self._const_rhs.get(key)
+        if m_dev is None:
+            L, c = self.L, self.tables[name]
+            m = np.zeros((L, 3 * out_cols), np.int8)
+            for j, sh in enumerate(_C_SHIFTS):
+                for i in range(L):
+                    for k in range(i, min(i + L, out_cols)):
+                        m[i, j * out_cols + k] = (int(c[k - i]) >> sh) & 31
+            with jax.ensure_compile_time_eval():
+                m_dev = self._const_rhs[key] = jnp.asarray(m)
+        return m_dev
+
+    def _const_mul_cols(self, a: jax.Array, name: str,
+                        out_cols: int) -> jax.Array:
+        """Fixed-multiplicand column product as int8 MXU matmuls (the
+        construction above `_A_SHIFTS`).  a: uint32[..., L] with limbs
+        < 2^17 -> uint32[..., out_cols] columns < 9·2^28 (callers must
+        _carry before further multiplies; out_cols == L drops the k >= L
+        columns — the mod-radix truncation the separated REDC needs).
+        Exact because every int8 chunk product is ≤ 63·31 and a dot
+        accumulates ≤ L of them in int32."""
+        lhs = jnp.stack(
+            [((a >> sh) & msk).astype(jnp.int8)
+             for sh, msk in zip(_A_SHIFTS, _A_MASKS)],
+            axis=-2)                            # [..., 3, L]
+        out = jax.lax.dot_general(
+            lhs, self._const_mul_rhs(name, out_cols),
+            dimension_numbers=(((lhs.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32)   # [..., 3, 3·out]
+        out = out.astype(jnp.uint32).reshape(
+            out.shape[:-2] + (3, 3, out_cols))  # [..., i, j, out]
+        cols = jnp.zeros(out.shape[:-3] + (out_cols,), jnp.uint32)
+        for i in range(3):
+            for j in range(3):
+                q, s = divmod(_A_SHIFTS[i] + _C_SHIFTS[j], B)
+                blk = out[..., i, j, :] << s
+                if q:           # one-column shift (2^B per column)
+                    blk = jnp.concatenate(
+                        [jnp.zeros_like(blk[..., :q]), blk[..., :-q]],
+                        axis=-1)
+                cols = cols + blk
+        return cols
+
+    def _redc(self, t: jax.Array, mxu: bool) -> jax.Array:
+        """Separated Montgomery reduction of carried columns t (2L limbs,
+        < 2^16): out = (t + (t·N' mod R)·N) / R."""
+        L = self.L
+        if mxu:
+            m_cols = self._const_mul_cols(t[..., :L], "nprime", L)
+        else:
+            m_cols = _mul_cols(t[..., :L], self.jconst("nprime"), L)
+        m = _carry(m_cols)                         # limbs < 2^16 (redundant)
+        # mod R: mask ONLY the top limb (drops multiples of R, legal;
+        # masking other limbs would change m mod R and break divisibility)
+        m = _set_top(m, m[..., -1:] & MASK)
+        if mxu:
+            mn_cols = self._const_mul_cols(m, "p", 2 * L)
+            # MXU columns reach ~2^31; one value-preserving carry pass brings
+            # them under 2^17 so the 0-or-R low-half residual argument below
+            # holds (residual < R + 2^(C-13) < 2R)
+            s = _carry(mn_cols + t)
+        else:
+            s = _mul_cols(m, self.jconst("p"), 2 * L) + t  # < 2^25 ✓ uint32
+        # low half of s has value ≡ 0 (mod R): carry into the high half is
+        # (s_{L-1} >> B) + (1 iff any low residue bits remain)
+        low_resid = jnp.concatenate(
+            [s[..., :L - 1], (s[..., L - 1:L] & MASK)], axis=-1)
+        delta = jnp.any(low_resid != 0, axis=-1,
+                        keepdims=True).astype(jnp.uint32)
+        c = (s[..., L - 1:L] >> B) + delta
+        out_cols = s[..., L:]                      # L columns
+        out_cols = jnp.concatenate(
+            [out_cols[..., :1] + c, out_cols[..., 1:]], axis=-1)
+        return _carry(out_cols)
+
+    def mont_mul(self, a: jax.Array, b: jax.Array) -> jax.Array:
+        """Montgomery product a·b·R⁻¹ (mod N, redundant representation)."""
+        t_cols = _mul_cols(a, b, 2 * self.L)       # 2L columns < 2^24
+        t = _carry(t_cols)                         # 2L limbs < 2^16
+        return self._redc(t, _use_mxu_redc())
 
 
-def mont_sqr(a: jax.Array) -> jax.Array:
-    return mont_mul(a, a)
+# --- the base field, under the names its callers use -------------------------
+
+FP = MontField(P_INT, 27)      # 405 bits of capacity for 381-bit values
+
+L = FP.L
+R_INT = FP.R_INT
+ONE_M = FP.tables["one_m"]
+
+_int_to_limbs = FP.int_to_limbs
+_limbs_to_int = FP.limbs_to_int
+_jconst = FP.jconst
+add = FP.add
+sub = FP.sub
+neg = FP.neg
+scale_small = FP.scale_small
+mont_mul = FP.mont_mul
+to_mont = FP.to_mont
+from_mont = FP.from_mont
 
 
 # --- device-side canonical tests --------------------------------------------
@@ -397,10 +447,9 @@ def canon_digits(x: jax.Array) -> jax.Array:
 
 @functools.cache
 def _kp_digit_consts() -> jax.Array:
-    """Digit vectors of {0, P, 2P, 3P, 4P}: every multiple of P up to and
-    including the 2^383 mont_mul output bound (4P ≈ 2^382.7 — included
-    for margin even though the only caller multiplies by plain 1, whose
-    output is far smaller)."""
+    """Digit vectors of {0, P, 2P, 3P, 4P}: every multiple of P a
+    mont_mul by plain 1 can return (x·R⁻¹ + P < 2P for x < R·P; the
+    rest is margin, which tests/test_bigint.py holds to < 5P)."""
     with jax.ensure_compile_time_eval():
         return jnp.asarray(
             np.stack([_int_to_limbs(k * P_INT) for k in range(5)]),
@@ -411,32 +460,9 @@ def is_zero_mod_p_device(x: jax.Array) -> jax.Array:
     """Per-lane x ≡ 0 (mod P) for redundant limb rows, ON DEVICE.
 
     Lowers x through one Montgomery mul by plain 1 (out ≡ x·R⁻¹ mod P,
-    value ≤ the 2^383 mul bound), canonicalizes, and compares against
-    every multiple of P up to that bound.  x ≡ 0 ⟺ x·R⁻¹ ≡ 0 (R
-    invertible).  Returns bool[...] (limb axis reduced)."""
+    value < 5P), canonicalizes, and compares against every multiple of
+    P below that.  x ≡ 0 ⟺ x·R⁻¹ ≡ 0 (R invertible).  Returns
+    bool[...] (limb axis reduced)."""
     w = mont_mul(x, jnp.broadcast_to(_jconst("one_plain"), x.shape))
     d = canon_digits(w)
     return (d[..., None, :] == _kp_digit_consts()).all(-1).any(-1)
-
-
-# --- host boundary ----------------------------------------------------------
-
-def to_mont(v: int | np.ndarray) -> np.ndarray:
-    """int (or array of ints) -> Montgomery limb vector(s)."""
-    if isinstance(v, (int, np.integer)):
-        return _int_to_limbs((int(v) * R_INT) % P_INT)
-    flat = [(int(x) * R_INT) % P_INT for x in np.ravel(np.asarray(v, object))]
-    out = np.stack([_int_to_limbs(x) for x in flat])
-    return out.reshape(np.shape(v) + (L,))
-
-
-def from_mont(limbs) -> int | np.ndarray:
-    """Montgomery limb vector(s) -> canonical int(s)."""
-    arr = np.asarray(limbs)
-    rinv = pow(R_INT, -1, P_INT)
-    if arr.ndim == 1:
-        return (_limbs_to_int(arr) * rinv) % P_INT
-    flat = arr.reshape(-1, arr.shape[-1])
-    vals = np.array(
-        [(_limbs_to_int(x) * rinv) % P_INT for x in flat], dtype=object)
-    return vals.reshape(arr.shape[:-1])

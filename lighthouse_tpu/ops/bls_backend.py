@@ -42,7 +42,7 @@ from lighthouse_tpu.crypto.bls import api, curve as cv
 from lighthouse_tpu.ops import program_store as _pstore
 
 # AOT program-store coverage (lhlint LH606): the fused verify plane is
-# prewarmed by the "bls" driver, the final-exp ladder by "pairing"
+# prewarmed by the "bls" driver
 _pstore.register_entry("ops/bls_backend.py::_pipeline_fused@_pipeline_fused",
                        driver="bls")
 _pstore.register_entry(
@@ -51,17 +51,13 @@ _pstore.register_entry(
 _pstore.register_entry(
     "ops/bls_backend.py::_g1_subgroup_kernel@_g1_subgroup_kernel",
     driver="bls")
-_pstore.register_entry("ops/bls_backend.py::<module>@final_exp_hard_device",
-                       driver="pairing")
 from lighthouse_tpu.ops import bigint as bi
 from lighthouse_tpu.ops import ec
 from lighthouse_tpu.ops import msm as _msm
 from lighthouse_tpu.ops import faults
 from lighthouse_tpu.ops.bls12_381 import (
     batch_miller_loop,
-    final_exp_hard_device,
     fq12_from_device,
-    fq12_to_device,
     multi_pairing_device,
     reduce_product,
 )
@@ -417,15 +413,6 @@ def _dispatch_subgroup_check(sigs):
     return dp.AsyncVerdict(dev_ok, len(pts), on_pass=mark)
 
 
-def _ensure_subgroup_checked(sigs) -> bool:
-    """Batch-check any signatures whose G2 membership is still pending,
-    synchronously.  Returns False if any fails (callers bisect to
-    attribute).  The pipeline uses the async form above; this wrapper
-    remains for callers that need the verdict immediately."""
-    verdict = _dispatch_subgroup_check(sigs)
-    return verdict is not None and verdict.commit()
-
-
 def _g2_limbs(points) -> list[np.ndarray]:
     return [ec.ints_to_mont_limbs(v) for v in (
         [p[0].a for p in points], [p[0].b for p in points],
@@ -443,42 +430,13 @@ def _g1_neg_limbs():
     return _G1_NEG_LIMBS
 
 
-_final_exp_hard_jit = jax.jit(final_exp_hard_device)
-_final_exp_hard_jit = _dtel.instrument(
-    "ops/bls_backend.py::<module>@final_exp_hard_device",
-    _final_exp_hard_jit)
-_DEVICE_FINAL_EXP: bool | None = None
-
-
-def _use_device_final_exp() -> bool:
-    """Hard part on device on TPU (it removes the ~32 ms host Python tail
-    from the batch critical path); XLA-CPU runs the limb ladder slower
-    than host Python, so the CPU fallback keeps the host path.
-    Override with LHTPU_DEVICE_FINAL_EXP=0/1."""
-    global _DEVICE_FINAL_EXP
-    if _DEVICE_FINAL_EXP is None:
-        from lighthouse_tpu.common import env as envreg
-
-        env = envreg.get("LHTPU_DEVICE_FINAL_EXP")
-        if env is not None:
-            _DEVICE_FINAL_EXP = env.lower() in ("1", "true")
-        else:
-            _DEVICE_FINAL_EXP = jax.devices()[0].platform == "tpu"
-    return _DEVICE_FINAL_EXP
-
-
 def _final_exp_is_one(f_host) -> bool:
     """Full final exponentiation of the batch product, result == 1?
 
-    Path order (round-4 TPU ledger, BLS_LEDGER_TPU_r04.json): native C++
-    (~ms) > host python (~32 ms) > device single-lane ladder (measured
-    1.9 s on the v5e — one lane through a 315-step sequential scan keeps
-    the device idle; it only made sense before the native layer)."""
-    from lighthouse_tpu.crypto.bls.fields import (
-        Fq12,
-        final_exp_easy,
-        final_exponentiation_fast,
-    )
+    Native C++ (~4 ms on the chip's host), else host Python (~32 ms);
+    never the device: one lane through a 315-step sequential scan leaves
+    it idle (1.9 s measured on a v5e, BLS_LEDGER_TPU_r04.json)."""
+    from lighthouse_tpu.crypto.bls.fields import final_exponentiation_fast
 
     try:
         from lighthouse_tpu.ops import native_bls
@@ -489,11 +447,7 @@ def _final_exp_is_one(f_host) -> bool:
         from lighthouse_tpu.common.metrics import record_swallowed
 
         record_swallowed("bls_backend.native_final_exp", e)
-    if not _use_device_final_exp():
-        return final_exponentiation_fast(f_host).is_one()
-    m = final_exp_easy(f_host)        # one host inversion (~µs, ext-gcd)
-    out = _final_exp_hard_jit(fq12_to_device(m))
-    return fq12_from_device(jax.device_get(out)) == Fq12.ONE
+    return final_exponentiation_fast(f_host).is_one()
 
 
 def verify_sets_pipeline(sets: Sequence[api.SignatureSet],

@@ -1,16 +1,12 @@
-"""Batched arithmetic in the BLS12-381 SCALAR field Fr on TPU.
+"""What is the BLS12-381 SCALAR field Fr's own on TPU: inversion, the
+blob byte layout and the KZG barycentric evaluation.
 
-Same limb scheme as ops/bigint.py (which covers the 381-bit BASE field):
-15-bit limbs in uint32 lanes, redundant representation, one data-parallel
-carry pass, separated-REDC Montgomery multiplication.  Fr's modulus
+The field arithmetic itself — limbs, carry pass, REDC, the value-bound
+ledger — is ops/bigint.py's ``MontField``, instantiated here for
 
     R = 0x73eda753299d7d483339d80809a1d80553bda402fffe5bfeffffffff00000001
 
-is 255 bits, so elements are 18 limbs (270 bits of capacity) and the
-Montgomery radix is 2^270.  Value-bound ledger (mirrors bigint.py's):
-
-    mul out < 2^257    add out < in + 2^258    fold keeps values < 2^260
-    limbs < 2^15 + 2^11; top limb < 2^5 — capacity margin 270-260 = 10 bits
+(255 bits: 18 limbs of 15 bits, Montgomery radix 2^270).
 
 The headline consumer is KZG batch verification
 (/root/reference/crypto/kzg/src/lib.rs:105-131): the per-blob barycentric
@@ -28,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from lighthouse_tpu.common import device_telemetry as _dtel
+from lighthouse_tpu.ops import bigint as _bigint
 from lighthouse_tpu.ops import program_store as _pstore
 
 # AOT program-store coverage (lhlint LH606): the barycentric-eval plane
@@ -38,198 +35,20 @@ _pstore.register_entry("ops/fr.py::_to_mont_kernel@_to_mont_kernel",
 
 R_INT = 0x73EDA753299D7D483339D80809A1D80553BDA402FFFE5BFEFFFFFFFF00000001
 
-B = 15
-L = 18
-MASK = (1 << B) - 1
-RADIX_BITS = B * L            # 270
-RADIX = 1 << RADIX_BITS       # Montgomery radix for Fr
+# the construction of ops/bigint.py for Fr: 18 limbs, 270 bits of capacity
+FR = _bigint.MontField(R_INT, 18)
 
-
-def _int_to_limbs(v: int, n: int = L) -> np.ndarray:
-    out = np.zeros(n, np.uint32)
-    for i in range(n):
-        out[i] = (v >> (B * i)) & MASK
-    assert v >> (B * n) == 0, "value does not fit"
-    return out
-
-
-def _limbs_to_int(limbs) -> int:
-    return sum(int(x) << (B * i) for i, x in enumerate(np.asarray(limbs)))
-
-
-R_LIMBS = _int_to_limbs(R_INT)
-NPRIME_INT = (-pow(R_INT, -1, RADIX)) % RADIX
-NPRIME_LIMBS = _int_to_limbs(NPRIME_INT)
-# top-limb fold: 2^(17·15+4) = 2^259 ≡ FOLD (mod R)
-FOLD_INT = (1 << 259) % R_INT
-FOLD_LIMBS = _int_to_limbs(FOLD_INT)
-ONE_M = _int_to_limbs(RADIX % R_INT)          # 1 in Montgomery form
-R2_INT = (RADIX * RADIX) % R_INT              # for host->Mont via one mul
-R2_LIMBS = _int_to_limbs(R2_INT)
-
-_CONSTS: dict[str, jax.Array] = {}
-
-
-def _jconst(name: str) -> jax.Array:
-    c = _CONSTS.get(name)
-    if c is None:
-        # the first call may land inside a jit trace: materialize the
-        # constant OUTSIDE the trace or the cached value is a leaked
-        # tracer (poisons every later trace)
-        with jax.ensure_compile_time_eval():
-            c = _CONSTS[name] = jnp.asarray(
-                {"r": R_LIMBS, "nprime": NPRIME_LIMBS, "fold": FOLD_LIMBS,
-                 "one_m": ONE_M, "r2": R2_LIMBS}[name], jnp.uint32)
-    return c
-
-
-def _set_top(x: jax.Array, top: jax.Array) -> jax.Array:
-    return jnp.concatenate([x[..., :-1], top], axis=-1)
-
-
-def _carry(cols: jax.Array) -> jax.Array:
-    hi = cols >> B
-    lo = cols & MASK
-    shifted = jnp.concatenate(
-        [jnp.zeros_like(hi[..., :1]), hi[..., :-1]], axis=-1)
-    out = lo + shifted
-    return _set_top(out, out[..., -1:] + ((cols[..., -1:] >> B) << B))
-
-
-def _fold_top(x: jax.Array) -> jax.Array:
-    """2^259 ≡ FOLD (mod R): push top-limb bits >= 4 back down."""
-    e = x[..., -1:] >> 4
-    x = _set_top(x, x[..., -1:] & 0xF)
-    return _carry(x + e * _jconst("fold"))
-
-
-def add(a: jax.Array, b: jax.Array) -> jax.Array:
-    return _fold_top(_carry(a + b))
-
-
-# subtraction support: a - b + k·R with k·R decomposed so limbs 0..L-2
-# sit in [2^15+2^10, 2^16+2^10) — dominating any redundant operand limb —
-# and the top limb in [2^6, 2^7): same construction (and same bound
-# proof) as bigint._neg_const, instantiated for R.
-def _neg_const() -> np.ndarray:
-    lo_limb = (1 << B) + (1 << 10)
-    hi_limb = lo_limb + (1 << B)
-    top_lo, top_hi = 1 << 6, 1 << 7
-    lo = top_lo << (B * (L - 1))
-    hi = (top_hi - 1) << (B * (L - 1))
-    for i in range(L - 1):
-        lo += lo_limb << (B * i)
-        hi += (hi_limb - 1) << (B * i)
-    k = lo // R_INT + 1
-    v = k * R_INT
-    assert lo <= v <= hi, "no representable multiple of R in range"
-    out = np.zeros(L, np.uint32)
-    rem = v
-    for i in range(L - 1, -1, -1):
-        unit = 1 << (B * i)
-        lo_i, hi_i = (top_lo, top_hi - 1) if i == L - 1 else (
-            lo_limb, hi_limb - 1)
-        low_rest = sum(lo_limb << (B * j) for j in range(i))
-        hi_rest = sum((hi_limb - 1) << (B * j) for j in range(i))
-        d_max = min(hi_i, (rem - low_rest) // unit)
-        d_min = max(lo_i, -((hi_rest - rem) // unit) if rem > hi_rest
-                    else lo_i)
-        d = max(d_min, min(d_max, (rem - low_rest) // unit))
-        assert (lo_i <= d <= hi_i
-                and low_rest <= rem - d * unit <= hi_rest) or i == 0, (
-            i, hex(d))
-        out[i] = d
-        rem -= d * unit
-    assert rem == 0 and _limbs_to_int(out) == v
-    return out
-
-
-NEG_CONST = _neg_const()
-
-
-def sub(a: jax.Array, b: jax.Array) -> jax.Array:
-    neg = jnp.asarray(NEG_CONST, jnp.uint32)
-    return _fold_top(_carry(a + (neg - b)))
-
-
-def _shift_pad(x: jax.Array, off: int, width: int) -> jax.Array:
-    pads = [(0, 0, 0)] * (x.ndim - 1) + [(off, width - off - x.shape[-1], 0)]
-    return jax.lax.pad(x, jnp.uint32(0), pads)
-
-
-def _mul_cols(a: jax.Array, b: jax.Array, out_cols: int) -> jax.Array:
-    rows = min(L, out_cols)
-    b_stack = jnp.stack(
-        [_shift_pad(b[..., : min(L, out_cols - i)], i, out_cols)
-         for i in range(rows)], axis=-2)
-    p = a[..., :rows, None] * b_stack
-    lo = p & MASK
-    hi = p >> B
-    hi = jnp.concatenate(
-        [jnp.zeros_like(hi[..., :1]), hi[..., :-1]], axis=-1)
-    return (lo + hi).sum(axis=-2, dtype=jnp.uint32)
-
-
-# MXU constant-multiplicand REDC: the int8-chunk matmul construction is
-# shared with the base field — ONE implementation in
-# bigint.make_const_mul (same B; this module only supplies its limb
-# count and constant tables).  Fr is the KZG batch verifier's hot field
-# (per-blob barycentric evaluation lanes).
-
-from lighthouse_tpu.ops.bigint import make_const_mul as _make_const_mul
-
-_mul_cols_const = _make_const_mul(L, {"r": R_LIMBS,
-                                      "nprime": NPRIME_LIMBS})
-
-
-def _redc(t: jax.Array, mxu: bool) -> jax.Array:
-    if mxu:
-        m_cols = _mul_cols_const(t[..., :L], "nprime", L)
-    else:
-        m_cols = _mul_cols(t[..., :L], _jconst("nprime"), L)
-    m = _carry(m_cols)
-    m = _set_top(m, m[..., -1:] & MASK)
-    if mxu:
-        s = _carry(_mul_cols_const(m, "r", 2 * L) + t)
-    else:
-        s = _mul_cols(m, _jconst("r"), 2 * L) + t
-    low_resid = jnp.concatenate(
-        [s[..., :L - 1], (s[..., L - 1:L] & MASK)], axis=-1)
-    delta = jnp.any(low_resid != 0, axis=-1, keepdims=True).astype(jnp.uint32)
-    c = (s[..., L - 1:L] >> B) + delta
-    out_cols = s[..., L:]
-    out_cols = jnp.concatenate(
-        [out_cols[..., :1] + c, out_cols[..., 1:]], axis=-1)
-    return _carry(out_cols)
-
-
-def mont_mul(a: jax.Array, b: jax.Array) -> jax.Array:
-    """a·b·RADIX⁻¹ (mod R), redundant representation."""
-    from lighthouse_tpu.ops.bigint import _use_mxu_redc
-
-    t_cols = _mul_cols(a, b, 2 * L)
-    t = _carry(t_cols)
-    return _redc(t, _use_mxu_redc())
-
-
-# --- host boundary ----------------------------------------------------------
-
-def to_mont_host(v) -> np.ndarray:
-    if isinstance(v, (int, np.integer)):
-        return _int_to_limbs((int(v) * RADIX) % R_INT)
-    return np.stack(
-        [_int_to_limbs((int(x) * RADIX) % R_INT) for x in v])
-
-
-def from_mont_host(limbs) -> np.ndarray:
-    arr = np.asarray(limbs)
-    rinv = pow(RADIX, -1, R_INT)
-    if arr.ndim == 1:
-        return (_limbs_to_int(arr) * rinv) % R_INT
-    flat = arr.reshape(-1, arr.shape[-1])
-    vals = np.array(
-        [(_limbs_to_int(x) * rinv) % R_INT for x in flat], dtype=object)
-    return vals.reshape(arr.shape[:-1])
+B = _bigint.B
+MASK = _bigint.MASK
+L = FR.L
+_int_to_limbs = FR.int_to_limbs
+_limbs_to_int = FR.limbs_to_int
+_jconst = FR.jconst
+add = FR.add
+sub = FR.sub
+mont_mul = FR.mont_mul
+to_mont_host = FR.to_mont
+from_mont_host = FR.from_mont
 
 
 # field elements one pass of be32_bytes_to_limbs converts: the word

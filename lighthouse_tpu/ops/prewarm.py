@@ -158,21 +158,14 @@ def _drv_bls(scale: str) -> None:
 
 def _drv_pairing(scale: str) -> None:
     """The pairing plane outside the fused pipeline: multi-pairing
-    Miller+reduce, the chunk-combine Fq12 kernel, the device
-    final-exponentiation ladder."""
-    import jax
-
+    Miller+reduce and the chunk-combine Fq12 kernel."""
     from lighthouse_tpu.crypto.bls import curve as cv
-    from lighthouse_tpu.crypto.bls.fields import final_exp_easy
     from lighthouse_tpu.ops import bls12_381 as b381
-    from lighthouse_tpu.ops import bls_backend as bb
     from lighthouse_tpu.ops import dispatch_pipeline as dp
 
     f = b381.multi_pairing_device([(cv.g1_generator(), cv.g2_generator())])
     dev = b381.fq12_to_device(f)
     dp.combine_partials([dev, dev])
-    m = final_exp_easy(f)
-    jax.device_get(bb._final_exp_hard_jit(b381.fq12_to_device(m)))
 
 
 def _drv_sharded(scale: str) -> None:

@@ -807,10 +807,6 @@ def mix_in_length(root: bytes, length: int) -> bytes:
     return hashlib.sha256(root + length.to_bytes(32, "little")).digest()
 
 
-def mix_in_selector(root: bytes, selector: int) -> bytes:
-    return hashlib.sha256(root + selector.to_bytes(32, "little")).digest()
-
-
 def sha256(data: bytes) -> bytes:
     """Host one-shot SHA-256 (control-plane use)."""
     return hashlib.sha256(data).digest()

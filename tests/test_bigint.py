@@ -1,8 +1,11 @@
-"""Limb-arithmetic tests: device Fp ops vs Python bigints.
+"""Limb-arithmetic tests: the device field construction (ops/bigint's
+MontField) vs Python bigints, for BOTH of its instances — the base field
+Fp and the scalar field Fr.
 
 The differential oracle strategy from SURVEY.md §7 gate (b): every device
 op is checked against plain modular integers, including bound-stressing
-chains and edge values.
+chains and edge values.  The asserts on limbs and top limbs are the
+value-bound ledger of bigint.py's header, enforced.
 """
 
 import random
@@ -14,146 +17,227 @@ import jax
 import jax.numpy as jnp
 
 from lighthouse_tpu.ops import bigint as bi
+from lighthouse_tpu.ops import fr
 
 P = bi.P_INT
 
+# the ledger's limb bound, and its top-limb bounds
+LIMB_BOUND = (1 << 15) + (1 << 11)
+TOP_BOUND = 1 << 5
 
-def _batch(vals):
-    return jnp.asarray(np.stack([bi.to_mont(v) for v in vals]))
+
+@pytest.fixture(scope="module", params=["fp", "fr"])
+def field(request):
+    return {"fp": bi.FP, "fr": fr.FR}[request.param]
+
+
+def _batch(vals, F):
+    return jnp.asarray(F.to_mont(vals))
+
+
+def _rand(F):
+    rng = random.Random(7)
+    xs = [rng.randrange(F.P_INT) for _ in range(32)]
+    ys = [rng.randrange(F.P_INT) for _ in range(32)]
+    return xs, ys
+
+
+def _edge(F):
+    N = F.P_INT
+    return [0, 1, 2, N - 1, N - 2, (N + 1) // 2,
+            (1 << (N.bit_length() - 1)) % N, 12345]
 
 
 @pytest.fixture(scope="module")
 def rand_vals():
-    random.seed(7)
-    xs = [random.randrange(P) for _ in range(32)]
-    ys = [random.randrange(P) for _ in range(32)]
-    return xs, ys
+    return _rand(bi.FP)
 
 
-def test_constants():
-    assert bi._limbs_to_int(bi.P_LIMBS) == P
-    assert (bi._limbs_to_int(bi.NEG_CONST)) % P == 0
-    assert (bi.NPRIME_INT * P) % bi.R_INT == bi.R_INT - 1
-    assert bi._limbs_to_int(bi.FOLDQ_LIMBS) == (1 << 394) % P
+def _ints(F, limbs):
+    return [int(g) for g in F.from_mont(np.asarray(limbs))]
 
 
-def test_roundtrip(rand_vals):
-    xs, _ = rand_vals
-    for x in xs[:8]:
-        assert bi.from_mont(bi.to_mont(x)) == x
+def _in_ledger(z, top_bound):
+    z = np.asarray(z)
+    return z.max() < LIMB_BOUND and z[..., -1].max() < top_bound
 
 
-def test_mont_mul(rand_vals):
-    xs, ys = rand_vals
-    out = np.asarray(jax.jit(bi.mont_mul)(_batch(xs), _batch(ys)))
-    got = bi.from_mont(out)
-    assert all(int(g) == (x * y) % P for g, x, y in zip(got, xs, ys))
-    # limb bound invariant
-    assert out.max() < (1 << 15) + (1 << 12)
+def test_constants(field):
+    F, N, L = field, field.P_INT, field.L
+    assert F.R_INT == 1 << (bi.B * L)
+    # the fold bit F = C - 11 sits at least 4 bits over the modulus
+    assert bi.B * L - 11 - N.bit_length() >= 4
+    assert F.limbs_to_int(F.tables["p"]) == N
+    assert (F.NPRIME_INT * N) % F.R_INT == F.R_INT - 1
+    assert F.limbs_to_int(F.tables["nprime"]) == F.NPRIME_INT
+    assert F.limbs_to_int(F.tables["foldq"]) == (
+        1 << (bi.B * (L - 1) + 4)) % N
+    assert F.limbs_to_int(F.tables["one_m"]) == F.R_INT % N
+    assert F.limbs_to_int(F.tables["r2"]) == F.R_INT ** 2 % N
+    # sub's constant: a multiple of N whose limbs dominate any operand's
+    neg = F.tables["neg"]
+    assert F.limbs_to_int(neg) % N == 0
+    assert neg[:-1].min() >= LIMB_BOUND - (1 << 10)
+    assert neg[:-1].max() < (1 << 16) + (1 << 10)
+    assert (1 << 6) <= neg[-1] < (1 << 7)
+    # one device object a name: a second trace reference adds no constant
+    assert F.jconst("neg") is F.jconst("neg")
 
 
-def test_add_sub_neg(rand_vals):
-    xs, ys = rand_vals
-    ax, ay = _batch(xs), _batch(ys)
-    assert all(int(g) == (x + y) % P for g, x, y in
-               zip(bi.from_mont(np.asarray(bi.add(ax, ay))), xs, ys))
-    assert all(int(g) == (x - y) % P for g, x, y in
-               zip(bi.from_mont(np.asarray(bi.sub(ax, ay))), xs, ys))
-    assert all(int(g) == (-x) % P for g, x in
-               zip(bi.from_mont(np.asarray(bi.neg(ax))), xs))
+def test_module_names_are_the_instances():
+    """The names callers use are bound to the two instances."""
+    assert (bi.L, fr.L) == (27, 18) and bi.B == fr.B == 15
+    assert bi.R_INT == 1 << 405 and bi.FP.P_INT == P
+    assert fr.FR.P_INT == fr.R_INT and fr.FR.R_INT == 1 << 270
+    assert bi.mont_mul == bi.FP.mont_mul and fr.sub == fr.FR.sub
+    assert fr.to_mont_host == fr.FR.to_mont and bi.ONE_M is bi.FP.tables["one_m"]
 
 
-def test_scale_small(rand_vals):
-    xs, _ = rand_vals
-    ax = _batch(xs)
+def test_roundtrip(field):
+    F = field
+    xs, _ = _rand(F)
+    for x in xs[:8] + _edge(F):
+        assert F.from_mont(F.to_mont(x)) == x
+
+
+def test_host_boundary_takes_scalars_and_arrays(field):
+    """to_mont / from_mont: one implementation for an int, a list and an
+    array of any rank."""
+    F = field
+    xs, _ = _rand(F)
+    one = F.to_mont(xs[0])
+    assert one.shape == (F.L,) and one.dtype == np.uint32
+    assert isinstance(F.from_mont(one), int)
+    rows = F.to_mont(xs[:6])
+    assert rows.shape == (6, F.L) and (rows[0] == one).all()
+    grid = F.to_mont(np.array(xs[:6], dtype=object).reshape(2, 3))
+    assert grid.shape == (2, 3, F.L)
+    back = F.from_mont(grid)
+    assert back.shape == (2, 3) and back.ravel().tolist() == xs[:6]
+    assert (F.int_to_limbs(xs[0]) < (1 << 15)).all()
+    with pytest.raises(AssertionError):
+        F.int_to_limbs(F.R_INT)
+
+
+def test_mont_mul(field):
+    F, N = field, field.P_INT
+    xs, ys = _rand(F)
+    out = np.asarray(jax.jit(F.mont_mul)(_batch(xs, F), _batch(ys, F)))
+    assert _ints(F, out) == [(x * y) % N for x, y in zip(xs, ys)]
+    # mul out < 2^(C-19): nothing reaches the top limb's bit 4
+    assert _in_ledger(out, 1 << 4)
+
+
+def test_add_sub_neg(field):
+    F, N = field, field.P_INT
+    xs, ys = _rand(F)
+    ax, ay = _batch(xs, F), _batch(ys, F)
+    s, d, n = F.add(ax, ay), jax.jit(F.sub)(ax, ay), F.neg(ax)
+    assert _ints(F, s) == [(x + y) % N for x, y in zip(xs, ys)]
+    assert _ints(F, d) == [(x - y) % N for x, y in zip(xs, ys)]
+    assert _ints(F, n) == [(-x) % N for x in xs]
+    assert all(_in_ledger(z, TOP_BOUND) for z in (s, d, n))
+
+
+def test_scale_small(field):
+    F, N = field, field.P_INT
+    xs, _ = _rand(F)
+    ax = _batch(xs, F)
     for k in (2, 3, 8, 16):
-        got = bi.from_mont(np.asarray(bi.scale_small(ax, k)))
-        assert all(int(g) == (k * x) % P for g, x in zip(got, xs))
+        got = F.scale_small(ax, k)
+        assert _ints(F, got) == [(k * x) % N for x in xs]
+    # the ledger's one two-sided line: Fr's fold bit sits 4 bits over its
+    # modulus, so sixteen folded values reach the top limb's bit 5
+    top = TOP_BOUND if F is bi.FP else 2 * TOP_BOUND
+    z = F.neg(ax)                       # a fold output, not a canonical one
+    for _ in range(4):
+        z = F.scale_small(z, 16)
+        assert _in_ledger(z, top)
+    assert _ints(F, z) == [(-(16 ** 4) * x) % N for x in xs]
+    assert _in_ledger(F.sub(ax, z), TOP_BOUND)
+    assert _in_ledger(F.add(z, z), TOP_BOUND)
 
 
-def test_edge_values():
-    edge = [0, 1, 2, P - 1, P - 2, (P + 1) // 2, (1 << 380) % P]
-    ae = _batch(edge)
-    got = bi.from_mont(np.asarray(bi.mont_mul(ae, ae)))
-    assert all(int(g) == (x * x) % P for g, x in zip(got, edge))
-    z = bi.from_mont(np.asarray(bi.sub(ae, ae)))
-    assert all(int(g) == 0 for g in z)
+def test_edge_values(field):
+    F, N = field, field.P_INT
+    edge = _edge(F)
+    ae = _batch(edge, F)
+    assert _ints(F, jax.jit(F.mont_mul)(ae, ae)) == [
+        (x * x) % N for x in edge]
+    assert _ints(F, F.sub(ae, ae)) == [0] * len(edge)
 
 
-def test_deep_chain_keeps_bounds(rand_vals):
+def test_deep_chain_keeps_bounds(field):
     """60 rounds of mul/sub/add/neg: redundant-representation invariants
-    hold and values stay exact."""
-    xs, ys = rand_vals
-    ax, ay = _batch(xs), _batch(ys)
-    mm = jax.jit(bi.mont_mul)
+    hold after every op and values stay exact."""
+    F, N = field, field.P_INT
+    xs, ys = _rand(F)
+    ax, ay = _batch(xs, F), _batch(ys, F)
+    mm = jax.jit(F.mont_mul)
     z, zv = ax, list(xs)
-    maxlimb = 0
     for _ in range(60):
         z = mm(z, ay)
-        zv = [(a * b) % P for a, b in zip(zv, ys)]
-        z = bi.sub(z, ax)
-        zv = [(a - b) % P for a, b in zip(zv, xs)]
-        z = bi.add(z, z)
-        zv = [(2 * a) % P for a in zv]
-        z = bi.neg(z)
-        zv = [(-a) % P for a in zv]
-        maxlimb = max(maxlimb, int(np.asarray(z).max()))
-    got = bi.from_mont(np.asarray(z))
-    assert all(int(g) == w for g, w in zip(got, zv))
-    assert maxlimb < (1 << 15) + (1 << 12), maxlimb
+        assert _in_ledger(z, 1 << 4)
+        zv = [(a * b) % N for a, b in zip(zv, ys)]
+        z = F.sub(z, ax)
+        assert _in_ledger(z, TOP_BOUND)
+        zv = [(a - b) % N for a, b in zip(zv, xs)]
+        z = F.add(z, z)
+        assert _in_ledger(z, TOP_BOUND)
+        zv = [(2 * a) % N for a in zv]
+        z = F.neg(z)
+        assert _in_ledger(z, TOP_BOUND)
+        zv = [(-a) % N for a in zv]
+    assert _ints(F, z) == zv
 
 
-def _mont_mul_mxu(a, b):
-    """mont_mul with the MXU REDC path forced (matmul constant products),
-    bypassing the platform default — the differential oracle below must
-    hold on every platform."""
-    t = bi._carry(bi._mul_cols(a, b, 2 * bi.L))
-    return bi._redc(t, mxu=True)
-
-
-def test_mxu_redc_matches_schoolbook(rand_vals):
+def test_mxu_redc_matches_schoolbook(field):
     """The int8-matmul REDC is bit-value-equal to the schoolbook REDC on
     random, edge and worst-case-spread inputs, and keeps the output limb
-    bound (the fused BLS pipeline switches paths by platform — both must
-    be the same function)."""
-    xs, ys = rand_vals
-    edge = [0, 1, 2, P - 1, P - 2, (P + 1) // 2, (1 << 380) % P, 12345]
-    ax = jnp.concatenate([_batch(xs), _batch(edge)])
-    ay = jnp.concatenate([_batch(ys), _batch(edge[::-1])])
-    want = np.asarray(jax.jit(bi.mont_mul)(ax, ay))
-    got = np.asarray(jax.jit(_mont_mul_mxu)(ax, ay))
-    assert (bi.from_mont(got) == bi.from_mont(want)).all()
-    assert got.max() < (1 << 15) + (1 << 12), got.max()
+    bound (the device programs switch paths by platform — both must be
+    the same function; forced here, so the oracle holds on every
+    platform)."""
+    F, N = field, field.P_INT
+
+    def mxu(a, b):
+        return F._redc(bi._carry(bi._mul_cols(a, b, 2 * F.L)), mxu=True)
+
+    def schoolbook(a, b):
+        return F._redc(bi._carry(bi._mul_cols(a, b, 2 * F.L)), mxu=False)
+
+    xs, ys = _rand(F)
+    edge = _edge(F)
+    ax = jnp.concatenate([_batch(xs, F), _batch(edge, F)])
+    ay = jnp.concatenate([_batch(ys, F), _batch(edge[::-1], F)])
+    want = np.asarray(jax.jit(schoolbook)(ax, ay))
+    got = np.asarray(jax.jit(mxu)(ax, ay))
+    assert _ints(F, got) == _ints(F, want) == [
+        (x * y) % N for x, y in zip(xs + edge, ys + edge[::-1])]
+    assert _in_ledger(got, 1 << 4)
 
     # worst-case redundant encodings (limbs at the op-invariant bound)
-    rows = np.stack([_spread_limbs(x + (x % 4) * P) for x in xs[:8]])
+    rows = np.stack([_spread_limbs(x + (x % 4) * N, F) for x in xs[:8]])
     aw = jnp.asarray(rows)
-    got2 = bi.from_mont(np.asarray(_mont_mul_mxu(aw, ay[:8])))
-    want2 = bi.from_mont(np.asarray(bi.mont_mul(aw, ay[:8])))
-    assert (got2 == want2).all()
+    assert _ints(F, mxu(aw, ay[:8])) == _ints(F, schoolbook(aw, ay[:8]))
 
     # deep chain through the MXU path: bounds must not drift
     z = ax
-    maxlimb = 0
-    mm = jax.jit(_mont_mul_mxu)
+    mm = jax.jit(mxu)
     for _ in range(30):
-        z = mm(z, ay)
-        z = bi.add(z, ax)
-        maxlimb = max(maxlimb, int(np.asarray(z).max()))
-    assert maxlimb < (1 << 15) + (1 << 12), maxlimb
+        z = F.add(mm(z, ay), ax)
+        assert _in_ledger(z, TOP_BOUND)
 
 
-def _spread_limbs(v: int,
-                  limit: int = (1 << 15) + (1 << 11) - 1) -> np.ndarray:
+def _spread_limbs(v: int, F, limit: int = LIMB_BOUND - 1) -> np.ndarray:
     """Worst-case redundant encoding of v: same value, limbs pushed to
     the op-invariant bound by borrowing 2^15-units from higher limbs."""
-    d = [int(x) for x in bi._int_to_limbs(v)]
-    for i in range(bi.L - 1):
+    d = [int(x) for x in F.int_to_limbs(v)]
+    for i in range(F.L - 1):
         m = min(d[i + 1], (limit - d[i]) >> bi.B)
         d[i] += m << bi.B
         d[i + 1] -= m
     out = np.array(d, np.uint32)
-    assert bi._limbs_to_int(out) == v
+    assert F.limbs_to_int(out) == v
     return out
 
 
@@ -168,13 +252,13 @@ def test_is_zero_mod_p_device_bound_coupling():
     eps = (1 << 380) % P  # nonzero residue
     rows, want = [], []
     for k in range(5):
-        rows.append(_spread_limbs(k * P))
+        rows.append(_spread_limbs(k * P, bi.FP))
         want.append(True)
         rows.append(bi._int_to_limbs(k * P))
         want.append(True)
-        rows.append(_spread_limbs(k * P + 1))
+        rows.append(_spread_limbs(k * P + 1, bi.FP))
         want.append(False)
-        rows.append(_spread_limbs(k * P + eps))
+        rows.append(_spread_limbs(k * P + eps, bi.FP))
         want.append(False)
     near_bound = (1 << 394) - 12345
     assert near_bound % P != 0
@@ -196,8 +280,8 @@ def test_fp2_tower_ops(rand_vals):
     from lighthouse_tpu.ops import bls12_381 as dev
 
     xs, ys = rand_vals
-    x = (_batch(xs[:4]), _batch(ys[:4]))
-    y = (_batch(ys[4:8]), _batch(xs[4:8]))
+    x = (_batch(xs[:4], bi.FP), _batch(ys[:4], bi.FP))
+    y = (_batch(ys[4:8], bi.FP), _batch(xs[4:8], bi.FP))
     got = dev.fp2_mul(x, y)
     for i in range(4):
         want = Fq2(xs[i], ys[i]) * Fq2(ys[4 + i], xs[4 + i])

@@ -266,26 +266,26 @@ def test_deferred_subgroup_check_semantics():
         _ = sig.point
 
 
-def test_device_final_exp_matches_host():
-    import jax
-    import numpy as np
+def test_final_exp_without_native_is_the_host_python(monkeypatch):
+    """The route behind the native library: _final_exp_is_one is
+    final_exponentiation_fast, on a product that is one and on one that is
+    not, and no device program is entered for it."""
+    from lighthouse_tpu.common import device_telemetry as dtel
+    from lighthouse_tpu.crypto.bls.fields import final_exponentiation_fast
+    from lighthouse_tpu.crypto.bls.pairing_fast import multi_miller_fast
+    from lighthouse_tpu.ops import bls_backend as bb
+    from lighthouse_tpu.ops import native_bls
 
-    from lighthouse_tpu.crypto.bls.fields import (
-        Fq2, Fq6, Fq12, P, final_exp_easy, final_exp_hard,
-    )
-    from lighthouse_tpu.ops import bls12_381 as dev
-
-    rng = np.random.default_rng(7)
-
-    def f2():
-        return Fq2(int.from_bytes(rng.bytes(47), "big") % P,
-                   int.from_bytes(rng.bytes(47), "big") % P)
-
-    f = Fq12(Fq6(f2(), f2(), f2()), Fq6(f2(), f2(), f2()))
-    m = final_exp_easy(f)
-    out = jax.jit(dev.final_exp_hard_device)(dev.fq12_to_device(m))
-    got = dev.fq12_from_device(jax.tree_util.tree_map(np.asarray, out))
-    assert got == final_exp_hard(m)
+    g1, g2 = cv.g1_generator(), cv.g2_generator()
+    is_one = multi_miller_fast([(g1, g2), (cv.g1_neg(g1), g2)])
+    not_one = multi_miller_fast([(g1, g2), (g1, g2)])
+    monkeypatch.setattr(native_bls, "available", lambda: False)
+    dispatches = {e: st["dispatches"] for e, st in dtel.snapshot().items()}
+    for f, want in ((is_one, True), (not_one, False)):
+        assert final_exponentiation_fast(f).is_one() is want
+        assert bb._final_exp_is_one(f) is want
+    assert dispatches == {
+        e: st["dispatches"] for e, st in dtel.snapshot().items()}
 
 
 def test_grouped_layout_quantized():

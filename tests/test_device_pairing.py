@@ -207,10 +207,9 @@ class TestMessageGroupedPipeline:
         # 2 groups of 4 (s-major layout: lane = s*G + g, G=2)
         xs = ec.ints_to_mont_limbs([p[0] for p in pts])
         ys = ec.ints_to_mont_limbs([p[1] for p in pts])
-        # scalar 1 per lane: scalar-mul keeps the point, then group-sum
-        bits = ec.scalars_to_bits([1] * 8)
-        X, Y, Z = ec.g1_scalar_mul_batch(
-            jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(bits))
+        # Jacobian lanes with Z = 1 (Montgomery form), then group-sum
+        X, Y = jnp.asarray(xs), jnp.asarray(ys)
+        Z = jnp.broadcast_to(bi._jconst("one_m"), X.shape)
         Xg, Yg, Zg = jax.jit(ec.g1_segment_sum, static_argnums=3)(
             X, Y, Z, 2)
         for g in range(2):

@@ -1,5 +1,6 @@
-"""Scalar-field (Fr) device arithmetic + KZG barycentric evaluation
-(ops/fr.py) against independent Python big-int oracles."""
+"""What is Fr's own in ops/fr.py — inversion, the blob byte layout, KZG
+barycentric evaluation — against independent Python big-int oracles.
+The field operations themselves are cases of tests/test_bigint.py."""
 
 import secrets
 
@@ -22,39 +23,7 @@ def rand_pairs():
         fr.to_mont_host(b))
 
 
-class TestFieldOps:
-    def test_mont_mul(self, rand_pairs):
-        a, b, am, bm = rand_pairs
-        got = fr.from_mont_host(np.asarray(jax.jit(fr.mont_mul)(am, bm)))
-        assert all(int(g) == x * y % R for g, x, y in zip(got, a, b))
-
-    def test_mxu_redc_matches_schoolbook(self, rand_pairs):
-        """The int8-matmul REDC (TPU default) must be value-equal to the
-        schoolbook REDC and keep the limb bound — mirrors the bigint
-        differential."""
-        a, b, am, bm = rand_pairs
-
-        def mxu(x, y):
-            return fr._redc(fr._carry(fr._mul_cols(x, y, 2 * fr.L)),
-                            mxu=True)
-
-        got = np.asarray(jax.jit(mxu)(am, bm))
-        want = np.asarray(jax.jit(fr.mont_mul)(am, bm))
-        assert (fr.from_mont_host(got) == fr.from_mont_host(want)).all()
-        assert got.max() < (1 << 15) + (1 << 12)
-        edge = jnp.asarray(fr.to_mont_host(
-            [0, 1, 2, R - 1, R - 2, (1 << 254) % R, 7, R // 2]))
-        ge = fr.from_mont_host(np.asarray(mxu(edge, edge)))
-        we = fr.from_mont_host(np.asarray(fr.mont_mul(edge, edge)))
-        assert (ge == we).all()
-
-    def test_add_sub(self, rand_pairs):
-        a, b, am, bm = rand_pairs
-        gs = fr.from_mont_host(np.asarray(jax.jit(fr.add)(am, bm)))
-        gd = fr.from_mont_host(np.asarray(jax.jit(fr.sub)(am, bm)))
-        assert all(int(g) == (x + y) % R for g, x, y in zip(gs, a, b))
-        assert all(int(g) == (x - y) % R for g, x, y in zip(gd, a, b))
-
+class TestInversionAndLayout:
     def test_batch_inverse_tree(self, rand_pairs):
         """Product-tree simultaneous inversion == per-lane Fermat ==
         python pow, over a [N, W] grid (the barycentric denominator
@@ -72,12 +41,6 @@ class TestFieldOps:
         a, _, am, _ = rand_pairs
         inv = fr.from_mont_host(np.asarray(jax.jit(fr.inv_mont)(am)))
         assert all(int(g) == pow(x, -1, R) for g, x in zip(inv, a))
-
-    def test_edge_values(self):
-        vals = [0, 1, R - 1, R - 2, 2**254]
-        vm = jnp.asarray(fr.to_mont_host(vals))
-        sq = fr.from_mont_host(np.asarray(jax.jit(fr.mont_mul)(vm, vm)))
-        assert all(int(g) == v * v % R for g, v in zip(sq, vals))
 
     def test_bytes_to_limbs(self):
         raw = np.stack([

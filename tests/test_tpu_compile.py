@@ -336,14 +336,6 @@ def test_shuffle_rounds(one_chip, tpu_branches):
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
 
 
-@pytest.mark.slow  # ~7 min; the native C++ final exponentiation serves
-def test_final_exp_hard_device(one_chip, tpu_branches):
-    from lighthouse_tpu.ops import bls_backend as bb
-
-    _compile("final_exp_hard_device", bb._final_exp_hard_jit._fn,
-             _fq12(one_chip))
-
-
 def _pipeline_args(sh, n):
     rows = [_limbs(sh, n)] * 10
     return (*rows,
