@@ -407,6 +407,18 @@ def count_eval_lanes(live: int, padding: int) -> None:
     lanes.labels(kind="padding").inc(padding)
 
 
+def count_eval_products(resident: int, materialized: int) -> None:
+    """Fr lane-products the evaluation slices dispatched (ops/fr.py), by
+    the multiply that ran them: ``resident`` is `MontField.mont_mul_lm`
+    (partial products stay in the core), ``materialized`` `mont_mul`
+    (they are arrays of the program)."""
+    products = REGISTRY.counter(
+        "kzg_eval_products_total",
+        "Fr lane-products of the evaluation slices, by multiply")
+    products.labels(multiply="resident").inc(resident)
+    products.labels(multiply="materialized").inc(materialized)
+
+
 def count_eval_slice(overlapped: bool) -> None:
     """One evaluation slice dispatched (ops/fr.py): ``exposed`` when the
     host prepared it with no evaluation slice of its batch in flight

@@ -13,9 +13,9 @@ exceed the modulus N, inside a capacity of C = 15·L bits.  The
 redundancy is what makes the arithmetic vectorize:
 
 - products of two sub-2^16 limbs fit uint32 exactly;
-- every product is split into 15-bit hi/lo halves before accumulation, so
-  a full LxL schoolbook column sum stays < 2^24 — no carry chains in
-  the hot path;
+- every product (in the limb-major multiply, below: every three) is split
+  into 15-bit hi/lo halves before accumulation, so a full LxL schoolbook
+  column sum stays < 2^24 — no carry chains in the hot path;
 - ONE data-parallel carry pass (limb_k = (col_k & mask) + (col_{k-1}>>15))
   restores the limb bound.  The capacity margin makes the top limb tiny,
   so the pass never spills — no sequential ripple exists anywhere.
@@ -48,6 +48,37 @@ fields by the asserts in tests/test_bigint.py:
         [2^6, 2^7) — above every top limb this table allows; pre-fold
         values < 2^(F+4)
     limbs     < 2^15 + 2^11 everywhere
+
+The limb-major multiply (``mont_mul_lm``, with ``add_lm`` / ``sub_lm``;
+Fr's evaluation programs since PR 32, the Fp programs still to follow).
+The same construction for arrays uint32[L, ...lanes] whose FIRST axis is
+the limb.  ``mont_mul`` above makes every schoolbook product an array of
+the program ([.., L, 2L], written to HBM and read back: ~27 KB a Fr
+product-lane that needs 216 bytes); here the partial products, the
+column sums and both REDC products of a block of lanes live in vector
+registers and VMEM, so a product-lane costs HBM its two operands and its
+result.  One text, ``_mont_mul_lm``, with the limb axis leading; two
+launchers, chosen by ``jax.default_backend()`` as ``_use_mxu_redc``
+chooses: on a TPU a Pallas kernel over blocks uint32[L, 8k, 128] (a limb
+of 1,024 lanes is one vector register; N and N' are scalars of the
+kernel, not operands), elsewhere the same text on the whole arrays (the
+same text under plain XLA on a TPU makes [20, S, 128] arrays of its rows
+again: 7.8 GB a pass by the compile rehearsal of PR 32, so the kernel is
+what carries the bytes).  Its lines of the ledger:
+
+    mont_mul_lm in    limbs < 2^15 + 2^11 (THREE raw limb products are
+        summed in uint32 before one 15-bit split: 3·(2^15+2^11-1)^2 <
+        2^32; `mont_mul` splits each and takes limbs < 2^16), values
+        < 2^(F+1) as every line above leaves them; a fixed multiplicand
+        is one of the host limb tables, its limbs canonical
+    mont_mul_lm out   the `mul out` line: < 2^(2(F+1) − C) + N, top limb
+        0 (Fp), <= 1 (Fr), limbs < 2^15 + 2^11; congruent to `mont_mul`'s
+        mod N, not limb for limb (the columns split differently, so m may
+        differ by R and the product by N)
+    columns           < 2^21: ceil(L/3) low halves < 2^15 and as many
+        high halves < 2^17
+    add_lm / sub_lm   the `add / sub` line, limb for limb what `add` and
+        `sub` give
 
 Reference counterpart: the limb arithmetic inside blst
 (/root/reference/crypto/bls/src/impls/blst.rs's FFI layer).
@@ -165,6 +196,98 @@ def _use_mxu_redc() -> bool:
                 record_swallowed("bigint.mxu_probe", e)
                 _MXU_REDC = False
     return _MXU_REDC
+
+
+# --- limb-major pieces: the limb axis leads -----------------------------------
+#
+# The same carry and schoolbook as above for arrays uint32[n, ...lanes]
+# whose FIRST axis is the limb: inside the resident kernel a block
+# uint32[L, 8, 128] (a limb is one vector register), outside it the whole
+# array.  Nothing here indexes along a lane, so one text serves both; a
+# shift along the limb axis is a concatenation of whole limbs.
+
+def _cat(*parts: jax.Array) -> jax.Array:
+    """Concatenation along the limb axis of the parts that have a limb (a
+    kernel body may hold no empty vector)."""
+    return jnp.concatenate([p for p in parts if p.shape[0]])
+
+
+def _zeros(n: int, like: jax.Array) -> jax.Array:
+    return jnp.zeros((n,) + like.shape[1:], like.dtype)
+
+
+def _splat_lm(limbs: list, like: jax.Array) -> jax.Array:
+    """Constant limbs as limb-major splats over like's lanes, built from
+    scalars (a kernel body may capture no array)."""
+    return jnp.stack([jnp.full(like.shape[1:], c, jnp.uint32)
+                      for c in limbs])
+
+
+def _carry_lm(c: jax.Array) -> jax.Array:
+    """`_carry` along the leading axis (the top limb keeps its high
+    bits)."""
+    hi = c[:-1] >> B
+    return _cat(c[:1] & MASK, (c[1:-1] & MASK) + hi[:-1], c[-1:] + hi[-1:])
+
+
+def _add_at(cols: jax.Array, x: jax.Array, off: int) -> jax.Array:
+    """cols with x added to limbs [off, off + len(x)), cut at cols' end."""
+    n = min(x.shape[0], cols.shape[0] - off)
+    if n <= 0:
+        return cols
+    return _cat(cols[:off], cols[off:off + n] + x[:n], cols[off + n:])
+
+
+def _mul_cols_lm(a: jax.Array, b: jax.Array, out_cols: int) -> jax.Array:
+    """Schoolbook columns below `out_cols` of a·b, both uint32[L, ...].
+    Limbs are < 2^15 + 2^11, so THREE raw products fit uint32: rows of
+    the schoolbook go three at a time, each shifted one limb, summed and
+    only then split into 15-bit halves.  A column takes ceil(L/3) low
+    halves < 2^15 and as many high halves < 2^17, and stays < 2^21."""
+    L = a.shape[0]
+    cols = _zeros(out_cols, a)
+    for i0 in range(0, L, 3):
+        n = min(3, L - i0)
+        s = None
+        for r in range(n):
+            p = _cat(_zeros(r, a), a[i0 + r][None] * b, _zeros(n - 1 - r, a))
+            s = p if s is None else s + p
+        cols = _add_at(cols, s & MASK, i0)
+        cols = _add_at(cols, s >> B, i0 + 1)
+    return cols
+
+
+_RESIDENT: bool | None = None
+
+
+def _use_resident_kernel() -> bool:
+    """Whether `mont_mul_lm` launches its Pallas kernel: on a TPU, as
+    `_use_mxu_redc` decides by default (and by nothing else)."""
+    global _RESIDENT
+    if _RESIDENT is None:
+        _RESIDENT = jax.default_backend() == "tpu"
+    return _RESIDENT
+
+
+_BLOCK_LANES = 8 * 128        # the lanes of one [L, 8, 128] step of the kernel
+
+
+def _to_blocks(x: jax.Array) -> jax.Array:
+    """uint32[L, ...lanes] -> uint32[L, S, C], S a multiple of 8 and C of
+    128: as it is when it has such a shape, else flattened to C = 128 and
+    padded with zero lanes."""
+    if x.ndim == 3 and x.shape[1] % 8 == 0 and x.shape[2] % 128 == 0:
+        return x
+    flat = x.reshape(x.shape[0], -1)
+    fill = -flat.shape[1] % _BLOCK_LANES
+    return jnp.pad(flat, ((0, 0), (0, fill))).reshape(x.shape[0], -1, 128)
+
+
+def _from_blocks(x: jax.Array, shape: tuple) -> jax.Array:
+    if x.shape == shape:
+        return x
+    lanes = int(np.prod(shape[1:]))
+    return x.reshape(shape[0], -1)[:, :lanes].reshape(shape)
 
 
 class MontField:
@@ -397,6 +520,107 @@ class MontField:
         t_cols = _mul_cols(a, b, 2 * self.L)       # 2L columns < 2^24
         t = _carry(t_cols)                         # 2L limbs < 2^16
         return self._redc(t, _use_mxu_redc())
+
+    # --- the limb-major operations (header: "The limb-major multiply") ------
+
+    def _const_lm(self, name: str, like: jax.Array) -> jax.Array:
+        return _splat_lm(self.tables[name].tolist(), like)
+
+    def _mont_mul_lm(self, a: jax.Array, b: jax.Array) -> jax.Array:
+        """The separated REDC of `mont_mul` with the limb axis leading
+        (a, b uint32[L, ...lanes]); inputs and output by the header's
+        ledger.  The body of the resident kernel, and the whole multiply
+        where there is none."""
+        L = self.L
+        t = _carry_lm(_mul_cols_lm(a, b, 2 * L))
+        m = _carry_lm(_mul_cols_lm(t[:L], self._const_lm("nprime", a), L))
+        m = _cat(m[:-1], m[-1:] & MASK)            # mod R, as `_redc`
+        s = _mul_cols_lm(m, self._const_lm("p", a), 2 * L) + t
+        # the low half's value is 0 or R (the argument of `_redc`)
+        resid = s[L - 1] & MASK
+        for k in range(L - 1):
+            resid = resid | s[k]
+        c = (s[L - 1] >> B) + (resid != 0).astype(jnp.uint32)
+        return _carry_lm(_cat((s[L] + c)[None], s[L + 1:]))
+
+    def _fold_top_lm(self, x: jax.Array) -> jax.Array:
+        e = x[-1:] >> 4
+        x = _cat(x[:-1], x[-1:] & 0xF)
+        return _carry_lm(x + e * self._const_lm("foldq", x))
+
+    def _add_lm(self, a: jax.Array, b: jax.Array) -> jax.Array:
+        return self._fold_top_lm(_carry_lm(a + b))
+
+    def _sub_lm(self, a: jax.Array, b: jax.Array) -> jax.Array:
+        return self._fold_top_lm(
+            _carry_lm(a + (self._const_lm("neg", a) - b)))
+
+    def add_lm(self, a: jax.Array, b: jax.Array) -> jax.Array:
+        """`add` on limb-major arrays uint32[L, ...lanes]."""
+        return self._launch(self._add_lm, 16 * self.L, a, b)
+
+    def sub_lm(self, a: jax.Array, b: jax.Array) -> jax.Array:
+        """`sub` on limb-major arrays uint32[L, ...lanes]."""
+        return self._launch(self._sub_lm, 16 * self.L, a, b)
+
+    def mont_mul_lm(self, a: jax.Array, b) -> jax.Array:
+        """Montgomery product of limb-major arrays uint32[L, ...lanes]; b
+        is an array of a's shape, or one of the host limb tables (a fixed
+        multiplicand, its limbs scalars of the program: `tables["r2"]` is
+        the way into Montgomery form)."""
+        ops_a_lane = 8 * self.L * self.L
+        if not isinstance(b, np.ndarray):
+            return self._launch(self._mont_mul_lm, ops_a_lane, a, b)
+        limbs = b.tolist()
+        return self._launch(
+            lambda x: self._mont_mul_lm(x, _splat_lm(limbs, x)),
+            ops_a_lane, a)
+
+    def _launch(self, fn, ops_a_lane: int, *ops: jax.Array) -> jax.Array:
+        """fn over limb-major arrays of one shape.  On a TPU their lanes
+        run through the resident kernel in blocks of [L, 8k, 128], padded
+        up to one where they are fewer or off a block's edge; elsewhere
+        fn runs on the whole arrays."""
+        assert all(x.shape == ops[0].shape for x in ops) and (
+            ops[0].shape[0] == self.L), [x.shape for x in ops]
+        if not _use_resident_kernel():
+            return fn(*ops)
+        out = self._resident_call(
+            fn, ops_a_lane, [_to_blocks(x) for x in ops])
+        return _from_blocks(out, ops[0].shape)
+
+    def _resident_call(self, fn, ops_a_lane: int, ops: list,
+                       interpret: bool = False) -> jax.Array:
+        """pallas_call of fn over uint32[L, S, C] operands (S a multiple
+        of 8, C of 128): a grid of [L, rows, 128] blocks, each walked
+        eight sublanes at a time, so that a limb of the product in hand
+        is one vector register and no partial product leaves the core."""
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        L, S, C = ops[0].shape
+        rows = next(r for r in (64, 32, 16, 8) if S % r == 0)
+
+        def body(*refs):
+            def chunk(s, carry):
+                at = pl.ds(pl.multiple_of(s * 8, 8), 8)
+                refs[-1][:, at, :] = fn(*[r[:, at, :] for r in refs[:-1]])
+                return carry
+
+            jax.lax.fori_loop(0, rows // 8, chunk, 0)
+
+        spec = pl.BlockSpec((L, rows, 128), lambda i, j: (0, i, j))
+        return pl.pallas_call(
+            body, grid=(S // rows, C // 128), in_specs=[spec] * len(ops),
+            out_specs=spec,
+            out_shape=jax.ShapeDtypeStruct(ops[0].shape, jnp.uint32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
+            # what a lane costs HBM: its operands and its result
+            cost_estimate=pl.CostEstimate(
+                flops=ops_a_lane * S * C, transcendentals=0,
+                bytes_accessed=4 * L * S * C * (len(ops) + 1)),
+            interpret=interpret)(*ops)
 
 
 # --- the base field, under the names its callers use -------------------------

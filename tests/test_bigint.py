@@ -228,6 +228,126 @@ def test_mxu_redc_matches_schoolbook(field):
         assert _in_ledger(z, TOP_BOUND)
 
 
+# --- the limb-major operations (the limb axis leads) --------------------------
+
+def _extreme_rows(F):
+    """Limb rows at the extremes the ledger allows: every limb at
+    2^15 + 2^11 - 1 with the top limb at its bound, 0, N - 1, a lone top
+    limb, and worst-case spreads of random values."""
+    N, L = F.P_INT, F.L
+    full = np.full(L, LIMB_BOUND - 1, np.uint32)
+    full[-1] = TOP_BOUND - 1
+    top_only = np.zeros(L, np.uint32)
+    top_only[-1] = TOP_BOUND - 1
+    xs, _ = _rand(F)
+    return np.stack(
+        [full, np.zeros(L, np.uint32), F.int_to_limbs(N - 1), top_only,
+         F.int_to_limbs(1)]
+        + [_spread_limbs(x + (x % 4) * N, F) for x in xs[:11]])
+
+
+def _residues(F, rows):
+    return [F.limbs_to_int(r) % F.P_INT for r in np.asarray(rows)]
+
+
+def test_mont_mul_lm_at_the_ledgers_extremes(field):
+    """The limb-major multiply (whole arrays: the launcher every backend
+    but a TPU takes) against Python integers and against `mont_mul`, with
+    its output inside the ledger's line for `mul out`."""
+    F, N = field, field.P_INT
+    a = _extreme_rows(F)
+    b = a[::-1].copy()
+    want = [(F.limbs_to_int(x) * F.limbs_to_int(y) * F.R_INV) % N
+            for x, y in zip(a, b)]
+    got = np.asarray(jax.jit(F.mont_mul_lm)(jnp.asarray(a.T),
+                                            jnp.asarray(b.T))).T
+    assert _residues(F, got) == want
+    assert _residues(F, jax.jit(F.mont_mul)(jnp.asarray(a),
+                                            jnp.asarray(b))) == want
+    # mul out < 2^(2(F+1) - C) + N: top limb 0 (Fp), <= 1 (Fr)
+    assert _in_ledger(got, 1 if F is bi.FP else 2)
+    cap = 2 * (bi.B * F.L - 10) - bi.B * F.L
+    assert all(F.limbs_to_int(r) < (1 << cap) + N + (N >> 8) for r in got)
+
+
+def test_mont_mul_lm_lane_shapes_and_fixed_multiplicand(field):
+    """Any lane shape, and a host limb table as the fixed multiplicand
+    (the way into Montgomery form multiplies by R^2 mod N)."""
+    F, N = field, field.P_INT
+    xs, ys = _rand(F)
+    a = jnp.asarray(F.to_mont(xs)).T.reshape(F.L, 4, 8)
+    b = jnp.asarray(F.to_mont(ys)).T.reshape(F.L, 4, 8)
+    out = np.asarray(jax.jit(F.mont_mul_lm)(a, b)).reshape(F.L, 32).T
+    assert _ints(F, out) == [(x * y) % N for x, y in zip(xs, ys)]
+    raw = jnp.asarray(np.stack([F.int_to_limbs(x) for x in xs]).T)
+    mont = np.asarray(
+        jax.jit(lambda v: F.mont_mul_lm(v, F.tables["r2"]))(raw)).T
+    assert _ints(F, mont) == xs
+    assert _in_ledger(mont, 2)
+
+
+def test_resident_kernel_interpreted(field):
+    """The Pallas launcher, interpreted on the CPU at one small shape
+    (lanes off a block's edge, so padded): the same limbs as the whole-
+    array launcher, for the multiply, its fixed form and add/sub."""
+    F = field
+    a = jnp.asarray(_extreme_rows(F).T)                  # [L, 16]
+    b = jnp.asarray(_extreme_rows(F)[::-1].copy().T)
+    blocks = [bi._to_blocks(a), bi._to_blocks(b)]
+    assert blocks[0].shape == (F.L, 8, 128)
+    for fn in (F._mont_mul_lm, F._add_lm, F._sub_lm):
+        got = bi._from_blocks(
+            F._resident_call(fn, 1, blocks, interpret=True), a.shape)
+        assert (np.asarray(got) == np.asarray(fn(a, b))).all()
+    k = F.tables["r2"]
+    fixed = lambda x: F._mont_mul_lm(  # noqa: E731
+        x, bi._splat_lm(k.tolist(), x))
+    got = bi._from_blocks(
+        F._resident_call(fixed, 1, blocks[:1], interpret=True), a.shape)
+    assert (np.asarray(got) == np.asarray(F.mont_mul_lm(a, k))).all()
+
+
+def test_blocks_keep_a_blocked_shape_and_pad_any_other():
+    x = jnp.arange(3 * 16 * 256, dtype=jnp.uint32).reshape(3, 16, 256)
+    assert bi._to_blocks(x) is x
+    y = jnp.arange(3 * 5 * 7, dtype=jnp.uint32).reshape(3, 5, 7)
+    blocked = bi._to_blocks(y)
+    assert blocked.shape == (3, 8, 128)
+    assert (np.asarray(bi._from_blocks(blocked, y.shape))
+            == np.asarray(y)).all()
+    assert int(np.asarray(blocked).reshape(3, -1)[:, 35:].max()) == 0
+
+
+def test_add_sub_lm_and_a_deep_chain_keep_the_ledger(field):
+    """add_lm / sub_lm against integers, and 30 rounds of mul/sub/add on
+    limb-major arrays: every output inside the ledger, values exact."""
+    F, N = field, field.P_INT
+    xs, ys = _rand(F)
+    ax, ay = jnp.asarray(F.to_mont(xs)).T, jnp.asarray(F.to_mont(ys)).T
+    assert _ints(F, np.asarray(F.add_lm(ax, ay)).T) == [
+        (x + y) % N for x, y in zip(xs, ys)]
+    assert _ints(F, np.asarray(jax.jit(F.sub_lm)(ax, ay)).T) == [
+        (x - y) % N for x, y in zip(xs, ys)]
+    mm = jax.jit(F.mont_mul_lm)
+    z, zv = ax, list(xs)
+    for _ in range(30):
+        z = mm(z, ay)
+        assert _in_ledger(np.asarray(z).T, 1 << 4)
+        zv = [(a * b) % N for a, b in zip(zv, ys)]
+        z = F.sub_lm(z, ax)
+        assert _in_ledger(np.asarray(z).T, TOP_BOUND)
+        zv = [(a - b) % N for a, b in zip(zv, xs)]
+        z = F.add_lm(z, z)
+        assert _in_ledger(np.asarray(z).T, TOP_BOUND)
+        zv = [(2 * a) % N for a in zv]
+    assert _ints(F, np.asarray(z).T) == zv
+    # the same limbs as the limb-row operations give
+    assert (np.asarray(F.sub_lm(ax, ay)).T
+            == np.asarray(F.sub(ax.T, ay.T))).all()
+    assert (np.asarray(F.add_lm(ax, ay)).T
+            == np.asarray(F.add(ax.T, ay.T))).all()
+
+
 def _spread_limbs(v: int, F, limit: int = LIMB_BOUND - 1) -> np.ndarray:
     """Worst-case redundant encoding of v: same value, limbs pushed to
     the op-invariant bound by borrowing 2^15-units from higher limbs."""

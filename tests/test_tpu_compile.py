@@ -61,8 +61,9 @@ def tpu_branches():
     """Steer the code to the branches a TPU node takes, and keep the
     described-device executables out of the persistent cache.
 
-    ``bigint._use_mxu_redc()`` probes ``jax.default_backend()``, which is
-    the CPU here, so the switch is set in the test; the tracing caches
+    ``bigint._use_mxu_redc()`` and ``bigint._use_resident_kernel()`` probe
+    ``jax.default_backend()``, which is the CPU here, so the two
+    switches are set in the test; the tracing caches
     are dropped on both sides so no program traced for the other branch
     is reused."""
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -70,13 +71,13 @@ def tpu_branches():
     from lighthouse_tpu.ops import bigint as bi
 
     was_cache = jax.config.jax_enable_compilation_cache
-    was_mxu = bi._MXU_REDC
+    was_mxu, was_resident = bi._MXU_REDC, bi._RESIDENT
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    bi._MXU_REDC = True
+    bi._MXU_REDC = bi._RESIDENT = True
     jax.clear_caches()
     yield
-    bi._MXU_REDC = was_mxu
+    bi._MXU_REDC, bi._RESIDENT = was_mxu, was_resident
     jax.clear_caches()
     jax.config.update("jax_enable_compilation_cache", was_cache)
     cc.reset_cache()
@@ -197,20 +198,40 @@ def _fr_rows(sh, *lead):
 
 def test_kzg_eval_slice(one_chip, tpu_branches):
     """One evaluation slice: fr._EVAL_MAX_BLOBS blobs of 4,096 field
-    elements through the to-Montgomery program and _eval_kernel.  All 768
-    blobs in ONE dispatch are refused (22.79 GB wanted of 15.75 GB; the
-    to-Montgomery program alone 16.08 GB of temporaries), 128 compile to
-    5.46 GB, the cap's 64 to 2.74 GB — which is why the cap exists."""
+    elements through the to-Montgomery program and _eval_kernel, on the
+    multiply whose partial products stay in the core (PR 32).  What one
+    slice moves through HBM is held here by the compiler's own count.
+    Before that multiply (the parent of PR 32: every product a
+    [64, 4096, 18, 36] array of the program) the to-Montgomery program
+    read 7.04 GB, _eval_kernel 50.9 GB with 2.74 GB of temporaries, 128
+    blobs wanted 5.46 GB and the 768 of a full response were refused
+    (22.79 GB of 15.75 GB), which is where the cap of 64 came from."""
+    from lighthouse_tpu.ops import bigint as bi
     from lighthouse_tpu.ops import fr
 
+    assert bi._use_resident_kernel()
     n = fr._EVAL_MAX_BLOBS
     assert 768 % n == 0  # a full response is whole slices: no fill
-    _compile(f"_to_mont_kernel@{n}x4096", fr._to_mont_kernel._fn,
-             _fr_rows(one_chip, n, BLOB_WIDTH))
-    c = _compile(f"_eval_kernel@{n}x4096", fr._eval_kernel._fn,
-                 _fr_rows(one_chip, n, BLOB_WIDTH), _fr_rows(one_chip, n),
-                 _fr_rows(one_chip, BLOB_WIDTH), _fr_rows(one_chip))
-    assert c.memory_analysis().temp_size_in_bytes < 4 << 30
+    programs = {
+        "_to_mont_kernel": _compile(
+            f"_to_mont_kernel@{n}x4096", fr._to_mont_kernel._fn,
+            _fr_rows(one_chip, n, BLOB_WIDTH)),
+        "_eval_kernel": _compile(
+            f"_eval_kernel@{n}x4096", fr._eval_kernel._fn,
+            _fr_rows(one_chip, n, BLOB_WIDTH), _fr_rows(one_chip, n),
+            _fr_rows(one_chip, BLOB_WIDTH), _fr_rows(one_chip))}
+    limits = {"_to_mont_kernel": 0.5e9, "_eval_kernel": 6e9}
+    for name, c in programs.items():
+        read = c.cost_analysis()["bytes accessed"]
+        print("TPU_COMPILE " + json.dumps(
+            {"program": name, "bytes_accessed": int(read)}), flush=True)
+        assert read < limits[name]
+        assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+        text = c.as_text()
+        assert "tpu_custom_call" in text
+        # no schoolbook product is an array of the program
+        entry = text[text.index("\nENTRY "):]
+        assert "[64,4096,18,3" not in entry and ",18,36]" not in entry
 
 
 def test_g1_subgroup_kernel_blob_batch(one_chip, tpu_branches):
