@@ -1,4 +1,5 @@
-"""Data-availability checker (Deneb).
+"""Data-availability checker (Deneb; the column branch of its segment
+entry is fulu's).
 
 Rebuild of /root/reference/beacon_node/beacon_chain/src/
 data_availability_checker.rs (:32,:61) + its overflow LRU cache: pending
@@ -17,16 +18,26 @@ from dataclasses import dataclass, field
 
 def verify_kzg_for_rpc_blocks(settings, blocks_sidecars) -> bool:
     """KZG verification for a chain segment that arrived over RPC (range
-    sync, backfill): ``blocks_sidecars`` holds, block by block, the blob
-    sidecars that came with it (anything with ``blob``,
-    ``kzg_commitment`` and ``kzg_proof``).  Every sidecar of the segment
-    goes through `validate_blobs` in one call — up to
-    MAX_REQUEST_BLOB_SIDECARS (768) of them, one
-    `verify_blob_kzg_proof_batch` — and one invalid proof anywhere fails
-    the segment, as in the reference."""
+    sync, backfill, a lookup): ``blocks_sidecars`` holds, block by block,
+    what came with it.
+
+    Blob sidecars (anything with ``blob``, ``kzg_commitment`` and
+    ``kzg_proof``): every sidecar of the segment goes through
+    `validate_blobs` in one call, up to MAX_REQUEST_BLOB_SIDECARS (768) of
+    them, one `verify_blob_kzg_proof_batch`.  Data-column sidecars
+    (anything with ``index``, ``column``, ``kzg_commitments`` and
+    ``kzg_proofs``; PeerDAS, fulu): every sidecar of the segment through
+    `validate_data_columns`, one `verify_cell_kzg_proof_batch`.  One
+    invalid proof anywhere fails the segment, as in the reference."""
+    sidecars = [s for block in blocks_sidecars for s in block]
+    if sidecars and hasattr(sidecars[0], "column"):
+        from lighthouse_tpu.chain.data_column_verification import (
+            validate_data_columns,
+        )
+
+        return validate_data_columns(settings, sidecars)
     from lighthouse_tpu.chain.blob_verification import validate_blobs
 
-    sidecars = [s for block in blocks_sidecars for s in block]
     return validate_blobs(
         settings,
         [s.kzg_commitment for s in sidecars],

@@ -1,10 +1,9 @@
 """PeerDAS data-availability-sampling cells (EIP-7594 shape).
 
-The reference's cell functions are TODO stubs returning zeros
-(/root/reference/crypto/kzg/src/lib.rs:169-216, "use proper crypto once
-ckzg merges das branch"); this module implements the real polynomial
-math: a blob's evaluations extend onto the doubled domain (Reed-Solomon
-rate-1/2), cells are the bit-reversal-permuted cosets of that extended
+The cell functions of consensus-specs
+specs/fulu/polynomial-commitments-sampling.md (compute_cells,
+recover_cells_and_kzg_proofs, verify_cell_kzg_proof_batch): a blob's
+evaluations extend onto the doubled domain (Reed-Solomon rate-1/2), cells are the bit-reversal-permuted cosets of that extended
 domain, and any half of the cells recovers the rest via the
 vanishing-polynomial / coset-division algorithm.
 
@@ -15,9 +14,10 @@ holding the blob.  Corruption among RECEIVED cells during recovery is
 detected whenever the caller supplies more than the minimum half (at
 exactly half there is no redundancy — proof-verify cells first).
 
-All arithmetic is over the BLS scalar field; the FFTs are host-side
-python ints today (the fr limb kernel in ops/fr.py is the device path
-for these butterflies when DAS hits the hot path).
+All arithmetic is over the BLS scalar field.  Batch verification runs on
+the device (the aggregated interpolation in ops/fr.py, the two sums and
+the pairing in kzg._kzg_fused); cell computation and recovery are
+host-side Python integers.
 """
 
 from __future__ import annotations
@@ -122,8 +122,8 @@ def _cell_field_elements(cell: bytes, cell_size: int) -> list[int]:
 def recover_all_cells(cell_ids: list[int], cells: list[bytes],
                       settings) -> list[bytes]:
     """Erasure recovery: any >= half of the cells reconstructs all of
-    them (vanishing-polynomial + coset-division, the c-kzg das
-    algorithm the reference is waiting on).
+    them (vanishing-polynomial + coset-division, the spec's
+    recover_cells_and_kzg_proofs).
 
     Steps: build Z(x) vanishing on the missing cells' cosets (each coset
     is {h·w : w^cell_size = 1}, so its vanishing factor is the sparse
@@ -223,7 +223,6 @@ def recover_all_cells(cell_ids: list[int], cells: list[bytes],
 # coset's vanishing polynomial (sparse — synthetic division is O(n)).
 # Verification: e(C − [I_c(τ)]₁, −G₂) · e(π_c, [Z_c(τ)]₂) == 1 with
 # [Z_c(τ)]₂ = τ^cs·G₂ − h_c^cs·G₂ from the setup's G2 monomials.
-# (The functions the reference stubs out pending c-kzg's das branch.)
 
 
 def _coset_start(cid: int, cell_size: int, ext_roots, nat_of_brp) -> int:
@@ -407,87 +406,347 @@ def verify_cell_kzg_proof(commitment_bytes: bytes, cell_id: int,
     ])
 
 
+RANDOM_CHALLENGE_KZG_CELL_BATCH_DOMAIN = b"RCKZGCBATCH__V1_"
+
+# below this many cells the device round-trips are not worth it
+_CELL_BATCH_FUSED_MIN = 8
+# lanes of one multi-scalar multiplication of a check: the bucket whose
+# `_kzg_fused` program (2 x 2,048 lanes) a node already holds for a full
+# blob_sidecars_by_range response.  A cell batch runs as the fewest
+# checks that fit it: an 8,192-lane program for one full block's 2,773
+# lanes would cost minutes of compile, a minute of reload at every start
+# and the same device seconds
+_FUSED_MSM_LANES = 2048
+# lanes of one interpolation dispatch (rows x slots x groups x cell
+# size): 32 x 64 x 2 x 64 for one full block of 21 blobs
+_INTERP_MAX_LANES = 1 << 18
+
+
+def compute_verify_cell_kzg_proof_batch_challenge(
+        commitments: list[bytes], commitment_indices: list[int],
+        cell_indices: list[int], cells: list[bytes], proofs: list[bytes],
+        settings) -> int:
+    """The spec's Fiat-Shamir challenge of a cell batch
+    (polynomial-commitments-sampling.md): the domain, the blob and cell
+    widths, the numbers of distinct commitments and of cells, the
+    commitments, then a cell's commitment index, cell index, bytes and
+    proof in turn."""
+    import hashlib
+
+    _, cell_size = _cell_geometry(settings.width)
+    h = hashlib.sha256(RANDOM_CHALLENGE_KZG_CELL_BATCH_DOMAIN)
+    for v in (settings.width, cell_size, len(commitments), len(cells)):
+        h.update(v.to_bytes(8, "big"))
+    h.update(b"".join(commitments))
+    for ci, cid, cell, proof in zip(commitment_indices, cell_indices, cells,
+                                    proofs):
+        h.update(ci.to_bytes(8, "big") + cid.to_bytes(8, "big"))
+        h.update(cell)
+        h.update(proof)
+    return int.from_bytes(h.digest(), "big") % BLS_MODULUS
+
+
+class _CellDomain:
+    """What cell verification reads of the extended domain, once a
+    settings object: a column's coset shift h_c (as a_c = h_c^size and as
+    the Montgomery limb rows of h_c^(-m) / size) and the inverse
+    transform's omega^(-e_j m), e_j the bit-reversed position of
+    evaluation j in its coset."""
+
+    def __init__(self, width: int):
+        self.n_cells, self.size = n_cells, size = _cell_geometry(width)
+        ext_roots = _compute_roots_of_unity(2 * width)
+        nat_of_brp = _bit_reversal_permutation(list(range(2 * width)))
+        shifts = [ext_roots[nat_of_brp[c * size]] for c in range(n_cells)]
+        self.a = [pow(h, size, BLS_MODULUS) for h in shifts]
+        size_inv = pow(size, -1, BLS_MODULUS)
+        scale = []
+        for h in shifts:
+            h_inv, acc = pow(h, -1, BLS_MODULUS), size_inv
+            for _ in range(size):
+                scale.append(acc)
+                acc = acc * h_inv % BLS_MODULUS
+        self.scale = _mont_limbs(scale).reshape(n_cells, size, -1)
+        omega_inv = pow(ext_roots[(2 * width // size) % (2 * width)], -1,
+                        BLS_MODULUS)
+        powers = [pow(omega_inv, i, BLS_MODULUS) for i in range(size)]
+        e = _bit_reversal_permutation(list(range(size)))
+        self.idft = _mont_limbs(
+            [powers[e[j] * m % size] for j in range(size)
+             for m in range(size)]).reshape(size, size, -1)
+
+
+def _cell_domain(settings) -> _CellDomain:
+    dom = getattr(settings, "_cell_domain", None)
+    if dom is None:
+        dom = settings._cell_domain = _CellDomain(settings.width)
+    return dom
+
+
+def _mont_limbs(values: list[int]):
+    """Montgomery limb rows uint32[n, L] of field elements, through the
+    vectorized byte layout."""
+    import numpy as np
+
+    from lighthouse_tpu.ops import fr
+
+    raw = b"".join((v * fr.FR.R_INT % BLS_MODULUS).to_bytes(32, "big")
+                   for v in values)
+    return fr.be32_bytes_to_limbs(
+        np.frombuffer(raw, np.uint8).reshape(-1, 32))
+
+
+def _pow2(n: int) -> int:
+    return 1 << max(n - 1, 0).bit_length()
+
+
+def _split_groups(cell_ids: list[int], commitment_idx: list[int],
+                  cell_size: int) -> list[tuple[int, int]]:
+    """[(lo, hi)]: the cells of a batch as the fewest runs of whole
+    sidecars, equal to within one, whose check fits the compiled bucket
+    (its distinct commitments + its cells + the monomial points <=
+    `_FUSED_MSM_LANES`).  A sidecar is a stretch of one cell index; one
+    too long to fit alone is cut."""
+    n = len(cell_ids)
+    longest = (_FUSED_MSM_LANES - cell_size) // 2
+    bounds = [0]
+    for k in range(1, n):
+        if cell_ids[k] != cell_ids[k - 1] or k - bounds[-1] >= longest:
+            bounds.append(k)
+    bounds.append(n)
+    units = len(bounds) - 1
+    for g in range(-(-(n + cell_size) // _FUSED_MSM_LANES), units + 1):
+        cuts = [bounds[i * units // g] for i in range(g + 1)]
+        groups = list(zip(cuts, cuts[1:]))
+        if all(len(set(commitment_idx[lo:hi])) + hi - lo + cell_size
+               <= _FUSED_MSM_LANES for lo, hi in groups):
+            return groups
+    raise AssertionError("a unit fits alone")
+
+
+def _interp_layouts(groups, cell_ids, dom: _CellDomain):
+    """The interpolation dispatches of a batch: [(first cell, shape
+    (rows, slots, groups), position of each cell, column of each slot)].
+    A slot is one column of one group; a cell's position is (row, slot,
+    group) flattened.  Groups go together as long as the lanes allow;
+    where a group's columns would not fit (few columns with many cells
+    beside many with few), each of its cells takes a slot."""
+    per_group = []
+    for lo, hi in groups:
+        keys = cell_ids[lo:hi]
+        slot_of, rows = {}, {}
+        place = []
+        for c in keys:
+            s = slot_of.setdefault(c, len(slot_of))
+            place.append((rows.get(s, 0), s))
+            rows[s] = place[-1][0] + 1
+        if (_pow2(max(rows.values())) * _pow2(len(slot_of)) * dom.size
+                > _INTERP_MAX_LANES):
+            place = [(0, s) for s in range(hi - lo)]
+            columns = list(keys)
+        else:
+            columns = list(slot_of)
+        per_group.append((place, columns))
+    out, g0 = [], 0
+    while g0 < len(groups):
+        take = 1
+        while g0 + take < len(groups):
+            b, c, g = _layout_shape(per_group[g0:g0 + take + 1])
+            if b * c * g * dom.size > _INTERP_MAX_LANES:
+                break
+            take += 1
+        chunk = per_group[g0:g0 + take]
+        b, c, g = _layout_shape(chunk)
+        position, columns = [], [[0] * g for _ in range(c)]
+        for gi, (place, cols) in enumerate(chunk):
+            position += [(row * c + s) * g + gi for row, s in place]
+            for s, col in enumerate(cols):
+                columns[s][gi] = col
+        out.append((groups[g0][0], (b, c, g), position, columns))
+        g0 += take
+    return out
+
+
+def _layout_shape(chunk) -> tuple[int, int, int]:
+    """(rows, slots, groups) of groups laid out together, each a power
+    of two."""
+    return (_pow2(max(row for place, _ in chunk for row, _ in place) + 1),
+            _pow2(max(len(cols) for _, cols in chunk)), _pow2(len(chunk)))
+
+
 def verify_cell_kzg_proof_batch(commitments: list[bytes],
                                 cell_ids: list[int], cells: list[bytes],
                                 proofs: list[bytes], settings) -> bool:
-    """Batch cell-proof verification (every triplet must hold).
+    """The spec's verify_cell_kzg_proof_batch
+    (consensus-specs specs/fulu/polynomial-commitments-sampling.md, the
+    universal verification equation): every (commitment, cell index,
+    cell, proof) must hold.
 
-    Production batches (>= 8 cells — a PeerDAS sampling round checks
-    hundreds) fold into ONE fused dispatch by random linear combination:
-    each cell check  e(Cᵢ − Iᵢ, −G₂)·e(πᵢ, (τⁿ − aᵢ)G₂) == 1  (n =
-    cell_size, aᵢ = hᵢⁿ the coset vanishing constant) rewrites as
-    e(Cᵢ − Iᵢ + aᵢπᵢ, −G₂)·e(πᵢ, τⁿG₂) == 1, so with verifier scalars
-    rᵢ the whole batch is
+    With n cells, D distinct commitments C_i (deduplicated in order of
+    first appearance), r the spec's challenge, h_c the coset shift of
+    column c and a_c = h_c^size:
 
-      e(Σ rᵢ(Cᵢ − Iᵢ + aᵢπᵢ), −G₂) · e(Σ rᵢπᵢ, τⁿG₂) == 1
+      e(sum_k r^k pi_k, [tau^size]G2)
+        == e(sum_i w_i C_i - [A(tau)]G1 + sum_k r^k a_c(k) pi_k, G2)
 
-    — the exact 2-MSM + 2-pairing shape of kzg._kzg_fused_check (the
-    blob batch path), with τⁿG₂ = g2_monomial[cell_size] in the second
-    slot.  The interpolation commitments Iᵢ never materialize: their
-    monomial coefficients fold onto the g1_monomial setup points with
-    AGGREGATED scalars −Σᵢ rᵢ·coeffᵢₘ (cell_size extra lanes total, not
-    per cell).  Small batches keep the per-cell loop.  Matches the
-    reference's c-kzg verify_cell_kzg_proof_batch fold
-    (/root/reference/crypto/kzg/src/lib.rs cell-proof surface)."""
+    w_i the sum of r^k over the cells of commitment i, A(X) = sum_k r^k
+    I_k(X) the aggregated interpolation polynomial (degree < size,
+    committed on g1_monomial[:size]): the two-sum, two-pairing shape of
+    kzg._kzg_fused.  Batches of >= _CELL_BATCH_FUSED_MIN cells ride the
+    device plane: commitments (once each) and proofs decompressed and
+    their membership dispatched, not waited for; the cells' field check,
+    the challenge and limbs; A's coefficients from one device program
+    (ops/fr._cell_interp_kernel, summed by column first); then the batch
+    as the fewest GROUPS of whole sidecars that fit the compiled bucket
+    (`_split_groups`), each its own check under its own powers of the one
+    r, the verdict their conjunction; a group is packed while the device
+    runs the one before.  Smaller batches keep the per-cell loop.  Which
+    served is the ``path`` of the ``kzg.verify_cell_batch`` span and of
+    ``kzg_cells_verified_total``."""
+    from lighthouse_tpu.crypto import kzg as _kzg
+
     n = len(commitments)
     if not (n == len(cell_ids) == len(cells) == len(proofs)):
         return False
-    if n < 8:
-        return all(
-            verify_cell_kzg_proof(c, cid, cell, pf, settings)
-            for c, cid, cell, pf in zip(commitments, cell_ids, cells,
-                                        proofs))
+    if n == 0:
+        return True
+    n_cells, _ = _cell_geometry(settings.width)
+    cell_ids = [int(c) for c in cell_ids]
+    if any(not 0 <= c < n_cells for c in cell_ids):
+        return False
+    fused = n >= _CELL_BATCH_FUSED_MIN
+    path = "fused" if fused else "host"
+    with _kzg.stage_span("kzg.verify_cell_batch", "verify_cell_batch",
+                         cells=n, columns=len(set(cell_ids)), path=path):
+        if fused:
+            verdict = _verify_cell_batch_fused(
+                commitments, cell_ids, cells, proofs, settings)
+        else:
+            verdict = all(
+                verify_cell_kzg_proof(c, cid, cell, pf, settings)
+                for c, cid, cell, pf in zip(commitments, cell_ids, cells,
+                                            proofs))
+    _kzg.count_cells_verified(path, n)
+    return verdict
 
-    import hashlib
-    import secrets
 
+def _verify_cell_batch_fused(commitments, cell_ids, cells, proofs,
+                             settings) -> bool:
+    import numpy as np
+
+    from lighthouse_tpu.common import tracing
     from lighthouse_tpu.crypto import kzg as _kzg
     from lighthouse_tpu.crypto.bls import curve as cv
+    from lighthouse_tpu.ops import fr
+    from lighthouse_tpu.ops import msm as _msm
+    from lighthouse_tpu.ops.bls_backend import dispatch_subgroup_check_g1
 
-    width = settings.width
-    n_cells, cell_size = _cell_geometry(width)
+    span = _kzg.stage_span
+    n = len(cells)
     try:
-        _require_monomials(settings, cell_size)
+        _require_monomials(settings, _cell_geometry(settings.width)[1])
     except KzgError:
         return False
-    try:
-        cs_pts = [cv.g1_from_bytes(b) for b in commitments]
-        pi_pts = [cv.g1_from_bytes(b) for b in proofs]
-        coeffs = []
-        for cid, cell in zip(cell_ids, cells):
-            if not 0 <= int(cid) < n_cells:
-                return False
-            coeffs.append(_interpolation_coeffs(cell, int(cid), settings))
-    except (ValueError, KzgError):
+    dom = _cell_domain(settings)
+    size = dom.size
+    if any(len(cell) != size * 32 for cell in cells):
         return False
+    index_of: dict[bytes, int] = {}
+    commitment_idx = [index_of.setdefault(c, len(index_of))
+                      for c in commitments]
+    distinct = list(index_of)
+    groups = _split_groups(cell_ids, commitment_idx, size)
+    tracing.add_attrs(commitments=len(distinct), groups=len(groups))
 
-    seed = hashlib.sha256(
-        b"LHTPU_RLC_CELL_BATCH_" + width.to_bytes(16, "big")
-        + n.to_bytes(16, "big") + b"".join(commitments)
-        + b"".join(proofs)
-        + b"".join(int(c).to_bytes(8, "big") for c in cell_ids)
-        + secrets.token_bytes(32)).digest()
-    r = int.from_bytes(seed, "big") % BLS_MODULUS
-    r_list = [pow(r, i + 1, BLS_MODULUS) for i in range(n)]
+    c_pts: dict[int, object] = {}
+    pi_pts: list = []
+    memberships = []
 
-    ext_roots = _compute_roots_of_unity(2 * width)
-    nat_of_brp = _bit_reversal_permutation(list(range(2 * width)))
-    lhs_points = list(cs_pts)
-    lhs_scalars = list(r_list)
-    mono_scalars = [0] * cell_size
-    for ri, cid, cf, pi in zip(r_list, cell_ids, coeffs, pi_pts):
-        for m_i, cm in enumerate(cf):
-            mono_scalars[m_i] = (mono_scalars[m_i] - ri * cm) % BLS_MODULUS
-        h = _coset_start(int(cid), cell_size, ext_roots, nat_of_brp)
-        a = pow(h, cell_size, BLS_MODULUS)
-        lhs_points.append(pi)
-        lhs_scalars.append(ri * a % BLS_MODULUS)
-    lhs_points.extend(settings.g1_monomial[:cell_size])
-    lhs_scalars.extend(mono_scalars)
-    return _kzg._kzg_fused_check(
-        lhs_points, lhs_scalars, pi_pts, r_list, settings,
-        tau_g2=settings.g2_monomial[cell_size],
-        cache_attr="_fused_g2_rows_cell")
+    def decode(lo, hi):
+        """The points of cells [lo, hi): their proofs, and the
+        commitments no earlier group brought; their membership
+        dispatched, not waited for."""
+        with span("kzg.decode", "decode", points=hi - lo):
+            fresh = [i for i in dict.fromkeys(commitment_idx[lo:hi])
+                     if i not in c_pts]
+            for i in fresh:
+                c_pts[i] = cv.g1_from_bytes(distinct[i],
+                                            subgroup_check=False)
+            pi_pts.extend(cv.g1_from_bytes(p, subgroup_check=False)
+                          for p in proofs[lo:hi])
+            memberships.append(dispatch_subgroup_check_g1(
+                [p for p in [c_pts[i] for i in fresh] + pi_pts[lo:hi]
+                 if p is not cv.INF]))
+
+    try:
+        decode(*groups[0])
+        with span("kzg.canonical", "canonical"):
+            raw = np.frombuffer(b"".join(cells), np.uint8).reshape(
+                n, size, 32)
+            if not _kzg._blob_fields_canonical(raw):
+                return False
+        with span("kzg.challenge", "challenge"):
+            r = compute_verify_cell_kzg_proof_batch_challenge(
+                distinct, commitment_idx, cell_ids, cells, proofs,
+                settings)
+            r_pows = [1] * n
+            for k in range(1, n):
+                r_pows[k] = r_pows[k - 1] * r % BLS_MODULUS
+        with span("kzg.limbs", "limbs"):
+            limbs = fr.be32_bytes_to_limbs(raw)
+            rk = _mont_limbs(r_pows)
+        interpolations = []
+        with span("kzg.interp.dispatch", "interp_dispatch"):
+            for lo, (b, c, g), position, columns in _interp_layouts(
+                    groups, cell_ids, dom):
+                hi = lo + len(position)
+                v = np.zeros((b * c * g, size, fr.L), np.uint32)
+                w = np.zeros((b * c * g, fr.L), np.uint32)
+                v[position] = limbs[lo:hi]
+                w[position] = rk[lo:hi]
+                out, products = fr.interpolate_cells_dispatch(
+                    v.reshape(b, c, g, size, fr.L),
+                    w.reshape(b, c, g, fr.L), dom.idft,
+                    dom.scale[np.asarray(columns)])
+                _kzg.count_interp_products(products)
+                interpolations.append(out)
+            del limbs, raw
+        for lo, hi in groups[1:]:
+            decode(lo, hi)
+    except ValueError:  # a point that is no point; in-flight work dropped
+        return False
+    with span("kzg.rlc", "rlc"):
+        weights = []   # a group's ({commitment: w_i}, [r^k a_c])
+        for lo, hi in groups:
+            w_i: dict[int, int] = {}
+            for k in range(lo, hi):
+                i = commitment_idx[k]
+                w_i[i] = (w_i.get(i, 0) + r_pows[k]) % BLS_MODULUS
+            weights.append((w_i, [r_pows[k] * dom.a[cell_ids[k]]
+                                  % BLS_MODULUS for k in range(lo, hi)]))
+    with span("kzg.interp.fetch", "interp_fetch"):
+        a_rows = [row for out in interpolations
+                  for row in fr.interpolation_scalars(out)]
+    checks = []
+    for gi, ((lo, hi), (w_i, ra)) in enumerate(zip(groups, weights)):
+        # no point enters a fold before its membership verdict is in; the
+        # programs of every group were dispatched before the first check
+        with span("kzg.decode.verdict", "decode"):
+            if not memberships[gi].commit():
+                return False
+        lhs_points = ([c_pts[i] for i in w_i] + pi_pts[lo:hi]
+                      + list(settings.g1_monomial[:size]))
+        lhs_scalars = (list(w_i.values()) + ra
+                       + [-a % BLS_MODULUS for a in a_rows[gi][:size]])
+        live = len(lhs_points) + hi - lo
+        _kzg.count_cell_lanes(
+            live, 2 * _msm.bucket(len(lhs_points)) - live)
+        checks.append(_kzg._kzg_fused_dispatch(
+            lhs_points, lhs_scalars, pi_pts[lo:hi], r_pows[lo:hi],
+            settings, tau_g2=settings.g2_monomial[size],
+            cache_attr="_fused_g2_rows_cell"))
+    # every check is read, so that a rejected batch is the same work
+    return all([_kzg._kzg_fused_verdict(f) for f in checks])
 
 
 def verify_cells_match_blob(cells: list[bytes], cell_ids: list[int],
@@ -508,6 +767,10 @@ __all__ = [
     "CELLS_PER_EXT_BLOB",
     "cells_to_blob",
     "compute_cells",
+    "compute_cells_and_kzg_proofs",
+    "compute_verify_cell_kzg_proof_batch_challenge",
     "recover_all_cells",
+    "verify_cell_kzg_proof",
+    "verify_cell_kzg_proof_batch",
     "verify_cells_match_blob",
 ]

@@ -377,14 +377,15 @@ _STAGE_BUCKETS = (0.0001, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 30.0)
 
 
 def record_stage(stage: str, seconds: float) -> None:
-    """One stage of a blob batch verification (sole registration site of
-    the kzg_* families — lhlint LH501 FAMILY_OWNERS)."""
+    """One stage of a blob or cell batch verification (sole registration
+    site of the kzg_* families — lhlint LH501 FAMILY_OWNERS)."""
     try:
         REGISTRY.histogram(
             "kzg_verify_stage_seconds",
-            "blob batch verification wall time by stage (eval_dispatch "
-            "and fused_dispatch time the enqueue, eval_fetch and "
-            "fused_wait the host blocked on the device)",
+            "blob and cell batch verification wall time by stage "
+            "(eval_dispatch, interp_dispatch and fused_dispatch time the "
+            "enqueue, eval_fetch, interp_fetch and fused_wait the host "
+            "blocked on the device)",
             buckets=_STAGE_BUCKETS,
         ).labels(stage=stage).observe(seconds)
     except Exception as e:
@@ -417,6 +418,34 @@ def count_eval_products(resident: int, materialized: int) -> None:
         "Fr lane-products of the evaluation slices, by multiply")
     products.labels(multiply="resident").inc(resident)
     products.labels(multiply="materialized").inc(materialized)
+
+
+def count_cells_verified(path: str, cells: int) -> None:
+    """Cells through das.verify_cell_kzg_proof_batch, by the path that
+    served the batch."""
+    REGISTRY.counter(
+        "kzg_cells_verified_total",
+        "cells through verify_cell_kzg_proof_batch, by the path that "
+        "served the batch").labels(path=path).inc(cells)
+
+
+def count_cell_lanes(live: int, padding: int) -> None:
+    """MSM lanes of one group of a cell batch through `_kzg_fused`: the
+    points of both sums, and what fills their buckets."""
+    lanes = REGISTRY.counter(
+        "kzg_cell_lanes_total",
+        "MSM lanes of the cell batch checks dispatched, by kind")
+    lanes.labels(kind="live").inc(live)
+    lanes.labels(kind="padding").inc(padding)
+
+
+def count_interp_products(products: int) -> None:
+    """Fr lane-products of the aggregated coset interpolation dispatched
+    (ops/fr._cell_interp_kernel, all on `MontField.mont_mul_lm`)."""
+    REGISTRY.counter(
+        "kzg_interp_products_total",
+        "Fr lane-products of the cell interpolation programs "
+        "dispatched").inc(products)
 
 
 def count_eval_slice(overlapped: bool) -> None:
@@ -492,7 +521,33 @@ def _kzg_fused_program():
 def _kzg_fused_check(lhs_points, lhs_scalars, pis, r_pows,
                      settings, tau_g2=None,
                      cache_attr: str = "_fused_g2_rows") -> bool:
-    """BOTH RLC MSMs and the 2-lane pairing as ONE device dispatch.
+    """`_kzg_fused_dispatch` and, at once, `_kzg_fused_verdict`: one check,
+    nothing else in flight (the blob batch)."""
+    return _kzg_fused_verdict(_kzg_fused_dispatch(
+        lhs_points, lhs_scalars, pis, r_pows, settings, tau_g2, cache_attr))
+
+
+def _kzg_fused_verdict(f) -> bool:
+    """Wait for a dispatched check and read its verdict: the fetch of its
+    Fq12 and the native final exponentiation."""
+    import jax
+
+    from lighthouse_tpu.ops.bls12_381 import fq12_from_device
+    from lighthouse_tpu.ops.bls_backend import _final_exp_is_one
+
+    with stage_span("kzg.fused.wait", "fused_wait"):
+        f_host = fq12_from_device(jax.device_get(f))
+    with stage_span("kzg.final_exp", "final_exp"):
+        return _final_exp_is_one(f_host)
+
+
+def _kzg_fused_dispatch(lhs_points, lhs_scalars, pis, r_pows,
+                        settings, tau_g2=None,
+                        cache_attr: str = "_fused_g2_rows"):
+    """BOTH RLC MSMs and the 2-lane pairing as ONE device dispatch, not
+    waited for: the device Fq12 `_kzg_fused_verdict` reads.  A caller with
+    several checks (crypto/das.py: the groups of a cell batch) packs the
+    next while the device runs this one.
 
     Lanes interleave s-major (even = lhs MSM, odd = proof MSM) through
     one windowed scalar-mul scan + a 2-segment sum; the two folded
@@ -505,13 +560,10 @@ def _kzg_fused_check(lhs_points, lhs_scalars, pis, r_pows,
     a full blob_sidecars_by_range response (2n+1 = 1,537 -> 2,048 lanes
     an MSM, 4,096 in all) the TPU compiler reports 2.16 GB of
     temporaries for a described v5e, so no lane cap is needed."""
-    import jax
     import jax.numpy as jnp
 
     from lighthouse_tpu.ops import ec
     from lighthouse_tpu.ops import msm as _msm
-    from lighthouse_tpu.ops.bls12_381 import fq12_from_device
-    from lighthouse_tpu.ops.bls_backend import _final_exp_is_one
 
     program = _kzg_fused_program()
     m = _msm.bucket(len(lhs_points))
@@ -552,12 +604,8 @@ def _kzg_fused_check(lhs_points, lhs_scalars, pis, r_pows,
             setattr(settings, cache_attr, g2rows)
 
     with stage_span("kzg.fused.dispatch", "fused_dispatch"):
-        f = program(jnp.asarray(xs), jnp.asarray(ys),
-                    jnp.asarray(digits), *g2rows)
-    with stage_span("kzg.fused.wait", "fused_wait"):
-        f_host = fq12_from_device(jax.device_get(f))
-    with stage_span("kzg.final_exp", "final_exp"):
-        return _final_exp_is_one(f_host)
+        return program(jnp.asarray(xs), jnp.asarray(ys),
+                       jnp.asarray(digits), *g2rows)
 
 
 def verify_blob_kzg_proof_batch(

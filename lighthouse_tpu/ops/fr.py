@@ -335,6 +335,85 @@ def evaluate_polynomials_batch(polys_raw_limbs: np.ndarray,
         max_blobs=max_blobs)[1]
 
 
+# --- PeerDAS: the aggregated coset interpolation of a cell batch --------------
+#
+# verify_cell_kzg_proof_batch (crypto/das.py) commits to A(X) = sum_k r^k
+# I_k(X), I_k the interpolation polynomial of cell k on its coset.  Cells of
+# one column share the coset shift h, so with the cells of a check laid out
+# by slot (one column of one group of the check) the sum over a slot's
+# cells comes first:
+#
+#   W[slot, j] = sum_{k in slot} r^k * v[k, j]                  B*C*G*S products
+#   T[slot, m] = sum_j omega^(-e_j m) * W[slot, j]              S*C*G*S
+#   A[g, m]    = sum_{slots c of g} h_c^(-m) / S * T[(c, g), m]   C*G*S
+#
+# (S field elements a cell, e_j the bit-reversed position of evaluation j
+# in its coset).  Every sum runs over the LEADING axis of its lanes, so it
+# is `_halves` and `add_lm`, as in the evaluation above.
+
+_pstore.register_entry("ops/fr.py::_cell_interp_kernel@_cell_interp_kernel",
+                       driver="fr")
+
+
+@jax.jit
+def _cell_interp_kernel(v, rk, idft, scale):
+    """v: uint32[B, C, G, S, L] RAW (non-Montgomery) limb rows of the
+    cells, row b of slot c of group g (an absent cell is a zero row); rk:
+    uint32[B, C, G, L] Montgomery r^k of each; idft: uint32[S, S, L]
+    Montgomery omega^(-e_j m) at [j, m]; scale: uint32[C, G, S, L]
+    Montgomery h^(-m) / S of the slot's column.  B, C, G, S powers of
+    two.  Returns uint32[G, S, L]: the coefficients A_m of each group's
+    aggregated interpolation polynomial, raw and redundant (a raw value
+    times a Montgomery one is raw)."""
+    B_, C, G, S, _ = v.shape
+    slots = C * G
+    v_lm = _lm(jnp.moveaxis(v, -1, 0))
+    rk_b = _lm(jnp.broadcast_to(
+        jnp.moveaxis(rk, -1, 0).reshape(L, B_ * slots, 1),
+        (L, B_ * slots, S)))
+    w = mont_mul_lm(v_lm, rk_b)
+    while _lanes(w) > slots * S:                 # over a slot's cells
+        w = add_lm(*_halves(w))
+    w_j = jnp.transpose(w.reshape(L, slots, S), (0, 2, 1))
+    t = mont_mul_lm(
+        _lm(jnp.broadcast_to(w_j[:, :, :, None], (L, S, slots, S))),
+        _lm(jnp.broadcast_to(
+            jnp.moveaxis(idft, -1, 0)[:, :, None, :], (L, S, slots, S))))
+    while _lanes(t) > slots * S:                 # over j
+        t = add_lm(*_halves(t))
+    a = mont_mul_lm(t, _lm(jnp.moveaxis(scale, -1, 0)))
+    while _lanes(a) > G * S:                     # over a group's slots
+        a = add_lm(*_halves(a))
+    return jnp.moveaxis(a.reshape(L, G, S), 0, -1)
+
+
+_cell_interp_kernel = _dtel.instrument(
+    "ops/fr.py::_cell_interp_kernel@_cell_interp_kernel", _cell_interp_kernel)
+
+
+def _interp_products(rows: int, slots: int, groups: int, size: int) -> int:
+    """Fr lane-products of one `_cell_interp_kernel` dispatch at
+    [rows, slots, groups, size] (all on `mont_mul_lm`): the weights, the
+    transforms, the scaling.  Static per shape."""
+    return (rows + size + 1) * slots * groups * size
+
+
+def interpolate_cells_dispatch(v, rk, idft, scale):
+    """One dispatch of `_cell_interp_kernel` on host arrays, not waited
+    for: the device array uint32[G, S, L] (`interpolation_scalars` reads
+    it), and the products it runs."""
+    out = _cell_interp_kernel(jnp.asarray(v), jnp.asarray(rk),
+                              jnp.asarray(idft), jnp.asarray(scale))
+    return out, _interp_products(*v.shape[:4])
+
+
+def interpolation_scalars(out) -> list[list[int]]:
+    """The fetched rows of `interpolate_cells_dispatch` as canonical
+    integers: [group][m]."""
+    rows = np.asarray(jax.device_get(out))
+    return [[_limbs_to_int(r) % R_INT for r in group] for group in rows]
+
+
 __all__ = [
     "B",
     "L",
@@ -344,6 +423,8 @@ __all__ = [
     "evaluate_polynomial_slices",
     "evaluate_polynomials_batch",
     "from_mont_host",
+    "interpolate_cells_dispatch",
+    "interpolation_scalars",
     "inv_mont",
     "mont_mul",
     "sub",

@@ -297,6 +297,13 @@ def _drv_fr(scale: str) -> None:
     raw = np.stack([np.stack([fr_ops._int_to_limbs(v) for v in p])
                     for p in polys])
     fr_ops.evaluate_polynomials_batch(raw, [11, 13], settings.roots_brp)
+    # the cell batch's aggregated interpolation: two cells in two columns
+    out, _ = fr_ops.interpolate_cells_dispatch(
+        raw[:, :2].reshape(1, 2, 1, 2, fr_ops.L),
+        fr_ops.to_mont_host([[[3], [5]]]),
+        fr_ops.to_mont_host([[1, 1], [1, FR_MOD - 1]]),
+        fr_ops.to_mont_host([[[1, 2]], [[1, 4]]]))
+    fr_ops.interpolation_scalars(out)
 
 
 def _drv_epoch(scale: str) -> None:

@@ -30,6 +30,7 @@ from lighthouse_tpu.types.spec import Preset
 DEPOSIT_CONTRACT_TREE_DEPTH = 32
 JUSTIFICATION_BITS_LENGTH = 4
 KZG_COMMITMENT_INCLUSION_PROOF_DEPTH = 17
+NUMBER_OF_COLUMNS = 128    # PeerDAS (fulu), configs/mainnet.yaml
 
 
 # --- preset-independent containers -----------------------------------------
@@ -48,6 +49,13 @@ class ForkData(ssz.Container):
 class Checkpoint(ssz.Container):
     epoch: ssz.uint64
     root: ssz.Bytes32
+
+
+class DataColumnsByRootIdentifier(ssz.Container):
+    """One block's columns in a data_column_sidecars_by_root request."""
+
+    block_root: ssz.Bytes32
+    columns: ssz.List(ssz.uint64, NUMBER_OF_COLUMNS)
 
 
 class Validator(ssz.Container):
@@ -639,6 +647,21 @@ def make_types(preset: Preset) -> SimpleNamespace:
         ("signed_block_header", SignedBeaconBlockHeader),
         ("kzg_commitment_inclusion_proof", ssz.Vector(
             ssz.Bytes32, KZG_COMMITMENT_INCLUSION_PROOF_DEPTH)),
+    ])
+
+    # PeerDAS (fulu): one column of cells across a block's blobs, a cell
+    # proof a cell, the block's commitments and their depth-4 proof under
+    # the body root
+    Cell = ssz.ByteVector(P.field_elements_per_cell * 32)
+    DataColumnSidecar = _container("DataColumnSidecar", [
+        ("index", ssz.uint64),
+        ("column", ssz.List(Cell, P.max_blob_commitments_per_block)),
+        ("kzg_commitments", KzgCommitments),
+        ("kzg_proofs", ssz.List(ssz.Bytes48,
+                                P.max_blob_commitments_per_block)),
+        ("signed_block_header", SignedBeaconBlockHeader),
+        ("kzg_commitments_inclusion_proof", ssz.Vector(
+            ssz.Bytes32, P.kzg_commitments_inclusion_proof_depth)),
     ])
 
     ns = SimpleNamespace(**{

@@ -61,6 +61,11 @@ class Preset:
     max_blob_commitments_per_block: int
     field_elements_per_blob: int
     max_blobs_per_block: int = 6
+    # PeerDAS (fulu): consensus-specs presets/mainnet/fulu.yaml
+    field_elements_per_cell: int = 64
+    field_elements_per_ext_blob: int = 8192
+    cells_per_ext_blob: int = 128
+    kzg_commitments_inclusion_proof_depth: int = 4
     # electra
     max_attester_slashings_electra: int = 1
     max_attestations_electra: int = 8
@@ -224,6 +229,16 @@ class ChainSpec:
     deneb_fork_epoch: int = 269568
     electra_fork_epoch: int = FAR_FUTURE_EPOCH
 
+    # PeerDAS (fulu): consensus-specs configs/mainnet.yaml.  The blob
+    # maximum is a schedule since the blob-parameter-only forks:
+    # (epoch, MAX_BLOBS_PER_BLOCK), ascending; before its first entry
+    # electra's maximum holds, before electra the preset's
+    number_of_columns: int = 128
+    number_of_custody_groups: int = 128
+    max_request_data_column_sidecars: int = 16384
+    max_blobs_per_block_electra: int = 9
+    blob_schedule: tuple = ((412672, 15), (419072, 21))
+
     # domains (4-byte little-endian tags)
     domain_beacon_proposer: int = 0
     domain_beacon_attester: int = 1
@@ -290,6 +305,17 @@ class ChainSpec:
         hardcoded suffix tuples like `fork in ("deneb", "electra")` —
         those silently exclude every later fork added to FORKS."""
         return FORKS.index(fork) >= FORKS.index(base)
+
+    def max_blobs_per_block_at(self, epoch: int) -> int:
+        """get_blob_parameters(epoch).max_blobs_per_block: the newest
+        entry of the schedule at or before ``epoch``."""
+        current = (self.max_blobs_per_block_electra
+                   if epoch >= self.electra_fork_epoch
+                   else self.preset.max_blobs_per_block)
+        for at, maximum in self.blob_schedule:
+            if at <= epoch:
+                current = maximum
+        return current
 
     def compute_epoch_at_slot(self, slot: int) -> int:
         return slot // self.slots_per_epoch

@@ -1749,7 +1749,7 @@ def test_aot_pass_wrong_id_still_flags(tmp_path):
 
 
 def test_aot_real_tree_every_manifest_entry_registered():
-    """The real-tree LH606 gate: all 20 shape-manifest entries carry a
+    """The real-tree LH606 gate: all 21 shape-manifest entries carry a
     program_store.register_entry registration (zero findings, zero
     waivers today), and the runtime registry agrees with the static
     sweep once the owner modules import."""

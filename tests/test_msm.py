@@ -418,7 +418,7 @@ def test_manifest_msm_family_unified():
     """One program-store registration point per (track, bucket): the
     four per-consumer MSM kernels are gone from the shape manifest,
     replaced by exactly three ops/msm.py entries — the MSM-family entry
-    count went DOWN (4 legacy -> 3 unified; 20 entries in all)."""
+    count went DOWN (4 legacy -> 3 unified; 21 entries in all)."""
     import pathlib
 
     manifest = pathlib.Path(__file__).parent.parent / "tools" / "lint" \
@@ -437,7 +437,7 @@ def test_manifest_msm_family_unified():
                        "ops/msm.py::_fold_kernel@_fold_kernel",
                        "ops/msm.py::_gather_fold@_gather_fold"]
     assert len(unified) < len(legacy)
-    assert len(entries) == 20
+    assert len(entries) == 21
     # and every unified entry is registered at runtime with the msm
     # prewarm driver (the one registration point)
     from lighthouse_tpu.ops import msm  # noqa: F401  (registers)

@@ -234,6 +234,33 @@ def test_kzg_eval_slice(one_chip, tpu_branches):
         assert "[64,4096,18,3" not in entry and ",18,36]" not in entry
 
 
+def test_cell_interp_full_block(one_chip, tpu_branches):
+    """The aggregated coset interpolation of one full block's 128 data
+    column sidecars (21 blobs: rows 32, two groups of 64 columns, cells of
+    64 field elements), the one shape `columns-21x128` dispatches, on the
+    multiply whose partial products stay in the core."""
+    from lighthouse_tpu.ops import fr
+
+    rows, slots, groups, size = 32, 64, 2, 64
+    c = _compile(
+        "_cell_interp_kernel@32x64x2x64", fr._cell_interp_kernel._fn,
+        _fr_rows(one_chip, rows, slots, groups, size),
+        _fr_rows(one_chip, rows, slots, groups),
+        _fr_rows(one_chip, size, size),
+        _fr_rows(one_chip, slots, groups, size))
+    read = c.cost_analysis()["bytes accessed"]
+    print("TPU_COMPILE " + json.dumps(
+        {"program": "_cell_interp_kernel", "bytes_accessed": int(read)}),
+        flush=True)
+    # 786,432 + 8,192 lane-products of 18 limbs: operands and results are
+    # ~230 MB; no [.., 18, 36] schoolbook product is an array
+    assert read < 1.5e9
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    assert ",18,36]" not in text[text.index("\nENTRY "):]
+
+
 def test_g1_subgroup_kernel_blob_batch(one_chip, tpu_branches):
     """The membership dispatch of a 768-sidecar batch: 1,536 points."""
     from lighthouse_tpu.ops import bls_backend as bb

@@ -63,7 +63,10 @@ FAMILY_OWNERS = {
     "merkle_stage_": "lighthouse_tpu/ops/sha256.py",
     "state_root_": "lighthouse_tpu/state_transition/slot_processing.py",
     # the stage spans of the blob plane (PR 29): the batch verifier and
-    # the sliced evaluation (ops/fr.py) record through kzg's helpers
+    # the sliced evaluation (ops/fr.py) record through kzg's helpers; so
+    # does the column plane (PR 33: crypto/das.py's cell batch and
+    # chain/data_column_verification.py): kzg_cells_verified_total,
+    # kzg_cell_lanes_total, kzg_interp_products_total
     "kzg_": "lighthouse_tpu/crypto/kzg.py",
     # the observatory plane (PR 11): each subsystem owns its families —
     # flight events/trips, manifest-keyed jit telemetry + the cold-start
