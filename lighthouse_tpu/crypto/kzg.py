@@ -408,16 +408,32 @@ def count_eval_lanes(live: int, padding: int) -> None:
     lanes.labels(kind="padding").inc(padding)
 
 
+def _count_by_multiply(products, resident: int, materialized: int) -> None:
+    products.labels(multiply="resident").inc(resident)
+    products.labels(multiply="materialized").inc(materialized)
+
+
 def count_eval_products(resident: int, materialized: int) -> None:
     """Fr lane-products the evaluation slices dispatched (ops/fr.py), by
     the multiply that ran them: ``resident`` is `MontField.mont_mul_lm`
     (partial products stay in the core), ``materialized`` `mont_mul`
     (they are arrays of the program)."""
-    products = REGISTRY.counter(
+    _count_by_multiply(REGISTRY.counter(
         "kzg_eval_products_total",
-        "Fr lane-products of the evaluation slices, by multiply")
-    products.labels(multiply="resident").inc(resident)
-    products.labels(multiply="materialized").inc(materialized)
+        "Fr lane-products of the evaluation slices, by multiply"),
+        resident, materialized)
+
+
+def count_fused_products(resident: int, materialized: int) -> None:
+    """Fp lane-products of one dispatched `_kzg_fused` check, by the
+    multiply that runs them: ``resident`` the G1 fold's (window tables,
+    scan, segment sum: ops/msm.fold_segments_g1, on `mont_mul_lm`),
+    ``materialized`` the two-lane Miller loop's and `reduce_product`'s
+    (`mont_mul`)."""
+    _count_by_multiply(REGISTRY.counter(
+        "kzg_fused_products_total",
+        "Fp lane-products of the fused KZG checks dispatched, by multiply"),
+        resident, materialized)
 
 
 def count_cells_verified(path: str, cells: int) -> None:
@@ -518,6 +534,20 @@ def _kzg_fused_program():
     return _KZG_FUSED_JIT
 
 
+@lru_cache(maxsize=None)
+def _fused_products(lanes: int, windows: int) -> tuple[int, int]:
+    """(resident, materialized) Fp lane-products of one `_kzg_fused`
+    dispatch at ``lanes`` MSM lanes of ``windows`` digits, as the program
+    routes them: the fold of two segments on `mont_mul_lm`; on `mont_mul`
+    the zero test of the two folded Z, the Miller loop of two Jacobian
+    lanes (7 products a lane before its 63 steps of 235) and the Fq12
+    product of the two lanes (54).  Static per shape, so reckoned once."""
+    from lighthouse_tpu.ops import msm as _msm
+
+    return (_msm.fold_products(lanes, 2, windows),
+            2 + 2 * (7 + 63 * 235) + 54)
+
+
 def _kzg_fused_check(lhs_points, lhs_scalars, pis, r_pows,
                      settings, tau_g2=None,
                      cache_attr: str = "_fused_g2_rows") -> bool:
@@ -558,8 +588,9 @@ def _kzg_fused_dispatch(lhs_points, lhs_scalars, pis, r_pows,
 
     One dispatch at every batch size a node sees: at the 768 sidecars of
     a full blob_sidecars_by_range response (2n+1 = 1,537 -> 2,048 lanes
-    an MSM, 4,096 in all) the TPU compiler reports 2.16 GB of
-    temporaries for a described v5e, so no lane cap is needed."""
+    an MSM, 4,096 in all) the TPU compiler reports 62 MB of temporaries
+    for a described v5e (2.16 GB while the fold's products were arrays
+    of the program), so no lane cap is needed."""
     import jax.numpy as jnp
 
     from lighthouse_tpu.ops import ec
@@ -604,8 +635,10 @@ def _kzg_fused_dispatch(lhs_points, lhs_scalars, pis, r_pows,
             setattr(settings, cache_attr, g2rows)
 
     with stage_span("kzg.fused.dispatch", "fused_dispatch"):
-        return program(jnp.asarray(xs), jnp.asarray(ys),
-                       jnp.asarray(digits), *g2rows)
+        f = program(jnp.asarray(xs), jnp.asarray(ys),
+                    jnp.asarray(digits), *g2rows)
+    count_fused_products(*_fused_products(2 * m, digits.shape[0]))
+    return f
 
 
 def verify_blob_kzg_proof_batch(

@@ -49,22 +49,22 @@ fields by the asserts in tests/test_bigint.py:
         values < 2^(F+4)
     limbs     < 2^15 + 2^11 everywhere
 
-The limb-major multiply (``mont_mul_lm``, with ``add_lm`` / ``sub_lm``;
-Fr's evaluation programs since PR 32, the Fp programs still to follow).
-The same construction for arrays uint32[L, ...lanes] whose FIRST axis is
-the limb.  ``mont_mul`` above makes every schoolbook product an array of
-the program ([.., L, 2L], written to HBM and read back: ~27 KB a Fr
-product-lane that needs 216 bytes); here the partial products, the
-column sums and both REDC products of a block of lanes live in vector
-registers and VMEM, so a product-lane costs HBM its two operands and its
-result.  One text, ``_mont_mul_lm``, with the limb axis leading; two
-launchers, chosen by ``jax.default_backend()`` as ``_use_mxu_redc``
-chooses: on a TPU a Pallas kernel over blocks uint32[L, 8k, 128] (a limb
-of 1,024 lanes is one vector register; N and N' are scalars of the
-kernel, not operands), elsewhere the same text on the whole arrays (the
-same text under plain XLA on a TPU makes [20, S, 128] arrays of its rows
-again: 7.8 GB a pass by the compile rehearsal of PR 32, so the kernel is
-what carries the bytes).  Its lines of the ledger:
+The limb-major multiply (``mont_mul_lm`` with ``add_lm``, ``sub_lm``,
+``scale_small_lm``: Fr's evaluation programs since PR 32; of Fp, the G1
+fold of ops/msm.py since PR 34, under `_kzg_fused`, `_fold_kernel` and
+`_gather_fold`; `_pipeline_fused`, `_blinded_fold` and the two subgroup
+kernels remain on ``mont_mul``).  The same construction for arrays
+uint32[L, ...lanes] whose FIRST axis is the limb.  ``mont_mul`` makes
+every schoolbook product an array of the program ([.., L, 2L], to HBM and
+back: ~27 KB a Fr product-lane that needs 216 bytes); here the partial
+products, the column sums and both REDC products of a block of lanes
+live in vector registers and VMEM, so a product-lane costs HBM its two
+operands and its result.  One text, ``_mont_mul_lm``; two launchers,
+chosen by ``jax.default_backend()`` as ``_use_mxu_redc`` chooses: on a
+TPU a Pallas kernel over blocks uint32[L, 8k, 128] (a limb of 1,024
+lanes is one vector register; N and N' are scalars of the kernel),
+elsewhere the text on the whole arrays (under plain XLA on a TPU it makes
+arrays of its rows again: 7.8 GB a pass, PR 32).  Its lines of the ledger:
 
     mont_mul_lm in    limbs < 2^15 + 2^11 (THREE raw limb products are
         summed in uint32 before one 15-bit split: 3·(2^15+2^11-1)^2 <
@@ -77,8 +77,8 @@ what carries the bytes).  Its lines of the ledger:
         differ by R and the product by N)
     columns           < 2^21: ceil(L/3) low halves < 2^15 and as many
         high halves < 2^17
-    add_lm / sub_lm   the `add / sub` line, limb for limb what `add` and
-        `sub` give
+    add_lm / sub_lm / scale_small_lm(k <= 16)   the `add / sub` and the
+        `scale_small` lines, limb for limb: legal `mont_mul_lm in` (Fp)
 
 Reference counterpart: the limb arithmetic inside blst
 (/root/reference/crypto/bls/src/impls/blst.rs's FFI layer).
@@ -584,7 +584,7 @@ class MontField:
         assert all(x.shape == ops[0].shape for x in ops) and (
             ops[0].shape[0] == self.L), [x.shape for x in ops]
         if not _use_resident_kernel():
-            return fn(*ops)
+            return _jitted(fn)(*ops)
         out = self._resident_call(
             fn, ops_a_lane, [_to_blocks(x) for x in ops])
         return _from_blocks(out, ops[0].shape)
@@ -621,6 +621,27 @@ class MontField:
                 flops=ops_a_lane * S * C, transcendentals=0,
                 bytes_accessed=4 * L * S * C * (len(ops) + 1)),
             interpret=interpret)(*ops)
+
+    def _scale_small_lm(self, a: jax.Array, k: int) -> jax.Array:
+        return self._fold_top_lm(_carry_lm(a * k))
+
+    def scale_small_lm(self, a: jax.Array, k: int) -> jax.Array:
+        """`scale_small` on limb-major arrays uint32[L, ...lanes]."""
+        return self._launch(self._scale_fn(k), 16 * self.L, a)
+
+    @functools.cache
+    def _scale_fn(self, k: int):
+        """One callable a factor, so that what `_launch` keeps by its
+        function (`_jitted`) is found again."""
+        assert 0 < k <= 16
+        return lambda x: self._scale_small_lm(x, k)
+
+
+# Off the TPU `_launch` runs a limb-major text through ONE jitted function
+# an operation: a program that holds hundreds of them (the G1 fold: ~60
+# multiplies, ~250 additions) then traces each once a shape and calls it,
+# where tracing every use anew took XLA:CPU's tests four times as long.
+_jitted = functools.lru_cache(maxsize=64)(jax.jit)
 
 
 # --- the base field, under the names its callers use -------------------------

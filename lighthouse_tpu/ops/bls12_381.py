@@ -137,17 +137,28 @@ def fp12_conj(x):
 # which also feeds the vector units k·N-wide lanes.
 
 class _MulQueue:
-    """Collects Fp products; `run` executes them in one mont_mul."""
+    """Collects Fp products; `run` executes them in one mont_mul — and
+    the limb-major products of a round (`fp_lm`: the G1 fold of ops/ec.py)
+    in one `mont_mul_lm` beside it."""
 
     def __init__(self):
         self._a: list = []
         self._b: list = []
         self._out = None
+        self._lm: list = []
+        self._lm_out: list = []
 
     def fp(self, a, b) -> int:
         self._a.append(a)
         self._b.append(b)
         return len(self._a) - 1
+
+    def fp_lm(self, a, b):
+        """Queue a product of limb-major arrays uint32[L, lanes]; returns
+        a resolver."""
+        i = len(self._lm)
+        self._lm.append((a, b))
+        return lambda: self._lm_out[i]
 
     def fp2(self, x, y):
         """Queue a Karatsuba Fq2 product; returns a resolver."""
@@ -232,7 +243,15 @@ class _MulQueue:
         return resolve
 
     def run(self):
-        self._out = bi.mont_mul(jnp.stack(self._a), jnp.stack(self._b))
+        if self._a:
+            self._out = bi.mont_mul(jnp.stack(self._a), jnp.stack(self._b))
+        if self._lm:
+            # one launch a round: the products side by side along the lanes
+            out = bi.FP.mont_mul_lm(
+                jnp.concatenate([a for a, _ in self._lm], axis=1),
+                jnp.concatenate([b for _, b in self._lm], axis=1))
+            ends = np.cumsum([a.shape[1] for a, _ in self._lm]).tolist()
+            self._lm_out = jnp.split(out, ends[:-1], axis=1)
 
     def __getitem__(self, i: int):
         return self._out[i]

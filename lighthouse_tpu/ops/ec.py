@@ -47,9 +47,14 @@ from lighthouse_tpu.ops.bls12_381 import (
 # --- field adapters ---------------------------------------------------------
 #
 # The Jacobian formulas below are written once against this tiny protocol;
-# G1 instantiates it over Fp lanes (uint32[N, 27]), G2 over Fq2 pairs.
+# G1 instantiates it over Fp lanes (uint32[N, 27]), G2 over Fq2 pairs, and
+# the G1 fold of the MSM plane over limb-major Fp lanes (uint32[27, N]).
+# `lane_axis` says where an array's lanes are, for the formulas that
+# concatenate, split, tile or mask along them.
 
 class _FpAdapter:
+    lane_axis = 0
+
     @staticmethod
     def mul(q: _MulQueue, x, y):
         i = q.fp(x, y)
@@ -76,7 +81,41 @@ class _FpAdapter:
         return jnp.broadcast_to(bi._jconst("one_m"), x.shape)
 
 
+class _FpLmAdapter:
+    """Fp over limb-major lanes uint32[27, N], on `MontField.mont_mul_lm`
+    (ops/bigint.py: the partial products stay in the core): a round's
+    products go side by side along the lanes into one launch."""
+
+    lane_axis = 1
+
+    @staticmethod
+    def mul(q: _MulQueue, x, y):
+        return q.fp_lm(x, y)
+
+    add = staticmethod(bi.FP.add_lm)
+    sub = staticmethod(bi.FP.sub_lm)
+    scale = staticmethod(bi.FP.scale_small_lm)
+
+    @staticmethod
+    def is_zero(x):
+        return jnp.all(x == 0, axis=0)
+
+    @staticmethod
+    def select(cond, a, b):
+        return jnp.where(cond[None], a, b)
+
+    @staticmethod
+    def zeros_like(x):
+        return jnp.zeros_like(x)
+
+    @staticmethod
+    def one_like(x):
+        return jnp.broadcast_to(bi._jconst("one_m")[:, None], x.shape)
+
+
 class _Fq2Adapter:
+    lane_axis = 0
+
     @staticmethod
     def mul(q: _MulQueue, x, y):
         return q.fp2(x, y)
@@ -324,21 +363,22 @@ def _window_tables(bases, width: int = 4):
     through shared queues — ~24 mul rounds total.
 
     bases: [(F, (xb, yb))].  Returns per track a (X, Y, Z) tuple whose
-    leaves are [2^w, N, L] stacks (Fq2 leaves are pairs of stacks)."""
+    leaves are [2^w, N, L] stacks ([2^w, L, N] on the limb-major track;
+    Fq2 leaves are pairs of stacks)."""
     n_entries = 1 << width
 
     def cat(F, entries, coord):
         if F is _Fq2Adapter:
             return (jnp.concatenate([e[coord][0] for e in entries]),
                     jnp.concatenate([e[coord][1] for e in entries]))
-        return jnp.concatenate([e[coord] for e in entries])
+        return jnp.concatenate([e[coord] for e in entries], F.lane_axis)
 
     def split(F, arr, count):
         if F is _Fq2Adapter:
             a0 = jnp.split(arr[0], count)
             a1 = jnp.split(arr[1], count)
             return list(zip(a0, a1))
-        return jnp.split(arr, count)
+        return jnp.split(arr, count, F.lane_axis)
 
     tabs = []
     for F, (xb, yb) in bases:
@@ -364,9 +404,9 @@ def _window_tables(bases, width: int = 4):
                           F.one_like((jnp.tile(xb[0], (count, 1)),
                                       jnp.tile(xb[1], (count, 1)))))
             else:
-                base_j = (jnp.tile(xb, (count, 1)),
-                          jnp.tile(yb, (count, 1)),
-                          F.one_like(jnp.tile(xb, (count, 1))))
+                reps = (count, 1) if F.lane_axis == 0 else (1, count)
+                base_j = (jnp.tile(xb, reps), jnp.tile(yb, reps),
+                          F.one_like(jnp.tile(xb, reps)))
             add_items.append((F, dbl, base_j))
         odds = _jac_add_full_multi(add_items)
         for (F, _), tab, dbl, odd in zip(bases, tabs, doubles, odds):
@@ -392,7 +432,8 @@ def _window_tables(bases, width: int = 4):
 
 
 def _table_pick(F, tab, digit):
-    """Per-lane table pick: tab leaves [2^w, N, L], digit uint32[N].
+    """Per-lane table pick: tab leaves [2^w, N, L] ([2^w, L, N] on the
+    limb-major track), digit uint32[N].
 
     One-hot select chain instead of a dynamic gather: XLA:CPU's AOT
     serializer (the persistent compile-cache writer) segfaults on
@@ -402,7 +443,8 @@ def _table_pick(F, tab, digit):
     def g(arr):
         out = arr[0]
         for d in range(1, arr.shape[0]):
-            out = jnp.where((digit == d)[:, None], arr[d], out)
+            out = jnp.where(jnp.expand_dims(digit == d, 1 - F.lane_axis),
+                            arr[d], out)
         return out
 
     def pick(coord):
@@ -413,9 +455,10 @@ def _table_pick(F, tab, digit):
 
 def g1_scalar_mul_windowed(xp, yp, digits):
     """Single-track windowed scalar mul over G1 lanes (the MSM's form:
-    arbitrary-width scalars as [W, N] window digits).  Same table/flag
-    machinery as the merged scan."""
-    F1 = _FpAdapter
+    arbitrary-width scalars as [W, N] window digits), LIMB-MAJOR: xp, yp
+    and the Jacobian (X, Y, Z) it returns are uint32[27, N].  Same
+    table/flag machinery as the merged scan, on `_FpLmAdapter`."""
+    F1 = _FpLmAdapter
     (tab1,) = _window_tables([(F1, (xp, yp))])
     s1 = (F1.zeros_like(xp), F1.zeros_like(yp), F1.zeros_like(xp))
     inf = jnp.ones(digits.shape[1:], bool)
@@ -553,12 +596,6 @@ def g2_sum_reduce(X, Y, Z):
     return _sum_reduce(_Fq2Adapter, take, X, Y, Z, X[0].shape[0])
 
 
-def g1_sum_reduce(X, Y, Z):
-    """Tree-reduce G1 Jacobian lanes to one point."""
-    take = lambda t, sl: t[sl]  # noqa: E731
-    return _sum_reduce(_FpAdapter, take, X, Y, Z, X.shape[0])
-
-
 def g1_segment_sum(X, Y, Z, n_segments: int):
     """Segmented Jacobian tree-sum: lanes laid out s-major ([S*G] with
     lane index s·G + g) reduce to one point per segment g.
@@ -578,13 +615,27 @@ def g1_segment_sum(X, Y, Z, n_segments: int):
     return Xo[0], Yo[0], Zo[0]
 
 
+def g1_segment_sum_lm(X, Y, Z, n_segments: int):
+    """`g1_segment_sum` for limb-major lanes uint32[27, S*G] (s-major as
+    there: the first half of the lanes is the first half of every
+    segment) -> (X, Y, Z) uint32[27, G]."""
+    total = X.shape[1]
+    assert total % n_segments == 0
+    S = total // n_segments
+    assert S & (S - 1) == 0, "segment size must be a power of two"
+    take = lambda t, sl: t[:, sl.start * n_segments:  # noqa: E731
+                           sl.stop * n_segments]
+    return _sum_reduce(_FpLmAdapter, take, X, Y, Z, S)
+
+
 def g1_msm_windowed(xp, yp, digits):
-    """Multi-scalar multiplication Σ k_i·P_i over G1 lanes, from window
-    digits ([W, N] from scalars_to_digits): ~40% fewer products and
-    ~1.4x fewer sequential rounds than a binary scan for the KZG MSM's
-    255-bit scalars.  Returns one Jacobian point."""
-    X, Y, Z = g1_scalar_mul_windowed(xp, yp, digits)
-    return g1_sum_reduce(X, Y, Z)
+    """Multi-scalar multiplication Σ k_i·P_i over G1 lanes uint32[N, 27],
+    from window digits ([W, N] from scalars_to_digits): ~40% fewer
+    products and ~1.4x fewer sequential rounds than a binary scan for the
+    KZG MSM's 255-bit scalars.  Returns one Jacobian point, rows
+    uint32[1, 27]; inside, the limb-major scan and segment sum."""
+    X, Y, Z = g1_scalar_mul_windowed(xp.T, yp.T, digits)
+    return tuple(c.T for c in g1_segment_sum_lm(X, Y, Z, 1))
 
 
 # --- batched G2 subgroup check (ψ test) -------------------------------------

@@ -90,9 +90,23 @@ def fold_segments_g1(xs, ys, digits, n_segments):
     """Windowed G1 scalar-mul over lanes + s-major segment sum ->
     Jacobian rows (X, Y, Z) uint32[n_segments, L].  ``digits`` are
     MSB-first base-16 window digits (ec.scalars_to_digits); lane count
-    must be a multiple of n_segments with a pow2 segment length."""
-    X, Y, Z = ec.g1_scalar_mul_windowed(xs, ys, digits)
-    return ec.g1_segment_sum(X, Y, Z, n_segments)
+    must be a multiple of n_segments with a pow2 segment length.  The
+    lanes (xs, ys uint32[N, L]) are turned limb-major on entry and the
+    rows back on exit: in between every product is
+    `MontField.mont_mul_lm`'s."""
+    X, Y, Z = ec.g1_scalar_mul_windowed(xs.T, ys.T, digits)
+    return tuple(c.T for c in ec.g1_segment_sum_lm(X, Y, Z, n_segments))
+
+
+def fold_products(lanes: int, n_segments: int, windows: int) -> int:
+    """Fp lane-products of `fold_segments_g1`, all on `mont_mul_lm`: a
+    Jacobian doubling is 7 and a full addition 16; the window tables
+    double and add 7 entries a lane, a window of the scan is four
+    doublings and one addition, and the segment sum adds every lane but
+    the last of each segment."""
+    dbl, add = 7, 16
+    return ((7 * (dbl + add) + windows * (4 * dbl + add)) * lanes
+            + add * (lanes - n_segments))
 
 
 def fold_segments_gj(xp, yp, xq, yq, digits, n_segments):

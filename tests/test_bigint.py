@@ -299,6 +299,10 @@ def test_resident_kernel_interpreted(field):
         got = bi._from_blocks(
             F._resident_call(fn, 1, blocks, interpret=True), a.shape)
         assert (np.asarray(got) == np.asarray(fn(a, b))).all()
+    scale = F._scale_fn(3)
+    got = bi._from_blocks(
+        F._resident_call(scale, 1, blocks[:1], interpret=True), a.shape)
+    assert (np.asarray(got) == np.asarray(scale(a))).all()
     k = F.tables["r2"]
     fixed = lambda x: F._mont_mul_lm(  # noqa: E731
         x, bi._splat_lm(k.tolist(), x))
@@ -346,6 +350,26 @@ def test_add_sub_lm_and_a_deep_chain_keep_the_ledger(field):
             == np.asarray(F.sub(ax.T, ay.T))).all()
     assert (np.asarray(F.add_lm(ax, ay)).T
             == np.asarray(F.add(ax.T, ay.T))).all()
+
+
+@pytest.mark.parametrize("k", [2, 3, 4, 8, 16])
+def test_scale_small_lm_gives_a_legal_multiplicand(field, k):
+    """scale_small_lm at the ledger's extremes: k·x against integers, limb
+    for limb what `scale_small` gives, inside the ledger's line (so THREE
+    raw products of its limbs fit uint32), and exact through the multiply
+    that takes it next."""
+    F, N = field, field.P_INT
+    rows = _extreme_rows(F)
+    a = jnp.asarray(rows.T)
+    got = np.asarray(jax.jit(F.scale_small_lm, static_argnums=1)(a, k)).T
+    assert _residues(F, got) == [(k * F.limbs_to_int(r)) % N for r in rows]
+    assert (got == np.asarray(F.scale_small(jnp.asarray(rows), k))).all()
+    assert _in_ledger(got, TOP_BOUND if F is bi.FP else 2 * TOP_BOUND)
+    assert 3 * int(got.max()) ** 2 < 1 << 32
+    sq = np.asarray(F.mont_mul_lm(jnp.asarray(got.T), jnp.asarray(got.T))).T
+    assert _residues(F, sq) == [
+        ((k * F.limbs_to_int(r)) ** 2 * F.R_INV) % N for r in rows]
+    assert _in_ledger(sq, 1 << 4)
 
 
 def _spread_limbs(v: int, F, limit: int = LIMB_BOUND - 1) -> np.ndarray:

@@ -83,6 +83,107 @@ def test_g1_windowed_msm_matches_oracle():
     assert _jac_to_affine_fp(Xw, Yw, Zw, 0) == want
 
 
+# --- the limb-major G1 fold (ops/msm.fold_segments_g1: the windowed scan,
+# its tables and the segment sum on `MontField.mont_mul_lm`) -----------------
+#
+# One compiled shape serves every case: 8 lanes of 256-bit scalars in two
+# segments, lane 2s + g the s-th lane of segment g.
+
+_R = 0x73EDA753299D7D483339D80809A1D80553BDA402FFFE5BFEFFFFFFFF00000001
+_FOLD_CASES = {
+    # scalars whose windows are zero at the top, in the middle, at the
+    # bottom, everywhere but one, and nowhere
+    "zero_windows": ([1, 16, 1 << 252, 0xF0F0, 15 << 128, _R - 1,
+                      (1 << 255) - 19, 0x1000000000000001], None),
+    # zero scalars beside live ones: lanes that stay at infinity
+    "zero_scalars": ([0, 5, 7, 0, 0, 11, 13, 0], None),
+    # points at infinity enter as (0, 0) under a zero scalar
+    "lanes_at_infinity": ([3, 0, 0, 9, 4, 0, 0, 6], (1, 2, 5, 6)),
+    # segment 1 is padding alone: its row is the exact-zero infinity
+    "all_padding_segment": ([3, 0, 5, 0, 7, 0, 9, 0], (1, 3, 5, 7)),
+    # segment 0 holds k·P and (r - k)·P twice over: every chord of its tree
+    # is a degenerate one and the row comes back with Z = 0 mod p, the
+    # lane `_kzg_fused` masks to e(INF, ·) = 1
+    "segment_folds_to_infinity": ([12345, 2, 99, 3, _R - 12345, 4,
+                                   _R - 99, 5], None),
+}
+
+
+@pytest.fixture(scope="module")
+def fold_2x4():
+    from lighthouse_tpu.ops import msm
+
+    return jax.jit(lambda xs, ys, dg: msm.fold_segments_g1(xs, ys, dg, 2))
+
+
+def _fold_points():
+    # lanes 0/4 and 2/6 share a point, so that k·P + (r - k)·P is a chord
+    g = cv.g1_generator()
+    return [cv.g1_mul(g, 21 + (i % 4 if i % 2 == 0 else i))
+            for i in range(8)]
+
+
+def _fold_case(scalars, at_infinity):
+    pts = _fold_points()
+    for i in at_infinity or ():
+        pts[i] = cv.INF
+    xs = jnp.asarray(ec.ints_to_mont_limbs(
+        [0 if p is cv.INF else p[0] for p in pts]))
+    ys = jnp.asarray(ec.ints_to_mont_limbs(
+        [0 if p is cv.INF else p[1] for p in pts]))
+    want = []
+    for seg in range(2):
+        acc = cv.INF
+        for p, k in list(zip(pts, scalars))[seg::2]:
+            if p is not cv.INF and k:
+                acc = cv.g1_add(acc, cv.g1_mul(p, k))
+        want.append(acc)
+    return xs, ys, jnp.asarray(
+        ec.scalars_to_digits(scalars, n_bits=256)), want
+
+
+@pytest.mark.parametrize("case", sorted(_FOLD_CASES))
+def test_limb_major_fold_matches_oracle(fold_2x4, case):
+    scalars, at_infinity = _FOLD_CASES[case]
+    xs, ys, digits, want = _fold_case(scalars, at_infinity)
+    X, Y, Z = jax.device_get(fold_2x4(xs, ys, digits))
+    assert X.shape == (2, bi.L)
+    for seg in range(2):
+        assert _jac_to_affine_fp(X, Y, Z, seg) == want[seg], (case, seg)
+    if case == "all_padding_segment":
+        # never added to: exact zero limbs, not a multiple of p
+        assert not (X[1].any() or Y[1].any() or Z[1].any())
+    if case == "segment_folds_to_infinity":
+        assert want[0] is cv.INF and want[1] is not cv.INF
+        assert np.asarray(bi.is_zero_mod_p_device(jnp.asarray(Z))).tolist() \
+            == [True, False]
+
+
+def test_limb_major_scan_and_sum_one_segment():
+    """The scan's lanes one by one (zero scalars canonical: exact zeros),
+    then the same lanes through the one-segment sum `g1_msm_windowed`
+    ends in."""
+    scalars, _ = _FOLD_CASES["zero_scalars"]
+    xs, ys, digits, _ = _fold_case(scalars, None)
+    pts = _fold_points()
+
+    @jax.jit
+    def both(xs, ys, digits):
+        lanes = ec.g1_scalar_mul_windowed(xs.T, ys.T, digits)
+        return lanes, ec.g1_segment_sum_lm(*lanes, 1)
+
+    (X, Y, Z), (Xs, Ys, Zs) = jax.device_get(both(xs, ys, digits))
+    assert X.shape == (bi.L, 8) and Xs.shape == (bi.L, 1)
+    total = cv.INF
+    for i, k in enumerate(scalars):
+        want = cv.g1_mul(pts[i], k) if k else cv.INF
+        assert _jac_to_affine_fp(X.T, Y.T, Z.T, i) == want, i
+        if not k:
+            assert not (X[:, i].any() or Y[:, i].any() or Z[:, i].any())
+        total = cv.g1_add(total, want)
+    assert _jac_to_affine_fp(Xs.T, Ys.T, Zs.T, 0) == total
+
+
 def test_g2_sum_reduce_matches_oracle():
     g = cv.g2_generator()
     pts = [cv.g2_mul(g, k) for k in (11, 22, 33, 44)]

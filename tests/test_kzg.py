@@ -112,6 +112,77 @@ def test_blob_proof_batch_fused_device_path(settings):
     assert not kzg.verify_blob_kzg_proof_batch(evil, cs, proofs, settings)
 
 
+def test_fused_check_counts_its_products(settings):
+    """`kzg_fused_products_total` grows by `_fused_products` a dispatched
+    check (the batch shape of the test above: 2·8+1 points in a bucket of
+    32, 64 lanes, no new program), nearly all of it resident."""
+    from lighthouse_tpu.common.metrics import REGISTRY
+
+    def grown():
+        fam = REGISTRY.counter(
+            "kzg_fused_products_total",
+            "Fp lane-products of the fused KZG checks dispatched, by "
+            "multiply")
+        return {k: fam.labels(multiply=k).value
+                for k in ("resident", "materialized")}
+
+    n = kzg._DEVICE_EVAL_MIN
+    blobs = [_blob(settings, 30 + i) for i in range(n)]
+    cs = [kzg.blob_to_kzg_commitment(b, settings) for b in blobs]
+    proofs = [kzg.compute_blob_kzg_proof(b, c, settings)
+              for b, c in zip(blobs, cs)]
+    before = grown()
+    assert kzg.verify_blob_kzg_proof_batch(blobs, cs, proofs, settings)
+    after = grown()
+    res, mat = kzg._fused_products(64, 64)
+    assert after["resident"] - before["resident"] == res
+    assert after["materialized"] - before["materialized"] == mat
+    assert 100 * res / (res + mat) > 85
+
+
+@pytest.mark.parametrize("lanes", [4, 16])
+def test_fused_products_are_what_the_program_traces(monkeypatch, lanes):
+    """`_fused_products` against a tally of the lanes each multiply of
+    `_kzg_fused` is traced with, a scan's body counted once a step."""
+    import jax
+    import jax.numpy as jnp
+
+    from lighthouse_tpu.ops import bigint as bi
+
+    tally = {"resident": 0, "materialized": 0}
+    steps = [1]
+    lm, mm, scan = bi.FP.mont_mul_lm, bi.mont_mul, jax.lax.scan
+
+    def count_lm(a, b):
+        tally["resident"] += steps[-1] * int(np.prod(a.shape[1:]))
+        return lm(a, b)
+
+    def count_mm(a, b):
+        tally["materialized"] += steps[-1] * int(np.prod(a.shape[:-1]))
+        return mm(a, b)
+
+    def count_scan(f, init, xs, *args, **kwargs):
+        steps.append(steps[-1] * jax.tree_util.tree_leaves(xs)[0].shape[0])
+        try:
+            return scan(f, init, xs, *args, **kwargs)
+        finally:
+            steps.pop()
+
+    monkeypatch.setattr(bi.FP, "mont_mul_lm", count_lm)
+    monkeypatch.setattr(bi, "mont_mul", count_mm)
+    monkeypatch.setattr(jax.lax, "scan", count_scan)
+    rows = lambda n: jax.ShapeDtypeStruct((n, bi.L), jnp.uint32)  # noqa: E731
+    jax.eval_shape(
+        kzg._kzg_fused_program()._fn, rows(lanes), rows(lanes),
+        jax.ShapeDtypeStruct((64, lanes), jnp.uint32), *[rows(2)] * 4)
+    assert kzg._fused_products(lanes, 64) == (
+        tally["resident"], tally["materialized"])
+    # the cells' shape: 4,096 lanes of 256-bit scalars
+    res, mat = kzg._fused_products(4096, 64)
+    assert (res, mat) == (12_259_296, 29_680)
+    assert 100 * res / (res + mat) > 99
+
+
 def test_constant_blob_infinity_proof(settings):
     """Constant polynomial -> zero quotient -> infinity proof point."""
     vals = [42] * settings.width

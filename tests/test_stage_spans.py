@@ -299,3 +299,22 @@ from benchmarks.tests.test_stage_metrics import (  # noqa: E402,F401
     test_merkle_pad_waste_reads_a_synthetic_window,
     test_new_histogram_metric_reads_a_synthetic_window,
 )
+
+
+@pytest.mark.parametrize("cell", ["blobs", "columns"])
+def test_fused_resident_share_reads_a_synthetic_window(cell):
+    """`kzg_fused_resident_pct.{blobs,columns}` (PR 34): the share of
+    `kzg_fused_products_total`'s growth on `multiply="resident"`; a program
+    without the family (the parent commit) reads nothing and does not
+    raise."""
+    from benchmarks.tests.test_stage_metrics import _read
+
+    family = "kzg_fused_products_total"
+    res, mat = (frozenset({"multiply": k}.items())
+                for k in ("resident", "materialized"))
+    ctx = {"before": {(family, res): 100.0, (family, mat): 10.0},
+           "after": {(family, res): 1090.0, (family, mat): 20.0},
+           "requests": 3}
+    metric = f"kzg_fused_resident_pct.{cell}"
+    assert _read(metric, ctx) == pytest.approx(99.0)
+    assert _read(metric, {"before": {}, "after": {}, "requests": 3}) is None

@@ -166,7 +166,7 @@ def test_blinded_fold_block_layout(one_chip, tpu_branches):
     assert c.memory_analysis().temp_size_in_bytes < 4 << 30
 
 
-@pytest.mark.slow  # ~3 min, 291 MB of code; not on chip_smoke's path
+@pytest.mark.slow  # ~1 min (46 s of it the trace), 63 MB of code; not on chip_smoke's path
 def test_gather_fold_16_committees(one_chip, tpu_branches):
     """The pubkey plane's fold over a 16,384-row table at 16 groups x
     512 keys.  At the 131-set x 512-key layout (131,072 lanes) the TPU
@@ -269,11 +269,13 @@ def test_g1_subgroup_kernel_blob_batch(one_chip, tpu_branches):
              *[_limbs(one_chip, BLOB_POINTS)] * 2)
 
 
-@pytest.mark.slow  # ~3.5 min: 4,096 lanes of 256-bit windowed scalar-mul
+@pytest.mark.slow  # ~3 min: the trace of ~420 kernel bodies is half of it
 def test_kzg_fused_768_blobs(one_chip, tpu_branches):
     """Both RLC MSMs and the two-lane Jacobian Miller loop in one dispatch
-    at 768 blobs: 2.16 GB of temporaries, so _kzg_fused_check needs no
-    lane cap."""
+    at 768 blobs, the G1 fold on the multiply whose partial products stay
+    in the core (PR 34): 62 MB of temporaries (2.16 GB while every product
+    of the fold was a [.., 27, 54] array of the program), so
+    _kzg_fused_check needs no lane cap."""
     from lighthouse_tpu.crypto import kzg
 
     c = _compile(
@@ -282,7 +284,14 @@ def test_kzg_fused_768_blobs(one_chip, tpu_branches):
         jax.ShapeDtypeStruct((64, FUSED_LANES), jnp.uint32,
                              sharding=one_chip),
         *[_limbs(one_chip, 2)] * 4)
-    assert c.memory_analysis().temp_size_in_bytes < 4 << 30
+    print("TPU_COMPILE " + json.dumps(
+        {"program": "_kzg_fused", "bytes_accessed": int(
+            c.cost_analysis()["bytes accessed"])}), flush=True)
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    # the fold's lanes carry no schoolbook product as an array
+    assert "[4096,27,5" not in text[text.index("\nENTRY "):]
 
 
 def test_hash_pairs_device(one_chip, tpu_branches):
