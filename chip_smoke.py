@@ -259,10 +259,8 @@ class Warm:
             return lambda: bb._g2_subgroup_kernel(*[limbs(n)] * 4)
 
         def blinded_fold():
-            seg = 2 * bb._next_pow2(sz["set_keys"])
-            n_pad = min(bb._next_pow2(n_block),
-                        max(bb._AGG_MAX_LANES // seg, 1))
-            rows = limbs(seg * n_pad)
+            max_k, n_pad = bb._fold_shape([sz["set_keys"]] * n_block)
+            rows = limbs(2 * max_k * n_pad)
             return msm._blinded_fold(rows, rows, rows, limbs(1), limbs(1),
                                      n_pad)
 

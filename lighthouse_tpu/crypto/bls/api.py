@@ -489,6 +489,25 @@ def record_stage(backend: str, stage: str, seconds: float) -> None:
         record_swallowed("bls.record_stage", e)
 
 
+def count_fold_lanes(key: int, blinding: int, padding: int) -> None:
+    """Lanes of one dispatched slice of the key-aggregation fold
+    (ops/bls_backend.aggregate_pubkeys_device): key lanes that carry a
+    member, blinding lanes that carry a pool point, and the rest."""
+    try:
+        from lighthouse_tpu.common.metrics import REGISTRY
+
+        lanes = REGISTRY.counter(
+            "bls_fold_lanes_total",
+            "lanes of the key-aggregation fold slices dispatched, by kind")
+        lanes.labels(kind="key").inc(key)
+        lanes.labels(kind="blinding").inc(blinding)
+        lanes.labels(kind="padding").inc(padding)
+    except Exception as e:
+        from lighthouse_tpu.common.metrics import record_swallowed
+
+        record_swallowed("bls.count_fold_lanes", e)
+
+
 def _verify_signature_sets_reference(sets: Sequence[SignatureSet],
                                      chunk_size: int | None = None) -> bool:
     """Randomized batch verification (one multi-pairing for the batch).

@@ -246,17 +246,22 @@ def _grouped_layout(n: int, n_groups: int,
 _BLIND_U: list[int] = []
 _BLIND_POINTS: list[tuple] = []
 _BLIND_NEG_TOTAL: dict[int, tuple] = {}     # max_k -> -[Σ_{j<k} u_j]G limbs
+_BLIND_LANES: dict[tuple, tuple] = {}       # (max_k, n_pad) -> X0, Y0, Z0
 import threading as _threading
 
 _BLIND_LOCK = _threading.Lock()
 
 
-def _blinding(max_k: int):
+def _blinding(max_k: int, n_pad: int):
+    """((X0, Y0, Z0), neg_total) for slices of ``n_pad`` segments of
+    ``max_k`` key lanes: the slice's lane rows with the blinding half
+    filled (read-only: a slice copies them and writes its keys), and
+    the limbs of -[Σ u_j]G.  Laid out once a shape, not once a request."""
     with _BLIND_LOCK:
-        return _blinding_locked(max_k)
+        return _blinding_locked(max_k, n_pad)
 
 
-def _blinding_locked(max_k: int):
+def _blinding_locked(max_k: int, n_pad: int):
     while len(_BLIND_U) < max_k:
         u = 0
         while u == 0:
@@ -273,16 +278,49 @@ def _blinding_locked(max_k: int):
         neg = (jnp.asarray(ec.ints_to_mont_limbs([npt[0]])),
                jnp.asarray(ec.ints_to_mont_limbs([npt[1]])))
         _BLIND_NEG_TOTAL[max_k] = neg
-    return _BLIND_POINTS[:max_k], neg
+    lanes = _BLIND_LANES.get((max_k, n_pad))
+    if lanes is None:
+        lanes = tuple(np.zeros((2 * max_k * n_pad, bi.L), np.uint32)
+                      for _ in range(3))
+        for j, (bx, by) in enumerate(_BLIND_POINTS[:max_k]):
+            rows = slice((max_k + j) * n_pad, (max_k + j + 1) * n_pad)
+            lanes[0][rows] = bx
+            lanes[1][rows] = by
+            lanes[2][rows] = bi.ONE_M
+        for a in lanes:
+            a.setflags(write=False)
+        _BLIND_LANES[(max_k, n_pad)] = lanes
+    return lanes, neg
 
 
-# lanes one blinded-fold dispatch may carry.  The fold's temporaries grow
-# with the lane count (limb rows pad 27 -> 128 on the TPU's tiling): the
-# TPU compiler reports 15.5 GB of temporaries at the 262,144 lanes of a
-# mainnet block's 131 sets x 512 keys — the whole of a 16 GB chip — and
-# ~1.9 GB at this cap.  Wider batches fold in equal-shaped slices of
-# sets; one compiled program serves all of them.
+# lanes one blinded-fold dispatch may carry, whatever the batch: no count
+# of sets and no width of a set steps over it.  The fold's temporaries
+# grow with the lane count (limb rows pad 27 -> 128 on the TPU's tiling):
+# the TPU compiler reports 15.5 GB of temporaries at 262,144 lanes (a
+# phase0 block's 131 sets x 512 keys in one dispatch; one electra
+# aggregate of 64 committees x 2,048 keys) — the whole of a 16 GB chip —
+# and ~1.9 GB at this cap.  Wider batches fold in equal-shaped slices of
+# segments, and a set wider than a segment as several segments; one
+# compiled program serves all of them.
 _AGG_MAX_LANES = 1 << 15
+
+
+def _fold_shape(widths) -> tuple[int, int]:
+    """(key lanes a segment, segments a slice) of the blinded fold for a
+    batch of sets with ``widths`` keys.
+
+    A segment is the widest set's pow2 width, up to the widest segment:
+    a slice at the cap is cut into segments of its lane count's
+    two-thirds power — 32 x 1,024 lanes at 32,768, a mainnet committee's
+    512 keys and their 512 blinding lanes — so every batch with a set
+    that wide or wider, an electra aggregate of 64 committees like a
+    phase0 block of 131, runs the one compiled shape.  A wider set takes
+    several segments, a single key none."""
+    cap_bits = _AGG_MAX_LANES.bit_length() - 1
+    widest_segment = 1 << (2 * cap_bits // 3)     # lanes: keys + blinding
+    max_k = min(_next_pow2(max(widths)), max(widest_segment // 2, 1))
+    n_segments = sum(-(-k // max_k) for k in widths if k > 1)
+    return max_k, min(_next_pow2(n_segments), _AGG_MAX_LANES // (2 * max_k))
 
 
 def _stage_span(name: str, stage: str, **attrs):
@@ -297,41 +335,49 @@ def aggregate_pubkeys_device(sets):
     """Per-set pubkey aggregation as device segment-sums.
 
     Replaces the pure-Python per-set point additions (~20 µs each; a
-    128-attestation mainnet block carries ~66k member keys).  Returns
+    128-attestation phase0 block carries ~66k member keys, an electra
+    block of 8 aggregates over 64 committees 262k).  Returns
     (x_rows, y_rows, inf_flags): affine Montgomery limb rows
     uint32[n, L] per set plus a bool[n] marking identity aggregates
     (opposing keys — such sets can never verify).
 
+    The fold's unit is the segment (`_fold_shape`: the widest set's pow2
+    width, up to 512 keys at the cap): a set of up to a segment's keys
+    is one, a wider set is cut into sub-segments of that width, a
+    single-key set needs none (its aggregate is its key).
     Segment layout (s-major): first half pubkey lanes (infinity-padded),
     second half the blinding lanes B_0..B_{k-1} (see _blinding) — every
     level-0 pair joins a pubkey with a distinct blinding point, so
-    duplicate keys never produce the degenerate H == 0 chord.  Batches
-    over _AGG_MAX_LANES lanes fold in slices of sets, all dispatched
-    before the one fetch."""
+    duplicate keys never produce the degenerate H == 0 chord.  Segments
+    fold in equal-shaped slices of at most _AGG_MAX_LANES lanes, all
+    dispatched before the one fetch.
+
+    The second step (`bls.aggregate.combine`) runs on the host after the
+    fetch: a wide set's partial sums — 64 a mainnet electra aggregate —
+    are added by complete additions (`msm.host_lincomb_groups`: native
+    when the library is there), so a partial sum that is the identity
+    adds nothing, two equal ones double, and only a SET whose sum is the
+    identity is flagged."""
     n = len(sets)
-    max_k = _next_pow2(max(len(s.pubkeys) for s in sets))
+    widths = [len(s.pubkeys) for s in sets]
+    max_k, n_pad = _fold_shape(widths)
     seg = 2 * max_k
-    # sets per dispatch: the whole pow2-padded batch when it fits, else
-    # the pow2 slice the lane cap allows (bounds the jit shape cache)
-    n_pad = min(_next_pow2(n), max(_AGG_MAX_LANES // seg, 1))
-    blind_pts, neg_total = _blinding(max_k)
+    # (set, first key) of every segment, in set order
+    segments = [(i, lo) for i, k in enumerate(widths) if k > 1
+                for lo in range(0, k, max_k)]
+    (X0, Y0, Z0), neg_total = _blinding(max_k, n_pad)
     one = bi.ONE_M
-    # the blinding half is the same in every slice: laid out once
-    X0 = np.zeros((seg * n_pad, bi.L), np.uint32)
-    Y0 = np.zeros((seg * n_pad, bi.L), np.uint32)
-    Z0 = np.zeros((seg * n_pad, bi.L), np.uint32)
-    for j, (bx, by) in enumerate(blind_pts):
-        lanes = slice((max_k + j) * n_pad, (max_k + j + 1) * n_pad)
-        X0[lanes] = bx
-        Y0[lanes] = by
-        Z0[lanes] = one
-    tracing.add_attrs(slices=-(-n // n_pad), lanes=seg * n_pad)
+    tracing.add_attrs(slices=-(-len(segments) // n_pad), lanes=seg * n_pad,
+                      sets=n, segments=len(segments), widest=max(widths))
     outs = []
-    for lo in range(0, n, n_pad):
+    for first in range(0, len(segments), n_pad):
         with _stage_span("bls.aggregate.layout", "aggregate_layout"):
             X, Y, Z = X0.copy(), Y0.copy(), Z0.copy()
-            for i, s in enumerate(sets[lo:lo + n_pad]):
-                for j, pk in enumerate(s.pubkeys):
+            keys = 0
+            for i, (set_idx, lo) in enumerate(segments[first:first + n_pad]):
+                members = sets[set_idx].pubkeys[lo:lo + max_k]
+                keys += len(members)
+                for j, pk in enumerate(members):
                     xl, yl = pk.mont_limbs()
                     lane = j * n_pad + i   # s-major layout for g1_segment_sum
                     X[lane] = xl
@@ -340,10 +386,49 @@ def aggregate_pubkeys_device(sets):
         with _stage_span("bls.aggregate.dispatch", "aggregate_dispatch"):
             outs.append(_msm.blinded_fold_device(
                 X, Y, Z, neg_total[0], neg_total[1], n_pad))
+        api.count_fold_lanes(key=keys, blinding=max_k * n_pad,
+                             padding=max_k * n_pad - keys)
     with _stage_span("bls.aggregate.fetch", "aggregate_fetch"):
         fetched = jax.device_get(outs)
-    xa, ya, inf = (np.concatenate(cols)[:n] for cols in zip(*fetched))
-    return xa, ya, inf
+    with _stage_span("bls.aggregate.combine", "aggregate_combine"):
+        return _combine_segments(sets, segments, fetched)
+
+
+def _combine_segments(sets, segments, fetched):
+    """The fold's second step, on the host: the fetched rows of every
+    segment (slice by slice, a slice's unused rows behind its last
+    segment) -> the rows of every set, in set order."""
+    n = len(sets)
+    x_rows = np.zeros((n, bi.L), np.uint32)
+    y_rows = np.zeros((n, bi.L), np.uint32)
+    inf_rows = np.zeros(n, bool)
+    for i, s in enumerate(sets):
+        if len(s.pubkeys) == 1:
+            x_rows[i], y_rows[i] = s.pubkeys[0].mont_limbs()
+    if not segments:
+        return x_rows, y_rows, inf_rows
+    xa, ya, inf = (np.concatenate(cols)[:len(segments)]
+                   for cols in zip(*fetched))
+    seg_set = np.fromiter((i for i, _ in segments), np.int64, len(segments))
+    whole = np.bincount(seg_set, minlength=n)[seg_set] == 1
+    x_rows[seg_set[whole]] = xa[whole]
+    y_rows[seg_set[whole]] = ya[whole]
+    inf_rows[seg_set[whole]] = inf[whole]
+    wide = np.unique(seg_set[~whole])
+    if wide.size:
+        # identity partial sums (opposing keys inside one sub-segment)
+        # add nothing; the additions are complete, so equal ones double
+        parts = np.nonzero(~whole & ~inf)[0]
+        points = list(zip(bi.from_mont(xa[parts]), bi.from_mont(ya[parts])))
+        sums = _msm.host_lincomb_groups(
+            points, [1] * len(points),
+            np.searchsorted(wide, seg_set[parts]), len(wide))
+        for i, pt in zip(wide, sums):
+            if pt is cv.INF:
+                inf_rows[i] = True
+            else:
+                x_rows[i], y_rows[i] = ec.ints_to_mont_limbs(pt)
+    return x_rows, y_rows, inf_rows
 
 
 def _dispatch_g1_subgroup_kernel(points):
@@ -470,7 +555,8 @@ def verify_sets_pipeline(sets: Sequence[api.SignatureSet],
     ``aggregate``, ``prep_host``, ``limbs`` and ``pipeline`` (a dispatch
     time: nothing syncs there) per chunk, ``final_exp``; and as parts of
     them ``aggregate_layout`` / ``aggregate_dispatch`` / ``aggregate_fetch``
-    and ``subgroup_wait`` / ``pipeline_wait`` / ``final_exp_host``.  A stage
+    / ``aggregate_combine`` and ``subgroup_wait`` / ``pipeline_wait`` /
+    ``final_exp_host``.  A stage
     boundary sits only where the code blocks anyway or between two pieces
     of host code, so the spans measure the program that runs."""
     with tracing.span("bls.verify_pipeline", sets=len(sets)):

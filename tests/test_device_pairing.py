@@ -253,7 +253,7 @@ class TestDevicePubkeyAggregation:
 
     def test_lane_cap_slices_match_host_oracle(self, monkeypatch):
         """Over the lane cap the batch folds in equal-shaped slices of
-        sets (the mainnet block's 131 x 512 keys does not fit one
+        segments (the mainnet block's 131 x 512 keys does not fit one
         dispatch on a 16 GB chip); rows come back in set order."""
         from lighthouse_tpu.crypto import bls
         from lighthouse_tpu.ops import bigint as bi
@@ -264,8 +264,9 @@ class TestDevicePubkeyAggregation:
         sig = sks[0].sign(msg)
         sets = [bls.SignatureSet(sig, pks[:k], msg)
                 for k in (1, 5, 12, 3, 7)]
-        # seg = 2 * 16 lanes per set: cap at two sets per dispatch, the
-        # shape test_matches_host_oracle_ragged's program does not have
+        # a cap of 64 lanes cuts a slice into four segments of 8 key + 8
+        # blinding lanes: the 12-key set takes two, the single key none
+        # (tests/test_electra_fold.py holds the policy at every width)
         monkeypatch.setattr(bb, "_AGG_MAX_LANES", 64)
         xa, ya, inf = bb.aggregate_pubkeys_device(sets)
         assert xa.shape[0] == len(sets) and not inf.any()

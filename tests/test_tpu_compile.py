@@ -151,14 +151,17 @@ def test_fp12_mul_q(one_chip, tpu_branches):
 @pytest.mark.slow  # ~1.5 min: ten unrolled levels of Jacobian adds
 def test_blinded_fold_block_layout(one_chip, tpu_branches):
     """131 sets x 512 keys fold in slices of bls_backend._AGG_MAX_LANES
-    lanes: 32 sets x (512 key + 512 blinding lanes) per dispatch.  The
-    whole block in ONE dispatch (262,144 lanes) compiles to 15.5 GB of
-    temporaries — all of a 16 GB chip — which is why the cap exists."""
+    lanes: 32 segments x (512 key + 512 blinding lanes) per dispatch, and
+    so do an electra block's 8 aggregates of 32,768 keys, 64 segments
+    each.  The whole block in ONE dispatch (262,144 lanes) compiles to
+    15.5 GB of temporaries — all of a 16 GB chip — which is why the cap
+    exists."""
     from lighthouse_tpu.ops import bls_backend as bb
     from lighthouse_tpu.ops import msm
 
-    n_pad = bb._AGG_MAX_LANES // BLOCK_SEG
-    assert n_pad * BLOCK_SEG == 1 << 15
+    max_k, n_pad = bb._fold_shape([512] * 131)
+    assert (max_k, n_pad) == bb._fold_shape([32768] * 8 + [512, 1, 1])
+    assert 2 * max_k == BLOCK_SEG and n_pad * BLOCK_SEG == 1 << 15
     rows = _limbs(one_chip, BLOCK_SEG * n_pad)
     c = _compile("_blinded_fold@32x(512+512)", msm._blinded_fold._fn,
                  rows, rows, rows, _limbs(one_chip, 1), _limbs(one_chip, 1),
@@ -417,6 +420,19 @@ def test_pipeline_fused_flat_4(one_chip, tpu_branches):
 
     _compile("_pipeline_fused@4", bb._pipeline_fused._fn,
              *_pipeline_args(one_chip, 4))
+
+
+@pytest.mark.slow  # ~5 min: an electra block's 11 sets, a third bucket
+def test_pipeline_fused_flat_16(one_chip, tpu_branches):
+    """`block-8x32k`'s fused program: 8 aggregates, the sync set and two
+    single-key sets pad to 16 lanes (the 32,768 keys of an aggregate are
+    the fold's: `test_blinded_fold_block_layout`'s 32 x 1,024 shape)."""
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    _compile("_pipeline_fused@16", bb._pipeline_fused._fn,
+             *_pipeline_args(one_chip, 16))
+    _compile("_g2_subgroup_kernel@16", bb._g2_subgroup_kernel._fn,
+             *[_limbs(one_chip, 16)] * 4)
 
 
 @pytest.mark.slow  # ~5 min: the Miller loop once more, as a mesh program

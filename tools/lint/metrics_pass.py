@@ -38,6 +38,9 @@ FAMILY_OWNERS = {
     "bls_pipeline_": "lighthouse_tpu/ops/dispatch_pipeline.py",
     "bls_verify_": "lighthouse_tpu/crypto/bls/api.py",
     "bls_cache_": "lighthouse_tpu/crypto/bls/api.py",
+    # lanes of the key-aggregation fold by kind (PR 35): counted in
+    # ops/bls_backend.aggregate_pubkeys_device through api.count_fold_lanes
+    "bls_fold_": "lighthouse_tpu/crypto/bls/api.py",
     # the offload supervisor's health/fault series (PR 4): the breaker
     # transitions are the only legitimate writer
     "bls_backend_health": "lighthouse_tpu/crypto/bls/api.py",
