@@ -508,6 +508,27 @@ def count_fold_lanes(key: int, blinding: int, padding: int) -> None:
         record_swallowed("bls.count_fold_lanes", e)
 
 
+def count_fold_products(resident: int, materialized: int) -> None:
+    """Fp lane-products of one dispatched slice of the key-aggregation
+    fold (ops/msm.blinded_fold_products), by the multiply that runs
+    them: ``resident`` the segment sum's (`MontField.mont_mul_lm`),
+    ``materialized`` those of the rows behind it (`mont_mul`: the
+    unblinding addition, the inversion ladder, the zero test)."""
+    try:
+        from lighthouse_tpu.common.metrics import REGISTRY
+
+        products = REGISTRY.counter(
+            "bls_fold_products_total",
+            "Fp lane-products of the key-aggregation fold slices "
+            "dispatched, by multiply")
+        products.labels(multiply="resident").inc(resident)
+        products.labels(multiply="materialized").inc(materialized)
+    except Exception as e:
+        from lighthouse_tpu.common.metrics import record_swallowed
+
+        record_swallowed("bls.count_fold_products", e)
+
+
 def _verify_signature_sets_reference(sets: Sequence[SignatureSet],
                                      chunk_size: int | None = None) -> bool:
     """Randomized batch verification (one multi-pairing for the batch).

@@ -294,14 +294,18 @@ def _blinding_locked(max_k: int, n_pad: int):
 
 
 # lanes one blinded-fold dispatch may carry, whatever the batch: no count
-# of sets and no width of a set steps over it.  The fold's temporaries
-# grow with the lane count (limb rows pad 27 -> 128 on the TPU's tiling):
-# the TPU compiler reports 15.5 GB of temporaries at 262,144 lanes (a
-# phase0 block's 131 sets x 512 keys in one dispatch; one electra
-# aggregate of 64 committees x 2,048 keys) — the whole of a 16 GB chip —
-# and ~1.9 GB at this cap.  Wider batches fold in equal-shaped slices of
-# segments, and a set wider than a segment as several segments; one
-# compiled program serves all of them.
+# of sets and no width of a set steps over it.  The cap stands for the
+# SHAPE, no longer for the memory: wider batches fold in equal-shaped
+# slices of segments, and a set wider than a segment as several
+# segments, so one compiled program serves all of them, and the host
+# lays out slice k+1 while the device folds slice k.  The 15.5 GB of
+# temporaries at 262,144 lanes (a phase0 block's 131 sets x 512 keys in
+# one dispatch; one electra aggregate of 64 committees x 2,048 keys) and
+# the 1.94 GB at this cap were the materialized multiply's; with the
+# segment sum on `mont_mul_lm` the TPU compiler reports 284 MB and 15.2
+# GB accessed at 262,144 lanes, 7.9 MB and 1.89 GB accessed at this cap
+# (compile rehearsal, PR 36).  A wider slice is another compiled shape
+# and a longer first layout in front of the device: not measured.
 _AGG_MAX_LANES = 1 << 15
 
 
@@ -369,6 +373,7 @@ def aggregate_pubkeys_device(sets):
     one = bi.ONE_M
     tracing.add_attrs(slices=-(-len(segments) // n_pad), lanes=seg * n_pad,
                       sets=n, segments=len(segments), widest=max(widths))
+    products = _msm.blinded_fold_products(seg * n_pad, n_pad)
     outs = []
     for first in range(0, len(segments), n_pad):
         with _stage_span("bls.aggregate.layout", "aggregate_layout"):
@@ -388,6 +393,7 @@ def aggregate_pubkeys_device(sets):
                 X, Y, Z, neg_total[0], neg_total[1], n_pad))
         api.count_fold_lanes(key=keys, blinding=max_k * n_pad,
                              padding=max_k * n_pad - keys)
+        api.count_fold_products(*products)
     with _stage_span("bls.aggregate.fetch", "aggregate_fetch"):
         fetched = jax.device_get(outs)
     with _stage_span("bls.aggregate.combine", "aggregate_combine"):

@@ -159,8 +159,13 @@ def _blinded_fold(X, Y, Z, ux, uy, n_segments):
     """The blinded-merge track: segmented G1 sum over (payload +
     blinding) Jacobian lanes, minus the known blinding total (ux, uy),
     then affine conversion.  The infinity flag (Z ≡ 0) is resolved on
-    device — one bool row home, not a limb row."""
-    Xg, Yg, Zg = ec.g1_segment_sum(X, Y, Z, n_segments)
+    device — one bool row home, not a limb row.  The lanes (uint32[N, L])
+    are turned limb-major for the sum, as `fold_segments_g1` turns its
+    lanes, and its n_segments rows back: the sum's products are
+    `MontField.mont_mul_lm`'s, the rows' behind it `mont_mul`'s
+    (`blinded_fold_products`)."""
+    Xg, Yg, Zg = (c.T for c in ec.g1_segment_sum_lm(X.T, Y.T, Z.T,
+                                                    n_segments))
     one = jnp.broadcast_to(bi._jconst("one_m"), Xg.shape)
     Xr, Yr, Zr = ec._jac_add_full(
         ec._FpAdapter, (Xg, Yg, Zg),
@@ -172,6 +177,16 @@ def _blinded_fold(X, Y, Z, ux, uy, n_segments):
 
 _blinded_fold = _dtel.instrument(
     "ops/msm.py::_blinded_fold@_blinded_fold", _blinded_fold)
+
+
+def blinded_fold_products(lanes: int, n_segments: int) -> tuple[int, int]:
+    """(resident, materialized) Fp lane-products of one `_blinded_fold`
+    dispatch, as the program routes them: on `mont_mul_lm` the segment
+    sum's full additions (`fold_products`' price: 16 for every lane but
+    the last of each segment); on `mont_mul`, over the n_segments rows
+    behind it, the unblinding addition (16), the affine conversion (a
+    381-step inversion ladder of 2, then 4) and the zero test (1)."""
+    return 16 * (lanes - n_segments), (16 + 2 * 381 + 4 + 1) * n_segments
 
 
 # -- dispatch wrappers --------------------------------------------------------

@@ -411,6 +411,140 @@ def test_msm_calibration_step_env_pin_and_disable(store, monkeypatch):
         msm._CALIBRATED = saved[1]
 
 
+# -- the blinded fold's sum on the limb-major multiply (ISSUE 36) -------------
+
+_BF_KEYS, _BF_SEGS = 8, 4     # the slice tests/test_electra_fold.py compiles
+
+
+def _bf_cases():
+    """{case: the affine members of each of the slice's four segments}."""
+    p = _points(32, start=101)
+    opposing = [p[1], cv.g1_neg(p[1])]
+    return {
+        "random_keys": [p[:8], p[8:16], p[16:24], p[24:]],
+        "infinity_padded_key_lanes": [p[:3], p[3:8], p[8:9], p[9:16]],
+        "segment_of_padding_alone": [p[:8], p[8:13], [], p[13:15]],
+        "one_key_through_a_segment": [[p[5]] * 8, [p[6]] * 5, p[:8],
+                                      [p[5], p[6]] * 3],
+        "opposing_keys": [opposing * 4, opposing + [p[7]], p[:8],
+                          opposing * 2 + p[:4]],
+    }
+
+
+@pytest.fixture(scope="module")
+def limbs_last_fold():
+    """The composition `_blinded_fold` had before ISSUE 36: the segment
+    sum on limbs-last rows (`ec.g1_segment_sum`, `mont_mul`), the same
+    tail."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    from lighthouse_tpu.ops import bigint as bi
+    from lighthouse_tpu.ops import ec
+
+    @partial(jax.jit, static_argnums=(5,))
+    def fold(X, Y, Z, ux, uy, n_segments):
+        Xg, Yg, Zg = ec.g1_segment_sum(X, Y, Z, n_segments)
+        one = jnp.broadcast_to(bi._jconst("one_m"), Xg.shape)
+        Xr, Yr, Zr = ec._jac_add_full(
+            ec._FpAdapter, (Xg, Yg, Zg),
+            (jnp.broadcast_to(ux, Xg.shape), jnp.broadcast_to(uy, Yg.shape),
+             one))
+        xa, ya = ec.g1_jacobian_to_affine_batch(Xr, Yr, Zr)
+        return xa, ya, bi.is_zero_mod_p_device(Zr)
+
+    return fold
+
+
+@pytest.mark.parametrize("case", [
+    "random_keys", "infinity_padded_key_lanes", "segment_of_padding_alone",
+    "one_key_through_a_segment", "opposing_keys"])
+def test_blinded_fold_equals_limbs_last_composition(limbs_last_fold, case):
+    """`_blinded_fold` (segment sum limb-major, on `mont_mul_lm`) against
+    the limbs-last composition it replaces, on one slice laid out as
+    `aggregate_pubkeys_device` lays it out: the same flags, and under
+    every clear flag the same point, which is the members' sum."""
+    import jax
+
+    from lighthouse_tpu.ops import bigint as bi
+    from lighthouse_tpu.ops import bls_backend as bb
+    from lighthouse_tpu.ops import ec, msm
+
+    members = _bf_cases()[case]
+    (X0, Y0, Z0), neg = bb._blinding(_BF_KEYS, _BF_SEGS)
+    X, Y, Z = X0.copy(), Y0.copy(), Z0.copy()
+    for i, seg in enumerate(members):
+        if not seg:
+            continue
+        lanes = [j * _BF_SEGS + i for j in range(len(seg))]   # s-major
+        X[lanes] = ec.ints_to_mont_limbs([q[0] for q in seg])
+        Y[lanes] = ec.ints_to_mont_limbs([q[1] for q in seg])
+        Z[lanes] = bi.ONE_M
+    args = (X, Y, Z, neg[0], neg[1], _BF_SEGS)
+    xa, ya, inf = jax.device_get(msm.blinded_fold_device(*args))
+    xb, yb, inf_b = jax.device_get(limbs_last_fold(*args))
+    assert xa.shape == xb.shape == (_BF_SEGS, bi.L)
+    want = [_host_lincomb(seg, [1] * len(seg)) for seg in members]
+    assert inf.tolist() == inf_b.tolist() == [w is cv.INF for w in want]
+    for i, w in enumerate(want):
+        if w is cv.INF:
+            continue            # a flagged row's limbs are not read
+        got = (int(bi.from_mont(xa[i])), int(bi.from_mont(ya[i])))
+        assert got == (int(bi.from_mont(xb[i])), int(bi.from_mont(yb[i])))
+        assert got == w, (case, i)
+    if case == "opposing_keys":
+        assert inf.tolist() == [True, False, False, False]
+
+
+@pytest.mark.parametrize("lanes,segments", [(64, 4), (128, 2), (32768, 32)])
+def test_blinded_fold_products_are_what_the_program_traces(
+        monkeypatch, lanes, segments):
+    """`blinded_fold_products` (what `bls_fold_products_total` grows by a
+    slice) against a tally of the lanes each multiply of `_blinded_fold`
+    is traced with, a scan's body counted once a step; the last shape is
+    both block cells'."""
+    import jax
+    import jax.numpy as jnp
+
+    from lighthouse_tpu.ops import bigint as bi
+    from lighthouse_tpu.ops import msm
+
+    tally = {"resident": 0, "materialized": 0}
+    steps = [1]
+    lm, mm, scan = bi.FP.mont_mul_lm, bi.mont_mul, jax.lax.scan
+
+    def count_lm(a, b):
+        tally["resident"] += steps[-1] * int(np.prod(a.shape[1:]))
+        return lm(a, b)
+
+    def count_mm(a, b):
+        tally["materialized"] += steps[-1] * int(np.prod(a.shape[:-1]))
+        return mm(a, b)
+
+    def count_scan(f, init, xs, *args, **kwargs):
+        steps.append(steps[-1] * jax.tree_util.tree_leaves(xs)[0].shape[0])
+        try:
+            return scan(f, init, xs, *args, **kwargs)
+        finally:
+            steps.pop()
+
+    monkeypatch.setattr(bi.FP, "mont_mul_lm", count_lm)
+    monkeypatch.setattr(bi, "mont_mul", count_mm)
+    monkeypatch.setattr(jax.lax, "scan", count_scan)
+    rows = jax.ShapeDtypeStruct((lanes, bi.L), jnp.uint32)
+    row = jax.ShapeDtypeStruct((bi.L,), jnp.uint32)
+    jax.eval_shape(lambda *a: msm._blinded_fold._fn(*a, segments),
+                   rows, rows, rows, row, row)
+    res, mat = msm.blinded_fold_products(lanes, segments)
+    assert (res, mat) == (tally["resident"], tally["materialized"])
+    assert msm.blinded_fold_products(32768, 32) == (523776, 25056)
+    # the sum's additions at `fold_products`' price: that fold with no
+    # window, less its tables' seven doublings and additions a lane
+    assert res == msm.fold_products(lanes, segments, 0) - 7 * (7 + 16) * lanes
+
+
 # -- the manifest actually shrank ---------------------------------------------
 
 

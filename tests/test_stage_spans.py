@@ -129,6 +129,38 @@ def test_bls_pipeline_stage_spans():
     assert _closure(sink.find("bls.final_exp")[0]) >= 0.90
 
 
+def test_fold_counts_its_products_under_bls_aggregate():
+    """`bls_fold_products_total` is registered and grows, once a slice
+    dispatched under `bls.aggregate`, by `msm.blinded_fold_products` of
+    the slice's shape (the fold of the test above: one slice of 4
+    segments x 32 lanes, no new program)."""
+    from benchmarks import counters
+    from lighthouse_tpu.crypto import bls
+    from lighthouse_tpu.ops import msm
+    from lighthouse_tpu.ops.bls_backend import aggregate_pubkeys_device
+
+    def grown(before, after, multiply):
+        return counters.delta(before, after, "bls_fold_products_total",
+                              lambda labels: labels["multiply"] == multiply)
+
+    pks = [bls.SecretKey.from_bytes(int(500 + i).to_bytes(32, "big"))
+           .public_key() for i in range(12)]
+    sig = bls.Signature(b"\xc0" + b"\x00" * 95)    # never read by the fold
+    sets = [bls.SignatureSet(sig, pks[lo:hi], b"\x33" * 32)
+            for lo, hi in ((0, 8), (1, 12), (2, 9))]
+    before = counters.samples()
+    with _Roots() as sink, tracing.span("bls.aggregate"):
+        aggregate_pubkeys_device(sets)
+    after = counters.samples()
+    (aggregate,) = sink.find("bls.aggregate")
+    assert len(sink.find("bls.aggregate.dispatch")) == 1
+    assert aggregate["attrs"]["lanes"] == 128 and aggregate["attrs"]["slices"] == 1
+    res, mat = msm.blinded_fold_products(128, 4)
+    assert grown(before, after, "resident") == res == 16 * 124
+    assert grown(before, after, "materialized") == mat == 783 * 4
+    assert "# TYPE bls_fold_products_total counter" in REGISTRY.render()
+
+
 def test_verify_sets_pipeline_has_no_ledger_mode():
     import inspect
 
@@ -301,20 +333,23 @@ from benchmarks.tests.test_stage_metrics import (  # noqa: E402,F401
 )
 
 
-@pytest.mark.parametrize("cell", ["blobs", "columns"])
-def test_fused_resident_share_reads_a_synthetic_window(cell):
-    """`kzg_fused_resident_pct.{blobs,columns}` (PR 34): the share of
-    `kzg_fused_products_total`'s growth on `multiply="resident"`; a program
-    without the family (the parent commit) reads nothing and does not
-    raise."""
+@pytest.mark.parametrize("metric,family", [
+    ("kzg_fused_resident_pct.blobs", "kzg_fused_products_total"),
+    ("kzg_fused_resident_pct.columns", "kzg_fused_products_total"),
+    ("bls_fold_resident_pct.electra", "bls_fold_products_total"),
+    ("bls_fold_resident_pct.block", "bls_fold_products_total"),
+])
+def test_resident_share_reads_a_synthetic_window(metric, family):
+    """`kzg_fused_resident_pct.{blobs,columns}` (PR 34) and
+    `bls_fold_resident_pct.{electra,block}` (PR 36): the share of their
+    family's growth on `multiply="resident"`; a program without the
+    family (the parent commit) reads nothing and does not raise."""
     from benchmarks.tests.test_stage_metrics import _read
 
-    family = "kzg_fused_products_total"
     res, mat = (frozenset({"multiply": k}.items())
                 for k in ("resident", "materialized"))
     ctx = {"before": {(family, res): 100.0, (family, mat): 10.0},
            "after": {(family, res): 1090.0, (family, mat): 20.0},
            "requests": 3}
-    metric = f"kzg_fused_resident_pct.{cell}"
     assert _read(metric, ctx) == pytest.approx(99.0)
     assert _read(metric, {"before": {}, "after": {}, "requests": 3}) is None

@@ -148,17 +148,21 @@ def test_fp12_mul_q(one_chip, tpu_branches):
              _fq12(one_chip), _fq12(one_chip))
 
 
-@pytest.mark.slow  # ~1.5 min: ten unrolled levels of Jacobian adds
+@pytest.mark.slow  # ~45 s: ten unrolled levels of Jacobian adds, 180 kernels
 def test_blinded_fold_block_layout(one_chip, tpu_branches):
     """131 sets x 512 keys fold in slices of bls_backend._AGG_MAX_LANES
     lanes: 32 segments x (512 key + 512 blinding lanes) per dispatch, and
     so do an electra block's 8 aggregates of 32,768 keys, 64 segments
-    each.  The whole block in ONE dispatch (262,144 lanes) compiles to
-    15.5 GB of temporaries — all of a 16 GB chip — which is why the cap
-    exists."""
+    each.  The segment sum runs on the multiply whose partial products
+    stay in the core (PR 36): 7.9 MB of temporaries and 1.89 GB accessed
+    a slice, where every product of the sum was a [.., 27, 54] array of
+    the program (1.94 GB and 46.7 GB; 15.5 GB of temporaries for the
+    whole block in ONE dispatch of 262,144 lanes, now 284 MB)."""
+    from lighthouse_tpu.ops import bigint as bi
     from lighthouse_tpu.ops import bls_backend as bb
     from lighthouse_tpu.ops import msm
 
+    assert bi._use_resident_kernel()
     max_k, n_pad = bb._fold_shape([512] * 131)
     assert (max_k, n_pad) == bb._fold_shape([32768] * 8 + [512, 1, 1])
     assert 2 * max_k == BLOCK_SEG and n_pad * BLOCK_SEG == 1 << 15
@@ -166,7 +170,18 @@ def test_blinded_fold_block_layout(one_chip, tpu_branches):
     c = _compile("_blinded_fold@32x(512+512)", msm._blinded_fold._fn,
                  rows, rows, rows, _limbs(one_chip, 1), _limbs(one_chip, 1),
                  n_pad)
-    assert c.memory_analysis().temp_size_in_bytes < 4 << 30
+    read = c.cost_analysis()["bytes accessed"]
+    print("TPU_COMPILE " + json.dumps(
+        {"program": "_blinded_fold", "bytes_accessed": int(read)}),
+        flush=True)
+    assert read < 4e9
+    assert c.memory_analysis().temp_size_in_bytes < 64 << 20
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    # behind the sum only the 32 rows of the segments carry a
+    # schoolbook product as an array
+    entry = text[text.index("\nENTRY "):]
+    assert "[32768,27,5" not in entry and "[16384,27,5" not in entry
 
 
 @pytest.mark.slow  # ~1 min (46 s of it the trace), 63 MB of code; not on chip_smoke's path
