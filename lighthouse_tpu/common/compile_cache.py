@@ -12,7 +12,9 @@ It is also the one place where the data plane meets jax before anything
 runs, so it installs ``jax.profiler.TraceAnnotation`` as the annotator of
 ``common.tracing``: from then on every program span lands on the
 ``/host:CPU`` plane of any live profiler trace (a no-op while no profiler
-session is live).
+session is live), and the host runtime's probes beside it
+(``tracing.install_host_probes``: the collector's hook and the process's
+fault counts).
 """
 
 from __future__ import annotations
@@ -32,6 +34,7 @@ def configure() -> str:
     from lighthouse_tpu.common import tracing
 
     tracing.set_annotator(jax.profiler.TraceAnnotation)
+    tracing.install_host_probes()
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = os.path.join(_CHECKOUT, ".jax_cache")

@@ -345,9 +345,12 @@ _register("LHTPU_FLIGHT_DUMPS", "8",
           "Newest trip dumps kept on disk; older dump files are "
           "pruned.")
 _register("LHTPU_FLIGHT_SPAN_MS", "50",
-          "Latency floor in milliseconds above which a closing tracing "
-          "span is filed into the flight recorder as a slow_span "
-          "event.")
+          "Floor in milliseconds under the flight recorder's slow-request "
+          "rule: a closing root span is judged only above it, and files "
+          "one slow_request with its stage table when it is also over 1.5 "
+          "times the second longest of its name's last 64 closures "
+          "(until a name has 8 closures the floor alone decides "
+          "and the root is filed as a slow_span).")
 _register("LHTPU_SLO_BUDGET_MS", "4000",
           "Per-slot SLO budget in milliseconds for the full "
           "gossip-to-head block pipeline; per-stage budgets are fixed "

@@ -38,6 +38,20 @@ snapshots the whole ring into ``last_dump``, writes it atomically to
 ``LHTPU_FLIGHT_DIR`` (newest ``LHTPU_FLIGHT_DUMPS`` files kept), and the
 HTTP surface serves it at ``GET /lighthouse/observatory/flight``.
 
+Slow requests (``note_root``, called by ``common/tracing`` whenever a root
+span closes; nothing below a root is judged, a stage is slow only as part
+of its request): ``LHTPU_FLIGHT_SPAN_MS`` is the floor, not the rule.  Each
+root name keeps its last 64 durations, and once it has 8 a closure is slow
+only when it is over the floor and over one and a half times the second
+longest of them: a request that takes what its kind takes leaves the ring
+to the breaker and ladder events it exists for, on whichever of its levels
+it falls (the one slot in 32 with an epoch transition shares a name with
+the 31 without), and a stall that keeps coming back is told twice.  A root that closes slow files one
+``slow_request`` with its stage table (per span name the ms, off-CPU ms
+and collector ms), counted in ``slow_requests_total{root}`` and logged
+once at WARN; until a name has 8 closures the floor alone decides and an
+over-the-floor root files a ``slow_span``, as every such span once did.
+
 Cost model: ``emit`` is one small dict + one lock-protected deque append
 + one memoized counter inc — cheap enough to ride the supervisor/ladder
 transition paths, which are themselves rare relative to the work they
@@ -54,19 +68,31 @@ from __future__ import annotations
 
 import json
 import os
+import statistics
 import tempfile
 import threading
 import time
 from collections import deque
 
 from lighthouse_tpu.common import env as envreg
-from lighthouse_tpu.common.metrics import REGISTRY, record_swallowed
+from lighthouse_tpu.common.metrics import (
+    REGISTRY,
+    record_evicted,
+    record_swallowed,
+)
 
 #: documented trip reasons (``trip`` accepts any string so drills can
 #: add ad-hoc conditions)
 TRIP_REASONS = ("bls_breaker_open", "epoch_breaker_open", "dispatch_wedge",
                 "store_corruption", "peer_quarantine", "books_violation",
                 "deep_reorg", "finality_stall")
+
+
+#: the slow-request rule: a root name's last _BASELINE_LEN closures are its
+#: baseline; once it holds _BASELINE_MIN, slow is over _SLOW_FACTOR times the
+#: second longest of them (a level is what two closures reached); at most
+#: _BASELINE_NAMES names are kept (oldest out)
+_BASELINE_LEN, _BASELINE_MIN, _SLOW_FACTOR, _BASELINE_NAMES = 64, 8, 1.5, 1024
 
 
 def _jsonable(v):
@@ -122,60 +148,45 @@ class FlightRecorder:
         # both are touched from every producer thread in the process
         self._memo_lock = threading.Lock()
         self._dump_lock = threading.Lock()
+        # root span name -> its last durations (_judge).  Reentrant: the
+        # collector can strike inside the hold and run anything
+        self._baselines: dict[str, deque] = {}
+        self._baseline_lock = threading.RLock()
 
     # -- accounting helpers (memoized labeled children) ---------------------
 
-    def _count_event(self, kind: str) -> None:
-        child = self._counter_memo.get(("event", kind))
+    def _count(self, key, make) -> None:
+        """Increment the counter child ``make()`` builds, held under
+        ``key`` from then on (family names stay literals at the callers:
+        lhlint LH501)."""
+        child = self._counter_memo.get(key)
         if child is None:
             with self._memo_lock:
-                child = self._counter_memo.get(("event", kind))
+                child = self._counter_memo.get(key)
                 if child is None:
                     try:
-                        child = REGISTRY.counter(
-                            "flight_events_total",
-                            "flight-recorder events by kind",
-                        ).labels(kind=kind)
+                        child = make()
                     except Exception as e:
                         record_swallowed("flight.counter", e)
                         return
-                    self._counter_memo[("event", kind)] = child
+                    self._counter_memo[key] = child
         child.inc()
+
+    def _count_event(self, kind: str) -> None:
+        self._count(("event", kind), lambda: REGISTRY.counter(
+            "flight_events_total",
+            "flight-recorder events by kind").labels(kind=kind))
 
     def _count_evicted(self) -> None:
-        child = self._counter_memo.get("evicted")
-        if child is None:
-            with self._memo_lock:
-                child = self._counter_memo.get("evicted")
-                if child is None:
-                    try:
-                        child = REGISTRY.counter(
-                            "flight_evicted_total",
-                            "flight-recorder events rotated out by the "
-                            "ring bound")
-                    except Exception as e:
-                        record_swallowed("flight.counter", e)
-                        return
-                    self._counter_memo["evicted"] = child
-        child.inc()
+        self._count("evicted", lambda: REGISTRY.counter(
+            "flight_evicted_total",
+            "flight-recorder events rotated out by the ring bound"))
 
     def _count_trip(self, reason: str) -> None:
-        child = self._counter_memo.get(("trip", reason))
-        if child is None:
-            with self._memo_lock:
-                child = self._counter_memo.get(("trip", reason))
-                if child is None:
-                    try:
-                        child = REGISTRY.counter(
-                            "flight_trips_total",
-                            "flight-recorder trip conditions fired, "
-                            "by reason",
-                        ).labels(reason=reason)
-                    except Exception as e:
-                        record_swallowed("flight.counter", e)
-                        return
-                    self._counter_memo[("trip", reason)] = child
-        child.inc()
+        self._count(("trip", reason), lambda: REGISTRY.counter(
+            "flight_trips_total",
+            "flight-recorder trip conditions fired, by reason",
+        ).labels(reason=reason))
 
     # -- the ring ------------------------------------------------------------
 
@@ -305,21 +316,103 @@ class FlightRecorder:
             # in-memory last_dump (and the HTTP surface) still carry it
             record_swallowed("flight.dump_write", e)
 
-    # -- slow-span capture (called by common/tracing on span close) ----------
+    # -- slow-request capture (called by common/tracing on a root's close) ---
 
-    def note_span(self, name: str, duration_ms: float,
-                  slot: int | None, attrs: dict | None = None) -> None:
-        """File a span closure above the latency floor
-        (``LHTPU_FLIGHT_SPAN_MS``); sub-floor closures cost one float
-        compare."""
-        if not self.enabled or duration_ms < self.span_floor_ms:
+    def _judge(self, name: str, duration_ms: float) -> list | None:
+        """File one closure of root ``name`` into its baseline (the last
+        ``_BASELINE_LEN``) and return those before it, or None while they
+        are fewer than ``_BASELINE_MIN``."""
+        evicted = 0
+        with self._baseline_lock:
+            past = self._baselines.get(name)
+            if past is None:
+                past = self._baselines[name] = deque(maxlen=_BASELINE_LEN)
+                while len(self._baselines) > _BASELINE_NAMES:
+                    del self._baselines[next(iter(self._baselines))]
+                    evicted += 1
+            before = list(past) if len(past) >= _BASELINE_MIN else None
+            past.append(duration_ms)
+        if evicted:
+            record_evicted("flight_baseline", evicted)
+        return before
+
+    def note_root(self, root, duration_ms: float, slot: int | None) -> None:
+        """A root span (``tracing.Span``) closed.  Every closure feeds its
+        name's baseline; one over the latency floor
+        (``LHTPU_FLIGHT_SPAN_MS``) is slow when it is also over one and a
+        half times the second longest of the name's last closures (its
+        upper level, wherever the name has more than one), and files ONE
+        ``slow_request`` with its stage table.  While the name has no baseline the floor alone
+        decides, and the root is filed as a ``slow_span``."""
+        if not self.enabled:
             return
-        fields = {"name": name, "ms": round(duration_ms, 3)}
-        if slot is not None:
-            fields["slot"] = int(slot)
-        if attrs:
-            fields["attrs"] = {k: _jsonable(v) for k, v in attrs.items()}
-        self.emit("slow_span", **fields)
+        before = self._judge(root.name, duration_ms)
+        if duration_ms < self.span_floor_ms:
+            return
+        if before is None:
+            fields = {"name": root.name, "ms": round(duration_ms, 3)}
+            if slot is not None:
+                fields["slot"] = int(slot)
+            if root.attrs:
+                fields["attrs"] = {k: _jsonable(v)
+                                   for k, v in root.attrs.items()}
+            self.emit("slow_span", **fields)
+            return
+        before.sort()
+        level = before[-2]
+        if duration_ms > _SLOW_FACTOR * level:
+            self._slow_request(root, duration_ms, statistics.median(before),
+                               level, slot)
+
+    def _slow_request(self, root, duration_ms: float, median: float,
+                      level: float, slot) -> None:
+        """One event, one count and one WARN line for a root that closed
+        slow: its stage table says where the time went and, per span name,
+        how much of it was off the CPU and in the collector."""
+        stages, leaf_ms = {}, 0.0
+
+        def walk(sp):
+            nonlocal leaf_ms
+            ms = sp.duration_ms()
+            row = stages.setdefault(sp.name, dict.fromkeys(
+                ("n", "ms", "offcpu_ms", "gc_ms"), 0))
+            row["n"] += 1
+            row["ms"] += ms
+            row["offcpu_ms"] += sp.offcpu_s() * 1000.0
+            row["gc_ms"] += sp.gc_s * 1000.0
+            children = list(sp.children)
+            if not children and sp is not root:
+                leaf_ms += ms
+            for child in children:
+                walk(child)
+
+        walk(root)
+        fields = {
+            "root": root.name, "ms": round(duration_ms, 3),
+            "median_ms": round(median, 3), "level_ms": round(level, 3),
+            "slot": None if slot is None else int(slot),
+            # share of the root its leaf spans cover: what the table explains
+            "covered_pct": round(100.0 * leaf_ms / duration_ms, 1),
+            "stages": {name: {k: (round(v, 3) if isinstance(v, float) else v)
+                              for k, v in row.items()
+                              if v or k in ("n", "ms")}
+                       for name, row in stages.items()},
+        }
+        if root.attrs:
+            fields["attrs"] = {k: _jsonable(v) for k, v in root.attrs.items()}
+        self.emit("slow_request", **fields)
+        self._count(("slow_request", root.name), lambda: REGISTRY.counter(
+            "slow_requests_total",
+            "root spans that closed over the latency floor and over one "
+            "and a half times the second longest of their name's last "
+            "closures, by root span name").labels(root=root.name))
+        from lighthouse_tpu.common.logging import Logger
+
+        Logger("flight").warn(
+            "slow request", **{k: fields[k] for k in (
+                "root", "ms", "median_ms", "level_ms", "slot",
+                "covered_pct")},
+            stages=json.dumps(fields["stages"], separators=(",", ":")))
 
     def reconfigure(self) -> None:
         """Re-read the LHTPU_FLIGHT_* / LHTPU_OBS_ARMED knobs (tests

@@ -10,13 +10,33 @@ and finished root spans are filed into a bounded in-memory ring of
 per-slot timelines served by `GET /lighthouse/tracing/{slot}` (the
 Lighthouse block-delay breakdown analogue).
 
-Costs are bounded by construction: a span is one small object + two
-`perf_counter()` reads; the ring keeps the newest `capacity` slots and at
-most `max_spans_per_slot` root spans per slot — overflow rotates the
+Costs are bounded by construction: a span is one small object and, at
+each end, one read of `perf_counter()` and (unless its name runs brief,
+below) one of `thread_time()`; the ring keeps the newest `capacity` slots
+and at most `max_spans_per_slot` root spans per slot — overflow rotates the
 OLDEST root out (newest-wins), so a long-lived process's UNSLOTTED
 timeline shows recent device-plane activity, not frozen startup content.
 Tracing is always on — per-span cost is far below a single host<->device
 crossing, the thing being measured.
+
+A span also says WHY it took what it took, as far as the process can see:
+the opening thread's CPU seconds against the wall (`cpu_s`; `offcpu_ms` in
+the JSON: descheduled, blocked, or waiting for the GIL) and the collector's
+pauses that struck inside it (`gc_s`).  Both are inclusive of children,
+like the duration, and of the opening thread only: a span that hands its
+work to another thread and waits (`bls.verify` under the supervisor's
+watchdog) reads the wait as off-CPU and the worker's spans, nested under
+it, carry the worker's own readings.  The CPU clock is a system call (one
+that enters a user-space kernel on some hosts: 16 us where the span's own
+work is 3), so a span reads it only where the reading can say something: a
+name whose closures run under `_EVIDENCE_MIN_S` on average takes none after
+its first `_EVIDENCE_ALWAYS` (`cpu_s` stays None), and its time off the CPU
+shows in the spans around it.
+:func:`install_host_probes` hooks the collector (`gc.callbacks`): a
+collection of generation 1 or 2 inside a span is drawn as a `host.gc` span
+where it struck, every pause is added to the innermost open span, and
+`host_gc_pause_seconds_total` / `host_gc_collections_total{generation}`
+advance whenever a root span closes.
 
 Two hooks make a span the one timing primitive of the data plane:
 ``span(..., observe=fn)`` hands the span's duration (seconds) to ``fn`` on
@@ -33,12 +53,12 @@ from __future__ import annotations
 
 import contextvars
 import functools
+import gc
 import inspect
 import json
 import threading
 import time
 from collections import OrderedDict, deque
-from dataclasses import dataclass, field
 
 from lighthouse_tpu.common import flight_recorder as flight
 from lighthouse_tpu.common.metrics import REGISTRY, record_swallowed
@@ -55,6 +75,21 @@ _slot_ctx: contextvars.ContextVar[int | None] = contextvars.ContextVar(
 # factory(name, **scalar_attrs) -> context manager, entered/exited with
 # every span while set (see set_annotator)
 _annotator = None
+
+_perf = time.perf_counter
+# The thread's CPU clock.  A system call at each end of a span that reads
+# it: 0.3 us in the sandbox, 5.4 us back to back and ~16 us after a body on
+# the benchmark's machines (gVisor), where it moves in 10 ms ticks.
+_thread_cpu = time.thread_time
+# A name's closures under this on average read no CPU clock (after the
+# first _EVIDENCE_ALWAYS, which every name reads): two calls would cost the
+# dearest host over a hundredth of the span, for a reading under a fifth of
+# its tick.  The mean is over the last _MEAN_OVER closures or so.
+_EVIDENCE_MIN_S, _EVIDENCE_ALWAYS, _MEAN_OVER = 0.002, 8, 64
+# span name -> [closures counted (to _MEAN_OVER), their mean seconds, the
+# name's child of span_offcpu_seconds or None]; names are literals of the
+# code, so it is bounded by the code
+_names: dict[str, list] = {}
 
 
 def set_annotator(factory) -> None:
@@ -74,17 +109,32 @@ def _jsonable(v):
     return str(v)
 
 
-@dataclass
 class Span:
     """One timed region.  `start`/`end` are perf_counter seconds;
-    `wall_start` is epoch time so timelines can be correlated with logs."""
+    `wall_start` is epoch time so timelines can be correlated with logs.
+    `cpu_s` is the opening thread's CPU seconds between start and end (None
+    where the span read no CPU clock: open still, or a name that runs
+    brief), `gc_s` the collector's pauses inside the span; both inclusive
+    of children and set when the span closes (class-level defaults until
+    then: a span is built on the hot path)."""
 
-    name: str
-    attrs: dict = field(default_factory=dict)
-    start: float = 0.0
-    end: float | None = None
-    wall_start: float = 0.0
-    children: list["Span"] = field(default_factory=list)
+    cpu_s: float | None = None
+    gc_s: float = 0.0
+
+    def __init__(self, name: str, attrs: dict | None = None,
+                 start: float = 0.0, end: float | None = None,
+                 wall_start: float = 0.0,
+                 children: list["Span"] | None = None):
+        self.name = name
+        self.attrs = {} if attrs is None else attrs
+        self.start = start
+        self.end = end
+        self.wall_start = wall_start
+        self.children = [] if children is None else children
+
+    def __repr__(self) -> str:
+        return (f"Span({self.name!r}, {self.duration_ms():.3f} ms, "
+                f"{len(self.children)} children)")
 
     def duration_s(self) -> float:
         end = self.end if self.end is not None else time.perf_counter()
@@ -93,6 +143,16 @@ class Span:
     def duration_ms(self) -> float:
         return self.duration_s() * 1000.0
 
+    def offcpu_s(self) -> float:
+        """Wall seconds of a closed span its thread was not on the CPU (0
+        where the span read no CPU clock).  Not clamped: where the thread's
+        CPU clock moves in ticks (10 ms on the benchmark's machines) one
+        span's reading is good to a tick and can be negative, and the sum
+        over many spans stays unbiased."""
+        if self.cpu_s is None or self.end is None:
+            return 0.0
+        return self.end - self.start - self.cpu_s
+
     def to_dict(self, base: float | None = None) -> dict:
         base = self.start if base is None else base
         d: dict = {
@@ -100,6 +160,12 @@ class Span:
             "offset_ms": round((self.start - base) * 1000.0, 3),
             "duration_ms": round(self.duration_ms(), 3),
         }
+        # the evidence, only where there is any
+        offcpu_ms = round(self.offcpu_s() * 1000.0, 3)
+        if abs(offcpu_ms) >= 0.005:  # under it: the readings' own order
+            d["offcpu_ms"] = offcpu_ms
+        if self.gc_s:
+            d["gc_ms"] = round(self.gc_s * 1000.0, 3)
         if self.attrs:
             d["attrs"] = {k: _jsonable(v) for k, v in self.attrs.items()}
         if self.children:
@@ -135,6 +201,7 @@ class Tracer:
         self._ring: OrderedDict[int, _SlotTimeline] = OrderedDict()
         self._lock = threading.Lock()
         self.enabled = True
+        self._dropped = None  # tracing_spans_dropped_total, held once
         # root-span sinks (the SLO engine stitches slot timelines out of
         # finished roots); called OUTSIDE the ring lock, exceptions
         # swallowed-but-accounted — a broken sink must not break tracing
@@ -169,9 +236,11 @@ class Tracer:
             if len(tl.spans) == tl.max_spans:
                 # newest-wins: deque(maxlen) rotates the oldest root out
                 tl.dropped += 1
-                REGISTRY.counter(
-                    "tracing_spans_dropped_total",
-                    "root spans rotated out by the per-slot bound").inc()
+                if self._dropped is None:
+                    self._dropped = REGISTRY.counter(
+                        "tracing_spans_dropped_total",
+                        "root spans rotated out by the per-slot bound")
+                self._dropped.inc()
             tl.spans.append(sp)
         # snapshot: add_sink/remove_sink mutate the list from other
         # threads, and index-based iteration over a shifting list can
@@ -204,6 +273,102 @@ class Tracer:
 TRACER = Tracer()
 
 
+# -- the host runtime's own evidence -------------------------------------------
+
+_OFFCPU_BUCKETS = (0.0001, 0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+                   1.0, 2.5)
+
+
+def _offcpu_child(name: str):
+    return REGISTRY.histogram(
+        "span_offcpu_seconds",
+        "wall seconds of a stage span (one opened with observe=) its "
+        "thread was not on the CPU: descheduled, blocked, or waiting "
+        "for the interpreter lock; a name whose closures run under two "
+        "milliseconds on average reads no CPU clock and adds nothing",
+        buckets=_OFFCPU_BUCKETS).labels(span=name)
+
+
+class _HostProbes:
+    """The collector's hook.
+
+    The callback does plain additions and (generation 1 or 2) opens and
+    closes one span; the registry's counters advance in :meth:`flush`,
+    which the span primitive calls whenever a root closes.  Nothing the
+    callback runs takes a lock of this package: a collection can strike
+    between any two bytecodes, inside the recorder's and the tracer's
+    critical sections too, and a ``host.gc`` span is never a root, feeds
+    no histogram and is not the recorder's to judge."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._open: list = []      # (start, host.gc span or None), a stack
+        self.pause_s = [0.0, 0.0, 0.0]
+        self.collections = [0, 0, 0]
+        self._flushed = ([0.0, 0.0, 0.0], [0, 0, 0])
+        pauses = REGISTRY.counter(
+            "host_gc_pause_seconds_total",
+            "seconds the cyclic collector held the interpreter, by "
+            "generation collected")
+        runs = REGISTRY.counter(
+            "host_gc_collections_total",
+            "collections of the cyclic collector, by generation")
+        # the children exist from here on: a still family reads 0, not absent
+        self._pause_children = [pauses.labels(generation=g) for g in range(3)]
+        self._run_children = [runs.labels(generation=g) for g in range(3)]
+
+    def on_gc(self, phase: str, info: dict) -> None:
+        try:
+            if phase == "start":
+                cm = None
+                if info["generation"] >= 1 and _current.get() is not None:
+                    # a generation-0 pass is microseconds and only adds up;
+                    # so does a pass outside any span, which has nowhere
+                    # to nest and is no request of anybody's
+                    cm = span("host.gc", generation=info["generation"])
+                    cm.__enter__()
+                self._open.append((_perf(), cm))
+                return
+            t0, cm = self._open.pop()
+            pause = _perf() - t0
+            gen = info["generation"]
+            self.pause_s[gen] += pause
+            self.collections[gen] += 1
+            if cm is not None:
+                cm._span.attrs["collected"] = info["collected"]
+                cm.__exit__(None, None, None)
+            sp = _current.get()
+            if sp is not None:
+                sp.gc_s += pause
+        except Exception as e:
+            record_swallowed("tracing.gc_hook", e)
+
+    def flush(self) -> None:
+        with self._lock:
+            flushed_s, flushed_n = self._flushed
+            for gen in range(3):
+                moved = self.collections[gen] - flushed_n[gen]
+                if moved:
+                    self._run_children[gen].inc(moved)
+                    flushed_n[gen] += moved
+                    grown = self.pause_s[gen] - flushed_s[gen]
+                    self._pause_children[gen].inc(grown)
+                    flushed_s[gen] += grown
+
+
+_host: _HostProbes | None = None
+
+
+def install_host_probes() -> None:
+    """Hook the collector (idempotent; the data plane calls it from
+    ``compile_cache.configure()``, beside the annotator).  A process that
+    never calls it pays nothing at a root."""
+    global _host
+    if _host is None:
+        _host = _HostProbes()
+        gc.callbacks.append(_host.on_gc)
+
+
 class span:
     """Context manager AND decorator for one traced region.
 
@@ -226,6 +391,8 @@ class span:
     and feeds the metric.
     """
 
+    _annotation = None  # the annotator's context while the span is open
+
     def __init__(self, name: str, slot: int | None = None,
                  tracer: Tracer | None = None, observe=None, **attrs):
         self.name = name
@@ -236,15 +403,22 @@ class span:
 
     def __enter__(self) -> Span:
         attrs = dict(self.attrs)
-        if self.slot is not None:
-            attrs.setdefault("slot", int(self.slot))
-        self._span = Span(name=self.name, attrs=attrs,
-                          start=time.perf_counter(), wall_start=time.time())
-        self._parent = _current.get()
-        self._token = _current.set(self._span)
-        self._slot_token = (_slot_ctx.set(int(self.slot))
-                            if self.slot is not None else None)
-        self._annotation = None
+        slot = self.slot
+        if slot is not None:
+            attrs.setdefault("slot", int(slot))
+        sp = self._span = Span(self.name, attrs)
+        sp.wall_start = time.time()
+        stat = _names.get(self.name)
+        if stat is None:
+            stat = _names[self.name] = [0, 0.0, None]
+        # the thread's CPU just outside the wall readings, unless the name
+        # runs brief (see _EVIDENCE_MIN_S)
+        cpu0 = (None if stat[0] >= _EVIDENCE_ALWAYS
+                and stat[1] < _EVIDENCE_MIN_S else _thread_cpu())
+        sp.start = _perf()
+        # (parent, the name's record, the reading, the context tokens)
+        self._open = (_current.get(), stat, cpu0, _current.set(sp),
+                      None if slot is None else _slot_ctx.set(int(slot)))
         if _annotator is not None:
             try:
                 self._annotation = _annotator(self.name, **{
@@ -254,7 +428,7 @@ class span:
             except Exception as e:
                 self._annotation = None
                 record_swallowed("tracing.annotator", e)
-        return self._span
+        return sp
 
     def __exit__(self, exc_type, exc, tb):
         sp = self._span
@@ -263,25 +437,44 @@ class span:
                 self._annotation.__exit__(exc_type, exc, tb)
             except Exception as e:
                 record_swallowed("tracing.annotator", e)
-        sp.end = time.perf_counter()
+            self._annotation = None
+        end = sp.end = _perf()
+        parent, stat, cpu0, token, slot_token = self._open
+        if cpu0 is not None:
+            sp.cpu_s = _thread_cpu() - cpu0
         if exc_type is not None:
             sp.attrs.setdefault("error", exc_type.__name__)
         slot = self.slot if self.slot is not None else _slot_ctx.get()
-        _current.reset(self._token)
-        if self._slot_token is not None:
-            _slot_ctx.reset(self._slot_token)
-        # closures above the flight recorder's latency floor become
-        # black-box events (sub-floor spans pay one float compare)
-        dur_ms = (sp.end - sp.start) * 1000.0
-        if dur_ms >= flight.RECORDER.span_floor_ms:
-            flight.RECORDER.note_span(sp.name, dur_ms, slot, sp.attrs)
-        if self._parent is not None:
-            self._parent.children.append(sp)
+        _current.reset(token)
+        if slot_token is not None:
+            _slot_ctx.reset(slot_token)
+        dur_s = end - sp.start
+        # the name's running mean (threads race here: a closure lost or
+        # counted twice moves a mean nobody needs exact)
+        n = stat[0] = min(stat[0] + 1, _MEAN_OVER)
+        stat[1] += (dur_s - stat[1]) / n
+        if parent is not None:
+            # a parent on another thread may have closed already (a worker
+            # the watchdog abandoned that finishes late): harmless, the
+            # tree it appends to is filed and read-only users copy it
+            if sp.gc_s:
+                parent.gc_s += sp.gc_s
+            parent.children.append(sp)
         else:
             self.tracer.record_root(sp, slot)
+            # a root is a request: the flight recorder judges it against
+            # its name's own baseline (nothing below a root is judged)
+            flight.RECORDER.note_root(sp, dur_s * 1000.0, slot)
+            if _host is not None:
+                _host.flush()
         if self.observe is not None:
             try:
-                self.observe(sp.duration_s())
+                self.observe(dur_s)
+                if cpu0 is not None:
+                    child = stat[2]
+                    if child is None:
+                        child = stat[2] = _offcpu_child(sp.name)
+                    child.observe(sp.offcpu_s())
             except Exception as e:
                 record_swallowed("tracing.observe", e)
         return False

@@ -39,6 +39,7 @@ stack.
 
 from __future__ import annotations
 
+import contextvars
 import threading
 import time
 from dataclasses import dataclass, field
@@ -582,14 +583,23 @@ def run_with_deadline(fn, timeout_s: float, thread_name: str, what: str):
     The single implementation of the deadline idiom (supervised backend
     calls, deferred verdict fetches).  On timeout the thread is
     abandoned — daemonic, its late result or exception is discarded.
-    Exceptions from ``fn`` re-raise on the caller."""
+    Exceptions from ``fn`` re-raise on the caller.
+
+    ``fn`` runs inside a copy of the caller's ``contextvars`` context, so
+    the spans it opens nest under the caller's open span and inherit its
+    slot (``common/tracing``): a watchdogged request is one tree, and the
+    hand-off (thread start, the wait on ``done``) is the caller span's
+    self time.  An abandoned thread that finishes late closes its spans
+    into a parent that has closed already: it appends to a filed tree,
+    which raises nothing and which nobody reads again."""
     box: dict = {}
     done = threading.Event()
+    ctx = contextvars.copy_context()
 
     def _run():
         _UNDER_WATCHDOG.value = True
         try:
-            box["ok"] = fn()
+            box["ok"] = ctx.run(fn)
         except BaseException as e:  # lhlint: allow(LH902) — not swallowed:
             box["exc"] = e          # re-raised on the caller thread below
         finally:

@@ -109,6 +109,159 @@ def test_slow_span_capture(fresh_recorder):
     assert ("slow_span", "fast_thing") not in kinds
 
 
+def _closure(name, ms, children=(), slot=None):
+    """One span tree closed through the recorder's hook the way
+    ``tracing.span.__exit__`` does it: the root alone is judged."""
+    from lighthouse_tpu.common.tracing import Span
+
+    def build(name, ms, children):
+        sp = Span(name, start=0.0, end=ms / 1000.0)
+        sp.cpu_s = ms / 1000.0
+        sp.children = [build(*c) for c in children]
+        return sp
+
+    root = build(name, ms, children)
+    flight.RECORDER.note_root(root, root.duration_ms(), slot)
+    return root
+
+
+def _kinds(rec):
+    return [(e["kind"], e.get("name") or e.get("root"))
+            for e in rec.snapshot()]
+
+
+def test_routine_closures_stop_filing_once_the_baseline_stands(fresh_recorder):
+    """Twenty routine closures of one root over the floor: the first eight
+    are filed by the floor alone, the rest, within one and a half times
+    their kind's level, file nothing; nothing below a root is filed."""
+    fresh_recorder.span_floor_ms = 50.0
+    for i in range(20):
+        _closure("req", 570.0 + 5 * (i % 7), [("req.stage", 500.0, ())])
+    kinds = _kinds(fresh_recorder)
+    assert kinds.count(("slow_span", "req")) == 8
+    assert len(kinds) == 8
+
+
+def test_an_outlier_root_files_one_slow_request(fresh_recorder, capsys):
+    """A root three times its level files exactly one ``slow_request``:
+    its stage table says where the time went and accounts for the root,
+    it is counted, and logged once."""
+    from lighthouse_tpu.common.metrics import REGISTRY
+
+    fresh_recorder.span_floor_ms = 50.0
+    stages = [("req.layout", 420.0, ()), ("req.wait", 90.0, ()),
+              ("req.fold", 50.0, [("req.fold.fetch", 45.0, ())])]
+    for i in range(20):
+        _closure("req", 560.0 + i, stages)
+    fresh_recorder.clear()
+    counter = REGISTRY.counter("slow_requests_total").labels(root="req")
+    before = counter.value
+    slow = [("req.layout", 1560.0, ()), ("req.wait", 90.0, ()),
+            ("req.fold", 50.0, [("req.fold.fetch", 45.0, ())])]
+    _closure("req", 1710.0, slow, slot=12)
+    (evt,) = fresh_recorder.snapshot()
+    assert evt["kind"] == "slow_request" and evt["root"] == "req"
+    assert evt["ms"] == 1710.0 and evt["median_ms"] == 569.5
+    # the level is what two of the name's closures reached
+    assert evt["level_ms"] == 578.0
+    assert evt["slot"] == 12
+    table = evt["stages"]
+    assert list(table) == ["req", "req.layout", "req.wait", "req.fold",
+                           "req.fold.fetch"]
+    assert table["req.layout"] == {"n": 1, "ms": 1560.0}
+    # the leaves are the request: layout + wait + the fold's fetch
+    assert evt["covered_pct"] == pytest.approx(100 * 1695 / 1710, abs=0.1)
+    assert evt["covered_pct"] >= 90.0
+    assert counter.value == before + 1
+    err = capsys.readouterr().err
+    assert err.count("slow request") == 1 and "root=req" in err
+    # and the next routine request files nothing again
+    _closure("req", 575.0, stages)
+    assert len(fresh_recorder.snapshot()) == 1
+
+
+@pytest.mark.parametrize("levels", [
+    (112.0, 1152.0),                 # epoch-boundary's state.root, slot by slot
+    (60.0,) * 31 + (1900.0,),        # one slot in 32 crosses an epoch
+    (30.0, 30.0, 30.0, 700.0),       # a name that straddles the floor
+])
+def test_a_name_on_several_levels_files_nothing_on_any(fresh_recorder, levels):
+    """One name, closures on levels far more than one and a half times
+    apart (``epoch-boundary``: the slot that crosses the epoch and the one
+    that does not are both ``state.slot``): two of the name's last 64
+    reached the upper level, so neither is slow; the sub-floor closures
+    feed the baseline like the others."""
+    fresh_recorder.span_floor_ms = 50.0
+    for i in range(64):
+        _closure("state.slot", levels[i % len(levels)] * (1 + 0.01 * (i % 5)))
+    fresh_recorder.clear()
+    for i in range(64, 64 + 2 * len(levels)):
+        _closure("state.slot", levels[i % len(levels)] * (1 + 0.01 * (i % 5)))
+    assert fresh_recorder.snapshot() == []
+    # and an outlier of the upper level is still one
+    _closure("state.slot", 2.0 * max(levels))
+    assert _kinds(fresh_recorder) == [("slow_request", "state.slot")]
+
+
+def test_one_earlier_outlier_is_no_level(fresh_recorder):
+    """A second 4 s request after a first is as slow as the first was (a
+    level is what two closures reached); a third among the last 64 is what
+    the name does now."""
+    fresh_recorder.span_floor_ms = 50.0
+    for i in range(8):
+        _closure("req", 570.0 + i)
+    fresh_recorder.clear()
+    _closure("req", 4075.0)
+    _closure("req", 580.0)
+    _closure("req", 4100.0)
+    assert _kinds(fresh_recorder) == [("slow_request", "req")] * 2
+    _closure("req", 4090.0)
+    assert len(fresh_recorder.snapshot()) == 2
+
+
+def test_a_slow_stage_under_a_routine_root_files_nothing(fresh_recorder):
+    """A stage is slow as part of its request: below a root that took what
+    its kind takes, nothing is the ring's."""
+    fresh_recorder.span_floor_ms = 50.0
+    for _ in range(10):
+        _closure("req", 600.0, [("req.stage", 100.0, ())])
+    fresh_recorder.clear()
+    _closure("req", 700.0, [("req.stage", 200.0, ())], slot=3)
+    assert fresh_recorder.snapshot() == []
+
+
+def test_the_stage_table_carries_the_spans_evidence(fresh_recorder):
+    """Through the real span primitive: a root that sleeps where it used
+    to take a tenth of the time reads the sleep as off-CPU in its row."""
+    import time
+
+    from lighthouse_tpu.common import tracing
+
+    fresh_recorder.span_floor_ms = 1.0
+    for _ in range(9):
+        with tracing.span("evidence.req"):
+            with tracing.span("evidence.req.stage"):
+                time.sleep(0.003)
+    fresh_recorder.clear()
+    with tracing.span("evidence.req"):
+        with tracing.span("evidence.req.stage"):
+            time.sleep(0.06)
+    (evt,) = [e for e in fresh_recorder.snapshot()
+              if e["kind"] == "slow_request"]
+    row = evt["stages"]["evidence.req.stage"]
+    assert row["ms"] >= 60.0 and row["offcpu_ms"] >= 45.0
+    assert evt["covered_pct"] >= 90.0
+
+
+def test_the_baselines_are_bounded(fresh_recorder, monkeypatch):
+    monkeypatch.setattr(flight, "_BASELINE_NAMES", 4)
+    fresh_recorder.span_floor_ms = 1.0
+    for i in range(10):
+        _closure(f"name-{i}", 5.0)
+    assert list(fresh_recorder._baselines) == [f"name-{i}"
+                                               for i in range(6, 10)]
+
+
 # -- the trip matrix ----------------------------------------------------------
 
 

@@ -94,12 +94,8 @@ BLS_STAGES_NEW = {"aggregate_layout", "aggregate_dispatch", "aggregate_fetch",
                   "subgroup_wait", "pipeline_wait", "final_exp_host"}
 
 
-def test_bls_pipeline_stage_spans():
-    """The shapes of test_device_pairing's aggregation batch (3 sets of
-    8, 11 and 7 keys, one message): the fold and the fused program are in
-    the compile cache of any tree that ran that test."""
+def _aggregation_sets():
     from lighthouse_tpu.crypto import bls
-    from lighthouse_tpu.ops.bls_backend import verify_sets_pipeline
 
     sks = [bls.SecretKey.from_bytes(int(500 + i).to_bytes(32, "big"))
            for i in range(12)]
@@ -110,6 +106,16 @@ def test_bls_pipeline_stage_spans():
         sig = bls.Signature.aggregate([sks[k].sign(msg) for k in range(lo, hi)])
         sets.append(bls.SignatureSet(bls.Signature(sig.to_bytes()),
                                      pks[lo:hi], msg))
+    return sets
+
+
+def test_bls_pipeline_stage_spans():
+    """The shapes of test_device_pairing's aggregation batch (3 sets of
+    8, 11 and 7 keys, one message): the fold and the fused program are in
+    the compile cache of any tree that ran that test."""
+    from lighthouse_tpu.ops.bls_backend import verify_sets_pipeline
+
+    sets = _aggregation_sets()
     with _Roots() as sink:
         assert verify_sets_pipeline(sets)
     parents = sink.parents()
@@ -127,6 +133,48 @@ def test_bls_pipeline_stage_spans():
     assert _closure(pipeline) >= 0.90
     assert _closure(aggregate) >= 0.90
     assert _closure(sink.find("bls.final_exp")[0]) >= 0.90
+
+
+@pytest.mark.parametrize("slot", [None, 4242])
+def test_under_the_supervisor_a_request_is_one_tree(slot):
+    """The watchdog's thread runs in a copy of the caller's context: every
+    `bls.*` stage span's root is `bls.verify` (or the slotted caller's own
+    span above it), none is filed beside it, and a slotted caller finds
+    its signature stages in its slot's timeline."""
+    from contextlib import nullcontext
+
+    from lighthouse_tpu.crypto import bls
+    from lighthouse_tpu.testing import supervised_bls
+
+    sets = _aggregation_sets()
+    tracing.TRACER.clear()
+    with supervised_bls(LHTPU_SUPERVISOR_LADDER="tpu,reference"), \
+            _Roots() as sink:
+        with (tracing.span("block_import", slot=slot) if slot is not None
+              else nullcontext()):
+            assert bls.verify_signature_sets(sets, backend="tpu")
+    top = "bls.verify" if slot is None else "block_import"
+    assert [r["name"] for r in sink.roots] == [top]
+    parents = sink.parents()
+    assert parents["bls.verify"] == {None if slot is None else top}
+    assert parents["bls.verify_pipeline"] == {"bls.verify"}
+    for name, parent in BLS_PARENTS.items():
+        assert parents.get(name) == {parent}, name
+    (verify,) = sink.find("bls.verify")
+    assert verify["attrs"]["supervised"] is True
+    # the hand-off is the caller span's self time: the pipeline is the rest
+    assert _closure(verify) >= 0.90
+    if slot is not None:
+        (root,) = tracing.TRACER.timeline(slot)["spans"]
+        names = set()
+
+        def walk(d):
+            names.add(d["name"])
+            for child in d.get("children", ()):
+                walk(child)
+
+        walk(root)
+        assert {"bls.verify", "bls.verify_pipeline", *BLS_PARENTS} <= names
 
 
 def test_fold_counts_its_products_under_bls_aggregate():
@@ -333,6 +381,14 @@ from benchmarks.tests.test_stage_metrics import (  # noqa: E402,F401
 )
 
 
+from benchmarks.tests.test_host_stall_metrics import (  # noqa: E402,F401
+    test_a_still_family_reads_zero_not_nothing,
+    test_counter_per_request_sums_scales_and_tells_absent_from_still,
+    test_host_stall_metric_reads_a_synthetic_window,
+    test_the_new_entries_of_benchmark_json,
+)
+
+
 @pytest.mark.parametrize("metric,family", [
     ("kzg_fused_resident_pct.blobs", "kzg_fused_products_total"),
     ("kzg_fused_resident_pct.columns", "kzg_fused_products_total"),
@@ -353,3 +409,46 @@ def test_resident_share_reads_a_synthetic_window(metric, family):
            "requests": 3}
     assert _read(metric, ctx) == pytest.approx(99.0)
     assert _read(metric, {"before": {}, "after": {}, "requests": 3}) is None
+
+
+def test_the_host_runtime_families_are_there_after_real_work():
+    """After the verify and the state advance above (run again here at the
+    same small sizes, so that the case stands alone): `span_offcpu_seconds`
+    holds every BLS and tree stage the `host_offcpu_ms.*` files name that
+    these sizes reach, and the collector's families hold all their label
+    children."""
+    import json
+
+    from benchmarks import counters
+    from lighthouse_tpu.crypto import bls
+    from lighthouse_tpu.state_transition import state_advance
+    from lighthouse_tpu.testing import Harness, supervised_bls
+
+    with supervised_bls(LHTPU_SUPERVISOR_LADDER="tpu,reference"):
+        assert bls.verify_signature_sets(_aggregation_sets(), backend="tpu")
+    h = Harness(n_validators=64, fork="altair", real_crypto=False)
+    spe = h.spec.preset.slots_per_epoch
+    state_advance(h.state, h.spec, 2 * spe - 1)
+    h.state.validators.effective_balance[:] -= np.uint64(10**9)
+    h.state.balances[:] += np.uint64(12345)
+    state_advance(h.state, h.spec, 2 * spe + 1)
+    samples = counters.samples()
+    seen = {dict(labels)["span"] for (name, labels) in samples
+            if name == "span_offcpu_seconds_count"}
+
+    def named(suffix):
+        with open(os.path.join(ROOT, "benchmarks", "layer_metrics",
+                               f"host_offcpu_ms.{suffix}.json")) as f:
+            return set(json.load(f)["args"]["any_of"]["span"])
+
+    # the second step needs a set wider than a segment, which these are not
+    assert named("block") - {"bls.aggregate.combine"} <= seen
+    assert named("block") == named("electra")
+    tree = {s for s in named("epoch") if s.startswith(("tree.", "sha."))}
+    assert tree <= seen, tree - seen
+    assert {"epoch.registry_updates"} <= seen
+    for family in ("host_gc_pause_seconds_total",
+                   "host_gc_collections_total"):
+        for generation in "012":
+            assert (family, frozenset({"generation": generation}.items())) \
+                in samples
