@@ -69,7 +69,7 @@ def _hash_to_g2_memo(message: bytes):
 class PublicKey:
     """Compressed G1 public key with lazy decompression + caching."""
 
-    __slots__ = ("_bytes", "_point", "_limbs")
+    __slots__ = ("_bytes", "_point", "_limbs", "_fold_row")
 
     def __init__(self, data: bytes, point=None):
         if len(data) != 48:
@@ -77,6 +77,9 @@ class PublicKey:
         self._bytes = bytes(data)
         self._point = point
         self._limbs = None
+        # the key's row in the fold's resident key table
+        # (ops/bls_backend._FoldKeyTable), -1 until the table first sees it
+        self._fold_row = -1
 
     @property
     def point(self):
@@ -506,6 +509,26 @@ def count_fold_lanes(key: int, blinding: int, padding: int) -> None:
         from lighthouse_tpu.common.metrics import record_swallowed
 
         record_swallowed("bls.count_fold_lanes", e)
+
+
+def count_fold_key_rows(resident: int, uploaded: int) -> None:
+    """Key lanes of one dispatched slice of the key-aggregation fold by
+    where their row came from: ``resident`` rows were already in the
+    device's key table (ops/bls_backend._FoldKeyTable), ``uploaded`` rows
+    this request converted and uploaded."""
+    try:
+        from lighthouse_tpu.common.metrics import REGISTRY
+
+        rows = REGISTRY.counter(
+            "bls_fold_key_rows_total",
+            "key lanes of the key-aggregation fold slices dispatched, by "
+            "where their limb row came from")
+        rows.labels(source="resident").inc(resident)
+        rows.labels(source="uploaded").inc(uploaded)
+    except Exception as e:
+        from lighthouse_tpu.common.metrics import record_swallowed
+
+        record_swallowed("bls.count_fold_key_rows", e)
 
 
 def count_fold_products(resident: int, materialized: int) -> None:

@@ -246,17 +246,17 @@ def _grouped_layout(n: int, n_groups: int,
 _BLIND_U: list[int] = []
 _BLIND_POINTS: list[tuple] = []
 _BLIND_NEG_TOTAL: dict[int, tuple] = {}     # max_k -> -[Σ_{j<k} u_j]G limbs
-_BLIND_LANES: dict[tuple, tuple] = {}       # (max_k, n_pad) -> X0, Y0, Z0
+_BLIND_LANES: dict[tuple, tuple] = {}       # (max_k, n_pad) -> bx, by, bz
 import threading as _threading
 
 _BLIND_LOCK = _threading.Lock()
 
 
 def _blinding(max_k: int, n_pad: int):
-    """((X0, Y0, Z0), neg_total) for slices of ``n_pad`` segments of
-    ``max_k`` key lanes: the slice's lane rows with the blinding half
-    filled (read-only: a slice copies them and writes its keys), and
-    the limbs of -[Σ u_j]G.  Laid out once a shape, not once a request."""
+    """((bx, by, bz), neg_total) for slices of ``n_pad`` segments of
+    ``max_k`` key lanes: the blinding half of a slice's lanes on the
+    device (lane ``j * n_pad + i`` of it carries B_j), and the limbs of
+    -[Σ u_j]G.  Laid out and uploaded once a shape, not once a slice."""
     with _BLIND_LOCK:
         return _blinding_locked(max_k, n_pad)
 
@@ -280,17 +280,100 @@ def _blinding_locked(max_k: int, n_pad: int):
         _BLIND_NEG_TOTAL[max_k] = neg
     lanes = _BLIND_LANES.get((max_k, n_pad))
     if lanes is None:
-        lanes = tuple(np.zeros((2 * max_k * n_pad, bi.L), np.uint32)
-                      for _ in range(3))
-        for j, (bx, by) in enumerate(_BLIND_POINTS[:max_k]):
-            rows = slice((max_k + j) * n_pad, (max_k + j + 1) * n_pad)
-            lanes[0][rows] = bx
-            lanes[1][rows] = by
-            lanes[2][rows] = bi.ONE_M
-        for a in lanes:
-            a.setflags(write=False)
+        points = _BLIND_POINTS[:max_k]
+        lanes = tuple(jnp.asarray(np.repeat(np.stack(c), n_pad, axis=0))
+                      for c in zip(*points))
+        lanes += (jnp.asarray(np.broadcast_to(bi.ONE_M,
+                                              (max_k * n_pad, bi.L))),)
         _BLIND_LANES[(max_k, n_pad)] = lanes
     return lanes, neg
+
+
+_KEY_ROW_WORDS = 128
+
+
+class _FoldKeyTable:
+    """The fold's resident key table: one row of Montgomery limbs a key
+    (x, then y: uint32[capacity, 2L]), on the host and on the device,
+    keyed by the key's 48 bytes, so that two `PublicKey` objects of one
+    key share a row; each caches it (`PublicKey._fold_row`).
+
+    Rows are assigned under one lock and never move.  The capacity grows
+    by powers of two from ``floor``, so the gather program's table shape
+    changes a few times in a process's life.  The device copy is replaced
+    whole whenever rows were added, its rows padded to the 128 words of a
+    TPU tile: at 54 the compiler lays the whole table out again for the
+    gather on every call (2^18 keys: 134 MB on the device, 57 MB on the
+    host).  A key's row is published on its `PublicKey` only after the
+    device copy that holds it, so a reader that has read a row and then
+    takes `device` finds it there."""
+
+    def __init__(self, floor: int = 1 << 16):
+        self._lock = _threading.Lock()
+        self._rows: dict[bytes, int] = {}
+        self._host = np.zeros((floor, 2 * bi.L), np.uint32)
+        self.device = None
+
+    def add(self, pubkeys) -> range:
+        """Give every key of ``pubkeys`` that has none a row: the keys
+        new to the table are converted in one vectorised call and the
+        table uploaded.  Returns the rows this call added."""
+        pending = [pk for pk in pubkeys if pk._fold_row < 0]
+        # decompression may raise (an infinity key): before any row moves
+        points = [pk.point for pk in pending]
+        with self._lock:
+            new = {}
+            for pk, pt in zip(pending, points):
+                if pk.to_bytes() not in self._rows:
+                    new.setdefault(pk.to_bytes(), pt)
+            lo = len(self._rows)
+            if new:
+                hi = lo + len(new)
+                limbs = ec.ints_to_mont_limbs(
+                    [p[0] for p in new.values()] + [p[1] for p in new.values()])
+                host = self._host
+                if hi > len(host):
+                    host = np.zeros((_next_pow2(hi), 2 * bi.L), np.uint32)
+                    host[:lo] = self._host[:lo]
+                host[lo:hi, :bi.L] = limbs[:len(new)]
+                host[lo:hi, bi.L:] = limbs[len(new):]
+                self.device = jnp.asarray(np.pad(
+                    host, ((0, 0), (0, _KEY_ROW_WORDS - 2 * bi.L))))
+                self._host = host
+                self._rows.update(zip(new, range(lo, hi)))
+            for pk in pending:
+                pk._fold_row = self._rows[pk.to_bytes()]
+            return range(lo, len(self._rows))
+
+
+from operator import attrgetter as _attrgetter
+
+_FOLD_KEYS = _FoldKeyTable()
+_FOLD_ROW = _attrgetter("_fold_row")
+
+# prewarmed by the "msm" driver, which folds through
+# aggregate_pubkeys_device
+_pstore.register_entry("ops/bls_backend.py::_blinded_lanes@_blinded_lanes",
+                       driver="msm")
+
+
+@jax.jit
+def _blinded_lanes(table, rows, bx, by, bz):
+    """(X, Y, Z) uint32[2K, L], the lanes of one `_blinded_fold` slice:
+    K key lanes gathered from ``table`` (uint32[T, 128]: a key's x limbs,
+    then its y, then zeros) at ``rows`` (int32[K], s-major; -1 is a
+    padding lane, x, y and Z zero), Z one under every key, then the
+    blinding half (bx, by, bz uint32[K, L])."""
+    live = (rows >= 0)[:, None]
+    xy = jnp.where(live, jnp.take(table, rows, axis=0, mode="clip"), 0)
+    z = jnp.where(live, bi._jconst("one_m"), 0)
+    return (jnp.concatenate([xy[:, :bi.L], bx]),
+            jnp.concatenate([xy[:, bi.L:2 * bi.L], by]),
+            jnp.concatenate([z, bz]))
+
+
+_blinded_lanes = _dtel.instrument(
+    "ops/bls_backend.py::_blinded_lanes@_blinded_lanes", _blinded_lanes)
 
 
 # lanes one blinded-fold dispatch may carry, whatever the batch: no count
@@ -356,6 +439,13 @@ def aggregate_pubkeys_device(sets):
     fold in equal-shaped slices of at most _AGG_MAX_LANES lanes, all
     dispatched before the one fetch.
 
+    The host lays out a slice as its key lanes' rows in the resident key
+    table (`_FoldKeyTable`: the row each member's `PublicKey` caches, -1
+    a padding lane); the device gathers the lanes from the table and
+    puts the blinding half beside them (`_blinded_lanes`), then
+    folds them.  Keys the table has not seen are given rows, all of the
+    request's at once, when the first of them is met.
+
     The second step (`bls.aggregate.combine`) runs on the host after the
     fetch: a wide set's partial sums — 64 a mainnet electra aggregate —
     are added by complete additions (`msm.host_lincomb_groups`: native
@@ -369,30 +459,42 @@ def aggregate_pubkeys_device(sets):
     # (set, first key) of every segment, in set order
     segments = [(i, lo) for i, k in enumerate(widths) if k > 1
                 for lo in range(0, k, max_k)]
-    (X0, Y0, Z0), neg_total = _blinding(max_k, n_pad)
-    one = bi.ONE_M
+    blinding, neg_total = _blinding(max_k, n_pad)
     tracing.add_attrs(slices=-(-len(segments) // n_pad), lanes=seg * n_pad,
                       sets=n, segments=len(segments), widest=max(widths))
     products = _msm.blinded_fold_products(seg * n_pad, n_pad)
+    added = range(0)
     outs = []
     for first in range(0, len(segments), n_pad):
         with _stage_span("bls.aggregate.layout", "aggregate_layout"):
-            X, Y, Z = X0.copy(), Y0.copy(), Z0.copy()
+            rows = np.full((max_k, n_pad), -1, np.int32)
             keys = 0
             for i, (set_idx, lo) in enumerate(segments[first:first + n_pad]):
                 members = sets[set_idx].pubkeys[lo:lo + max_k]
                 keys += len(members)
-                for j, pk in enumerate(members):
-                    xl, yl = pk.mont_limbs()
-                    lane = j * n_pad + i   # s-major layout for g1_segment_sum
-                    X[lane] = xl
-                    Y[lane] = yl
-                    Z[lane] = one
+                got = np.fromiter(map(_FOLD_ROW, members), np.int32,
+                                  len(members))
+                if got.min() < 0:
+                    # keys the table has not seen: every such key of the
+                    # request at once
+                    added = _FOLD_KEYS.add(
+                        [pk for s in sets if len(s.pubkeys) > 1
+                         for pk in s.pubkeys])
+                    got = np.fromiter(map(_FOLD_ROW, members), np.int32,
+                                      len(members))
+                rows[:len(members), i] = got
+            rows = rows.reshape(-1)      # s-major: lane j * n_pad + i
+            uploaded = int(np.count_nonzero(
+                (rows >= added.start) & (rows < added.stop)))
+            # taken after the rows were read: it holds every one of them
+            table = _FOLD_KEYS.device
         with _stage_span("bls.aggregate.dispatch", "aggregate_dispatch"):
+            X, Y, Z = _blinded_lanes(table, rows, *blinding)
             outs.append(_msm.blinded_fold_device(
                 X, Y, Z, neg_total[0], neg_total[1], n_pad))
         api.count_fold_lanes(key=keys, blinding=max_k * n_pad,
                              padding=max_k * n_pad - keys)
+        api.count_fold_key_rows(resident=keys - uploaded, uploaded=uploaded)
         api.count_fold_products(*products)
     with _stage_span("bls.aggregate.fetch", "aggregate_fetch"):
         fetched = jax.device_get(outs)

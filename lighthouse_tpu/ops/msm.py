@@ -208,7 +208,7 @@ def gather_fold_device(tx, ty, lane_idx, digits, n_segments: int):
 
 
 def blinded_fold_device(X, Y, Z, ux, uy, n_segments: int):
-    """One blinded-track dispatch (host lane rows in, device rows out)."""
+    """One blinded-track dispatch (lane rows in, device rows out)."""
     return _blinded_fold(jnp.asarray(X), jnp.asarray(Y), jnp.asarray(Z),
                          ux, uy, int(n_segments))
 

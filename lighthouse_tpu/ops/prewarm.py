@@ -212,7 +212,8 @@ def _drv_msm(scale: str) -> None:
     got = (int(bi.from_mont(xa[0])), int(bi.from_mont(ya[0])))
     if bool(inf[0]) or got != want:
         raise RuntimeError("prewarmed gather fold mismatches host adds")
-    # blinded-merge track via the per-set aggregation front end
+    # blinded-merge track via the per-set aggregation front end (its
+    # lanes gathered from the resident key table by _blinded_lanes)
     sets = _fresh_sets(2, n_keys=2, tag=b"msm")
     bx, by, binf = bls_backend.aggregate_pubkeys_device(sets)
     for i, s in enumerate(sets):

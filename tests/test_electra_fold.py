@@ -12,6 +12,7 @@ reference ``benchmarks/reference/bls_plain.py``.
 
 import random
 
+import jax
 import numpy as np
 import pytest
 
@@ -137,9 +138,11 @@ def test_no_dispatch_over_the_lane_cap(keys, small_cap, dispatched,
     assert len(dispatched) == attrs["slices"] == -(
         -(-(-keys_in_set // SEG_KEYS) + 1) // SLICE)
     # the blinding lanes stay at the segment's width, laid out once a shape
+    # and kept on the device: the half of a slice beside its key lanes
     X0, _, Z0 = bb._BLIND_LANES[SEG_KEYS, SLICE]
-    assert X0.shape == (CAP, bi.L) and not X0.flags.writeable
-    assert int((Z0 != 0).any(axis=1).sum()) == SEG_KEYS * SLICE
+    assert X0.shape == Z0.shape == (CAP // 2, bi.L)
+    assert isinstance(X0, jax.Array)
+    assert int((np.asarray(Z0) != 0).any(axis=1).sum()) == SEG_KEYS * SLICE
 
 
 def test_sets_no_wider_than_a_segment_dispatch_as_before(keys, small_cap,
@@ -231,11 +234,19 @@ def _reference_verdict(sets, seed=35):
          for s in sets], random.Random(seed))
 
 
+@pytest.mark.parametrize("rows", ["resident", "first_seen"])
 @pytest.mark.parametrize("variant", ["good", "two_signatures_swapped"])
-def test_pipeline_on_an_electra_shaped_batch(keys, small_cap, variant):
+def test_pipeline_on_an_electra_shaped_batch(keys, small_cap, variant, rows):
     """Two aggregates wider than a segment, a sync set drawn with
-    replacement and a single-key set, each its own message."""
+    replacement and a single-key set, each its own message; with keys
+    the fold's key table holds, or keys it meets in this batch."""
     sks, pks = keys
+    if rows == "first_seen":
+        base = 7600 + 100 * (variant == "good")
+        sks = [bls.SecretKey.from_bytes(int(base + i).to_bytes(32, "big"))
+               for i in range(24)]
+        pks = [sk.public_key() for sk in sks]
+        assert all(pk._fold_row == -1 for pk in pks)
     rng = random.Random(7549)
     members = [rng.sample(range(24), 20), rng.sample(range(24), 17),
                [rng.randrange(24) for _ in range(8)], [5]]

@@ -473,8 +473,9 @@ def test_blinded_fold_equals_limbs_last_composition(limbs_last_fold, case):
     from lighthouse_tpu.ops import ec, msm
 
     members = _bf_cases()[case]
-    (X0, Y0, Z0), neg = bb._blinding(_BF_KEYS, _BF_SEGS)
-    X, Y, Z = X0.copy(), Y0.copy(), Z0.copy()
+    blinding, neg = bb._blinding(_BF_KEYS, _BF_SEGS)
+    X, Y, Z = (np.concatenate([np.zeros_like(b), b])
+               for b in map(np.asarray, blinding))
     for i, seg in enumerate(members):
         if not seg:
             continue
@@ -552,7 +553,8 @@ def test_manifest_msm_family_unified():
     """One program-store registration point per (track, bucket): the
     four per-consumer MSM kernels are gone from the shape manifest,
     replaced by exactly three ops/msm.py entries — the MSM-family entry
-    count went DOWN (4 legacy -> 3 unified; 21 entries in all)."""
+    count went DOWN (4 legacy -> 3 unified; 22 entries in all since the
+    fold's lane gather, ops/bls_backend.py::_blinded_lanes, PR 38)."""
     import pathlib
 
     manifest = pathlib.Path(__file__).parent.parent / "tools" / "lint" \
@@ -571,7 +573,7 @@ def test_manifest_msm_family_unified():
                        "ops/msm.py::_fold_kernel@_fold_kernel",
                        "ops/msm.py::_gather_fold@_gather_fold"]
     assert len(unified) < len(legacy)
-    assert len(entries) == 21
+    assert len(entries) == 22
     # and every unified entry is registered at runtime with the msm
     # prewarm driver (the one registration point)
     from lighthouse_tpu.ops import msm  # noqa: F401  (registers)

@@ -184,6 +184,28 @@ def test_blinded_fold_block_layout(one_chip, tpu_branches):
     assert "[32768,27,5" not in entry and "[16384,27,5" not in entry
 
 
+def test_blinded_lanes_from_the_key_table(one_chip, tpu_branches):
+    """The gather in front of the fold (PR 38): a slice's 16,384 key lanes
+    from the resident key table at `block-8x32k`'s 2^18 keys (x and y, 54
+    words a row padded to a tile's 128), and the blinding half: the
+    table is read in place, not laid out again on every call."""
+    from lighthouse_tpu.ops import bigint as bi
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    max_k, n_pad = bb._fold_shape([32768] * 8 + [512, 1, 1])
+    keys = max_k * n_pad
+    c = _compile(
+        "_blinded_lanes@2^18x16384", bb._blinded_lanes._fn,
+        jax.ShapeDtypeStruct((1 << 18, bb._KEY_ROW_WORDS), jnp.uint32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((keys,), jnp.int32, sharding=one_chip),
+        *[_limbs(one_chip, keys)] * 3)
+    ma = c.memory_analysis()
+    assert ma.temp_size_in_bytes < 1 << 20
+    assert ma.argument_size_in_bytes < 160 << 20
+    assert ma.output_size_in_bytes < 16 << 20
+
+
 @pytest.mark.slow  # ~1 min (46 s of it the trace), 63 MB of code; not on chip_smoke's path
 def test_gather_fold_16_committees(one_chip, tpu_branches):
     """The pubkey plane's fold over a 16,384-row table at 16 groups x
