@@ -214,13 +214,17 @@ def batch_verify(
     bisection fallback attributing failures to items
     (reference batch_verify_unaggregated_attestations, batch.rs:133).
 
-    The batch first passes through the pre-BLS coalescing stage
-    (pool/pre_aggregation): exact duplicates verify once and
-    same-message sets fold into blinded merges, so a mainnet-width
-    attestation sweep pays one pairing lane per (slot, committee,
-    beacon_block_root) instead of one per validator.  The fast path
-    verifies the COALESCED batch; on failure, bisection runs over the
-    ORIGINAL per-candidate sets so attribution is unchanged."""
+    The batch first passes through the pre-BLS stage
+    (pool/pre_aggregation): exact duplicates verify once.  Sets that
+    share a message (since EIP-7549 the signed data is the same for
+    every committee, so a slot's attestations share one message a head
+    vote) are then merged by the rung that serves the batch, so a
+    mainnet-width attestation sweep pays one pairing lane per head vote
+    instead of one per validator: the "tpu" backend merges them on the
+    device (ops/bls_backend -> ops/msm._blinded_merge), the reference
+    rung in Python (pool/pre_aggregation.merge_same_message).  The fast
+    path verifies the deduplicated batch; on failure, bisection runs
+    over the ORIGINAL per-candidate sets so attribution is unchanged."""
     from lighthouse_tpu.pool.pre_aggregation import coalesce_sets
 
     all_sets: list[bls.SignatureSet] = []

@@ -403,8 +403,8 @@ def _unique_set(chain, prep, u: int):
 
 def _should_premerge() -> bool:
     """Merged host folds are redundant when the fused device pipeline
-    serves verification — it groups same-message lanes internally
-    (ops/bls_backend._chunk_layout), so pre-merging would pay host
+    serves verification — it merges same-message lanes itself
+    (ops/bls_backend._merge_same_message), so pre-merging would pay host
     point math for nothing.  Honor the pre-BLS kill switch too."""
     from lighthouse_tpu.pool import pre_aggregation
 

@@ -552,13 +552,84 @@ def count_fold_products(resident: int, materialized: int) -> None:
         record_swallowed("bls.count_fold_products", e)
 
 
+def count_merged(path: str, sets: int) -> None:
+    """Signature sets folded into a blinded same-message merge, by where
+    the fold ran: ``host`` (the reference rung's `_merge_on_host`, pure
+    Python) or ``device`` (the "tpu" backend's merge program,
+    ops/msm._blinded_merge)."""
+    try:
+        from lighthouse_tpu.common.metrics import REGISTRY
+
+        REGISTRY.counter(
+            "pre_bls_merged_total",
+            "signature sets folded into blinded same-message merges, by "
+            "where the fold ran").labels(path=path).inc(sets)
+    except Exception as e:
+        from lighthouse_tpu.common.metrics import record_swallowed
+
+        record_swallowed("bls.count_merged", e)
+
+
+def count_merge_lanes(live: int, padding: int) -> None:
+    """Lanes of the same-message merge program dispatched: those that
+    carry a set, and the padding of the slices."""
+    try:
+        from lighthouse_tpu.common.metrics import REGISTRY
+
+        lanes = REGISTRY.counter(
+            "bls_merge_lanes_total",
+            "lanes of the same-message merge dispatches, by kind")
+        lanes.labels(kind="live").inc(live)
+        lanes.labels(kind="padding").inc(padding)
+    except Exception as e:
+        from lighthouse_tpu.common.metrics import record_swallowed
+
+        record_swallowed("bls.count_merge_lanes", e)
+
+
+def count_merge_products(products: int) -> None:
+    """Fp lane-products of one dispatched merge (ops/msm
+    .blinded_merge_products), all through `MontField.mont_mul_lm`."""
+    try:
+        from lighthouse_tpu.common.metrics import REGISTRY
+
+        REGISTRY.counter(
+            "bls_merge_products_total",
+            "Fp lane-products of the same-message merge dispatches").inc(
+                products)
+    except Exception as e:
+        from lighthouse_tpu.common.metrics import record_swallowed
+
+        record_swallowed("bls.count_merge_products", e)
+
+
+def _merge_on_host(sets: Sequence[SignatureSet]) -> Sequence[SignatureSet]:
+    """``sets`` with those that share a message folded into one blinded
+    set each, in Python (pool/pre_aggregation.merge_same_message): the
+    host rung's own merge, as the "tpu" backend merges such sets on the
+    device.  Unchanged when no message repeats or with LHTPU_PRE_BLS=0."""
+    if len({s.message for s in sets}) == len(sets):
+        return sets
+    from lighthouse_tpu.pool import pre_aggregation
+
+    if not pre_aggregation.enabled():
+        return sets
+    merged, stats = pre_aggregation.merge_same_message(list(sets))
+    if stats.merged:
+        count_merged("host", stats.merged)
+    return merged
+
+
 def _verify_signature_sets_reference(sets: Sequence[SignatureSet],
                                      chunk_size: int | None = None) -> bool:
-    """Randomized batch verification (one multi-pairing for the batch).
+    """Randomized batch verification (one multi-pairing for the batch),
+    sets that share a message first merged on the host (`_merge_on_host`)
+    so that a flood batch pays one pairing lane a message.
     ``chunk_size`` is accepted for seam compatibility and ignored: the
     host path has no device to overlap with."""
     if not sets:
         return False
+    sets = _merge_on_host(sets)
     t0 = time.perf_counter()
     prepared = []
     for s in sets:

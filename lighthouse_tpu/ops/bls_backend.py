@@ -14,10 +14,15 @@ crossings are counted on fingers):
   the one fetched Fq12;
 - device, one fused jit (_pipeline_fused): r_i·agg_pk_i over G1 lanes and
   r_i·sig_i over G2 lanes in ONE merged 4-bit-windowed scan (16 steps of
-  shared mul-queue rounds), the G2 tree-sum, per-message-group G1 segment
-  folds, every Miller loop (G1 lanes in JACOBIAN form via subfield line
-  scaling; the Σ r·sig lane in Jacobian Fq2 form via the zq path — no
-  Fermat inversion anywhere), and the product tree;
+  shared mul-queue rounds), the G2 tree-sum, every Miller loop (G1 lanes
+  in JACOBIAN form via subfield line scaling; the Σ r·sig lane in
+  Jacobian Fq2 form via the zq path — no Fermat inversion anywhere), and
+  the product tree;
+- device, one more jit in front of it when sets share a message (a
+  gossip flood: thousands of attestations over a slot's few head votes):
+  the blinded same-message merge (msm._blinded_merge), Σ r_i·agg_pk_i and
+  Σ r_i·sig_i a message on the limb-major multiply, so that the fused
+  program sees one lane a message;
 - device, one more jit when signatures are fresh: the batched ψ subgroup
   verdict (bool row home — ec.g2_subgroup_verdict_batch).
 
@@ -124,9 +129,11 @@ def _pipeline_fused(pkx, pky, sxa, sxb, sya, syb,
     """The WHOLE batch-verify data plane as ONE device program.
 
     Scalar-mults the G1 pubkey and G2 signature lanes, tree-sums Σ r·sig,
-    folds per-message groups when n_groups > 0, then runs every Miller
-    loop and the product tree.  Host boundary: uploads in, ONE Fq12
-    pytree out (final exp is native C++).
+    then runs every Miller loop and the product tree.  Host boundary:
+    uploads in, ONE Fq12 pytree out (final exp is native C++).
+    ``n_groups`` is 0: sets that share a message are merged before this
+    program, by `msm._blinded_merge`; the argument stays so that a caller
+    naming the program by its fifteen arguments keeps its shapes.
 
     The Σ r·sig lane enters the Miller loop in JACOBIAN form (zq path —
     its Zq⁵ line factors die in the final exponentiation), so no affine
@@ -142,8 +149,9 @@ def _pipeline_fused(pkx, pky, sxa, sxb, sya, syb,
     both run through ONE merged windowed scan (4 bits per step from
     16-entry Jacobian tables, shared mul-queue rounds — ~2.5x fewer
     sequential rounds than the two binary scans it replaces)."""
+    assert n_groups == 0
     (Xp, Yp, Zp), (SX, SY, SZ) = _msm.fold_segments_gj(
-        pkx, pky, (sxa, sxb), (sya, syb), bits, n_groups)
+        pkx, pky, (sxa, sxb), (sya, syb), bits)
     sum_ok = ~(bi.is_zero_mod_p_device(SZ[0])
                & bi.is_zero_mod_p_device(SZ[1]))
     one = jnp.broadcast_to(bi._jconst("one_m"), (1, bi.L))
@@ -214,26 +222,33 @@ def _next_pow2(x: int, floor: int = 1) -> int:
     return _msm.bucket(x, floor=floor)
 
 
-def _grouped_layout(n: int, n_groups: int,
-                    max_sz: int) -> tuple[int | None, int, int]:
-    """(seg, g_pad, padded_flat) for the grouped pipeline; seg is None
-    when the batch should use the flat layout.
+# The compiled shapes of a verification are bounded (each fused program is
+# a compile of many minutes).  `_pipeline_fused` pads a chunk's lanes to a
+# power of two of at least _PIPELINE_MIN_LANES.  A batch whose sets share
+# a message, over at most _MERGE_GROUPS messages, merges them first into
+# one lane a message: its lanes padded to the smallest of _MERGE_BUCKETS
+# that holds them (a longer batch in slices of the largest, a beacon
+# processor batch of gossip attestations: max_batch), its groups always
+# _MERGE_GROUPS, whatever the count of messages.  Such a batch and every
+# half bisection hands the seam then run two merge shapes and the fused
+# program at 4, 8 or 16 lanes (`merge_shapes`), the shapes the "msm"
+# prewarm driver warms.
+_PIPELINE_MIN_LANES = 4
+_MERGE_GROUPS = 16
+_MERGE_BUCKETS = (1 << 8, 1 << 11)
 
-    QUANTIZED so jit shapes cannot churn with batch composition: seg is
-    forced to exactly padded_flat//g_pad or 2·padded_flat//g_pad (lane
-    total == one or two flat layouts), never to next_pow2(max group
-    size).  Before this, a 32k-attestation flood compiled a fresh fused
-    program per batch whose committee mix shifted seg — each XLA compile
-    costs minutes and the bench child has a hard timeout."""
-    g_pad = _next_pow2(n_groups, floor=2)
-    padded_flat = _next_pow2(n, floor=4)
-    if n_groups >= n:
-        return None, g_pad, padded_flat
-    for total in (padded_flat, 2 * padded_flat):
-        seg = total // g_pad
-        if seg >= max_sz:
-            return seg, g_pad, padded_flat
-    return None, g_pad, padded_flat
+
+def merge_shapes(n: int, n_messages: int):
+    """(merge lanes, groups, fused lanes) of a batch of ``n`` sets over
+    ``n_messages`` messages: the shapes of its `_blinded_merge` slices
+    (None when the batch does not merge) and of its `_pipeline_fused`
+    chunk when it fits one."""
+    if n_messages < n and n_messages <= _MERGE_GROUPS:
+        lanes = next((b for b in _MERGE_BUCKETS if n <= b),
+                     _MERGE_BUCKETS[-1])
+        return (lanes, _MERGE_GROUPS,
+                _next_pow2(n_messages, floor=_PIPELINE_MIN_LANES))
+    return None, None, _next_pow2(n, floor=_PIPELINE_MIN_LANES)
 
 
 # blinding pool: lane j carries B_j = [u_j]G alongside the pubkeys, and
@@ -733,6 +748,24 @@ def _verify_sets_pipeline(sets: Sequence[api.SignatureSet],
             while r == 0:
                 r = secrets.randbits(RAND_BITS)
             scalars.append(r)
+        groups: dict[bytes, list[int]] = {}
+        for i, s in enumerate(sets):
+            groups.setdefault(s.message, []).append(i)
+
+    # sets that share a message: Π e(r_i·pk_i, H(m)) = e(Σ r_i·pk_i, H(m)),
+    # so one device program sums them and the fused program sees one lane
+    # a message, its blinding already in the sums (scalar 1)
+    lanes, g_pad, _ = merge_shapes(n, len(groups))
+    if lanes:
+        order = list(groups.values())
+        with _stage_span("bls.merge", "merge", sets=n, messages=len(order),
+                         lanes=lanes * -(-n // lanes)):
+            merged = _merge_same_message(order, sig_pts, h2cs, pk_rows_x,
+                                         pk_rows_y, scalars, lanes, g_pad)
+        if merged is None:
+            return False
+        sig_pts, h2cs, pk_rows_x, pk_rows_y, scalars = merged
+        n = len(scalars)
 
     # --- chunked double-buffered dispatch: each chunk's host layout runs
     # while the previous chunk's fused kernel is in flight (async JAX
@@ -746,13 +779,14 @@ def _verify_sets_pipeline(sets: Sequence[api.SignatureSet],
     for ci, (lo, hi) in enumerate(chunks):
         faults.fire("chunk", index=ci)
         with _stage_span("bls.limbs", "limbs", chunk=ci) as limbs:
-            args = _chunk_layout(sets[lo:hi], sig_pts[lo:hi], h2cs[lo:hi],
+            args = _chunk_layout(sig_pts[lo:hi], h2cs[lo:hi],
                                  pk_rows_x[lo:hi], pk_rows_y[lo:hi],
                                  scalars[lo:hi])
         # an unsynced dispatch: operand copies and the enqueue, not the
-        # program's execution (pipeline_wait below holds that)
+        # program's execution (pipeline_wait below holds that); ``lanes``
+        # is the chunk's live Miller lanes
         with _stage_span("bls.pipeline.dispatch", "pipeline",
-                         chunk=ci) as dispatch:
+                         chunk=ci, lanes=hi - lo) as dispatch:
             partials.append(_pipeline_fused(*args))
         if ci:
             # host work done while a dispatched chunk was executing
@@ -775,78 +809,114 @@ def _verify_sets_pipeline(sets: Sequence[api.SignatureSet],
             return _final_exp_is_one(f_host)
 
 
-def _chunk_layout(sets, sig_pts, h2cs, pk_rows_x, pk_rows_y, scalars):
+def _chunk_layout(sig_pts, h2cs, pk_rows_x, pk_rows_y, scalars):
     """Host-side lane layout for ONE chunk -> _pipeline_fused argument
-    tuple (uploads + static group count).
-
-    Message grouping (the TPU-shaped fold): sets sharing a message
-    satisfy Π e(r_i·pk_i, H(m)) = e(Σ r_i·pk_i, H(m)), so the expensive
-    Miller lanes shrink from n sets to G distinct messages.  Lanes are
-    laid out s-major over (segment, group) for g1_segment_sum; padding
-    lanes carry zero scalars (infinity = group identity)."""
-    n = len(sets)
-    groups: dict[bytes, list[int]] = {}
-    for i, s in enumerate(sets):
-        groups.setdefault(s.message, []).append(i)
-    n_groups = len(groups)
-    max_sz = max(len(v) for v in groups.values())
-    seg, g_pad, padded_flat = _grouped_layout(n, n_groups, max_sz)
-    use_grouped = seg is not None
-
-    if use_grouped:
-        order = list(groups.values())  # group g -> member set indices
-        lane_of = np.full(seg * g_pad, -1, np.int64)
-        for g, members in enumerate(order):
-            for s_i, set_idx in enumerate(members):
-                lane_of[s_i * g_pad + g] = set_idx
-
-        def scatter(rows, width=bi.L):
-            out = np.zeros((seg * g_pad, width), np.uint32)
-            src = np.nonzero(lane_of >= 0)[0]
-            out[src] = rows[lane_of[src]]
-            return out
-
-        pkx = scatter(pk_rows_x)
-        pky = scatter(pk_rows_y)
-        sg = [scatter(a) for a in _g2_limbs(sig_pts)]
-        lane_scalars = [0] * (seg * g_pad)
-        for lane, set_idx in enumerate(lane_of):
-            if set_idx >= 0:
-                lane_scalars[lane] = scalars[set_idx]
-        bits = jnp.asarray(ec.scalars_to_digits(lane_scalars))
-        h2 = _g2_limbs([h2cs[members[0]] for members in order])
-        ext = np.zeros((g_pad - n_groups, bi.L), np.uint32)
-        if g_pad != n_groups:
-            h2 = [np.concatenate([a, ext]) for a in h2]
-        n_seg_static = g_pad
-        padded = g_pad
-        n_real_lanes = n_groups
-    else:
-        pad = padded_flat - n
-        pkx, pky = pk_rows_x, pk_rows_y
-        sg = _g2_limbs(sig_pts)
-        h2 = _g2_limbs(h2cs)
-        if pad:
-            ext = np.zeros((pad, bi.L), np.uint32)
-            pkx, pky = (np.concatenate([a, ext]) for a in (pkx, pky))
-            sg = [np.concatenate([a, ext]) for a in sg]
-            h2 = [np.concatenate([a, ext]) for a in h2]
-        # padded lanes get zero scalars -> scalar-mul leaves them at
-        # infinity, adding nothing to Σ r·sig; their Miller lanes are
-        # masked out below
-        bits = jnp.asarray(ec.scalars_to_digits(scalars + [0] * pad))
-        n_seg_static = 0
-        padded = padded_flat
-        n_real_lanes = n
-
+    tuple: one lane a set (or a message, after the merge), padded to a
+    power of two of at least _PIPELINE_MIN_LANES.  Padding lanes carry
+    zero scalars (the scalar-mul leaves them at infinity, adding nothing
+    to Σ r·sig) and are masked out of the Miller product."""
+    n = len(scalars)
+    padded = _next_pow2(n, floor=_PIPELINE_MIN_LANES)
+    pad = padded - n
+    pkx, pky = pk_rows_x, pk_rows_y
+    sg = _g2_limbs(sig_pts)
+    h2 = _g2_limbs(h2cs)
+    if pad:
+        ext = np.zeros((pad, bi.L), np.uint32)
+        pkx, pky = (np.concatenate([a, ext]) for a in (pkx, pky))
+        sg = [np.concatenate([a, ext]) for a in sg]
+        h2 = [np.concatenate([a, ext]) for a in h2]
+    bits = jnp.asarray(ec.scalars_to_digits(list(scalars) + [0] * pad))
     lane_mask = np.zeros(padded, bool)
-    lane_mask[:n_real_lanes] = True
+    lane_mask[:n] = True
     g1x, g1y = _g1_neg_limbs()
     return (jnp.asarray(pkx), jnp.asarray(pky),
             *[jnp.asarray(a) for a in sg],
             *[jnp.asarray(a) for a in h2],
             bits, jnp.asarray(lane_mask),
-            jnp.asarray(g1x), jnp.asarray(g1y), n_seg_static)
+            jnp.asarray(g1x), jnp.asarray(g1y), 0)
+
+
+def _merge_same_message(order, sig_pts, h2cs, pk_rows_x, pk_rows_y,
+                        scalars, lanes, g_pad):
+    """The blinded same-message merge on the device: sets in ``order``
+    (a message's set indices, a list a message) -> one lane a message,
+    (sig_pts, h2cs, pk_rows_x, pk_rows_y, scalars) with Σ r_i·sig_i and
+    Σ r_i·pk_i of its sets and the scalar 1, or None when a message's
+    sum is the identity (the batch fails; bisection attributes).
+
+    Lanes go in slices of ``lanes`` (`msm._blinded_merge` with ``g_pad``
+    groups), all dispatched before the one fetch; the rows come back
+    Jacobian and the host turns them affine (one inversion a row) and
+    adds a longer batch's slices.  The membership program of the batch's
+    fresh signatures is queued ahead of the slices, so the fetch
+    (``bls.merge.wait``) waits for it too: dispatched after the merge it
+    would put the host's key, scalar and merge layouts in front of it
+    and lengthen the device's chain by them."""
+    n = len(scalars)
+    group = np.zeros(n, np.int32)
+    for g, members in enumerate(order):
+        group[members] = g
+    products = _msm.blinded_merge_products(lanes, g_pad)
+    outs = []
+    for lo in range(0, n, lanes):
+        hi = min(lo + lanes, n)
+        with _stage_span("bls.merge.layout", "merge_layout"):
+            args = _merge_layout(sig_pts[lo:hi], pk_rows_x[lo:hi],
+                                 pk_rows_y[lo:hi], scalars[lo:hi],
+                                 group[lo:hi], lanes)
+        with _stage_span("bls.merge.dispatch", "merge_dispatch"):
+            outs.append(_msm._blinded_merge(*args, g_pad))
+        api.count_merge_lanes(live=hi - lo, padding=lanes - (hi - lo))
+        api.count_merge_products(products)
+    with _stage_span("bls.merge.wait", "merge_wait"):
+        fetched = jax.device_get(outs)
+    api.count_merged("device", sum(len(m) for m in order if len(m) > 1))
+    with _stage_span("bls.merge.combine", "merge_combine"):
+        pks = [cv.INF] * len(order)
+        sigs = [cv.INF] * len(order)
+        for (P, S) in fetched:
+            for g, pt in enumerate(_msm.jacobian_rows_to_affine(*P)[
+                    :len(order)]):
+                pks[g] = cv.g1_add(pks[g], pt)
+            for g, pt in enumerate(_g2_rows_to_affine(S)[:len(order)]):
+                sigs[g] = cv.g2_add(sigs[g], pt)
+        if any(p is cv.INF for p in pks + sigs):
+            return None
+        return (sigs, [h2cs[m[0]] for m in order],
+                ec.ints_to_mont_limbs([p[0] for p in pks]),
+                ec.ints_to_mont_limbs([p[1] for p in pks]),
+                [1] * len(order))
+
+
+def _merge_layout(sig_pts, pk_x, pk_y, scalars, group, lanes):
+    """One `_blinded_merge` slice's operands, its lanes padded to
+    ``lanes``: padding lanes carry the scalar 0 (the identity)."""
+    pad = lanes - len(scalars)
+    ext = np.zeros((pad, bi.L), np.uint32)
+    cols = [np.concatenate([a, ext])
+            for a in (pk_x, pk_y, *_g2_limbs(sig_pts))]
+    digits = ec.scalars_to_digits(list(scalars) + [0] * pad)
+    return (*[jnp.asarray(a) for a in cols], jnp.asarray(digits),
+            jnp.asarray(np.concatenate([group, np.zeros(pad, np.int32)])))
+
+
+def _g2_rows_to_affine(S):
+    """HOST: Montgomery Jacobian Fq2 rows ((Xa, Xb), (Ya, Yb), (Za, Zb))
+    -> affine G2 points (cv.INF for identity rows)."""
+    from lighthouse_tpu.crypto.bls.fields import Fq2
+
+    X, Y, Z = ([Fq2(int(a), int(b)) for a, b in
+                zip(bi.from_mont(c[0]), bi.from_mont(c[1]))] for c in S)
+    out = []
+    for x, y, z in zip(X, Y, Z):
+        if z == Fq2(0, 0):
+            out.append(cv.INF)
+            continue
+        zi = z.inv()
+        zi2 = zi * zi
+        out.append((x * zi2, y * zi2 * zi))
+    return out
 
 
 def verify_signature_sets_device(sets: Sequence[api.SignatureSet],

@@ -368,15 +368,15 @@ def _window_tables(bases, width: int = 4):
     n_entries = 1 << width
 
     def cat(F, entries, coord):
-        if F is _Fq2Adapter:
-            return (jnp.concatenate([e[coord][0] for e in entries]),
-                    jnp.concatenate([e[coord][1] for e in entries]))
+        if F in _PAIRS:
+            return tuple(jnp.concatenate([e[coord][i] for e in entries],
+                                         F.lane_axis) for i in (0, 1))
         return jnp.concatenate([e[coord] for e in entries], F.lane_axis)
 
     def split(F, arr, count):
-        if F is _Fq2Adapter:
-            a0 = jnp.split(arr[0], count)
-            a1 = jnp.split(arr[1], count)
+        if F in _PAIRS:
+            a0 = jnp.split(arr[0], count, F.lane_axis)
+            a1 = jnp.split(arr[1], count, F.lane_axis)
             return list(zip(a0, a1))
         return jnp.split(arr, count, F.lane_axis)
 
@@ -396,13 +396,13 @@ def _window_tables(bases, width: int = 4):
         doubles = _jac_double_multi(items)
         add_items = []
         for (F, (xb, yb)), dbl in zip(bases, doubles):
-            if F is _Fq2Adapter:
-                base_j = ((jnp.tile(xb[0], (count, 1)),
-                           jnp.tile(xb[1], (count, 1))),
-                          (jnp.tile(yb[0], (count, 1)),
-                           jnp.tile(yb[1], (count, 1))),
-                          F.one_like((jnp.tile(xb[0], (count, 1)),
-                                      jnp.tile(xb[1], (count, 1)))))
+            if F in _PAIRS:
+                base_j = ((jnp.tile(xb[0], _reps(F, count)),
+                           jnp.tile(xb[1], _reps(F, count))),
+                          (jnp.tile(yb[0], _reps(F, count)),
+                           jnp.tile(yb[1], _reps(F, count))),
+                          F.one_like((jnp.tile(xb[0], _reps(F, count)),
+                                      jnp.tile(xb[1], _reps(F, count)))))
             else:
                 reps = (count, 1) if F.lane_axis == 0 else (1, count)
                 base_j = (jnp.tile(xb, reps), jnp.tile(yb, reps),
@@ -422,7 +422,7 @@ def _window_tables(bases, width: int = 4):
         level += 1
     out = []
     for (F, _), tab in zip(bases, tabs):
-        if F is _Fq2Adapter:
+        if F in _PAIRS:
             stack = lambda c: (jnp.stack([e[c][0] for e in tab]),  # noqa: E731
                                jnp.stack([e[c][1] for e in tab]))
         else:
@@ -448,7 +448,7 @@ def _table_pick(F, tab, digit):
         return out
 
     def pick(coord):
-        return (g(coord[0]), g(coord[1])) if F is _Fq2Adapter else g(coord)
+        return (g(coord[0]), g(coord[1])) if F in _PAIRS else g(coord)
 
     return (pick(tab[0]), pick(tab[1]), pick(tab[2]))
 
@@ -478,17 +478,17 @@ def g1_scalar_mul_windowed(xp, yp, digits):
     return tuple(F1.select(inf, zero, c) for c in s1)
 
 
-def gj_scalar_mul_windowed(xp, yp, xq, yq, digits):
+def gj_scalar_mul_windowed(xp, yp, xq, yq, digits, lm=False):
     """r_i·P_i (G1) and r_i·Q_i (G2) in ONE windowed scan.
 
     xp, yp: uint32[N, L] G1 affine; xq, yq: Fq2 limb pairs; digits:
     uint32[W, N] MSB-first base-16 window digits of the shared scalars
-    (ec.scalars_to_digits).  Returns (G1 Jacobian, G2 Jacobian); zero-
-    scalar lanes come back as exact-zero-limb infinity (the
-    g2_sum_reduce identity form).  Collision (H == 0) chords carry the
-    same honest-random-blinding contract as the binary scan — do NOT
-    feed adversarial scalars (subgroup checks keep the binary path)."""
-    F1, F2 = _FpAdapter, _Fq2Adapter
+    (ec.scalars_to_digits); ``lm``: every array limb-major, uint32[L, N],
+    on `_FpLmAdapter` and `_Fq2LmAdapter`.  Returns (G1 Jacobian, G2
+    Jacobian); zero-scalar lanes come back as exact-zero-limb infinity.
+    Collision (H == 0) chords carry the same honest-random-blinding
+    contract as the binary scan — do NOT feed adversarial scalars."""
+    F1, F2 = _LM_TRACKS if lm else (_FpAdapter, _Fq2Adapter)
     tab1, tab2 = _window_tables([(F1, (xp, yp)), (F2, ((xq[0], xq[1]),
                                                        (yq[0], yq[1])))])
 
@@ -892,3 +892,119 @@ def scalars_to_digits(scalars, n_bits: int = 64, w: int = 4) -> np.ndarray:
     digs = (bits.reshape(n, n_dig, w).astype(np.uint32) * weights).sum(
         axis=2, dtype=np.uint32)
     return np.ascontiguousarray(digs.T)
+
+
+# --- the limb-major Fq2 track (the same-message merge, ops/msm) -------------
+
+class _Fq2LmAdapter:
+    """Fq2 over pairs of limb-major lanes (uint32[27, N] each), on
+    `MontField.mont_mul_lm`: Karatsuba's three products go into the
+    round's one launch beside every other limb-major product of it."""
+
+    lane_axis = 1
+
+    @staticmethod
+    def mul(q: _MulQueue, x, y):
+        f = bi.FP
+        r0 = q.fp_lm(x[0], y[0])
+        r1 = q.fp_lm(x[1], y[1])
+        r2 = q.fp_lm(f.add_lm(x[0], x[1]), f.add_lm(y[0], y[1]))
+
+        def resolve():
+            t0, t1 = r0(), r1()
+            return (f.sub_lm(t0, t1), f.sub_lm(f.sub_lm(r2(), t0), t1))
+
+        return resolve
+
+    @staticmethod
+    def add(x, y):
+        return (bi.FP.add_lm(x[0], y[0]), bi.FP.add_lm(x[1], y[1]))
+
+    @staticmethod
+    def sub(x, y):
+        return (bi.FP.sub_lm(x[0], y[0]), bi.FP.sub_lm(x[1], y[1]))
+
+    @staticmethod
+    def scale(x, k: int):
+        return (bi.FP.scale_small_lm(x[0], k), bi.FP.scale_small_lm(x[1], k))
+
+    @staticmethod
+    def is_zero(x):
+        return jnp.all(x[0] == 0, axis=0) & jnp.all(x[1] == 0, axis=0)
+
+    @staticmethod
+    def select(cond, a, b):
+        return (jnp.where(cond[None], a[0], b[0]),
+                jnp.where(cond[None], a[1], b[1]))
+
+    @staticmethod
+    def zeros_like(x):
+        return (jnp.zeros_like(x[0]), jnp.zeros_like(x[1]))
+
+    @staticmethod
+    def one_like(x):
+        return (_FpLmAdapter.one_like(x[0]), jnp.zeros_like(x[1]))
+
+
+_PAIRS = (_Fq2Adapter, _Fq2LmAdapter)
+_LM_TRACKS = (_FpLmAdapter, _Fq2LmAdapter)
+
+
+def _reps(F, count: int):
+    """`jnp.tile`'s repetitions that repeat F's lanes ``count`` times."""
+    return (count, 1) if F.lane_axis == 0 else (1, count)
+
+
+def segment_sums_lm(tracks, n_segments: int):
+    """Segmented Jacobian sums of several limb-major tracks at once, in
+    one traced level: ``tracks`` is [(F, (X, Y, Z))] whose lanes are laid
+    out s-major over (S, n_segments) as in `g1_segment_sum_lm`; returns
+    per track its n_segments sums, lanes [0, n_segments).
+
+    Every level adds row 2j + 1 of every segment into row 2j, puts the
+    sums in rows [0, S/2) and exact zeros (the identity) above them: the
+    live rows stay a prefix, so the lane count never changes and one
+    `lax.scan` body of shared product rounds serves all log2(S) levels,
+    with more additions than a halving tree (log2(S)/2 times as many)
+    and one traced level where the tree traces log2(S)."""
+    total = tracks[0][1][0][0].shape[1] if tracks[0][0] in _PAIRS \
+        else tracks[0][1][0].shape[1]
+    S = total // n_segments
+    assert S * n_segments == total and S & (S - 1) == 0
+
+    def rows(F, c, parity):
+        def one(a):
+            r = a.reshape(a.shape[0], S // 2, 2, n_segments)
+            return r[:, :, parity, :].reshape(a.shape[0], -1)
+        return (one(c[0]), one(c[1])) if F in _PAIRS else one(c)
+
+    def level(coords, _):
+        items = []
+        for (F, _), (X, Y, Z) in zip(tracks, coords):
+            lo = tuple(rows(F, c, 0) for c in (X, Y, Z))
+            hi = tuple(rows(F, c, 1) for c in (X, Y, Z))
+            items.append((F, lo, hi))
+        out = []
+        for (F, _, _), sums in zip(items, _jac_add_full_multi(items)):
+            out.append(tuple(_pad_lanes(F, c) for c in sums))
+        return tuple(out), None
+
+    levels = S.bit_length() - 1
+    coords = tuple(pts for _, pts in tracks)
+    if levels:
+        coords, _ = jax.lax.scan(level, coords, jnp.arange(levels))
+    return [tuple(_lanes(F, c, 0, n_segments) for c in pts)
+            for (F, _), pts in zip(tracks, coords)]
+
+
+def _lanes(F, c, lo, hi):
+    if F in _PAIRS:
+        return (c[0][:, lo:hi], c[1][:, lo:hi])
+    return c[:, lo:hi]
+
+
+def _pad_lanes(F, c):
+    """Lanes doubled with exact zeros above them (the identity)."""
+    if F in _PAIRS:
+        return tuple(jnp.concatenate([a, jnp.zeros_like(a)], 1) for a in c)
+    return jnp.concatenate([c, jnp.zeros_like(c)], 1)

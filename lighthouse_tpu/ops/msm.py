@@ -109,16 +109,16 @@ def fold_products(lanes: int, n_segments: int, windows: int) -> int:
             + add * (lanes - n_segments))
 
 
-def fold_segments_gj(xp, yp, xq, yq, digits, n_segments):
+def fold_segments_gj(xp, yp, xq, yq, digits):
     """The joint G1×G2 track: one merged windowed scan over G1 lanes
     (xp, yp) and G2 lanes (xq, yq limb-pair tuples) sharing ``digits``,
-    then the per-group G1 segment fold (n_segments > 0; 0 keeps flat
-    lanes) and the G2 tree-sum.  Returns ((Xp, Yp, Zp), (SX, SY, SZ))
-    exactly as the fused verify pipeline consumes them."""
+    then the G2 tree-sum.  Returns ((Xp, Yp, Zp), (SX, SY, SZ)) exactly
+    as the fused verify pipeline consumes them: flat lanes, one a set.
+    Sets that share a message are summed before it, by the merge track
+    (`_blinded_merge`: the same scan on the limb-major multiply, then a
+    segmented sum), so the Miller loops run one lane a message."""
     (Xp, Yp, Zp), (SX, SY, SZ) = ec.gj_scalar_mul_windowed(
         xp, yp, xq, yq, digits)
-    if n_segments:
-        Xp, Yp, Zp = ec.g1_segment_sum(Xp, Yp, Zp, n_segments)
     SX, SY, SZ = ec.g2_sum_reduce(SX, SY, SZ)
     return (Xp, Yp, Zp), (SX, SY, SZ)
 
@@ -187,6 +187,59 @@ def blinded_fold_products(lanes: int, n_segments: int) -> tuple[int, int]:
     behind it, the unblinding addition (16), the affine conversion (a
     381-step inversion ladder of 2, then 4) and the zero test (1)."""
     return 16 * (lanes - n_segments), (16 + 2 * 381 + 4 + 1) * n_segments
+
+
+# the same-message merge of a batch verification: prewarmed by the "msm"
+# driver through the seam, with a batch that takes it
+_pstore.register_entry("ops/msm.py::_blinded_merge@_blinded_merge",
+                       driver="msm")
+
+
+@partial(jax.jit, static_argnums=(8,))
+def _blinded_merge(pkx, pky, sxa, sxb, sya, syb, digits, group, n_groups):
+    """The same-message merge of a batch verification: lane i carries a
+    set's aggregate key (pkx, pky) and its signature (sxa + sxb·u,
+    sya + syb·u), affine rows uint32[N, L], its blinding scalar r_i as
+    window digits (uint32[16, N], `ec.scalars_to_digits`) and the group
+    of its message (int32[N]; a padding lane has scalar 0 and adds the
+    identity wherever it points).  Returns, for every group g below the
+    static ``n_groups``, Σ r_i·pk_i (Jacobian rows (X, Y, Z) uint32[G, L])
+    and Σ r_i·sig_i (Jacobian Fq2 rows, pairs of them).
+
+    One windowed scan over both tracks (`ec.gj_scalar_mul_windowed`),
+    then every lane spread over the groups, the identity where it is not
+    its group's, s-major, and one segmented sum of both tracks
+    (`ec.segment_sums_lm`): the lanes are turned limb-major on entry and
+    the rows back on exit, and every product is `MontField.mont_mul_lm`'s
+    (`blinded_merge_products`)."""
+    P, S = ec.gj_scalar_mul_windowed(
+        pkx.T, pky.T, (sxa.T, sxb.T), (sya.T, syb.T), digits, lm=True)
+    live = group[None, :, None] == jnp.arange(n_groups)[None, None, :]
+
+    def spread(c):
+        return jnp.where(live, c[:, :, None], 0).reshape(c.shape[0], -1)
+
+    Pg, Sg = ec.segment_sums_lm(
+        [(ec._FpLmAdapter, tuple(spread(c) for c in P)),
+         (ec._Fq2LmAdapter, tuple((spread(c[0]), spread(c[1])) for c in S))],
+        n_groups)
+    return tuple(c.T for c in Pg), tuple((c[0].T, c[1].T) for c in Sg)
+
+
+_blinded_merge = _dtel.instrument(
+    "ops/msm.py::_blinded_merge@_blinded_merge", _blinded_merge)
+
+
+def blinded_merge_products(lanes: int, n_groups: int,
+                           windows: int = 16) -> int:
+    """Fp lane-products of one `_blinded_merge` dispatch, all on
+    `mont_mul_lm`: `fold_products`' scan and tables on the G1 lanes, three
+    times as many on the G2 lanes (an Fq2 product is three Karatsuba
+    products), then log2(lanes) levels of the segmented sum, each adding
+    half of the lanes x groups in both tracks (16 and 48 products)."""
+    scan = fold_products(lanes, lanes, windows)
+    levels = lanes.bit_length() - 1
+    return 4 * scan + levels * (lanes * n_groups // 2) * (16 + 48)
 
 
 # -- dispatch wrappers --------------------------------------------------------

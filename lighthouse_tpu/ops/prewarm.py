@@ -125,6 +125,28 @@ def _fresh_sets(n_sets: int, n_keys: int = 1, tag: bytes = b"prewarm"):
     return sets
 
 
+#: (sets, messages) of the merge track's prewarm batches: together they
+#: run `msm._blinded_merge` at both lane buckets and `_pipeline_fused` at
+#: 4, 8 and 16 lanes (ops/bls_backend.merge_shapes), every shape a batch
+#: over up to 16 messages and its bisection halves dispatch; the last
+#: also warms the membership program at a beacon processor batch
+MERGE_WARM = ((4, 1), (9, 8), (2048, 16))
+
+
+def _flood_sets(n_sets: int, n_messages: int):
+    """``n_sets`` single-key sets over ``n_messages`` messages, one key
+    signing each message once; every set a fresh signature object."""
+    from lighthouse_tpu.crypto import bls
+
+    sk = bls.SecretKey.generate()
+    pk = sk.public_key()
+    msgs = [b"merge" + bytes([m]) for m in range(n_messages)]
+    sigs = [sk.sign(m).to_bytes() for m in msgs]
+    return [bls.SignatureSet(bls.Signature(sigs[i % n_messages]), [pk],
+                             msgs[i % n_messages])
+            for i in range(n_sets)]
+
+
 def _drv_bls(scale: str) -> None:
     """The fused verify plane: pipeline, psi subgroup batches, the
     per-set aggregation segment-sum — plus the two cold-start headline
@@ -224,6 +246,15 @@ def _drv_msm(scale: str) -> None:
         if bool(binf[i]) or got != want:
             raise RuntimeError(
                 "prewarmed blinded fold mismatches host adds")
+    # the same-message merge track through the seam: sets that share a
+    # message (a gossip flood's) are summed by msm._blinded_merge
+    from lighthouse_tpu.crypto import bls
+
+    for n_sets, n_messages in (MERGE_WARM if scale == "production"
+                               else MERGE_WARM[:1]):
+        if not bls.verify_signature_sets(_flood_sets(n_sets, n_messages),
+                                         backend="tpu"):
+            raise RuntimeError("prewarmed merge verify rejected")
 
 
 def _drv_sha256(scale: str) -> None:

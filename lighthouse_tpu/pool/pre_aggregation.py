@@ -5,12 +5,17 @@
 account, and every overlapping committee contribution merged *before*
 verification is a pairing never paid for.  At mainnet width one slot's
 unaggregated attestations are thousands of signature sets that share a
-handful of distinct messages (one per (slot, committee index,
-beacon_block_root) — the AttestationData signing root), so the flood
-batch the dispatch thread sweeps up is massively mergeable.
+handful of distinct messages (the AttestationData signing root; since
+electra, EIP-7549, ``data.index`` is 0, so one message per head vote of
+the slot whatever the committee), so the flood batch the dispatch thread
+sweeps up is massively mergeable.
 
-Two stages, both applied to the flat ``SignatureSet`` list immediately
-before ``verify_signature_sets``:
+Two stages.  The first is the chain's, applied to the flat
+``SignatureSet`` list immediately before ``verify_signature_sets``
+(``coalesce_sets``).  The second belongs to the rung that serves the
+batch: the reference rung runs ``merge_same_message`` on the host
+(crypto/bls/api._merge_on_host), the "tpu" backend merges the same sets
+on the device (ops/bls_backend, one device program for the whole batch).
 
 1. **Exact-duplicate dedup** — a hostile duplicate flood (or honest
    gossip re-delivery) puts byte-identical sets in one sweep; the dup
@@ -37,7 +42,8 @@ sees the original sets and the existing bisection fallback attributes
 failures item-by-item.  Coalescing can only remove redundant pairings,
 never change a verdict.
 
-``LHTPU_PRE_BLS=0`` disables the stage (chaos/debug escape hatch).
+``LHTPU_PRE_BLS=0`` disables both stages on the host (chaos/debug escape
+hatch); the device's merge is the "tpu" backend's own and stays.
 """
 
 from __future__ import annotations
@@ -151,21 +157,16 @@ def _fold_group(group: list, message: bytes, bls, cv):
 
 
 def coalesce_sets(sets: list) -> tuple[list, "CoalesceStats"]:
-    """The full pre-BLS stage: dedup, then blinded same-message merge.
-    Returns the coalesced list and combined stats; with LHTPU_PRE_BLS=0
-    (or fewer than 2 sets) the input passes through untouched."""
+    """The chain's pre-BLS stage: dedup (the merge is the serving rung's,
+    module docstring).  Returns the deduplicated list and its stats; with
+    LHTPU_PRE_BLS=0 (or fewer than 2 sets) the input passes through
+    untouched."""
     stats = CoalesceStats(sets_in=len(sets), sets_out=len(sets))
     if len(sets) < 2 or not enabled():
         return list(sets), stats
-    unique, dstats = dedup_sets(sets)
-    merged, mstats = merge_same_message(unique)
-    stats.sets_out = len(merged)
-    stats.deduped = dstats.deduped
-    stats.merged = mstats.merged
-    stats.merge_groups = mstats.merge_groups
-    stats.unmergeable = mstats.unmergeable
+    unique, stats = dedup_sets(sets)
     _record(stats)
-    return merged, stats
+    return unique, stats
 
 
 def _record(stats: CoalesceStats) -> None:
@@ -178,10 +179,6 @@ def _record(stats: CoalesceStats) -> None:
             "pre_bls_deduped_total",
             "byte-identical signature sets dropped before BLS",
         ).inc(stats.deduped)
-        REGISTRY.counter(
-            "pre_bls_merged_total",
-            "signature sets folded into blinded same-message merges",
-        ).inc(stats.merged)
         REGISTRY.counter(
             "pre_bls_pairings_saved_total",
             "pairing lanes removed from batches by pre-BLS coalescing",

@@ -288,35 +288,55 @@ def test_final_exp_without_native_is_the_host_python(monkeypatch):
         e: st["dispatches"] for e, st in dtel.snapshot().items()}
 
 
-def test_grouped_layout_quantized():
-    """jit shapes must not churn with batch composition: the grouped
-    layout's lane total is exactly one or two flat layouts, seg stays a
-    power of two (g1_segment_sum's contract), and unquantizable batches
-    fall back to flat (seg None)."""
-    from lighthouse_tpu.ops.bls_backend import _grouped_layout
+@pytest.mark.parametrize("n_messages", range(1, 17))
+def test_merge_shapes_bounded_over_bisection(n_messages):
+    """Shapes must not churn with batch composition: a processor batch of
+    2,048 single-key attestations over up to 16 head votes, and every
+    half bisection hands the seam when any of them is bad, run only the
+    shapes the "msm" prewarm driver warms (ops/prewarm.MERGE_WARM): the
+    merge at two lane buckets of 16 groups, the fused program at 4, 8 or
+    16 lanes.  Over the flood cell's two votes, the four programs its
+    ``precompile`` hints name."""
+    import random
 
-    # the canonical ledger shape: 1024 sets over 64 messages, 16 each
-    seg, g_pad, flat = _grouped_layout(1024, 64, 16)
-    assert (seg, g_pad, flat) == (16, 64, 1024)
-    # a skewed committee mix bumps seg to the 2x bucket, not to
-    # next_pow2(max_sz)
-    seg2, g_pad2, flat2 = _grouped_layout(2048, 64, 40)
-    assert (seg2, g_pad2, flat2) == (64, 64, 2048)
-    assert seg2 * g_pad2 == 2 * flat2
-    # only two possible lane totals for any composition at this size
-    totals = {
-        _grouped_layout(2048, 64, m)[0] * 64
-        for m in (1, 7, 20, 32, 33, 64)}
-    assert totals <= {2048, 4096}
-    # hopelessly skewed: one group holds nearly everything -> flat
-    assert _grouped_layout(2048, 64, 100)[0] is None
-    # degenerate: all distinct messages -> flat
-    assert _grouped_layout(64, 64, 1)[0] is None
-    # seg power-of-two invariant across a sweep
-    for n in (8, 64, 512, 4096):
-        for g in (2, 8, 32):
-            for m in (1, 3, n // g if g < n else 1):
-                seg_i, g_i, _ = _grouped_layout(n, min(g, n - 1), m)
-                if seg_i is not None:
-                    assert seg_i & (seg_i - 1) == 0
-                    assert seg_i >= m
+    from lighthouse_tpu.ops import bls_backend as bb
+    from lighthouse_tpu.ops import prewarm
+
+    warmed = [bb.merge_shapes(n, m) for n, m in prewarm.MERGE_WARM]
+    merges = {(lanes, groups) for lanes, groups, _ in warmed}
+    fused = {lanes for _, _, lanes in warmed}
+    assert merges == {(256, 16), (2048, 16)} and fused == {4, 8, 16}
+    rng = random.Random(n_messages)
+    messages = list(range(n_messages)) + [
+        rng.randrange(n_messages) for _ in range(2048 - n_messages)]
+    rng.shuffle(messages)
+    seen = set()
+
+    def visit(lo, hi):
+        half = messages[lo:hi]
+        seen.add(bb.merge_shapes(len(half), len(set(half))))
+        if hi - lo > 1:
+            visit(lo, (lo + hi) // 2)
+            visit((lo + hi) // 2, hi)
+
+    visit(0, len(messages))
+    assert {(m, g) for m, g, _ in seen} - {(None, None)} <= merges
+    assert {f for _, _, f in seen} <= fused
+    if n_messages == 2:
+        assert seen == {(2048, 16, 4), (256, 16, 4), (None, None, 4)}
+
+
+def test_merge_shapes_buckets():
+    """A single set and small distinct batches keep the fused program's
+    flat buckets; a block's distinct sets theirs; a longer batch merges
+    in slices of the largest lane bucket; more messages than the merge's
+    groups: no merge."""
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    assert bb.merge_shapes(1, 1) == (None, None, 4)
+    assert bb.merge_shapes(11, 11) == (None, None, 16)
+    assert bb.merge_shapes(131, 131) == (None, None, 256)
+    assert bb.merge_shapes(3, 2) == (256, 16, 4)
+    assert bb.merge_shapes(5000, 3) == (2048, 16, 4)
+    assert bb.merge_shapes(2048, 16) == (2048, 16, 16)
+    assert bb.merge_shapes(2048, 17) == (None, None, 2048)

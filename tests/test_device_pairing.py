@@ -130,10 +130,11 @@ def test_tpu_backend_lazy_registration():
 
 
 class TestMessageGroupedPipeline:
-    """The grouped fold (ops/bls_backend.py): sets sharing a message
-    collapse to one Miller lane via e(Σ r_i·pk_i, H(m)).  Consensus-
-    critical soundness: grouped and flat layouts must agree with each
-    other and with the host oracle, on valid AND invalid batches."""
+    """The same-message merge (ops/bls_backend.py, msm._blinded_merge):
+    sets sharing a message collapse to one Miller lane via
+    e(Σ r_i·pk_i, H(m)).  Consensus-critical soundness: merged and flat
+    runs must agree with each other and with the host oracle, on valid
+    AND invalid batches."""
 
     def _sets(self, tamper: int | None = None):
         import numpy as np
@@ -147,13 +148,13 @@ class TestMessageGroupedPipeline:
                for i in range(8)]
         pks = [sk.public_key() for sk in sks]
         sets = []
-        for i in range(13):  # 13 sets over 4 messages -> grouped path
+        for i in range(13):  # 13 sets over 4 messages -> the merge
             sk = sks[i % len(sks)]
             m = msgs[i % len(msgs)]
             sets.append(bls.SignatureSet(sk.sign(m), [pks[i % len(sks)]], m))
         if tamper is not None:
             # sign the right message with the WRONG key (signer sks[0],
-            # claimed key pks[1]): only the grouped G1 fold could hide
+            # claimed key pks[1]): only the merged G1 sums could hide
             # this if the layout were broken
             sets[tamper] = bls.SignatureSet(
                 sks[0].sign(msgs[tamper % 4]), [pks[1]], msgs[tamper % 4])
@@ -164,7 +165,7 @@ class TestMessageGroupedPipeline:
         from lighthouse_tpu.ops import bls_backend as bb
 
         sets = self._sets()
-        assert bb.verify_sets_pipeline(sets)  # grouped (dup messages)
+        assert bb.verify_sets_pipeline(sets)  # merged (dup messages)
         # flat fallback on the same sets: unique messages per set
         uniq = [s for i, s in enumerate(sets) if i < 4]
         assert bb.verify_sets_pipeline(uniq)

@@ -4,14 +4,14 @@ Consensus-critical equivalence: the chunked/double-buffered verify path
 (ops/dispatch_pipeline + ops/bls_backend) must return verdicts identical
 to the single-shot pipeline — same randomized-scalar semantics, same
 fail-the-batch-then-bisect contract — across chunk boundaries, for
-valid and invalid batches, flat and grouped layouts.  Plus the beacon
+valid and invalid batches, flat and merged batches.  Plus the beacon
 processor's non-blocking dispatch contract: the manager keeps draining
 queues while a batch runs on the dedicated dispatch thread, and work
 queued during the flight coalesces into one next sweep.
 
 Shapes are chosen to reuse the persistently-cached compiled programs
 (flat 4-lane chunks); only the tiny partial-combine program and the
-cross-chunk grouped single-shot layout compile fresh on a cold cache.
+same-message merge compile fresh on a cold cache.
 """
 
 import asyncio
@@ -128,12 +128,12 @@ class TestChunkedEquivalence:
         assert list(mask) == [True, True, False, True]
 
     def test_grouped_messages_across_chunks(self):
-        """Messages repeating ACROSS chunk boundaries: each chunk sees
-        distinct messages (flat layout) while the single-shot run folds
-        them grouped — verdicts must agree."""
+        """Messages repeating ACROSS chunk boundaries: the merge sums
+        the whole batch a message before any chunk is laid out, so the
+        chunked and single-shot runs agree."""
         msgs = [b"\x61" * 32, b"\x62" * 32] * 2          # A B A B
         _, sets = _sets(4, messages=msgs)
-        assert bb.verify_sets_pipeline(sets)             # grouped fold
+        assert bb.verify_sets_pipeline(sets)             # merged
         assert bb.verify_sets_pipeline(_fresh(sets), chunk_size=2)
 
     def test_empty_and_single_set(self):

@@ -141,7 +141,7 @@ def test_rlc_two_segment_fold():
 
 def test_gj_joint_track_matches_direct_composition():
     """fold_segments_gj is the same trace as the direct ec composition
-    (joint G1 pubkey fold + G2 signature sum) — limb-identical."""
+    (joint G1 pubkey scan + G2 signature sum) — limb-identical."""
     import jax
     import jax.numpy as jnp
 
@@ -162,13 +162,11 @@ def test_gj_joint_track_matches_direct_composition():
     bits = jnp.asarray(ec.scalars_to_digits(blinders))
 
     def unified(xp, yp, sxa, sxb, sya, syb, bits):
-        return msm.fold_segments_gj(xp, yp, (sxa, sxb), (sya, syb),
-                                    bits, 1)
+        return msm.fold_segments_gj(xp, yp, (sxa, sxb), (sya, syb), bits)
 
     def direct(xp, yp, sxa, sxb, sya, syb, bits):
         (Xp, Yp, Zp), (SX, SY, SZ) = ec.gj_scalar_mul_windowed(
             xp, yp, (sxa, sxb), (sya, syb), bits)
-        Xp, Yp, Zp = ec.g1_segment_sum(Xp, Yp, Zp, 1)
         SX, SY, SZ = ec.g2_sum_reduce(SX, SY, SZ)
         return (Xp, Yp, Zp), (SX, SY, SZ)
 
@@ -553,8 +551,9 @@ def test_manifest_msm_family_unified():
     """One program-store registration point per (track, bucket): the
     four per-consumer MSM kernels are gone from the shape manifest,
     replaced by exactly three ops/msm.py entries — the MSM-family entry
-    count went DOWN (4 legacy -> 3 unified; 22 entries in all since the
-    fold's lane gather, ops/bls_backend.py::_blinded_lanes, PR 38)."""
+    count went DOWN (4 legacy -> 3 unified).  The same-message merge of
+    a batch verification is a fourth track of the file that replaced no
+    consumer's kernel (23 entries in all)."""
     import pathlib
 
     manifest = pathlib.Path(__file__).parent.parent / "tools" / "lint" \
@@ -570,10 +569,12 @@ def test_manifest_msm_family_unified():
     assert not (ids & legacy), ids & legacy
     unified = sorted(i for i in ids if i.startswith("ops/msm.py::"))
     assert unified == ["ops/msm.py::_blinded_fold@_blinded_fold",
+                       "ops/msm.py::_blinded_merge@_blinded_merge",
                        "ops/msm.py::_fold_kernel@_fold_kernel",
                        "ops/msm.py::_gather_fold@_gather_fold"]
-    assert len(unified) < len(legacy)
-    assert len(entries) == 22
+    replacing = [i for i in unified if "_blinded_merge" not in i]
+    assert len(replacing) < len(legacy)
+    assert len(entries) == 23
     # and every unified entry is registered at runtime with the msm
     # prewarm driver (the one registration point)
     from lighthouse_tpu.ops import msm  # noqa: F401  (registers)

@@ -150,7 +150,8 @@ class TestOperationPool:
 
 class TestPreAggregation:
     """Pre-BLS coalescing (pool/pre_aggregation): the blinded
-    same-message merge must verify iff ALL constituents verify — the
+    same-message merge (the reference rung's, crypto/bls/api
+    ._merge_on_host) must verify iff ALL constituents verify — the
     soundness property the firehose's pairing savings rest on.  Real
     crypto, tiny set counts (one pairing call per assertion)."""
 
@@ -182,16 +183,16 @@ class TestPreAggregation:
 
     def test_merged_verifies_when_all_constituents_valid(self, keys):
         from lighthouse_tpu.crypto import bls
-        from lighthouse_tpu.pool.pre_aggregation import coalesce_sets
+        from lighthouse_tpu.pool.pre_aggregation import merge_same_message
 
         msg = b"\x22" * 32
-        out, stats = coalesce_sets(self._singles(keys, msg))
+        out, stats = merge_same_message(self._singles(keys, msg))
         assert len(out) == 1 and stats.merged == len(keys)
         assert bls.verify_signature_sets(out)
 
     def test_merged_fails_when_any_constituent_invalid(self, keys):
         from lighthouse_tpu.crypto import bls
-        from lighthouse_tpu.pool.pre_aggregation import coalesce_sets
+        from lighthouse_tpu.pool.pre_aggregation import merge_same_message
 
         msg = b"\x33" * 32
         sets = self._singles(keys, msg)
@@ -199,7 +200,7 @@ class TestPreAggregation:
         # the fold proceeds — the merged verdict must still be False
         sets[2] = bls.SignatureSet(
             keys[2].sign(b"\x44" * 32), [keys[2].public_key()], msg)
-        out, _ = coalesce_sets(sets)
+        out, _ = merge_same_message(sets)
         assert len(out) == 1
         assert not bls.verify_signature_sets(out)
 
@@ -211,7 +212,7 @@ class TestPreAggregation:
         from lighthouse_tpu.crypto import bls
         from lighthouse_tpu.crypto.bls import curve as cv
         from lighthouse_tpu.crypto.bls.hash_to_curve import hash_to_g2
-        from lighthouse_tpu.pool.pre_aggregation import coalesce_sets
+        from lighthouse_tpu.pool.pre_aggregation import merge_same_message
 
         msg = b"\x55" * 32
         good = [sk.sign(msg) for sk in keys[:2]]
@@ -226,7 +227,7 @@ class TestPreAggregation:
         naive_sum = cv.g2_add(plus, minus)
         honest_sum = cv.g2_add(good[0].point, good[1].point)
         assert cv.g2_to_bytes(naive_sum) == cv.g2_to_bytes(honest_sum)
-        out, stats = coalesce_sets(sets)
+        out, stats = merge_same_message(sets)
         assert len(out) == 1 and stats.merged == 2
         assert not bls.verify_signature_sets(out)
 
@@ -235,7 +236,7 @@ class TestPreAggregation:
         attester) merge as a pubkey multiset: valid pair verifies, one
         bad aggregate poisons the merged verdict."""
         from lighthouse_tpu.crypto import bls
-        from lighthouse_tpu.pool.pre_aggregation import coalesce_sets
+        from lighthouse_tpu.pool.pre_aggregation import merge_same_message
 
         msg = b"\x66" * 32
         sig_a = bls.Signature.aggregate([keys[0].sign(msg),
@@ -246,34 +247,34 @@ class TestPreAggregation:
             sig_a, [keys[0].public_key(), keys[1].public_key()], msg)
         set_b = bls.SignatureSet(
             sig_b, [keys[1].public_key(), keys[2].public_key()], msg)
-        out, stats = coalesce_sets([set_a, set_b])
+        out, stats = merge_same_message([set_a, set_b])
         assert len(out) == 1 and stats.merged == 2
         assert bls.verify_signature_sets(out)
         # same overlap, but aggregate B is missing a contribution
         bad_b = bls.SignatureSet(
             bls.Signature(keys[1].sign(msg).to_bytes()),
             [keys[1].public_key(), keys[2].public_key()], msg)
-        out, _ = coalesce_sets([set_a, bad_b])
+        out, _ = merge_same_message([set_a, bad_b])
         assert len(out) == 1
         assert not bls.verify_signature_sets(out)
 
     def test_unmergeable_fake_signatures_pass_through(self):
         from lighthouse_tpu.crypto import bls
-        from lighthouse_tpu.pool.pre_aggregation import coalesce_sets
+        from lighthouse_tpu.pool.pre_aggregation import merge_same_message
 
         msg = b"\x77" * 32
         fake = [bls.SignatureSet(bls.Signature(bytes([i]) * 96),
                                  [], msg) for i in range(2, 4)]
-        out, stats = coalesce_sets(fake)
+        out, stats = merge_same_message(fake)
         assert len(out) == 2 and stats.merged == 0
         assert stats.unmergeable == 2
 
     def test_distinct_messages_stay_separate(self, keys):
-        from lighthouse_tpu.pool.pre_aggregation import coalesce_sets
+        from lighthouse_tpu.pool.pre_aggregation import merge_same_message
 
         sets = (self._singles(keys[:1], b"\x88" * 32)
                 + self._singles(keys[1:2], b"\x99" * 32))
-        out, stats = coalesce_sets(sets)
+        out, stats = merge_same_message(sets)
         assert len(out) == 2 and stats.merged == 0
 
     def test_env_kill_switch(self, keys, monkeypatch):
@@ -283,6 +284,23 @@ class TestPreAggregation:
         sets = self._singles(keys, b"\xaa" * 32)
         out, stats = coalesce_sets(sets)
         assert out == sets and stats.pairings_saved == 0
+
+    def test_reference_rung_merges_unless_switched_off(self, keys,
+                                                       monkeypatch):
+        """The reference rung folds same-message sets itself
+        (crypto/bls/api._merge_on_host), counted under path="host";
+        LHTPU_PRE_BLS=0 leaves them unmerged and the verdict unchanged."""
+        from lighthouse_tpu.common.metrics import REGISTRY
+        from lighthouse_tpu.crypto import bls
+
+        host = REGISTRY.counter("pre_bls_merged_total").labels(path="host")
+        sets = self._singles(keys, b"\xbb" * 32)
+        before = host.value
+        assert bls.verify_signature_sets(sets, backend="reference")
+        assert host.value - before == len(keys)
+        monkeypatch.setenv("LHTPU_PRE_BLS", "0")
+        assert bls.verify_signature_sets(sets, backend="reference")
+        assert host.value - before == len(keys)
 
 
 def test_pool_prunes_are_accounted():

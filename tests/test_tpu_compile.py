@@ -492,3 +492,24 @@ def test_sharded_miller_reduce_over_four_chips(mesh4, tpu_branches):
                  bls_sharded._sharded_miller_reduce(mesh4, per_dev)._fn,
                  *cols, mask)
     assert "all-gather" in c.as_text()
+
+
+@pytest.mark.slow  # ~3 min: the merge twice, the membership program once
+def test_blinded_merge_flood_buckets(one_chip, tpu_branches):
+    """A gossip flood's same-message merge at both lane buckets of
+    ops/bls_backend.merge_shapes, 16 groups each whatever the count of
+    messages, and the membership program at a beacon processor batch of
+    2,048 signatures: the shapes flood-2048 names in its hints beside
+    `_pipeline_fused` @4."""
+    from lighthouse_tpu.ops import bls_backend as bb
+    from lighthouse_tpu.ops import msm
+
+    for lanes in bb._MERGE_BUCKETS:
+        digits = jax.ShapeDtypeStruct((16, lanes), jnp.uint32,
+                                      sharding=one_chip)
+        group = jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=one_chip)
+        _compile(f"_blinded_merge@{lanes}x{bb._MERGE_GROUPS}",
+                 msm._blinded_merge._fn, *[_limbs(one_chip, lanes)] * 6,
+                 digits, group, bb._MERGE_GROUPS)
+    _compile("_g2_subgroup_kernel@2048", bb._g2_subgroup_kernel._fn,
+             *[_limbs(one_chip, 2048)] * 4)

@@ -36,6 +36,7 @@ ALLOWED_FUNCTIONS = {
     "_verify_sets_pipeline",     # batch fetch + final exp
     "_final_exp_is_one",         # device final-exp readback
     "aggregate_pubkeys_device",  # one segment-sum fetch per batch
+    "_merge_same_message",       # one same-message merge fetch per batch
     "batch_subgroup_check_g1",   # synchronous verdict wrappers
     "batch_subgroup_check_g2",
     "multi_pairing_sharded",     # mesh path: one combined fetch

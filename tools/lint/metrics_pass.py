@@ -41,6 +41,11 @@ FAMILY_OWNERS = {
     # lanes of the key-aggregation fold by kind (PR 35): counted in
     # ops/bls_backend.aggregate_pubkeys_device through api.count_fold_lanes
     "bls_fold_": "lighthouse_tpu/crypto/bls/api.py",
+    # the same-message merge's lanes and products, and every merge by
+    # where it ran: counted through api.count_merge_* and
+    # api.count_merged from ops/bls_backend and pool/pre_aggregation
+    "bls_merge_": "lighthouse_tpu/crypto/bls/api.py",
+    "pre_bls_merged_": "lighthouse_tpu/crypto/bls/api.py",
     # the offload supervisor's health/fault series (PR 4): the breaker
     # transitions are the only legitimate writer
     "bls_backend_health": "lighthouse_tpu/crypto/bls/api.py",
