@@ -548,6 +548,25 @@ def _fused_products(lanes: int, windows: int) -> tuple[int, int]:
             2 + 2 * (7 + 63 * 235) + 54)
 
 
+@lru_cache(maxsize=None)
+def _subgroup_products(lanes: int) -> tuple[int, int]:
+    """(resident, materialized) Fp lane-products of one dispatch of the
+    G1 membership program (ops/bls_backend._g1_subgroup_kernel) at
+    ``lanes`` lanes, all on `mont_mul_lm`: the [r-1]P scan, 255
+    double-and-add steps of 18 products a lane, the four products of the
+    residues and the zero tests of d1, d2 and Z; none on `mont_mul`."""
+    return lanes * (255 * 18 + 4 + 3), 0
+
+
+def count_subgroup_products(resident: int, materialized: int) -> None:
+    """Fp lane-products of one dispatched G1 membership program, by the
+    multiply that runs them (`_subgroup_products`)."""
+    _count_by_multiply(REGISTRY.counter(
+        "g1_subgroup_products_total",
+        "Fp lane-products of the G1 membership programs dispatched, by "
+        "multiply"), resident, materialized)
+
+
 def _kzg_fused_check(lhs_points, lhs_scalars, pis, r_pows,
                      settings, tau_g2=None,
                      cache_attr: str = "_fused_g2_rows") -> bool:

@@ -50,10 +50,10 @@ fields by the asserts in tests/test_bigint.py:
     limbs     < 2^15 + 2^11 everywhere
 
 The limb-major multiply (``mont_mul_lm`` with ``add_lm``, ``sub_lm``,
-``scale_small_lm``: Fr's evaluation programs since PR 32; of Fp, the G1
-fold of ops/msm.py since PR 34, under `_kzg_fused`, `_fold_kernel` and
-`_gather_fold`; `_pipeline_fused`, `_blinded_fold` and the two subgroup
-kernels remain on ``mont_mul``).  The same construction for arrays
+``scale_small_lm``: Fr's evaluation programs; of Fp, the G1 folds of
+ops/msm.py, the same-message merge and the G1 membership scan; the
+Miller loops, the unblinding ladder and the G2 membership kernel
+remain on ``mont_mul``).  The same construction for arrays
 uint32[L, ...lanes] whose FIRST axis is the limb.  ``mont_mul`` makes
 every schoolbook product an array of the program ([.., L, 2L], to HBM and
 back: ~27 KB a Fr product-lane that needs 216 bytes); here the partial
@@ -710,4 +710,15 @@ def is_zero_mod_p_device(x: jax.Array) -> jax.Array:
     bool[...] (limb axis reduced)."""
     w = mont_mul(x, jnp.broadcast_to(_jconst("one_plain"), x.shape))
     d = canon_digits(w)
+    return (d[..., None, :] == _kp_digit_consts()).all(-1).any(-1)
+
+
+def is_zero_mod_p_lm(x: jax.Array) -> jax.Array:
+    """`is_zero_mod_p_device` for limb-major arrays uint32[L, ...lanes]:
+    the multiply by plain 1 on `mont_mul_lm` (its m < R·(1 + 2^-9), so
+    the product of an x inside the ledger is < 2P, inside the multiples of
+    P compared), the carry pass and the comparison on the digits turned
+    back limbs last.  Returns bool[...lanes]."""
+    w = FP.mont_mul_lm(x, FP.tables["one_plain"])
+    d = canon_digits(jnp.moveaxis(w, 0, -1))
     return (d[..., None, :] == _kp_digit_consts()).all(-1).any(-1)

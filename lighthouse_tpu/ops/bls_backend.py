@@ -558,6 +558,8 @@ def _dispatch_g1_subgroup_kernel(points):
     """Dispatch (no host sync) the [r-1]P membership kernel over affine
     G1 points, generator-padded to a power of two (floor 4).  Returns
     the device bool row; callers read [:len(points)] when they sync."""
+    from lighthouse_tpu.crypto import kzg
+
     padded = _next_pow2(len(points), floor=4)
     pts = list(points) + [cv.g1_generator()] * (padded - len(points))
     xp = jnp.asarray(ec.ints_to_mont_limbs([p[0] for p in pts]))
@@ -565,7 +567,9 @@ def _dispatch_g1_subgroup_kernel(points):
     # deliberately outside the supervised verify path: trusted-setup
     # validation, cold-pubkey checks and the blob batch's membership
     # test handle errors directly
-    return _g1_subgroup_kernel(xp, yp)  # lhlint: allow(LH601)
+    out = _g1_subgroup_kernel(xp, yp)  # lhlint: allow(LH601)
+    kzg.count_subgroup_products(*kzg._subgroup_products(padded))
+    return out
 
 
 def batch_subgroup_check_g1(points) -> np.ndarray:

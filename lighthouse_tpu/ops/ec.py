@@ -48,7 +48,7 @@ from lighthouse_tpu.ops.bls12_381 import (
 #
 # The Jacobian formulas below are written once against this tiny protocol;
 # G1 instantiates it over Fp lanes (uint32[N, 27]), G2 over Fq2 pairs, and
-# the G1 fold of the MSM plane over limb-major Fp lanes (uint32[27, N]).
+# the G1 fold and membership scan over limb-major Fp lanes (uint32[27, N]).
 # `lane_axis` says where an array's lanes are, for the formulas that
 # concatenate, split, tile or mask along them.
 
@@ -740,8 +740,8 @@ def g2_subgroup_verdict_batch(xqa, xqb, yqa, yqb) -> jax.Array:
 def g1_subgroup_verdict_batch(xp, yp) -> jax.Array:
     """Device [r-1]P membership verdict per lane -> bool[n]."""
     d1, d2, Z = g1_subgroup_check_batch(xp, yp)
-    return (bi.is_zero_mod_p_device(d1) & bi.is_zero_mod_p_device(d2)
-            & ~bi.is_zero_mod_p_device(Z))
+    return (bi.is_zero_mod_p_lm(d1) & bi.is_zero_mod_p_lm(d2)
+            & ~bi.is_zero_mod_p_lm(Z))
 
 
 @_functools.cache
@@ -807,27 +807,27 @@ def g1_subgroup_check_batch(xp, yp):
 
         d1 = x_P·Z² - X_S,   d2 = y_P·Z³ + Y_S,   Z
 
-    for S = [r-1]P: a lane is in G1 iff d1 ≡ d2 ≡ 0 (mod P) and Z ≢ 0.
-    Same fail-closed shape as g2_subgroup_check_batch: a small-order lane
-    that hits the degenerate H == 0 chord mid-scan drives Z ≡ 0 and lands
-    in the reject branch."""
+    for S = [r-1]P, limb-major uint32[27, N] from rows xp, yp uint32[N, 27]:
+    a lane is in G1 iff d1 ≡ d2 ≡ 0 (mod P) and Z ≢ 0.  The scan and the
+    residues run on `_FpLmAdapter` (a round's products in one
+    `mont_mul_lm` launch).  Same fail-closed shape as
+    g2_subgroup_check_batch: a small-order lane that hits the degenerate
+    H == 0 chord mid-scan drives Z ≡ 0 and lands in the reject branch."""
+    F, xl, yl = _FpLmAdapter, xp.T, yp.T
     bits = jnp.broadcast_to(_r_minus_1_bits_const(), (255, xp.shape[0]))
-    X, Y, Z = _scalar_mul_batch(_FpAdapter, xp, yp, bits)
-
+    X, Y, Z = _scalar_mul_batch(F, xl, yl, bits)
     q = _MulQueue()
-    i_z2 = q.fp(Z, Z)
+    r_z2 = F.mul(q, Z, Z)
     q.run()
-    z2 = q[i_z2]
+    z2 = r_z2()
     q = _MulQueue()
-    i_xz = q.fp(xp, z2)
-    i_z3 = q.fp(z2, Z)
+    r_xz = F.mul(q, xl, z2)
+    r_z3 = F.mul(q, z2, Z)
     q.run()
-    xz, z3 = q[i_xz], q[i_z3]
     q = _MulQueue()
-    i_yz = q.fp(yp, z3)
+    r_yz = F.mul(q, yl, r_z3())
     q.run()
-    d1 = bi.sub(xz, X)
-    d2 = bi.add(q[i_yz], Y)
+    d1, d2 = F.sub(r_xz(), X), F.add(r_yz(), Y)
     return d1, d2, Z
 
 

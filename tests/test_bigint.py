@@ -393,6 +393,21 @@ def test_is_zero_mod_p_device_bound_coupling():
     verdicts and the <5P output-value bound directly, so a future
     mont_mul bound regression fails HERE instead of silently corrupting
     subgroup/infinity verdicts."""
+    rows, want = _zero_test_rows()
+    x = jnp.asarray(rows)
+    got = np.asarray(bi.is_zero_mod_p_device(x))
+    assert got.tolist() == want
+
+    one = jnp.broadcast_to(jnp.asarray(bi._int_to_limbs(1)), x.shape)
+    w = np.asarray(bi.mont_mul(x, one))
+    worst = max(bi._limbs_to_int(r) for r in w)
+    assert worst < 5 * P, hex(worst)
+
+
+def _zero_test_rows():
+    """Redundant encodings of kP and kP+eps (k=0..4, worst-case limb
+    spreads) and a near-2^394 value at the documented input bound, with
+    whether each is ≡ 0 (mod P)."""
     eps = (1 << 380) % P  # nonzero residue
     rows, want = [], []
     for k in range(5):
@@ -408,14 +423,19 @@ def test_is_zero_mod_p_device_bound_coupling():
     assert near_bound % P != 0
     rows.append(bi._int_to_limbs(near_bound))
     want.append(False)
-    x = jnp.asarray(np.stack(rows))
-    got = np.asarray(bi.is_zero_mod_p_device(x))
-    assert got.tolist() == want
+    return np.stack(rows), want
 
-    one = jnp.broadcast_to(jnp.asarray(bi._int_to_limbs(1)), x.shape)
-    w = np.asarray(bi.mont_mul(x, one))
+
+def test_is_zero_mod_p_lm_bound_coupling():
+    """The limb-major zero test (the G1 membership program's) on the same
+    encodings: the verdicts, and its multiply by plain 1 on `mont_mul_lm`
+    inside the {0..4P} comparison set (under 2P by its header's m)."""
+    rows, want = _zero_test_rows()
+    x = jnp.asarray(rows.T)                     # limb-major [27, n]
+    assert np.asarray(bi.is_zero_mod_p_lm(x)).tolist() == want
+    w = np.asarray(bi.FP.mont_mul_lm(x, bi.FP.tables["one_plain"])).T
     worst = max(bi._limbs_to_int(r) for r in w)
-    assert worst < 5 * P, hex(worst)
+    assert worst < 2 * P, hex(worst)
 
 
 def test_fp2_tower_ops(rand_vals):

@@ -291,3 +291,84 @@ def test_small_order_point_fails_closed():
     assert not cv.g2_in_subgroup_fast(pt)       # host ψ test
     ok = batch_subgroup_check_g2([pt, cv.g2_generator(), pt, pt])
     assert list(ok) == [False, True, False, False]
+
+
+# Points of small prime order d on E(Fp), whose order is
+# h1·r with h1 = 3 · 11² · 10177² · 859267² · 52437899²: each made on the
+# host by crypto/bls/curve as [h1·r / d^e]·(a point from a seeded x), then
+# multiplied by d until [d]P is the identity.  The [r-1]P scan of
+# g1_subgroup_check_batch meets the degenerate H == 0 chord with them at
+# its step 1 (order 3), 5 (order 11) and 222 (order 10177).
+G1_SMALL_ORDER = {
+    3: (0x0, 0x2),
+    11: (0x1147cbb50494bb589add054c469d2952269ebc12a4acdcaa223a73ea4d76d431c775c748666973e42cc8d4dd5cf29f0c,
+         0x19a94b4e74f2e4b18b259de5a6a8cb318ccb2fa3b3ecd28c3ba93f550bbc68bd00c7294c1e0856c6e312bc802c540d90),
+    10177: (0x147f5096f1506db1f243a63c0ff09a21fb3292aa247b896d2d9d7b6ed4b230cd4bfbc4fefb73b8bee5ee950d5512f08c,
+            0xae794cd9fc9299e82c2235e9861dd8c8cab5ba347b4bb33c4f1098991e0c20b18d09add5a91d109889f45b8942cf387),
+}
+
+
+def test_g1_small_order_points_are_what_they_claim():
+    from lighthouse_tpu.crypto.bls.fields import R
+
+    h1 = 3 * 11**2 * 10177**2 * 859267**2 * 52437899**2
+    assert h1 == 0x396C8C005555E1568C00AAAB0000AAAB
+    for d, pt in G1_SMALL_ORDER.items():
+        assert cv.g1_is_on_curve(pt)
+        assert cv.g1_mul(pt, d) is cv.INF
+        assert not cv.g1_in_subgroup(pt)
+        assert (R - 1) % d != d - 1     # [r-1]P != -P: the test must reject
+
+
+@pytest.mark.parametrize("order", sorted(G1_SMALL_ORDER))
+def test_g1_small_order_point_fails_closed(order):
+    """g1_subgroup_check_batch's fail-closed invariant (see
+    g2_subgroup_check_batch's docstring) on the limb-major multiply: the
+    H == 0 chord a small-order point meets inside the [r-1]P scan drives Z
+    to a value ≡ 0 (mod P), however its limbs read after `add_lm`,
+    `sub_lm` and `scale_small_lm`, and the lane REJECTS wherever it sits in
+    a padded batch: among members, between them, last of a full bucket."""
+    from lighthouse_tpu.ops.bls_backend import batch_subgroup_check_g1
+
+    pt, g = G1_SMALL_ORDER[order], cv.g1_generator()
+    g7 = cv.g1_mul(g, 7)
+    padded = [pt, g, pt, g7, pt]                  # 5 lanes of 8
+    assert list(batch_subgroup_check_g1(padded)) == [
+        False, True, False, True, False]
+    full = [g7, g, g7, g, g7, g, g7, pt]          # 8 lanes of 8
+    assert list(batch_subgroup_check_g1(full)) == [True] * 7 + [False]
+    # the reject is the Z ≡ 0 branch: the chord, not the residues
+    xp, yp = _g1_lanes(padded + [g] * 3)
+    _, _, Z = jax.jit(ec.g1_subgroup_check_batch)(xp, yp)
+    assert list(ec.is_zero_mod_p(np.asarray(Z).T)) == [
+        True, False, True, False, True, False, False, False]
+
+
+@pytest.mark.parametrize("n", [6, 1536])
+def test_g1_membership_against_the_oracle(n):
+    """The membership program's verdicts against cv.g1_in_subgroup over
+    members, points of small order and points of mixed order (a member
+    plus a small-order point: on the curve, off the subgroup, meeting no
+    chord), at a small bucket (6 points, 8 lanes) and at the blob batch's
+    (1,536 points, 2,048 lanes); the generator lanes that pad a batch to
+    its bucket pass."""
+    import random
+
+    from lighthouse_tpu.crypto.bls.fields import R
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    rng = random.Random(n)
+    g = cv.g1_generator()
+    members = [cv.g1_mul(g, rng.randrange(1, R)) for _ in range(4)]
+    mixed = [cv.g1_add(members[0], G1_SMALL_ORDER[11]),
+             cv.g1_add(members[1], G1_SMALL_ORDER[10177]),
+             cv.g1_add(members[2], G1_SMALL_ORDER[3])]
+    distinct = members + list(G1_SMALL_ORDER.values()) + mixed
+    want = [cv.g1_in_subgroup(p) for p in distinct]
+    assert want == [True] * 4 + [False] * 6
+    picks = [rng.randrange(len(distinct)) for _ in range(n)]
+    row = np.asarray(bb._dispatch_g1_subgroup_kernel(
+        [distinct[i] for i in picks]))
+    assert row.shape == (bb._next_pow2(n, floor=4),)
+    assert row[:n].tolist() == [want[i] for i in picks]
+    assert row[n:].all()

@@ -183,6 +183,49 @@ def test_fused_products_are_what_the_program_traces(monkeypatch, lanes):
     assert 100 * res / (res + mat) > 99
 
 
+@pytest.mark.parametrize("lanes", [4, 2048])
+def test_subgroup_products_are_what_the_program_traces(monkeypatch, lanes):
+    """`_subgroup_products` (what `g1_subgroup_products_total` grows by a
+    dispatch) against a tally of the lanes each multiply of
+    `_g1_subgroup_kernel` is traced with, a scan's body counted once a
+    step; 2,048 lanes is both KZG cells' shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from lighthouse_tpu.ops import bigint as bi
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    tally = {"resident": 0, "materialized": 0}
+    steps = [1]
+    lm, mm, scan = bi.FP.mont_mul_lm, bi.mont_mul, jax.lax.scan
+
+    def count_lm(a, b):
+        tally["resident"] += steps[-1] * int(np.prod(a.shape[1:]))
+        return lm(a, b)
+
+    def count_mm(a, b):
+        tally["materialized"] += steps[-1] * int(np.prod(a.shape[:-1]))
+        return mm(a, b)
+
+    def count_scan(f, init, xs, *args, **kwargs):
+        steps.append(steps[-1] * jax.tree_util.tree_leaves(xs)[0].shape[0])
+        try:
+            return scan(f, init, xs, *args, **kwargs)
+        finally:
+            steps.pop()
+
+    monkeypatch.setattr(bi.FP, "mont_mul_lm", count_lm)
+    monkeypatch.setattr(bi, "mont_mul", count_mm)
+    monkeypatch.setattr(jax.lax, "scan", count_scan)
+    rows = jax.ShapeDtypeStruct((lanes, bi.L), jnp.uint32)
+    # the function under the jit: a jit's cache would hide a shape
+    # another test traced before
+    jax.eval_shape(bb._g1_subgroup_kernel._fn.__wrapped__, rows, rows)
+    assert kzg._subgroup_products(lanes) == (
+        tally["resident"], tally["materialized"])
+    assert kzg._subgroup_products(2048) == (9_414_656, 0)
+
+
 def test_constant_blob_infinity_proof(settings):
     """Constant polynomial -> zero quotient -> infinity proof point."""
     vals = [42] * settings.width
@@ -317,6 +360,30 @@ class TestDeviceG1SubgroupCheck:
         assert not cv.g1_in_subgroup(G1_ORDER3_POINT)
         ok = batch_subgroup_check_g1(pts)
         assert list(ok) == [True, True, False, True]
+
+    def test_dispatch_counts_its_products(self):
+        """`g1_subgroup_products_total` grows by `_subgroup_products` of the
+        padded shape a dispatch, by the multiply that runs the products."""
+        from lighthouse_tpu.common.metrics import REGISTRY
+        from lighthouse_tpu.crypto.bls import curve as cv
+        from lighthouse_tpu.ops.bls_backend import batch_subgroup_check_g1
+
+        def grown():
+            fam = REGISTRY.counter(
+                "g1_subgroup_products_total",
+                "Fp lane-products of the G1 membership programs "
+                "dispatched, by multiply")
+            return {k: fam.labels(multiply=k).value
+                    for k in ("resident", "materialized")}
+
+        g = cv.g1_generator()
+        before = grown()
+        assert list(batch_subgroup_check_g1([g, G1_ORDER3_POINT, g])) == [
+            True, False, True]
+        after = grown()
+        res, mat = kzg._subgroup_products(4)
+        assert after["resident"] - before["resident"] == res
+        assert after["materialized"] - before["materialized"] == mat
 
     def test_validate_rejects_corrupt_setup(self, tmp_path):
         import json as _json

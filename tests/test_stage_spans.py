@@ -394,12 +394,15 @@ from benchmarks.tests.test_host_stall_metrics import (  # noqa: E402,F401
     ("kzg_fused_resident_pct.columns", "kzg_fused_products_total"),
     ("bls_fold_resident_pct.electra", "bls_fold_products_total"),
     ("bls_fold_resident_pct.block", "bls_fold_products_total"),
+    ("kzg_subgroup_resident_pct.columns", "g1_subgroup_products_total"),
+    ("kzg_subgroup_resident_pct.blobs", "g1_subgroup_products_total"),
 ])
 def test_resident_share_reads_a_synthetic_window(metric, family):
-    """`kzg_fused_resident_pct.{blobs,columns}` (PR 34) and
-    `bls_fold_resident_pct.{electra,block}` (PR 36): the share of their
+    """`kzg_fused_resident_pct.{blobs,columns}`,
+    `bls_fold_resident_pct.{electra,block}` and
+    `kzg_subgroup_resident_pct.{columns,blobs}`: the share of their
     family's growth on `multiply="resident"`; a program without the
-    family (the parent commit) reads nothing and does not raise."""
+    family (a parent commit) reads nothing and does not raise."""
     from benchmarks.tests.test_stage_metrics import _read
 
     res, mat = (frozenset({"multiply": k}.items())

@@ -302,11 +302,23 @@ def test_cell_interp_full_block(one_chip, tpu_branches):
 
 
 def test_g1_subgroup_kernel_blob_batch(one_chip, tpu_branches):
-    """The membership dispatch of a 768-sidecar batch: 1,536 points."""
+    """The membership dispatch of a 768-sidecar batch, 1,536 points, and
+    of each group of a full block's column sidecars.  The [r-1]P scan, the
+    residues and their zero tests run on the multiply whose partial
+    products stay in the core: 1.0 MB of temporaries and 2.7 MB of code,
+    where the scan on the materialized multiply made every product a
+    [.., 2048, 27, 54] array of the program (55.8 MB and 24.1 MB)."""
+    from lighthouse_tpu.ops import bigint as bi
     from lighthouse_tpu.ops import bls_backend as bb
 
-    _compile("_g1_subgroup_kernel@2048", bb._g1_subgroup_kernel._fn,
-             *[_limbs(one_chip, BLOB_POINTS)] * 2)
+    assert bi._use_resident_kernel()
+    c = _compile("_g1_subgroup_kernel@2048", bb._g1_subgroup_kernel._fn,
+                 *[_limbs(one_chip, BLOB_POINTS)] * 2)
+    assert c.memory_analysis().temp_size_in_bytes < 8 << 20
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    # no schoolbook product is an array of the program
+    assert ",27,54]" not in text[text.index("\nENTRY "):]
 
 
 @pytest.mark.slow  # ~3 min: the trace of ~420 kernel bodies is half of it
