@@ -1548,7 +1548,7 @@ def _bench_observatory() -> dict:
                                / max(time.monotonic() - t0, 1e-9))
 
     # --- phase 2: the manifest telemetry tour ------------------------------
-    from lighthouse_tpu.crypto import das, kzg
+    from lighthouse_tpu.crypto import kzg
     from lighthouse_tpu.crypto.bls import curve as cv
     from lighthouse_tpu.crypto.bls.fields import R as FR_MOD
     from lighthouse_tpu.ops import bls12_381 as b381
@@ -1646,8 +1646,6 @@ def _bench_observatory() -> dict:
                                                settings)
 
     step("kzg", kzg_tour)
-    step("das", lambda: das._batched_cell_proof_msms(
-        [[1, 2], [3, 4]], kzg.KzgSettings.dev(width=16)))
 
     def epoch_tour():
         state, spec = randomized_registry_state(256, "altair", seed=11,

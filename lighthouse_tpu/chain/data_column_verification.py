@@ -7,14 +7,17 @@ verify_data_column_sidecar_kzg_proofs) and the segment form of the last,
 `validate_data_columns`: structure sidecar by sidecar, then ONE
 `das.verify_cell_kzg_proof_batch` for every cell of every sidecar, as the
 reference's verify_kzg_for_data_column_list -> validate_data_columns does.
-Gossip topics, the by-root and by-range protocols and custody are not
-here (ROADMAP.md Queue 2 A7).
+And a supernode's reconstruction, `reconstruct_data_columns` (the
+reference's kzg_utils::reconstruct_data_columns).  Gossip topics, the
+by-root and by-range protocols, custody and the reconstruction's place in
+the availability checker are not here (ROADMAP.md Queue 2 A7).
 """
 
 from __future__ import annotations
 
 import hashlib
 
+from lighthouse_tpu.common import tracing
 from lighthouse_tpu.crypto import das, kzg
 from lighthouse_tpu.state_transition.misc import is_valid_merkle_branch
 from lighthouse_tpu.types.containers import make_types
@@ -101,3 +104,54 @@ def validate_data_columns(settings: kzg.KzgSettings, sidecars,
             proofs += [bytes(p) for p in sidecar.kzg_proofs]
     return das.verify_cell_kzg_proof_batch(
         commitments, cell_ids, cells, proofs, settings)
+
+
+def reconstruct_data_columns(settings: kzg.KzgSettings, sidecars,
+                             spec: ChainSpec | None = None) -> list:
+    """A supernode's reconstruction (consensus-specs fulu das-core.md,
+    "Reconstruction and cross-seeding"; the reference's
+    kzg_utils::reconstruct_data_columns): from at least half of one
+    block's verified column sidecars, every missing one, in column order,
+    each with its rebuilt cells and proofs and the header, commitments and
+    inclusion proof of the sidecars given (as a sidecar of the type given).
+
+    One `das.recover_cells_and_kzg_proofs_batch` over all of the block's
+    blobs.  Raises KzgError as that does (fewer than half the columns, a
+    repeated column, cells that are no codeword) and DataColumnError when
+    the sidecars are not of one block.  Span ``das.reconstruct`` with
+    ``blobs``, ``columns_in``, ``columns_out`` and ``path``."""
+    spec = spec or ChainSpec.mainnet()
+    n_cells, _ = das._cell_geometry(settings.width)
+    held = sorted(int(s.index) for s in sidecars)
+    missing = [c for c in range(min(n_cells, spec.number_of_columns))
+               if c not in set(held)]
+    template = sidecars[0]
+    blobs = len(template.column)
+    with tracing.span("das.reconstruct", blobs=blobs,
+                      columns_in=len(sidecars), columns_out=len(missing),
+                      path=das.recovery_path(settings)):
+        header = template.signed_block_header
+        root = header.message.hash_tree_root()
+        commitments = [bytes(c) for c in template.kzg_commitments]
+        for s in sidecars:
+            if ((s.signed_block_header is not header
+                 and s.signed_block_header.message.hash_tree_root() != root)
+                    or [bytes(c) for c in s.kzg_commitments] != commitments
+                    or len(s.column) != blobs):
+                raise DataColumnError("sidecars_of_several_blocks")
+        if not missing:
+            return []
+        ids = [int(s.index) for s in sidecars]
+        cells, proofs = das.recover_cells_and_kzg_proofs_batch(
+            ids, [[bytes(s.column[b]) for s in sidecars]
+                  for b in range(blobs)], settings)
+        with kzg.das_stage_span("das.sidecars", "sidecars",
+                                sidecars=len(missing)):
+            return [type(template)(
+                index=c, column=[cells[b][c] for b in range(blobs)],
+                kzg_commitments=list(template.kzg_commitments),
+                kzg_proofs=[proofs[b][c] for b in range(blobs)],
+                signed_block_header=template.signed_block_header,
+                kzg_commitments_inclusion_proof=list(
+                    template.kzg_commitments_inclusion_proof))
+                for c in missing]

@@ -16,8 +16,10 @@ exactly half there is no redundancy — proof-verify cells first).
 
 All arithmetic is over the BLS scalar field.  Batch verification runs on
 the device (the aggregated interpolation in ops/fr.py, the two sums and
-the pairing in kzg._kzg_fused); cell computation and recovery are
-host-side Python integers.
+the pairing in kzg._kzg_fused); so do, at production widths, a block's
+recovery (ops/fr._recover_kernel) and every cell proof (FK20,
+ops/msm._fk20_kernel).  Cell computation (`compute_cells`) and the
+small-width rung are host-side Python integers.
 """
 
 from __future__ import annotations
@@ -237,67 +239,19 @@ def _require_monomials(settings, cell_size: int):
             "(g1_monomial/g2_monomial in the ceremony file)")
 
 
-_CELL_PROOF_FUSED_MIN_WIDTH = 256   # device-batch at production widths
-_CELL_PROOF_MAX_LANES = 1 << 17     # chunk cells to bound HBM footprint
-
-
-def _batched_cell_proof_msms(q_lists: list[list[int]], settings
-                             ) -> list:
-    """All cells' quotient MSMs as chunked fused dispatches on the
-    unified MSM plane (ops/msm, plain g1 track).
-
-    The per-cell loop below issues one device MSM PER CELL (128
-    dispatches per blob on a proposer).  Here lanes lay out s-major
-    (lane s·G + g = monomial point s weighted by cell g's coefficient)
-    through ONE windowed scan + segment sum per chunk; chunk size caps
-    resident lanes so the 16-entry per-lane window tables stay inside
-    HBM.  Returns affine (x, y) int pairs or cv.INF per cell."""
-    import numpy as np
-
-    from lighthouse_tpu.ops import bigint as bi
-    from lighthouse_tpu.ops import ec
-    from lighthouse_tpu.ops import msm as _msm
-
-    seg_pad = _msm.bucket(len(q_lists[0]))
-    chunk = max(1, _CELL_PROOF_MAX_LANES // seg_pad)
-    chunk = 1 << (chunk.bit_length() - 1)   # floor to a power of two
-    mono = settings.g1_monomial[:seg_pad] + [None] * max(
-        0, seg_pad - len(settings.g1_monomial))
-    mx = ec.ints_to_mont_limbs(
-        [p[0] if p is not None else 0 for p in mono])
-    my = ec.ints_to_mont_limbs(
-        [p[1] if p is not None else 0 for p in mono])
-    out = []
-    for c0 in range(0, len(q_lists), chunk):
-        qs = q_lists[c0:c0 + chunk]
-        g = len(qs)
-        g_pad = _msm.bucket(g)
-        lanes = seg_pad * g_pad
-        xs = np.zeros((lanes, bi.L), np.uint32)
-        ys = np.zeros((lanes, bi.L), np.uint32)
-        scalars = [0] * lanes
-        for s in range(seg_pad):
-            base = s * g_pad
-            row_x, row_y = mx[s], my[s]
-            for gi, q in enumerate(qs):
-                k = q[s] if s < len(q) else 0
-                if k and mono[s] is not None:
-                    xs[base + gi] = row_x
-                    ys[base + gi] = row_y
-                    scalars[base + gi] = k
-        digits = ec.scalars_to_digits(scalars, n_bits=256)
-        X, Y, Z = _msm.fold_device(xs, ys, digits, g_pad)
-        out.extend(_msm.jacobian_rows_to_affine(X[:g], Y[:g], Z[:g]))
-    return out
+# the two device programs of cells and proofs (ops/fr._recover_kernel,
+# ops/msm._fk20_kernel) serve at and above this width; below it the host's
+# Python integers and per-cell g1_lincomb do
+_DEVICE_MIN_WIDTH = 256
 
 
 def compute_cells_and_kzg_proofs(blob: bytes, settings
                                  ) -> tuple[list[bytes], list[bytes]]:
     """Cells + one KZG multi-proof per cell.
 
-    Production widths batch ALL cells' quotient MSMs into chunked fused
-    dispatches (_batched_cell_proof_msms) instead of one device MSM per
-    cell; dev widths keep the per-cell g1_lincomb path."""
+    At and above `_DEVICE_MIN_WIDTH` every proof of the blob comes from
+    one FK20 dispatch (`_fk20_proofs`); below it each cell's quotient
+    (synthetic division by x^cs - a) is committed by `kzg.g1_lincomb`."""
     from lighthouse_tpu.crypto import kzg as _kzg
     from lighthouse_tpu.crypto.bls import curve as cv
 
@@ -306,9 +260,14 @@ def compute_cells_and_kzg_proofs(blob: bytes, settings
     _require_monomials(settings, cell_size)
     cells = compute_cells(blob, settings)
     coeffs = _poly_coeffs_from_blob(blob, width)
+    if width >= _DEVICE_MIN_WIDTH:
+        import jax.numpy as jnp
+
+        limbs = _plain_limbs(coeffs).T
+        return cells, _fk20_proofs(jnp.asarray(limbs), 1, settings)[0]
     ext_roots = _compute_roots_of_unity(2 * width)
     nat_of_brp = _bit_reversal_permutation(list(range(2 * width)))
-    q_lists = []
+    proofs = []
     for cid in range(n_cells):
         h = _coset_start(cid, cell_size, ext_roots, nat_of_brp)
         a = pow(h, cell_size, BLS_MODULUS)
@@ -317,14 +276,274 @@ def compute_cells_and_kzg_proofs(blob: bytes, settings
         for j in range(width - cell_size - 1, -1, -1):
             carry = q[j + cell_size] if j + cell_size < len(q) else 0
             q[j] = (coeffs[j + cell_size] + a * carry) % BLS_MODULUS
-        q_lists.append(q)
-    if width >= _CELL_PROOF_FUSED_MIN_WIDTH:
-        pts = _batched_cell_proof_msms(q_lists, settings)
-        proofs = [cv.g1_to_bytes(p) for p in pts]
-    else:
-        proofs = [cv.g1_to_bytes(
-            _kzg.g1_lincomb(settings.g1_monomial[:len(q)], q))
-            for q in q_lists]
+        proofs.append(cv.g1_to_bytes(
+            _kzg.g1_lincomb(settings.g1_monomial[:len(q)], q)))
+    return cells, proofs
+
+
+def _plain_limbs(values: list[int]):
+    """Raw (non-Montgomery) limb rows uint32[n, L] of field elements."""
+    import numpy as np
+
+    from lighthouse_tpu.ops import fr
+
+    raw = b"".join(v.to_bytes(32, "big") for v in values)
+    return fr.be32_bytes_to_limbs(np.frombuffer(raw, np.uint8).reshape(-1, 32))
+
+
+def _fk20_tables(settings):
+    """The setup's FK20 tables on the device, made once a settings object
+    from ``g1_monomial[:width]`` by `msm._fk20_tables` (c-kzg-4844 keeps
+    its FK20 tables the same way)."""
+    tables = getattr(settings, "_fk20_device_tables", None)
+    if tables is None:
+        import jax.numpy as jnp
+
+        from lighthouse_tpu.ops import msm as _msm
+
+        n_cells, cell_size = _cell_geometry(settings.width)
+        if len(settings.g1_monomial or ()) < settings.width:
+            raise KzgError("FK20 needs g1_monomial[:width] of the setup")
+        columns = _msm.fk20_setup_columns(settings.g1_monomial, cell_size,
+                                          n_cells)
+        tables = _msm._fk20_tables(*(jnp.asarray(c) for c in columns))
+        settings._fk20_device_tables = tables
+    return tables
+
+
+def _fk20_dispatch(coeffs, blobs: int, settings):
+    """One `msm._fk20_kernel` dispatch over device coefficients
+    uint32[18, width·blobs] (lane j·blobs + r), not waited for: the
+    Jacobian rows uint32[blobs, cells, 27] of every proof."""
+    from lighthouse_tpu.crypto import kzg as _kzg
+    from lighthouse_tpu.ops import msm as _msm
+
+    n_cells, cell_size = _cell_geometry(settings.width)
+    rows = _msm._fk20_kernel(coeffs, *_fk20_tables(settings))
+    _kzg.count_fk20_products(*_msm.fk20_products(blobs, cell_size, n_cells))
+    return rows
+
+
+def _compressed_proofs(X, Y, Z) -> list[list[bytes]]:
+    """Fetched Montgomery Jacobian rows uint32[blobs, cells, 27] ->
+    compressed points, [blob][cell]: the limbs read as integers a column
+    at a time and every Z inverted by one inversion (Montgomery's trick),
+    so a block's 2,688 proofs are no 2,688 modular inversions."""
+    from lighthouse_tpu.crypto.bls import curve as cv
+    from lighthouse_tpu.ops import bigint as bi
+    from lighthouse_tpu.ops import ec
+
+    p = bi.P_INT
+    blobs, cells, _ = X.shape
+    xs, ys, zs = (ec.limbs_to_int_vec(a.reshape(-1, bi.L)) for a in (X, Y, Z))
+    zs = [int(z) % p for z in zs]
+    live = [k for k, z in enumerate(zs) if z]
+    prefix, acc = [], 1
+    for k in live:
+        prefix.append(acc)
+        acc = acc * zs[k] % p
+    inv = pow(acc, -1, p)
+    out = [bytes([0xC0]) + bytes(47)] * len(zs)
+    r1, r2 = bi.R_INT % p, bi.R_INT * bi.R_INT % p
+    for j in range(len(live) - 1, -1, -1):
+        k = live[j]
+        zi = inv * prefix[j] % p          # 1 / Z_m
+        inv = inv * zs[k] % p
+        zi2 = zi * zi % p
+        # Montgomery X_m = X·R, Z_m = Z·R: X/Z^2 = X_m·R/Z_m^2 and
+        # Y/Z^3 = Y_m·R^2/Z_m^3
+        out[k] = cv.g1_to_bytes((int(xs[k]) * r1 % p * zi2 % p,
+                                 int(ys[k]) * r2 % p * zi2 % p * zi % p))
+    return [out[b * cells:(b + 1) * cells] for b in range(blobs)]
+
+
+def _fk20_proofs(coeffs, blobs: int, settings) -> list[list[bytes]]:
+    import jax
+
+    return _compressed_proofs(*jax.device_get(
+        _fk20_dispatch(coeffs, blobs, settings)))
+
+
+# --- recovery: cells and proofs of every blob of a block -------------------
+
+
+def recovery_path(settings) -> str:
+    """Which rung `recover_cells_and_kzg_proofs_batch` serves a width on:
+    ``device`` at and above `_DEVICE_MIN_WIDTH`, ``host`` below it."""
+    return "device" if settings.width >= _DEVICE_MIN_WIDTH else "host"
+
+
+class _RecoveryDomain:
+    """What recovery reads of the extended domain, once a width: each
+    cell's a_c = h_c^l, g^l for the shifted coset (g the primitive root),
+    and for the device the shift g^j / N, the unshift g^-j / N and the
+    twiddles of the size-N transforms (N = 2·width)."""
+
+    def __init__(self, width: int):
+        from lighthouse_tpu.crypto.kzg import PRIMITIVE_ROOT_OF_UNITY as g
+
+        self.width = width
+        self.n_cells, self.size = _cell_geometry(width)
+        self.ext_roots = _compute_roots_of_unity(2 * width)
+        nat_of_brp = _bit_reversal_permutation(list(range(2 * width)))
+        self.a = [pow(_coset_start(c, self.size, self.ext_roots, nat_of_brp),
+                      self.size, BLS_MODULUS) for c in range(self.n_cells)]
+        self.g = g
+        self.g_l = pow(g, self.size, BLS_MODULUS)
+        self._device = None
+
+    def device_constants(self):
+        """(shift, unshift, tw, itw) as device arrays, made on first use."""
+        if self._device is None:
+            import jax.numpy as jnp
+            import numpy as np
+
+            ext, roots = 2 * self.width, self.ext_roots
+            g_inv = pow(self.g, -1, BLS_MODULUS)
+            shift = [pow(ext, -1, BLS_MODULUS)]
+            unshift = [shift[0]]
+            for _ in range(ext - 1):
+                shift.append(shift[-1] * self.g % BLS_MODULUS)
+                unshift.append(unshift[-1] * g_inv % BLS_MODULUS)
+            stages = range(ext.bit_length() - 1)
+            tw = [_mont_limbs([roots[(k >> s) << s]
+                               for k in range(self.width)]) for s in stages]
+            itw = [_mont_limbs([roots[-((k >> s) << s) % ext]
+                                for k in range(self.width)])
+                   for s in reversed(stages)]
+            self._device = tuple(jnp.asarray(v) for v in (
+                _mont_limbs(shift), _mont_limbs(unshift), np.stack(tw),
+                np.stack(itw)))
+        return self._device
+
+    def vanishing(self, missing: list[int]):
+        """Z(X) = z(X^l), z(Y) = prod over the missing cells d of (Y - a_d):
+        Z on each cell's coset, z(a_c), and 1/Z on its shifted coset,
+        1/z(g^l a_c), as Montgomery limb rows uint32[cells, L]."""
+        from lighthouse_tpu.crypto.kzg import _batch_inverse
+
+        on, shifted = [], []
+        for a_c in self.a:
+            y, p, q = self.g_l * a_c % BLS_MODULUS, 1, 1
+            for d in missing:
+                p = p * (a_c - self.a[d]) % BLS_MODULUS
+                q = q * (y - self.a[d]) % BLS_MODULUS
+            on.append(p)
+            shifted.append(q)
+        return _mont_limbs(on), _mont_limbs(_batch_inverse(shifted))
+
+
+def _recovery_domain(settings) -> _RecoveryDomain:
+    dom = getattr(settings, "_recovery_domain", None)
+    if dom is None:
+        dom = settings._recovery_domain = _RecoveryDomain(settings.width)
+    return dom
+
+
+def _check_recovery_input(cell_ids: list[int], cells_by_blob, n_cells: int,
+                          cell_size: int) -> None:
+    """The spec's asserts of recover_cells_and_kzg_proofs, as KzgError."""
+    if len(set(cell_ids)) != len(cell_ids):
+        raise KzgError("duplicate cell ids")
+    if any(not 0 <= c < n_cells for c in cell_ids):
+        raise KzgError("cell id out of range")
+    if len(cell_ids) < n_cells // 2:
+        raise KzgError(
+            f"need at least {n_cells // 2} cells, got {len(cell_ids)}")
+    for cells in cells_by_blob:
+        if len(cells) != len(cell_ids):
+            raise KzgError("cell_ids and cells length mismatch")
+        if any(len(c) != cell_size * 32 for c in cells):
+            raise KzgError("cell has the wrong size")
+
+
+def recover_cells_and_kzg_proofs_batch(cell_indices: list[int],
+                                       cells_by_blob: list[list[bytes]],
+                                       settings
+                                       ) -> tuple[list[list[bytes]],
+                                                  list[list[bytes]]]:
+    """The spec's recover_cells_and_kzg_proofs
+    (polynomial-commitments-sampling.md) for every blob of a block: the
+    blobs' cells at the same ``cell_indices`` (what a node holds of a
+    block's columns) -> per blob all its cells, in cell order (a received
+    cell as given), and all its cell proofs.
+
+    Raises KzgError as the spec asserts: a repeated or out-of-range index,
+    fewer than half of the cells, a cell of the wrong size or a
+    non-canonical field element; and where more than half are given and
+    they are no codeword (the recovered polynomial's degree reaches the
+    width).  At and above `_DEVICE_MIN_WIDTH` two device programs serve
+    every blob at once (``device``): `fr._recover_kernel` (the spec's
+    recover_polynomialcoeff as radix-2 transforms, then the extension) and
+    `msm._fk20_kernel` (every proof by FK20), one fetch after both; below
+    it `recover_all_cells` and `compute_cells_and_kzg_proofs` a blob at a
+    time (``host``).  Counter ``das_recovered_cells_total{path}``."""
+    from lighthouse_tpu.crypto import kzg as _kzg
+
+    n_cells, cell_size = _cell_geometry(settings.width)
+    ids = [int(c) for c in cell_indices]
+    _check_recovery_input(ids, cells_by_blob, n_cells, cell_size)
+    _require_monomials(settings, cell_size)
+    held = set(ids)
+    missing = [c for c in range(n_cells) if c not in held]
+    if recovery_path(settings) == "host":
+        out_cells, out_proofs = [], []
+        for cells in cells_by_blob:
+            full = recover_all_cells(ids, cells, settings)
+            out_cells.append(full)
+            out_proofs.append(compute_cells_and_kzg_proofs(
+                cells_to_blob(full, settings), settings)[1])
+        _kzg.count_recovered_cells("host", len(missing) * len(cells_by_blob))
+        return out_cells, out_proofs
+    out = _recover_on_device(ids, missing, cells_by_blob, settings)
+    _kzg.count_recovered_cells("device", len(missing) * len(cells_by_blob))
+    return out
+
+
+def _recover_on_device(ids, missing, cells_by_blob, settings):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from lighthouse_tpu.crypto import kzg as _kzg
+    from lighthouse_tpu.ops import fr
+
+    span = _kzg.das_stage_span
+    blobs = len(cells_by_blob)
+    n_cells, cell_size = _cell_geometry(settings.width)
+    dom = _recovery_domain(settings)
+    with span("das.recover.layout", "recover_layout", cells=len(ids) * blobs):
+        raw = np.frombuffer(b"".join(c for cells in cells_by_blob
+                                     for c in cells), np.uint8).reshape(
+            blobs, len(ids), cell_size, 32)
+        if not _kzg._blob_fields_canonical(raw):
+            raise KzgError("field element not canonical")
+        e = np.zeros((blobs, n_cells, cell_size, fr.L), np.uint32)
+        e[:, ids] = fr.be32_bytes_to_limbs(raw)
+        z, zinv = dom.vanishing(missing)
+    with span("das.recover.dispatch", "recover_dispatch"):
+        cells_d, coeffs, ok = fr._recover_kernel(
+            jnp.asarray(e.reshape(blobs, n_cells * cell_size, fr.L)),
+            jnp.asarray(z), jnp.asarray(zinv), *dom.device_constants())
+    _kzg.count_ntt_products(*fr._ntt_products(blobs, 2 * settings.width))
+    with span("das.fk20.dispatch", "fk20_dispatch"):
+        rows = _fk20_dispatch(coeffs, blobs, settings)
+    with span("das.fetch", "fetch"):
+        got, ok, (X, Y, Z) = jax.device_get((
+            cells_d.reshape(blobs, n_cells, cell_size, fr.L)[:, missing], ok,
+            rows))
+        if not bool(np.all(ok)):
+            raise KzgError("recovered polynomial exceeds blob degree "
+                           "(inconsistent cells)")
+        got = fr.limbs_to_be32_bytes(np.asarray(got))
+    with span("das.proofs.affine", "proofs_affine",
+              proofs=X.shape[0] * X.shape[1]):
+        proofs = _compressed_proofs(X, Y, Z)
+    slot_of = {c: k for k, c in enumerate(ids)}
+    rebuilt = {c: k for k, c in enumerate(missing)}
+    cells = [[cells_by_blob[r][slot_of[c]] if c in slot_of
+              else got[r, rebuilt[c]].tobytes() for c in range(n_cells)]
+             for r in range(blobs)]
     return cells, proofs
 
 
@@ -770,6 +989,7 @@ __all__ = [
     "compute_cells_and_kzg_proofs",
     "compute_verify_cell_kzg_proof_batch_challenge",
     "recover_all_cells",
+    "recover_cells_and_kzg_proofs_batch",
     "verify_cell_kzg_proof",
     "verify_cell_kzg_proof_batch",
     "verify_cells_match_blob",

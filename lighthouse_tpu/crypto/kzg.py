@@ -801,3 +801,55 @@ def _verify_batch_host(blobs, commitment_bytes_list, proof_bytes_list,
         (lhs, cv.g2_neg(cv.g2_generator())),
         (proof_comb, settings.g2_tau),
     ])
+
+
+# --- data-column reconstruction: its stage spans and counters -------------------
+
+def record_das_stage(stage: str, seconds: float) -> None:
+    """One stage of a data-column reconstruction (crypto/das.py's
+    recovery, chain/data_column_verification.reconstruct_data_columns)."""
+    try:
+        REGISTRY.histogram(
+            "das_reconstruct_stage_seconds",
+            "data-column reconstruction wall time by stage (recover_dispatch "
+            "and fk20_dispatch time the enqueue, fetch the host blocked on "
+            "the device and unpacked the cells)",
+            buckets=_STAGE_BUCKETS,
+        ).labels(stage=stage).observe(seconds)
+    except Exception as e:
+        record_swallowed("kzg.record_das_stage", e)
+
+
+def das_stage_span(name: str, stage: str, **attrs):
+    """A span whose duration also feeds
+    ``das_reconstruct_stage_seconds{stage}``."""
+    return tracing.span(name, observe=partial(record_das_stage, stage),
+                        **attrs)
+
+
+def count_recovered_cells(path: str, cells: int) -> None:
+    """Cells rebuilt by das.recover_cells_and_kzg_proofs_batch, by the rung
+    that served the batch."""
+    REGISTRY.counter(
+        "das_recovered_cells_total",
+        "cells rebuilt by recover_cells_and_kzg_proofs_batch, by the path "
+        "that served the batch").labels(path=path).inc(cells)
+
+
+def count_ntt_products(resident: int, materialized: int) -> None:
+    """Fr lane-products of the recovery programs dispatched
+    (ops/fr._recover_kernel, all on `MontField.mont_mul_lm`)."""
+    REGISTRY.counter(
+        "das_ntt_products_total",
+        "Fr lane-products of the recovery programs dispatched").inc(
+            resident + materialized)
+
+
+def count_fk20_products(resident: int, materialized: int) -> None:
+    """Lane-products of the FK20 programs dispatched (ops/msm._fk20_kernel:
+    the Fr coefficient transforms and the Fp scalar multiplications, sums
+    and G1 transforms), by the multiply that runs them."""
+    _count_by_multiply(REGISTRY.counter(
+        "das_fk20_products_total",
+        "lane-products of the FK20 cell-proof programs dispatched, by "
+        "multiply"), resident, materialized)

@@ -722,3 +722,28 @@ def is_zero_mod_p_lm(x: jax.Array) -> jax.Array:
     w = FP.mont_mul_lm(x, FP.tables["one_plain"])
     d = canon_digits(jnp.moveaxis(w, 0, -1))
     return (d[..., None, :] == _kp_digit_consts()).all(-1).any(-1)
+
+
+# --- the lane order of the constant-geometry transforms ----------------------
+#
+# ops/fr.py's NTT and ops/ec.py's G1 FFT lay a batch of ``rows`` transforms
+# out limb-major with lane k·rows + r holding entry k of row r: the two
+# halves of a stage's pairs are the two halves of the lanes, and a stage's
+# outputs go back interleaved (Pease's constant geometry).
+
+
+def interleave_lm(a: jax.Array, b: jax.Array, rows: int) -> jax.Array:
+    """Lanes k·rows + r of a and b (uint32[L, lanes]) -> lanes
+    (2k)·rows + r and (2k + 1)·rows + r of one array."""
+    n_limbs, n = a.shape
+    return jnp.stack([a.reshape(n_limbs, n // rows, rows),
+                      b.reshape(n_limbs, n // rows, rows)],
+                     axis=2).reshape(n_limbs, 2 * n)
+
+
+def deinterleave_lm(x: jax.Array, rows: int) -> tuple:
+    """`interleave_lm` undone: the even and the odd entries of every row."""
+    n_limbs, n = x.shape
+    y = x.reshape(n_limbs, n // (2 * rows), 2, rows)
+    return (y[:, :, 0].reshape(n_limbs, n // 2),
+            y[:, :, 1].reshape(n_limbs, n // 2))

@@ -1008,3 +1008,179 @@ def _pad_lanes(F, c):
     if F in _PAIRS:
         return tuple(jnp.concatenate([a, jnp.zeros_like(a)], 1) for a in c)
     return jnp.concatenate([c, jnp.zeros_like(c)], 1)
+
+
+# --- complete projective G1 arithmetic (FK20 cell proofs, ops/msm) ----------
+#
+# Renes, Costello and Batina 2016 (ePrint 2015/1060), Algorithms 7 and 9 for
+# y² = x³ + b: projective (X : Y : Z), the identity (0 : 1 : 0).  The
+# formulas hold for every pair of points of a curve without points of order
+# two, the identity and P + P included, so nothing is flagged or tested:
+# the arithmetic is the same for honest, zero and adversarial data, and a
+# scalar may be any representative below 2^(4·windows).  An addition is 12
+# products in two rounds, a doubling 8 in two, on `_FpLmAdapter` lanes.
+
+_G1_B3 = 12   # 3·b for G1's b = 4
+
+
+def proj_identity(like):
+    """The identity (0 : 1 : 0) over limb-major lanes shaped like ``like``."""
+    F = _FpLmAdapter
+    return (F.zeros_like(like), F.one_like(like), F.zeros_like(like))
+
+
+def proj_neg(P):
+    F = _FpLmAdapter
+    return (P[0], F.sub(F.zeros_like(P[1]), P[1]), P[2])
+
+
+def proj_double_multi(points):
+    """2·P for every projective point of ``points`` (limb-major lanes),
+    all of them through the same two product rounds."""
+    F = _FpLmAdapter
+    q = _MulQueue()
+    first = [(F.mul(q, Y, Y), F.mul(q, Y, Z), F.mul(q, Z, Z),
+              F.mul(q, X, Y)) for X, Y, Z in points]
+    q.run()
+    q = _MulQueue()
+    second = []
+    for r_yy, r_yz, r_zz, r_xy in first:
+        yy = r_yy()
+        z8 = F.scale(yy, 8)
+        t2 = F.scale(r_zz(), _G1_B3)
+        t0 = F.sub(yy, F.scale(t2, 3))
+        second.append((F.mul(q, t2, z8), F.mul(q, r_yz(), z8),
+                       F.mul(q, t0, F.add(yy, t2)), F.mul(q, t0, r_xy())))
+    q.run()
+    return [(F.scale(r_x(), 2), F.add(r_x8(), r_y()), r_z())
+            for r_x8, r_z, r_y, r_x in second]
+
+
+def proj_add_multi(pairs):
+    """P + Q for every pair of projective points of ``pairs`` (limb-major
+    lanes), all of them through the same two product rounds."""
+    F = _FpLmAdapter
+    q = _MulQueue()
+    first = []
+    for (X1, Y1, Z1), (X2, Y2, Z2) in pairs:
+        first.append((
+            F.mul(q, X1, X2), F.mul(q, Y1, Y2), F.mul(q, Z1, Z2),
+            F.mul(q, F.add(X1, Y1), F.add(X2, Y2)),
+            F.mul(q, F.add(Y1, Z1), F.add(Y2, Z2)),
+            F.mul(q, F.add(X1, Z1), F.add(X2, Z2))))
+    q.run()
+    q = _MulQueue()
+    second = []
+    for r_xx, r_yy, r_zz, r_xy, r_yz, r_xz in first:
+        xx, yy, zz = r_xx(), r_yy(), r_zz()
+        t3 = F.sub(r_xy(), F.add(xx, yy))
+        t4 = F.sub(r_yz(), F.add(yy, zz))
+        y3 = F.scale(F.sub(r_xz(), F.add(xx, zz)), _G1_B3)
+        t0 = F.scale(xx, 3)
+        t2 = F.scale(zz, _G1_B3)
+        z3 = F.add(yy, t2)
+        t1 = F.sub(yy, t2)
+        second.append((F.mul(q, t4, y3), F.mul(q, t3, t1), F.mul(q, y3, t0),
+                       F.mul(q, t1, z3), F.mul(q, t0, t3), F.mul(q, z3, t4)))
+    q.run()
+    return [(F.sub(r_b(), r_a()), F.add(r_d(), r_c()), F.add(r_f(), r_e()))
+            for r_a, r_b, r_c, r_d, r_e, r_f in second]
+
+
+def proj_scalar_mul_lm(P, digits):
+    """k_i·P_i over limb-major projective lanes P (uint32[27, N] each), the
+    scalars as MSB-first base-16 window digits uint32[W, N]
+    (`scalars_to_digits`, or digits made on the device): a table of the
+    sixteen multiples a lane, then W windows of four doublings and one
+    addition, all complete."""
+    F = _FpLmAdapter
+    tab = [proj_identity(P[0]), P]
+    while len(tab) < 16:
+        doubles = proj_double_multi(tab[len(tab) // 2:])
+        odds = proj_add_multi([(d, P) for d in doubles])
+        for d, o in zip(doubles, odds):
+            tab += [d, o]
+    tab = tuple(jnp.stack([e[c] for e in tab]) for c in range(3))
+
+    def step(acc, digit):
+        for _ in range(4):
+            (acc,) = proj_double_multi([acc])
+        (acc,) = proj_add_multi([(acc, _table_pick(F, tab, digit))])
+        return acc, None
+
+    acc, _ = jax.lax.scan(step, proj_identity(P[0]), digits)
+    return acc
+
+
+def proj_scalar_mul_products(windows: int) -> int:
+    """Fp products of one lane of `proj_scalar_mul_lm`: seven doublings (8)
+    and seven additions (12) for the table, four doublings and one addition
+    a window."""
+    return 7 * (8 + 12) + windows * (4 * 8 + 12)
+
+
+def proj_sum_halves_lm(P, parts: int):
+    """Sum of ``parts`` (a power of two) equal stretches of the lanes of P:
+    a tree of halves, log2(parts) levels of one addition a lane."""
+    while parts > 1:
+        h = P[0].shape[1] // 2
+        (P,) = proj_add_multi([(tuple(c[:, :h] for c in P),
+                                tuple(c[:, h:] for c in P))])
+        parts //= 2
+    return P
+
+
+def g1_fft_stage_lm(P, digits, rows: int):
+    """One stage of `g1_fft_lm`: the two halves of the lanes paired, their
+    sum and their difference times the stage's twiddles (window digits
+    uint32[W, m/2]), interleaved."""
+    h = P[0].shape[1] // 2
+    a = tuple(c[:, :h] for c in P)
+    b = tuple(c[:, h:] for c in P)
+    s, d = proj_add_multi([(a, b), (a, proj_neg(b))])
+    d = proj_scalar_mul_lm(d, jnp.repeat(digits, rows, axis=1))
+    return tuple(bi.interleave_lm(x, y, rows) for x, y in zip(s, d))
+
+
+def g1_fft_lm(P, digits, rows: int):
+    """Radix-2 transform of size m over G1, for ``rows`` rows at once:
+    limb-major projective lanes P (uint32[27, m·rows], lane k·rows + r is
+    entry k of row r) in natural order -> the transform in bit-reversed
+    order.  ``digits`` uint32[log2 m, W, m/2]: stage s multiplies pair k by
+    omega^((k >> s) << s), as window digits.  Constant geometry (Pease):
+    every stage pairs the two halves of the lanes, so one `lax.scan` body
+    serves all of them."""
+    P, _ = jax.lax.scan(lambda P, dg: (g1_fft_stage_lm(P, dg, rows), None),
+                        P, digits)
+    return P
+
+
+def bit_reverse_lm(x, size: int, rows: int):
+    """Lanes k·rows + r -> lanes brp(k)·rows + r, for k < size (a power of
+    two): a transpose of the index's bits."""
+    bits = size.bit_length() - 1
+    y = x.reshape((x.shape[0],) + (2,) * bits + (rows,))
+    y = jnp.transpose(y, (0,) + tuple(range(bits, 0, -1)) + (bits + 1,))
+    return y.reshape(x.shape)
+
+
+def g1_fft_products(size: int, windows: int = 64) -> int:
+    """Fp products a row of `g1_fft_lm` at ``size``: log2(size) stages of
+    size/2 pairs, each a scalar multiplication, an addition and an
+    addition of the negation."""
+    stages = size.bit_length() - 1
+    return stages * (size // 2) * (proj_scalar_mul_products(windows) + 24)
+
+
+def proj_to_jacobian_lm(P):
+    """(X : Y : Z) projective -> Jacobian (X·Z, Y·Z², Z): the same point,
+    the identity at Z = 0.  Three products a lane."""
+    F = _FpLmAdapter
+    X, Y, Z = P
+    q = _MulQueue()
+    r_xz, r_zz = F.mul(q, X, Z), F.mul(q, Z, Z)
+    q.run()
+    q = _MulQueue()
+    r_y = F.mul(q, Y, r_zz())
+    q.run()
+    return r_xz(), r_y(), Z

@@ -414,6 +414,168 @@ def interpolation_scalars(out) -> list[list[int]]:
     return [[_limbs_to_int(r) % R_INT for r in group] for group in rows]
 
 
+
+# --- PeerDAS recovery: recover_polynomialcoeff as radix-2 transforms ----------
+#
+# consensus-specs polynomial-commitments-sampling.md recover_polynomialcoeff
+# for every blob of a request at once.  Rows (blobs) share the missing
+# cells, so they share Z(X) = prod over missing cells c of (X^l - a_c): on
+# the extended domain Z is constant on a cell's coset (x^l = a_c there) and
+# on the shifted coset too ((g x)^l = g^l a_c), so the host hands the
+# program Z and 1/Z(g x) as one value a cell.  Values stay plain (not
+# Montgomery) from the cells' bytes to the coefficients: every product has
+# one Montgomery operand, a constant.  A transform is Pease's constant
+# geometry (`ntt_lm`): one `lax.scan` body for every stage.
+
+_pstore.register_entry("ops/fr.py::_recover_kernel@_recover_kernel",
+                       driver="fr")
+
+
+def ntt_lm(x: jax.Array, twiddles: jax.Array, rows: int,
+           inverse: bool = False) -> jax.Array:
+    """Radix-2 transforms of size N over ``rows`` rows: x uint32[L, N·rows]
+    limb-major, lane k·rows + r entry k of row r (`bigint.interleave_lm`);
+    ``twiddles`` uint32[log2 N, L, N/2] Montgomery, stage s's pair k
+    multiplied by omega^((k >> s) << s) (forward) or its inverse, the stages
+    in the order they run.  Forward: natural order in, the transform out in
+    bit-reversed order.  Inverse: bit-reversed in, N times the inverse
+    transform out in natural order."""
+    h = x.shape[1] // 2
+
+    def forward(x, w):
+        a, b = x[:, :h], x[:, h:]
+        t = mont_mul_lm(sub_lm(a, b), jnp.repeat(w, rows, axis=1))
+        return _bigint.interleave_lm(add_lm(a, b), t, rows), None
+
+    def backward(x, w):
+        u, v = _bigint.deinterleave_lm(x, rows)
+        t = mont_mul_lm(v, jnp.repeat(w, rows, axis=1))
+        return jnp.concatenate([add_lm(u, t), sub_lm(u, t)], axis=1), None
+
+    x, _ = jax.lax.scan(backward if inverse else forward, x, twiddles)
+    return x
+
+
+def _carry_digits_lm(x: jax.Array) -> list:
+    """Limb-major limbs < 2^16 -> the value's exact 15-bit digits, one row
+    a digit (a sequential carry over the L limbs)."""
+    out, c = [], jnp.zeros_like(x[0])
+    for i in range(x.shape[0]):
+        s = x[i] + c
+        out.append(s & MASK)
+        c = s >> B
+    return out
+
+
+_R_DIGITS = tuple(int(v) for v in FR.int_to_limbs(R_INT))
+
+
+def _reduce_lm(x: jax.Array) -> list:
+    """A value's residue mod R as 15-bit digit rows below R: one product by
+    Montgomery 1 (any legal x -> below 2R, `mul out`), the carry, one
+    conditional subtraction of R."""
+    d = _carry_digits_lm(mont_mul_lm(x, FR.tables["one_m"]))
+    out, borrow = [], jnp.zeros_like(d[0])
+    for di, ri in zip(d, _R_DIGITS):
+        s = di + (1 << B) - ri - borrow
+        out.append(s & MASK)
+        borrow = 1 - (s >> B)
+    keep = borrow != 0             # x < R: nothing to subtract
+    return [jnp.where(keep, di, oi) for di, oi in zip(d, out)]
+
+
+def canonical_lm(x: jax.Array) -> jax.Array:
+    """Limb-major values -> their canonical residues mod R, as exact 15-bit
+    limbs uint32[L, ...] (the byte layout's, `limbs_to_be32_bytes`)."""
+    return jnp.stack(_reduce_lm(x))
+
+
+def is_zero_mod_r_lm(x: jax.Array) -> jax.Array:
+    """Per-lane x ≡ 0 (mod R) for limb-major values -> bool[...lanes]."""
+    acc = None
+    for di in _reduce_lm(x):
+        acc = di if acc is None else acc | di
+    return acc == 0
+
+
+@jax.jit
+def _recover_kernel(e, z_cells, zinv_cells, shift, unshift, tw, itw):
+    """The spec's recover_polynomialcoeff for R blobs missing the same
+    cells, then the re-extension.
+
+    e: uint32[R, N, L] raw limb rows of the received extended evaluations
+    in bit-reversed order (the cells' own order), zero at missing cells;
+    z_cells, zinv_cells: uint32[m, L] Montgomery Z and 1/Z(g x) on each of
+    the m cells' cosets; shift, unshift: uint32[N, L] Montgomery g^j / N and
+    g^-j / N; tw, itw: uint32[log2 N, N/2, L] Montgomery forward and inverse
+    twiddles (`ntt_lm`'s, stages in the order they run).
+
+    Steps: E·Z, inverse transform (E·Z's coefficients), shift, transform
+    on the coset, times 1/Z there, inverse transform, unshift: D's
+    coefficients.  D must have degree below n = N/2: the per-blob verdict.
+    Then D's transform is the N extended evaluations in bit-reversed order,
+    the cells.  Returns (cells uint32[R, N, L] canonical limbs, D's first n
+    coefficients uint32[L, n·R] raw, limb-major, lane j·R + r; ok bool[R])."""
+    rows, N, _ = e.shape
+    n, ell = N // 2, N // z_cells.shape[0]
+
+    def per_cell(c):
+        return jnp.repeat(c.T, ell * rows, axis=1)
+
+    def per_position(c):
+        return jnp.repeat(c.T, rows, axis=1)
+
+    tw, itw = jnp.moveaxis(tw, -1, 1), jnp.moveaxis(itw, -1, 1)
+    x = jnp.transpose(e, (2, 1, 0)).reshape(L, N * rows)
+    x = mont_mul_lm(x, per_cell(z_cells))
+    x = ntt_lm(x, itw, rows, inverse=True)
+    x = ntt_lm(mont_mul_lm(x, per_position(shift)), tw, rows)
+    x = ntt_lm(mont_mul_lm(x, per_cell(zinv_cells)), itw, rows, inverse=True)
+    d = mont_mul_lm(x, per_position(unshift))
+    low, high = d[:, :n * rows], d[:, n * rows:]
+    ok = jnp.all(is_zero_mod_r_lm(high).reshape(n, rows), axis=0)
+    cells = canonical_lm(ntt_lm(
+        jnp.concatenate([low, jnp.zeros_like(high)], axis=1), tw, rows))
+    return jnp.transpose(cells.reshape(L, N, rows), (2, 1, 0)), low, ok
+
+
+_recover_kernel = _dtel.instrument(
+    "ops/fr.py::_recover_kernel@_recover_kernel", _recover_kernel)
+
+
+def ntt_products(rows: int, size: int) -> int:
+    """Fr lane-products of one `ntt_lm` transform of ``size`` over ``rows``
+    rows: log2(size) stages of size/2 products a row."""
+    return (size.bit_length() - 1) * (size // 2) * rows
+
+
+@functools.cache
+def _ntt_products(rows: int, size: int) -> tuple[int, int]:
+    """(resident, materialized) Fr lane-products of one `_recover_kernel`
+    dispatch of ``rows`` blobs at ``size`` extended evaluations, all on
+    `mont_mul_lm`: four transforms, four products a position (Z, the shift,
+    1/Z, the unshift), the degree check's reduction of the upper half and
+    the cells' of every position."""
+    per_position = 4 + 1 + 1
+    return (4 * ntt_products(rows, size) + per_position * size * rows
+            - size // 2 * rows, 0)
+
+
+def limbs_to_be32_bytes(limbs: np.ndarray) -> np.ndarray:
+    """`be32_bytes_to_limbs` undone: exact 15-bit limb rows uint32[..., L]
+    of values below 2^256 -> their 32-byte big-endian encodings
+    uint8[..., 32]."""
+    x = np.ascontiguousarray(limbs, np.uint32)
+    flat = x.reshape(-1, L).astype(np.uint64)
+    words = np.zeros((flat.shape[0], 4), np.uint64)
+    for i in range(L):
+        j, s = divmod(B * i, 64)
+        words[:, j] |= flat[:, i] << np.uint64(s)
+        if s > 64 - B and j < 3:
+            words[:, j + 1] |= flat[:, i] >> np.uint64(64 - s)
+    be = words[:, ::-1].astype(">u8")
+    return be.view(np.uint8).reshape(x.shape[:-1] + (32,))
+
 __all__ = [
     "B",
     "L",
@@ -425,6 +587,8 @@ __all__ = [
     "from_mont_host",
     "interpolate_cells_dispatch",
     "interpolation_scalars",
+    "limbs_to_be32_bytes",
+    "ntt_lm",
     "inv_mont",
     "mont_mul",
     "sub",

@@ -525,3 +525,177 @@ def _publish_thresholds() -> None:
             ).labels(track=t).set(_DEVICE_MIN.get(t, _STATIC_DEVICE_MIN))
     except Exception as e:
         record_swallowed("msm.publish_thresholds", e)
+
+
+# -- FK20: every cell proof of a blob by two transforms over G1 ----------------
+#
+# Feist and Khovratovich 2020 (ePrint 2023/033), for the spec's cells.  With
+# n the blob's width, l the cell size, K = n / l and m = 2K cells, cell c's
+# proof is pi_c = sum_{k < K-1} a_c^k H_k, a_c = h_c^l an m-th root of
+# unity, and H_k = sum_{i < l} sum_{j <= K-2-k} S_{jl+i} f_{(k+1+j)l+i}
+# (S_j = g1_monomial[j], f the blob's coefficients): for each i a Toeplitz
+# product, embedded in a circulant of size m.  With T_i the transform of
+# setup column i (made once, `_fk20_tables`) and F_i that of coefficient
+# column i, H is the inverse transform of sum_i T_i F_i (entry k of the
+# inverse is entry -k of the transform, over m), and the proofs are the
+# transform of H, which lands them in cell order (a_c = omega_m^brp(c)).
+# A blob: m x l fixed-base scalar multiplications, two G1 transforms of
+# size m.  Every point is projective under complete formulas
+# (ec.proj_add_multi), so zero blobs and zero quotients need no flag.
+
+import functools as _functools  # noqa: E402
+
+_pstore.register_entry("ops/msm.py::_fk20_kernel@_fk20_kernel", driver="msm")
+_pstore.register_entry("ops/msm.py::_fk20_tables@_fk20_tables", driver="msm")
+
+
+@jax.jit
+def _fk20_tables(sx, sy, sz):
+    """The FK20 setup: the l circulant setup columns as projective
+    limb-major points uint32[27, m, l] each (`fk20_setup_columns`: entry k
+    of column i at [:, k, i]) -> their transforms uint32[27, l, m], entry u
+    of column i at [:, i, u] in bit-reversed order."""
+    _, m, ell = sx.shape
+    T = ec.g1_fft_lm(tuple(c.reshape(bi.L, -1) for c in (sx, sy, sz)),
+                     jnp.asarray(fk20_twiddle_digits(m)), ell)
+    return tuple(c.reshape(bi.L, m, ell).transpose(0, 2, 1) for c in T)
+
+
+_fk20_tables = _dtel.instrument(
+    "ops/msm.py::_fk20_tables@_fk20_tables", _fk20_tables)
+
+
+def _window_digits_lm(digits15: list, windows: int = 64) -> jax.Array:
+    """Exact 15-bit digit rows of values below 2^(4·windows) -> their
+    MSB-first base-16 window digits uint32[windows, lanes]."""
+    out = []
+    for w in range(windows - 1, -1, -1):
+        j, off = divmod(4 * w, 15)
+        v = digits15[j] >> off
+        if off > 11 and j + 1 < len(digits15):
+            v = v | (digits15[j + 1] << (15 - off))
+        out.append(v & 15)
+    return jnp.stack(out)
+
+
+@jax.jit
+def _fk20_kernel(coeffs, tx, ty, tz):
+    """Every cell proof of R blobs.
+
+    coeffs: uint32[18, n·R] raw Fr limb-major lanes j·R + r, coefficient j
+    of blob r (`fr._recover_kernel`'s, or the host's); tx, ty, tz: the
+    setup transforms uint32[27, l, m] of `_fk20_tables`.  Returns Jacobian
+    rows (X, Y, Z) uint32[R, m, 27]: row r, cell c."""
+    from lighthouse_tpu.ops import fr
+
+    _, ell, m = tx.shape
+    K = m // 2
+    rows = coeffs.shape[1] // (K * ell)
+    fr_tw, inv_m = _fk20_constants(m)
+    # coefficient column i: f_{(t+1)l+i} at t < K-1, zero to m
+    cols = coeffs.reshape(fr.L, K, ell * rows)[:, 1:]
+    cols = jnp.concatenate(
+        [cols, jnp.zeros((fr.L, m - K + 1, ell * rows), jnp.uint32)], axis=1)
+    F = fr.ntt_lm(cols.reshape(fr.L, -1), jnp.asarray(fr_tw), ell * rows)
+    # F / m as a plain value below 2^256, its 64 window digits; lanes from
+    # (u, i, r) to (i, u, r), the order of the setup lanes
+    plain = fr.mont_mul_lm(F, inv_m)
+    digits = _window_digits_lm(fr._carry_digits_lm(plain))
+    digits = digits.reshape(64, m, ell, rows).transpose(0, 2, 1, 3).reshape(
+        64, -1)
+    P = tuple(jnp.repeat(c.reshape(bi.L, -1), rows, axis=1)
+              for c in (tx, ty, tz))
+    P = ec.proj_sum_halves_lm(ec.proj_scalar_mul_lm(P, digits), ell)
+    # both transforms of size m in one scan of forward stages: after the
+    # first, entry -k of m·IDFT is H_k (natural order once the transform's
+    # bit-reversed order is undone), entries K-1 and up are no part of H
+    k = jnp.arange(m * rows) // rows
+    one = ec.proj_identity(P[0])
+
+    def to_h(X):
+        X = ec.bit_reverse_lm(X, m, rows).reshape(bi.L, m, rows)
+        return jnp.concatenate([X[:, :1], X[:, :0:-1]], axis=1).reshape(
+            bi.L, -1)
+
+    def stage(P, xs):
+        dg, s = xs
+        P = ec.g1_fft_stage_lm(P, dg, rows)
+        H = tuple(jnp.where((k < K - 1)[None], to_h(c), e)
+                  for c, e in zip(P, one))
+        return tuple(jnp.where(s == log_m - 1, h, c)
+                     for c, h in zip(P, H)), None
+
+    log_m = m.bit_length() - 1
+    digits2 = jnp.asarray(np.concatenate([fk20_twiddle_digits(m)] * 2))
+    P = tuple(ec.bit_reverse_lm(c, m, rows) for c in P)
+    P, _ = jax.lax.scan(stage, P, (digits2, jnp.arange(2 * log_m)))
+    return tuple(c.reshape(bi.L, m, rows).transpose(2, 1, 0)
+                 for c in ec.proj_to_jacobian_lm(P))
+
+
+_fk20_kernel = _dtel.instrument(
+    "ops/msm.py::_fk20_kernel@_fk20_kernel", _fk20_kernel)
+
+
+def fk20_products(blobs: int, ell: int = 64, m: int = 128
+                  ) -> tuple[int, int]:
+    """(resident, materialized) lane-products of one `_fk20_kernel`
+    dispatch over ``blobs`` blobs of cell size ``ell`` and ``m`` cells, all
+    on `mont_mul_lm`: in Fr the coefficient columns' transforms and the
+    scaling by 1/m; in Fp the m·l scalar multiplications of a blob, the sum
+    over its l columns, the two G1 transforms and the Jacobian rows."""
+    from lighthouse_tpu.ops import fr
+
+    lanes = m * ell * blobs
+    fr_side = fr.ntt_products(ell * blobs, m) + lanes
+    fp_side = (lanes * ec.proj_scalar_mul_products(64)
+               + 12 * (lanes - m * blobs)
+               + 2 * blobs * ec.g1_fft_products(m) + 3 * m * blobs)
+    return fr_side + fp_side, 0
+
+
+def fk20_setup_columns(g1_monomial, ell: int, m: int):
+    """HOST: the l circulant setup columns of FK20 as projective Montgomery
+    limb-major points uint32[27, m, l] (`_fk20_tables`' input): column i
+    holds S_i at entry 0, S_{(m-q)l+i} at entries q = K+2 .. m-1 and the
+    identity elsewhere."""
+    K = m // 2
+    xs, ys, zs = [], [], []
+    for q in range(m):
+        for i in range(ell):
+            j = (m - q) % m
+            p = g1_monomial[j * ell + i] if j <= K - 2 else cv.INF
+            if p is cv.INF:
+                xs.append(0), ys.append(1), zs.append(0)
+            else:
+                xs.append(p[0]), ys.append(p[1]), zs.append(1)
+    return tuple(ec.ints_to_mont_limbs(v).T.reshape(bi.L, m, ell)
+                 for v in (xs, ys, zs))
+
+
+@_functools.cache
+def fk20_twiddle_digits(m: int) -> np.ndarray:
+    """HOST: the window digits of `ec.g1_fft_lm`'s twiddles at size m."""
+    from lighthouse_tpu.crypto.kzg import _compute_roots_of_unity
+
+    roots = _compute_roots_of_unity(m)
+    return np.stack([
+        ec.scalars_to_digits([roots[(k >> s) << s] for k in range(m // 2)],
+                             n_bits=256)
+        for s in range(m.bit_length() - 1)])
+
+
+@_functools.cache
+def _fk20_constants(m: int):
+    """The FK20 program's constants at m cells: the Fr transform's
+    twiddles (`fr.ntt_lm`'s form) and Montgomery 1/m as a host limb
+    table."""
+    from lighthouse_tpu.crypto.kzg import _compute_roots_of_unity
+    from lighthouse_tpu.ops import fr
+
+    roots = _compute_roots_of_unity(m)
+    stages = m.bit_length() - 1
+    tw = np.stack([fr.to_mont_host([roots[(k >> s) << s]
+                                    for k in range(m // 2)]).T
+                   for s in range(stages)])
+    return tw, fr.to_mont_host(pow(m, -1, fr.R_INT))

@@ -200,10 +200,11 @@ def _drv_sharded(scale: str) -> None:
 
 def _drv_msm(scale: str) -> None:
     """The unified MSM plane (ops/msm): every track's program at its
-    prewarm bucket — the plain g1 fold (kzg lincomb + das cell-proof
-    chunk shapes), the fused gather fold, and the blinded merge — each
-    gated by host point math (a mis-prewarmed program must never serve
-    commitments or committee aggregates)."""
+    prewarm bucket — the plain g1 fold (kzg lincomb shapes), the FK20
+    cell proofs and their setup tables, the fused gather fold, and the
+    blinded merge — each gated by host point math (a mis-prewarmed
+    program must never serve commitments, proofs or committee
+    aggregates)."""
     import numpy as np
 
     from lighthouse_tpu.crypto import das, kzg
@@ -217,9 +218,16 @@ def _drv_msm(scale: str) -> None:
     want = cv.g1_add(cv.g1_mul(pts[0], 3), cv.g1_mul(pts[1], 5))
     if got != want:
         raise RuntimeError("prewarmed g1 fold mismatches host adds")
-    # the das cell-proof chunk shape rides the same program
-    das._batched_cell_proof_msms([[1, 2], [3, 4]],
-                                 kzg.KzgSettings.dev(width=16))
+    # FK20 at the first width it serves: p(x) = x^l (l the cell size) has
+    # the constant quotient 1 by every cell's x^l - a, so every cell proof
+    # is the generator
+    settings = kzg.KzgSettings.dev(width=das._DEVICE_MIN_WIDTH)
+    ell = das._cell_geometry(settings.width)[1]
+    blob = b"".join(kzg.bls_field_to_bytes(pow(w, ell, kzg.BLS_MODULUS))
+                    for w in settings.roots_brp)
+    _, proofs = das.compute_cells_and_kzg_proofs(blob, settings)
+    if set(proofs) != {cv.g1_to_bytes(cv.g1_generator())}:
+        raise RuntimeError("prewarmed FK20 proofs mismatch the known answer")
     # gather track over a tiny resident table
     lanes = 64 if scale == "production" else 2
     table = pubkey_kernels.build_table(pts)
@@ -336,6 +344,18 @@ def _drv_fr(scale: str) -> None:
         fr_ops.to_mont_host([[1, 1], [1, FR_MOD - 1]]),
         fr_ops.to_mont_host([[[1, 2]], [[1, 4]]]))
     fr_ops.interpolation_scalars(out)
+    # a supernode's recovery at the first width the device serves: half of
+    # one blob's cells back to all of them (and FK20's proofs)
+    from lighthouse_tpu.crypto import das
+
+    settings = kzg.KzgSettings.dev(width=das._DEVICE_MIN_WIDTH)
+    blob = b"".join(kzg.bls_field_to_bytes(w) for w in settings.roots_brp)
+    cells = das.compute_cells(blob, settings)
+    ids = list(range(0, len(cells), 2))
+    got, _ = das.recover_cells_and_kzg_proofs_batch(
+        ids, [[cells[i] for i in ids]], settings)
+    if got != [cells]:
+        raise RuntimeError("prewarmed recovery mismatches the cells")
 
 
 def _drv_epoch(scale: str) -> None:

@@ -244,15 +244,15 @@ class TestFusedCellBatch:
         blob = b"".join(kzg.bls_field_to_bytes(int(v))
                         for v in rng.integers(0, 2**62, size=s.width))
         commitment = kzg.blob_to_kzg_commitment(blob, s)
-        # fixture via the per-cell builder: the fused COMPUTE path has
-        # its own (slow-marked) equivalence test; here only the fused
-        # VERIFY shape is under test
-        orig = das._CELL_PROOF_FUSED_MIN_WIDTH
-        das._CELL_PROOF_FUSED_MIN_WIDTH = 1 << 30
+        # fixture via the per-cell builder: the FK20 COMPUTE path has
+        # its own equivalence tests (tests/test_das_recovery.py); here
+        # only the fused VERIFY shape is under test
+        orig = das._DEVICE_MIN_WIDTH
+        das._DEVICE_MIN_WIDTH = 1 << 30
         try:
             cells, proofs = das.compute_cells_and_kzg_proofs(blob, s)
         finally:
-            das._CELL_PROOF_FUSED_MIN_WIDTH = orig
+            das._DEVICE_MIN_WIDTH = orig
         ids = list(range(0, 96, 12))  # 8 cells
         assert das.verify_cell_kzg_proof_batch(
             [commitment] * len(ids), ids, [cells[i] for i in ids],
@@ -268,12 +268,10 @@ class TestFusedCellBatch:
 
 @pytest.mark.skipif(
     __import__("os").environ.get("LHTPU_SLOW") != "1",
-    reason="32k-lane scan is minutes on XLA-CPU; set LHTPU_SLOW=1 "
-           "(validated in-session: byte-identical proofs, twice)")
+    reason="256 per-cell MSMs are minutes of host Python; set LHTPU_SLOW=1")
 def test_batched_cell_proofs_match_percell_path():
-    """Width 256 crosses _CELL_PROOF_FUSED_MIN_WIDTH: the one-dispatch
-    batched quotient MSMs must produce byte-identical proofs to the
-    per-cell g1_lincomb path."""
+    """Width 256 reaches _DEVICE_MIN_WIDTH: the FK20 program's proofs
+    must be byte-identical to the per-cell g1_lincomb path's."""
     import numpy as np
 
     s = kzg.KzgSettings.dev(width=256)
@@ -284,12 +282,12 @@ def test_batched_cell_proofs_match_percell_path():
     # per-cell oracle: force the g1_lincomb path on the same quotients
     import lighthouse_tpu.crypto.das as das_mod
 
-    orig = das_mod._CELL_PROOF_FUSED_MIN_WIDTH
-    das_mod._CELL_PROOF_FUSED_MIN_WIDTH = 1 << 30
+    orig = das_mod._DEVICE_MIN_WIDTH
+    das_mod._DEVICE_MIN_WIDTH = 1 << 30
     try:
         cells2, proofs2 = das.compute_cells_and_kzg_proofs(blob, s)
     finally:
-        das_mod._CELL_PROOF_FUSED_MIN_WIDTH = orig
+        das_mod._DEVICE_MIN_WIDTH = orig
     assert cells == cells2
     assert proofs == proofs2
     # and the proofs actually verify (fused batch verifier)

@@ -105,16 +105,34 @@ def test_kzg_lincomb_all_identity():
 
 
 def test_das_cell_proof_chunk_identity():
-    """One das cell-proof chunk through the plane equals the per-cell
-    host monomial lincomb (the pre-refactor per-cell idiom)."""
+    """The cell proofs of the plane's FK20 program (ops/msm._fk20_kernel)
+    equal the per-cell host monomial lincomb of each cell's quotient (the
+    spec's per-cell idiom), blob for blob: a random blob and p(x) = 0,
+    whose proofs are all the identity."""
+    import random
+
+    import jax.numpy as jnp
+
     from lighthouse_tpu.crypto import das, kzg
 
     settings = kzg.KzgSettings.dev(width=16)
-    q_lists = [[1, 2], [3, 4], [5, 0]]   # non-pow2 cell count
-    got = das._batched_cell_proof_msms(q_lists, settings)
-    for q, cell in zip(q_lists, got):
-        want = _host_lincomb(settings.g1_monomial[:len(q)], q)
-        assert cell == want
+    n_cells, size = das._cell_geometry(16)
+    rng = random.Random(19)
+    polys = [[rng.randrange(R) for _ in range(16)], [0] * 16]
+    lanes = [polys[r][j] for j in range(16) for r in range(2)]
+    got = das._fk20_proofs(jnp.asarray(das._plain_limbs(lanes).T), 2,
+                           settings)
+    ext_roots = das._compute_roots_of_unity(32)
+    brp = das._bit_reversal_permutation(list(range(32)))
+    for f, proofs in zip(polys, got):
+        for cid in range(n_cells):
+            a = pow(ext_roots[brp[cid * size]], size, R)
+            q = [0] * (16 - size)
+            for j in range(15 - size, -1, -1):
+                carry = q[j + size] if j + size < len(q) else 0
+                q[j] = (f[j + size] + a * carry) % R
+            want = _host_lincomb(settings.g1_monomial[:len(q)], q)
+            assert proofs[cid] == cv.g1_to_bytes(want)
 
 
 def test_rlc_two_segment_fold():
@@ -553,7 +571,9 @@ def test_manifest_msm_family_unified():
     replaced by exactly three ops/msm.py entries — the MSM-family entry
     count went DOWN (4 legacy -> 3 unified).  The same-message merge of
     a batch verification is a fourth track of the file that replaced no
-    consumer's kernel (23 entries in all)."""
+    consumer's kernel, and FK20's cell proofs and their setup tables
+    (which replaced the cell-proof chunk fold's use of the plain track)
+    two more (26 entries in all, with ops/fr.py's recovery)."""
     import pathlib
 
     manifest = pathlib.Path(__file__).parent.parent / "tools" / "lint" \
@@ -570,11 +590,14 @@ def test_manifest_msm_family_unified():
     unified = sorted(i for i in ids if i.startswith("ops/msm.py::"))
     assert unified == ["ops/msm.py::_blinded_fold@_blinded_fold",
                        "ops/msm.py::_blinded_merge@_blinded_merge",
+                       "ops/msm.py::_fk20_kernel@_fk20_kernel",
+                       "ops/msm.py::_fk20_tables@_fk20_tables",
                        "ops/msm.py::_fold_kernel@_fold_kernel",
                        "ops/msm.py::_gather_fold@_gather_fold"]
-    replacing = [i for i in unified if "_blinded_merge" not in i]
+    replacing = [i for i in unified
+                 if "_blinded_merge" not in i and "_fk20" not in i]
     assert len(replacing) < len(legacy)
-    assert len(entries) == 23
+    assert len(entries) == 26
     # and every unified entry is registered at runtime with the msm
     # prewarm driver (the one registration point)
     from lighthouse_tpu.ops import msm  # noqa: F401  (registers)

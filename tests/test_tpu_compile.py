@@ -321,6 +321,60 @@ def test_g1_subgroup_kernel_blob_batch(one_chip, tpu_branches):
     assert ",27,54]" not in text[text.index("\nENTRY "):]
 
 
+def test_recover_kernel_full_block(one_chip, tpu_branches):
+    """The recovery of one full block of 21 blobs at the preset's 8,192
+    extended evaluations (recover-21x64's one shape): four radix-2
+    transforms of 13 stages, one `lax.scan` body each, on the multiply
+    whose partial products stay in the core (~25 s: 87 MB of temporaries,
+    53 MB of code)."""
+    from lighthouse_tpu.ops import fr
+
+    N = 8192
+    c = _compile("_recover_kernel@21x8192", fr._recover_kernel._fn,
+                 _fr_rows(one_chip, 21, N), _fr_rows(one_chip, 128),
+                 _fr_rows(one_chip, 128), _fr_rows(one_chip, N),
+                 _fr_rows(one_chip, N), _fr_rows(one_chip, 13, N // 2),
+                 _fr_rows(one_chip, 13, N // 2))
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    assert ",18,36]" not in text[text.index("\nENTRY "):]
+
+
+@pytest.mark.slow  # ~25 s: the setup's 64 G1 transforms of 128
+def test_fk20_tables(one_chip, tpu_branches):
+    from lighthouse_tpu.ops import bigint as bi
+    from lighthouse_tpu.ops import msm
+
+    cols = jax.ShapeDtypeStruct((bi.L, 128, 64), jnp.uint32,
+                                sharding=one_chip)
+    c = _compile("_fk20_tables@64x128", msm._fk20_tables._fn, cols, cols,
+                 cols)
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.slow  # ~2.5 min: 38 s of trace, 118 s of compile
+def test_fk20_full_block(one_chip, tpu_branches):
+    """Every cell proof of 21 blobs: 172,032 fixed-base scalar
+    multiplications and 42 G1 transforms of 128 in one program, all on the
+    multiply whose partial products stay in the core: 3.3 GB of
+    temporaries (the lanes' window tables are 892 MB of it), 259 MB of
+    code."""
+    from lighthouse_tpu.ops import bigint as bi
+    from lighthouse_tpu.ops import fr, msm
+
+    table = jax.ShapeDtypeStruct((bi.L, 64, 128), jnp.uint32,
+                                 sharding=one_chip)
+    coeffs = jax.ShapeDtypeStruct((fr.L, 4096 * 21), jnp.uint32,
+                                  sharding=one_chip)
+    c = _compile("_fk20_kernel@21", msm._fk20_kernel._fn, coeffs, table,
+                 table, table)
+    assert c.memory_analysis().temp_size_in_bytes < 6 << 30
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    assert ",27,54]" not in text[text.index("\nENTRY "):]
+
+
 @pytest.mark.slow  # ~3 min: the trace of ~420 kernel bodies is half of it
 def test_kzg_fused_768_blobs(one_chip, tpu_branches):
     """Both RLC MSMs and the two-lane Jacobian Miller loop in one dispatch

@@ -76,6 +76,10 @@ FAMILY_OWNERS = {
     # chain/data_column_verification.py): kzg_cells_verified_total,
     # kzg_cell_lanes_total, kzg_interp_products_total
     "kzg_": "lighthouse_tpu/crypto/kzg.py",
+    # data-column reconstruction (crypto/das.py's recovery and
+    # chain/data_column_verification.reconstruct_data_columns) records
+    # through kzg's helpers too
+    "das_": "lighthouse_tpu/crypto/kzg.py",
     # the observatory plane (PR 11): each subsystem owns its families —
     # flight events/trips, manifest-keyed jit telemetry + the cold-start
     # headline, SLO scoring, invariant breaches, and the shared
