@@ -552,6 +552,26 @@ def count_fold_products(resident: int, materialized: int) -> None:
         record_swallowed("bls.count_fold_products", e)
 
 
+def count_g2_subgroup_products(resident: int, materialized: int) -> None:
+    """Fp lane-products of one dispatched ψ membership program
+    (ops/bls_backend._g2_subgroup_products), by the multiply that runs
+    them: ``resident`` `MontField.mont_mul_lm`, ``materialized``
+    `mont_mul` (its schoolbook products are arrays of the program)."""
+    try:
+        from lighthouse_tpu.common.metrics import REGISTRY
+
+        products = REGISTRY.counter(
+            "g2_subgroup_products_total",
+            "Fp lane-products of the G2 membership programs dispatched, "
+            "by multiply")
+        products.labels(multiply="resident").inc(resident)
+        products.labels(multiply="materialized").inc(materialized)
+    except Exception as e:
+        from lighthouse_tpu.common.metrics import record_swallowed
+
+        record_swallowed("bls.count_g2_subgroup_products", e)
+
+
 def count_merged(path: str, sets: int) -> None:
     """Signature sets folded into a blinded same-message merge, by where
     the fold ran: ``host`` (the reference rung's `_merge_on_host`, pure

@@ -51,9 +51,9 @@ fields by the asserts in tests/test_bigint.py:
 
 The limb-major multiply (``mont_mul_lm`` with ``add_lm``, ``sub_lm``,
 ``scale_small_lm``: Fr's evaluation programs; of Fp, the G1 folds of
-ops/msm.py, the same-message merge and the G1 membership scan; the
-Miller loops, the unblinding ladder and the G2 membership kernel
-remain on ``mont_mul``).  The same construction for arrays
+ops/msm.py, the same-message merge and the G1 and G2 membership
+programs; the Miller loops and the unblinding ladder remain on
+``mont_mul``).  The same construction for arrays
 uint32[L, ...lanes] whose FIRST axis is the limb.  ``mont_mul`` makes
 every schoolbook product an array of the program ([.., L, 2L], to HBM and
 back: ~27 KB a Fr product-lane that needs 216 bytes); here the partial

@@ -33,7 +33,7 @@ _resolve_backend's lazy hook).
 from __future__ import annotations
 
 import secrets
-from functools import partial
+from functools import lru_cache, partial
 from typing import Sequence
 
 import numpy as np
@@ -189,14 +189,14 @@ _g2_subgroup_kernel = _dtel.instrument(
 def _dispatch_g2_subgroup_kernel(points):
     """Dispatch (no host sync) the batched ψ verdict kernel over affine
     G2 points, generator-padded to a power of two (floor 4) so small
-    batches share compiled shapes.  Returns the device bool row; callers
-    read [:len(points)] when they sync.  The verdict is computed on
-    device (ec.g2_subgroup_verdict_batch) — one bool-row fetch, not six
-    limb rows."""
+    batches share compiled shapes.  Returns the device bool row (one
+    fetch: ec.g2_subgroup_verdict_batch); callers read [:len(points)]."""
     padded = _next_pow2(len(points), floor=4)
     pts = list(points) + [cv.g2_generator()] * (padded - len(points))
     xqa, xqb, yqa, yqb = (jnp.asarray(a) for a in _g2_limbs(pts))
-    return _g2_subgroup_kernel(xqa, xqb, yqa, yqb)
+    out = _g2_subgroup_kernel(xqa, xqb, yqa, yqb)
+    api.count_g2_subgroup_products(*_g2_subgroup_products(padded))
+    return out
 
 
 def batch_subgroup_check_g2(points) -> np.ndarray:
@@ -936,3 +936,13 @@ def verify_signature_sets_device(sets: Sequence[api.SignatureSet],
 
 
 api.register_backend("tpu", verify_signature_sets_device)
+
+
+@lru_cache(maxsize=None)
+def _g2_subgroup_products(lanes: int) -> tuple[int, int]:
+    """(resident, materialized) Fp lane-products of one dispatch of the
+    ψ membership program (`_g2_subgroup_kernel`) at ``lanes`` lanes, all
+    on `mont_mul_lm`: the [|x|]Q scan, 64 double-and-add steps of 18
+    Karatsuba Fq2 products (3 each), ψ's 2 and the residues' 4 Fq2
+    products, and the six zero tests of d1, d2 and Z; none on `mont_mul`."""
+    return lanes * (3 * (64 * 18 + 2 + 4) + 6), 0

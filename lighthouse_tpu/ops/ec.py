@@ -643,9 +643,9 @@ def g1_msm_windowed(xp, yp, digits):
 # ψ(Q) == [x]Q characterizes G2 membership on E'(Fq2) (Scott 2021; the
 # host oracle/fast pair lives in crypto/bls/curve.py).  On device the
 # 64-bit |x| scalar mul is one fixed-bit _scalar_mul_batch scan shared by
-# every lane — ~4x cheaper than a [r]Q check and batched over all fresh
-# signatures of a verify call (the 14 ms/signature host check was the
-# flood-path killer, round-3 ledger).
+# every lane, batched over all fresh signatures of a verify call, over
+# limb-major Fq2 lanes (_Fq2LmAdapter): a round's Karatsuba products go
+# side by side into one `mont_mul_lm` launch, and none is an array.
 
 import functools as _functools
 
@@ -667,30 +667,33 @@ def _x_bits_const():
             [[int(b)] for b in bin(BLS_X)[2:]], jnp.uint32)  # [64, 1]
 
 
-def g2_psi_batch(xqa, xqb, yqa, yqb):
-    """ψ per lane: (c_x·x̄, c_y·ȳ), x̄ the Frobenius conjugate."""
-    cx, cy = _psi_const_limbs()
-    bcast = lambda c: (jnp.broadcast_to(c[0], xqa.shape),  # noqa: E731
-                       jnp.broadcast_to(c[1], xqa.shape))
-    q = _MulQueue()
-    r_x = q.fp2((xqa, bi.neg(xqb)), bcast(cx))
-    r_y = q.fp2((yqa, bi.neg(yqb)), bcast(cy))
-    q.run()
-    return r_x(), r_y()
+def g2_psi_batch(q: _MulQueue, x, y):
+    """Queue ψ of limb-major Fq2 lanes on q: (c_x·x̄, c_y·ȳ), x̄ the
+    Frobenius conjugate (its imaginary half `sub_lm` from exact zeros,
+    which is `neg`), its six products in q's round; returns resolvers."""
+    F, (cx, cy) = _Fq2LmAdapter, _psi_const_limbs()
+    bcast = lambda c: (jnp.broadcast_to(c[0].T, x[0].shape),  # noqa: E731
+                       jnp.broadcast_to(c[1].T, x[0].shape))
+
+    def conj(c):
+        return c[0], bi.FP.sub_lm(jnp.zeros_like(c[1]), c[1])
+
+    return F.mul(q, conj(x), bcast(cx)), F.mul(q, conj(y), bcast(cy))
 
 
 def g2_subgroup_check_batch(xqa, xqb, yqa, yqb):
     """Device half of the batched ψ membership test.
 
-    Inputs: affine G2 lanes (on-curve already guaranteed by
-    decompression).  Computes S = [|x|]Q and ψ(Q), and returns the
-    Jacobian-vs-affine equality residues for ψ(Q) == -S (x is negative):
+    Inputs: affine G2 rows uint32[N, 27] (on-curve already guaranteed by
+    decompression), turned limb-major.  Computes S = [|x|]Q and ψ(Q), and
+    returns the Jacobian-vs-affine equality residues for ψ(Q) == -S (x is
+    negative), each an Fq2 pair of limb-major lanes uint32[27, N]:
 
         d1 = x_ψ·Z_S² - X_S,   d2 = y_ψ·Z_S³ + Y_S,   Z_S
 
-    each an Fq2 limb pair.  A lane is in G2 iff d1 ≡ d2 ≡ 0 (mod P) and
-    Z_S ≢ 0 — the host finishes with is_zero_mod_p (redundant limbs can't
-    be zero-tested on device).
+    A lane is in G2 iff d1 ≡ d2 ≡ 0 (mod P) and Z_S ≢ 0 (the redundant
+    limbs are zero-tested mod P: g2_subgroup_verdict_batch).  The scan,
+    ψ and the residues run on `_Fq2LmAdapter`, in 64 x 6 + 3 rounds.
 
     Fail-closed invariant (adversarial inputs!): unlike the blinded-scalar
     callers, these lanes are attacker-chosen twist points, so the
@@ -700,41 +703,38 @@ def g2_subgroup_check_batch(xqa, xqb, yqa, yqb):
     double/add step, so such lanes land in the Z_S ≡ 0 reject branch —
     they can never false-accept.  tests/test_ec.py pins this with a
     small-order cofactor point; keep that property if _dbl_add_step is
-    ever refactored."""
+    ever refactored, and keep its explicit infinity flag."""
+    F, xl, yl = _Fq2LmAdapter, (xqa.T, xqb.T), (yqa.T, yqb.T)
     bits = jnp.broadcast_to(_x_bits_const(), (64, xqa.shape[0]))
-    X, Y, Z = _scalar_mul_batch(_Fq2Adapter, (xqa, xqb), (yqa, yqb), bits)
-    px, py = g2_psi_batch(xqa, xqb, yqa, yqb)
-
+    X, Y, Z = _scalar_mul_batch(F, xl, yl, bits)
     q = _MulQueue()
-    r_z2 = q.fp2(Z, Z)
+    r_z2 = F.mul(q, Z, Z)
+    r_px, r_py = g2_psi_batch(q, xl, yl)
     q.run()
     z2 = r_z2()
     q = _MulQueue()
-    r_xz = q.fp2(px, z2)
-    r_z3 = q.fp2(z2, Z)
+    r_xz = F.mul(q, r_px(), z2)
+    r_z3 = F.mul(q, z2, Z)
     q.run()
-    xz, z3 = r_xz(), r_z3()
     q = _MulQueue()
-    r_yz = q.fp2(py, z3)
+    r_yz = F.mul(q, r_py(), r_z3())
     q.run()
-    d1 = fp2_sub(xz, X)
-    d2 = fp2_add(r_yz(), Y)
+    d1, d2 = F.sub(r_xz(), X), F.add(r_yz(), Y)
     return d1, d2, Z
-
-
-def _fq2_zero_mod_p(c) -> jax.Array:
-    return bi.is_zero_mod_p_device(c[0]) & bi.is_zero_mod_p_device(c[1])
 
 
 def g2_subgroup_verdict_batch(xqa, xqb, yqa, yqb) -> jax.Array:
     """Full ψ membership verdict per lane, ON DEVICE -> bool[n].
 
-    Folds the residue zero-tests (bi.is_zero_mod_p_device) into the same
-    program as g2_subgroup_check_batch so callers fetch one bool row
-    instead of six Fq limb rows."""
+    Folds the residue zero-tests into the same program as
+    g2_subgroup_check_batch so callers fetch one bool row instead of six
+    Fq limb rows: the six halves side by side along the lanes in one
+    `bigint.is_zero_mod_p_lm`.  Z is tested as a value, never by its
+    limbs: after Karatsuba's subtractions a Z ≡ 0 reads nonzero limbs."""
     d1, d2, Z = g2_subgroup_check_batch(xqa, xqb, yqa, yqb)
-    return (_fq2_zero_mod_p(d1) & _fq2_zero_mod_p(d2)
-            & ~_fq2_zero_mod_p(Z))
+    zero = bi.is_zero_mod_p_lm(jnp.concatenate([*d1, *d2, *Z], axis=1))
+    zero = zero.reshape(3, 2, xqa.shape[0]).all(axis=1)
+    return zero[0] & zero[1] & ~zero[2]
 
 
 def g1_subgroup_verdict_batch(xp, yp) -> jax.Array:

@@ -224,22 +224,21 @@ def test_ints_to_limbs_matches_scalar_path():
         assert int(bi.from_mont(gotm[i])) == v % bi.P_INT, i
 
 
+def _g2_cofactor_points(n: int):
+    rng = np.random.default_rng(9)
+    out = []
+    while len(out) < n:
+        x = Fq2(int.from_bytes(rng.bytes(47), "big") % P,
+                int.from_bytes(rng.bytes(47), "big") % P)
+        y = (x.square() * x + cv.B2).sqrt()
+        if y is not None and not cv.g2_in_subgroup((x, y)):
+            out.append((x, y))
+    return out
+
+
 class TestPsiSubgroupCheck:
     """The ψ membership test (curve.py g2_in_subgroup_fast + the batched
     device mirror) vs the definitional [r]Q oracle."""
-
-    def _cofactor_points(self, n=2):
-        from lighthouse_tpu.crypto.bls.fields import Fq2, P
-
-        rng = np.random.default_rng(9)
-        out = []
-        while len(out) < n:
-            x = Fq2(int.from_bytes(rng.bytes(47), "big") % P,
-                    int.from_bytes(rng.bytes(47), "big") % P)
-            y = (x.square() * x + cv.B2).sqrt()
-            if y is not None and not cv.g2_in_subgroup((x, y)):
-                out.append((x, y))
-        return out
 
     def test_host_fast_check_agrees_with_oracle(self):
         g = cv.g2_generator()
@@ -247,7 +246,7 @@ class TestPsiSubgroupCheck:
             q = cv.g2_mul(g, k)
             assert cv.g2_in_subgroup_fast(q)
             assert cv.g2_in_subgroup(q)
-        for pt in self._cofactor_points():
+        for pt in _g2_cofactor_points(2):
             assert not cv.g2_in_subgroup_fast(pt)
         assert cv.g2_in_subgroup_fast(cv.INF)
 
@@ -263,34 +262,146 @@ class TestPsiSubgroupCheck:
 
         g = cv.g2_generator()
         members = [cv.g2_mul(g, k) for k in (1, 5, 7)]
-        bad = self._cofactor_points(2)
+        bad = _g2_cofactor_points(2)
         ok = batch_subgroup_check_g2(members[:2] + bad + members[2:])
         assert list(ok) == [True, True, False, False, True]
 
 
-def test_small_order_point_fails_closed():
-    """g2_subgroup_check_batch's fail-closed invariant (see its docstring):
-    a small-order twist point can hit the degenerate H == 0 addition chord
-    inside the fixed-|x| scalar mul; the resulting Z ≡ 0 lane must REJECT.
+# A twist point of exact order 13 (13² | h2, the Sylow-13 subgroup of
+# E'(Fq2) has rank 2; made as [n2/13²]·random, then reduced by 13 until
+# its order is 13).
+G2_ORDER13 = (
+    Fq2(0x50c3dd2263b07fd4c50559754c4f0d4c4ab0cdc4a685b8b5cab7bd39bd46ceda6663d15c194176fc6e15f40a70b76bc,
+        0x2fce515472b308fa3da1ac9a6fa4019d7a8700cb6ca215771c98d4bc59edddbedf882c6cae0f702b73c6bdcb93746ac),
+    Fq2(0xdc3af5921e8ecd27695da0f537a9197d849deabb8cf404f28ba31790ce2e89a26bb85188dab735e6782210cd0a30381,
+        0x2eaa3a19068450560e6cc5788d89c55226e62b286277cecfaa019ad4712e2db26a4495408885d5923bed176515a1bb1),
+)
 
-    The pinned point has exact order 13 (13² | h2, the Sylow-13 subgroup
-    of E'(Fq2) has rank 2; constructed as [n2/13²]·random then reduced by
-    13 until order 13)."""
-    from lighthouse_tpu.crypto.bls.fields import Fq2
-    from lighthouse_tpu.ops.bls_backend import batch_subgroup_check_g2
+_g2_check = jax.jit(ec.g2_subgroup_check_batch)
 
-    pt = (
-        Fq2(0x50c3dd2263b07fd4c50559754c4f0d4c4ab0cdc4a685b8b5cab7bd39bd46ceda6663d15c194176fc6e15f40a70b76bc,
-            0x2fce515472b308fa3da1ac9a6fa4019d7a8700cb6ca215771c98d4bc59edddbedf882c6cae0f702b73c6bdcb93746ac),
-        Fq2(0xdc3af5921e8ecd27695da0f537a9197d849deabb8cf404f28ba31790ce2e89a26bb85188dab735e6782210cd0a30381,
-            0x2eaa3a19068450560e6cc5788d89c55226e62b286277cecfaa019ad4712e2db26a4495408885d5923bed176515a1bb1),
-    )
+
+def test_g2_order13_point_is_what_it_claims():
+    pt = G2_ORDER13
     assert cv.g2_is_on_curve(pt)
     assert cv.g2_mul(pt, 13) is cv.INF          # exact small order
     assert not cv.g2_in_subgroup(pt)            # oracle
     assert not cv.g2_in_subgroup_fast(pt)       # host ψ test
-    ok = batch_subgroup_check_g2([pt, cv.g2_generator(), pt, pt])
-    assert list(ok) == [False, True, False, False]
+
+
+@pytest.mark.parametrize("where", ["among", "between", "last_of_full"])
+def test_small_order_point_fails_closed(where):
+    """g2_subgroup_check_batch's fail-closed invariant (see its docstring)
+    on the limb-major multiply: the order-13 twist point meets the
+    degenerate H == 0 chord inside the fixed-|x| scalar mul, which drives
+    Z to a value ≡ 0 (mod P) however its limbs read after Karatsuba's
+    subtractions, and the lane REJECTS wherever it sits in a padded
+    bucket: among members, between them, last of a full bucket."""
+    from lighthouse_tpu.ops.bls_backend import batch_subgroup_check_g2
+
+    pt, g = G2_ORDER13, cv.g2_generator()
+    g7 = cv.g2_mul(g, 7)
+    batch = {"among": [pt, g, pt, g7, pt],           # 5 lanes of 8
+             "between": [g, pt, g7, pt, g, g7],      # 6 lanes of 8
+             "last_of_full": [g7, g, g7, g, g7, g, g7, pt]}[where]
+    want = [p is not pt for p in batch]
+    assert list(batch_subgroup_check_g2(batch)) == want
+    # the reject is the Z ≡ 0 branch: the chord, not the residues
+    lanes = _g2_lanes(batch + [g] * (8 - len(batch)))
+    _, _, Z = _g2_check(*lanes)
+    z0 = [ec.is_zero_mod_p(np.asarray(c).T) for c in Z]
+    assert list(z0[0] & z0[1]) == [not w for w in want] + [False] * (
+        8 - len(batch))
+
+
+@pytest.mark.parametrize("n", [6, 13])
+def test_g2_membership_against_the_oracle(n):
+    """The ψ membership program's verdicts against cv.g2_in_subgroup over
+    members, cofactor points (random points of E'(Fq2)), the order-13
+    point and a point of mixed order (a member plus the order-13 point:
+    on the curve, off the subgroup), at buckets of 8 and 16 lanes; the
+    generator lanes that pad a batch to its bucket pass."""
+    import random
+
+    from lighthouse_tpu.crypto.bls.fields import R
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    rng = random.Random(n)
+    g = cv.g2_generator()
+    members = [cv.g2_mul(g, rng.randrange(1, R)) for _ in range(3)]
+    distinct = (members + _g2_cofactor_points(2)
+                + [G2_ORDER13, cv.g2_add(members[0], G2_ORDER13)])
+    want = [cv.g2_in_subgroup(p) for p in distinct]
+    assert want == [True] * 3 + [False] * 4
+    picks = [i % len(distinct) for i in range(n)]
+    rng.shuffle(picks)
+    row = np.asarray(bb._dispatch_g2_subgroup_kernel(
+        [distinct[i] for i in picks]))
+    assert row.shape == (bb._next_pow2(n, floor=4),)
+    assert row[:n].tolist() == [want[i] for i in picks]
+    assert row[n:].all()
+
+
+@pytest.mark.parametrize("lanes", [4, 16, 256])
+def test_g2_subgroup_products_are_what_the_program_traces(monkeypatch,
+                                                           lanes):
+    """`_g2_subgroup_products` (what `g2_subgroup_products_total` grows by
+    a dispatch) against a tally of the lanes each multiply of
+    `_g2_subgroup_kernel` is traced with, a scan's body counted once a
+    step: every product on `mont_mul_lm`, none an array of the program
+    (`mont_mul`); 2,048 lanes, `flood-2048`'s shape, by the closed form."""
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    tally = {"resident": 0, "materialized": 0}
+    steps = [1]
+    lm, mm, scan = bi.FP.mont_mul_lm, bi.mont_mul, jax.lax.scan
+
+    def count_lm(a, b):
+        tally["resident"] += steps[-1] * int(np.prod(a.shape[1:]))
+        return lm(a, b)
+
+    def count_mm(a, b):
+        tally["materialized"] += steps[-1] * int(np.prod(a.shape[:-1]))
+        return mm(a, b)
+
+    def count_scan(f, init, xs, *args, **kwargs):
+        steps.append(steps[-1] * jax.tree_util.tree_leaves(xs)[0].shape[0])
+        try:
+            return scan(f, init, xs, *args, **kwargs)
+        finally:
+            steps.pop()
+
+    monkeypatch.setattr(bi.FP, "mont_mul_lm", count_lm)
+    monkeypatch.setattr(bi, "mont_mul", count_mm)
+    monkeypatch.setattr(jax.lax, "scan", count_scan)
+    rows = jax.ShapeDtypeStruct((lanes, bi.L), jnp.uint32)
+    # a new function object: a jit's cache would hide a shape traced before
+    jax.eval_shape(lambda *a: bb._g2_subgroup_kernel._fn.__wrapped__(*a),
+                   rows, rows, rows, rows)
+    assert bb._g2_subgroup_products(lanes) == (
+        tally["resident"], tally["materialized"])
+    assert bb._g2_subgroup_products(2048) == (7_127_040, 0)
+
+
+def test_g2_subgroup_products_counted_once_a_dispatch():
+    """A dispatch of the ψ membership program grows
+    `g2_subgroup_products_total` by its padded shape's reckoning, on the
+    resident multiply."""
+    from benchmarks import counters
+    from lighthouse_tpu.common.metrics import REGISTRY
+    from lighthouse_tpu.ops import bls_backend as bb
+
+    g = cv.g2_generator()
+    before = counters.samples()
+    row = np.asarray(bb._dispatch_g2_subgroup_kernel([g] * 5))
+    after = counters.samples()
+    assert row.tolist() == [True] * 8
+    for multiply, want in zip(("resident", "materialized"),
+                              bb._g2_subgroup_products(8)):
+        assert counters.delta(
+            before, after, "g2_subgroup_products_total",
+            lambda labels, m=multiply: labels.get("multiply") == m) == want
+    assert bb._g2_subgroup_products(8)[0] == 8 * 3_480
+    assert "# TYPE g2_subgroup_products_total counter" in REGISTRY.render()
 
 
 # Points of small prime order d on E(Fp), whose order is

@@ -396,11 +396,15 @@ from benchmarks.tests.test_host_stall_metrics import (  # noqa: E402,F401
     ("bls_fold_resident_pct.block", "bls_fold_products_total"),
     ("kzg_subgroup_resident_pct.columns", "g1_subgroup_products_total"),
     ("kzg_subgroup_resident_pct.blobs", "g1_subgroup_products_total"),
+    ("bls_subgroup_resident_pct.flood", "g2_subgroup_products_total"),
+    ("bls_subgroup_resident_pct.electra", "g2_subgroup_products_total"),
+    ("bls_subgroup_resident_pct.block", "g2_subgroup_products_total"),
 ])
 def test_resident_share_reads_a_synthetic_window(metric, family):
     """`kzg_fused_resident_pct.{blobs,columns}`,
-    `bls_fold_resident_pct.{electra,block}` and
-    `kzg_subgroup_resident_pct.{columns,blobs}`: the share of their
+    `bls_fold_resident_pct.{electra,block}`,
+    `kzg_subgroup_resident_pct.{columns,blobs}` and
+    `bls_subgroup_resident_pct.{flood,electra,block}`: the share of their
     family's growth on `multiply="resident"`; a program without the
     family (a parent commit) reads nothing and does not raise."""
     from benchmarks.tests.test_stage_metrics import _read

@@ -127,11 +127,26 @@ def test_mont_mul_with_mxu_redc(one_chip, tpu_branches):
     assert "convolution" in c.as_text() or "dot" in c.as_text()
 
 
+def _assert_resident(compiled, temp_bytes: int):
+    """The program's products run on the multiply whose partial products
+    stay in the core: a Pallas kernel is there, and no schoolbook product
+    is an array of the program ([.., 27, 54])."""
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_bytes
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ",27,54]" not in text[text.index("\nENTRY "):]
+
+
 def test_g2_subgroup_kernel(one_chip, tpu_branches):
+    """A block's membership dispatch: the [|x|]Q scan, ψ, the residues
+    and their zero tests on the resident multiply, 2.6 MB of temporaries
+    where the materialized scan made every product a [.., 256, 27, 54]
+    array of the program."""
     from lighthouse_tpu.ops import bls_backend as bb
 
-    _compile("_g2_subgroup_kernel@256", bb._g2_subgroup_kernel._fn,
-             *[_limbs(one_chip)] * 4)
+    _assert_resident(_compile("_g2_subgroup_kernel@256",
+                              bb._g2_subgroup_kernel._fn,
+                              *[_limbs(one_chip)] * 4), 8 << 20)
 
 
 def test_g1_subgroup_kernel(one_chip, tpu_branches):
@@ -312,13 +327,9 @@ def test_g1_subgroup_kernel_blob_batch(one_chip, tpu_branches):
     from lighthouse_tpu.ops import bls_backend as bb
 
     assert bi._use_resident_kernel()
-    c = _compile("_g1_subgroup_kernel@2048", bb._g1_subgroup_kernel._fn,
-                 *[_limbs(one_chip, BLOB_POINTS)] * 2)
-    assert c.memory_analysis().temp_size_in_bytes < 8 << 20
-    text = c.as_text()
-    assert "tpu_custom_call" in text
-    # no schoolbook product is an array of the program
-    assert ",27,54]" not in text[text.index("\nENTRY "):]
+    _assert_resident(_compile("_g1_subgroup_kernel@2048",
+                              bb._g1_subgroup_kernel._fn,
+                              *[_limbs(one_chip, BLOB_POINTS)] * 2), 8 << 20)
 
 
 def test_recover_kernel_full_block(one_chip, tpu_branches):
@@ -534,8 +545,9 @@ def test_pipeline_fused_flat_16(one_chip, tpu_branches):
 
     _compile("_pipeline_fused@16", bb._pipeline_fused._fn,
              *_pipeline_args(one_chip, 16))
-    _compile("_g2_subgroup_kernel@16", bb._g2_subgroup_kernel._fn,
-             *[_limbs(one_chip, 16)] * 4)
+    _assert_resident(_compile("_g2_subgroup_kernel@16",
+                              bb._g2_subgroup_kernel._fn,
+                              *[_limbs(one_chip, 16)] * 4), 8 << 20)
 
 
 @pytest.mark.slow  # ~5 min: the Miller loop once more, as a mesh program
@@ -577,5 +589,7 @@ def test_blinded_merge_flood_buckets(one_chip, tpu_branches):
         _compile(f"_blinded_merge@{lanes}x{bb._MERGE_GROUPS}",
                  msm._blinded_merge._fn, *[_limbs(one_chip, lanes)] * 6,
                  digits, group, bb._MERGE_GROUPS)
-    _compile("_g2_subgroup_kernel@2048", bb._g2_subgroup_kernel._fn,
-             *[_limbs(one_chip, 2048)] * 4)
+    # 3.5 MB of temporaries where the materialized scan took 619 MB
+    _assert_resident(_compile("_g2_subgroup_kernel@2048",
+                              bb._g2_subgroup_kernel._fn,
+                              *[_limbs(one_chip, 2048)] * 4), 16 << 20)
