@@ -1087,6 +1087,22 @@ def proj_add_multi(pairs):
             for r_a, r_b, r_c, r_d, r_e, r_f in second]
 
 
+def proj_multiples_lm(P):
+    """The sixteen multiples 0·P .. 15·P of every lane of P (limb-major
+    projective, uint32[27, N] each), stacked uint32[16, 27, N] a
+    coordinate: seven doublings and seven additions, level by level."""
+    tab = [proj_identity(P[0]), P]
+    while len(tab) < 16:
+        doubles = proj_double_multi(tab[len(tab) // 2:])
+        odds = proj_add_multi([(d, P) for d in doubles])
+        for d, o in zip(doubles, odds):
+            tab += [d, o]
+    return tuple(jnp.stack([e[c] for e in tab]) for c in range(3))
+
+
+PROJ_MULTIPLES_PRODUCTS = 7 * (8 + 12)   # a lane of `proj_multiples_lm`
+
+
 def proj_scalar_mul_lm(P, digits):
     """k_i·P_i over limb-major projective lanes P (uint32[27, N] each), the
     scalars as MSB-first base-16 window digits uint32[W, N]
@@ -1094,13 +1110,7 @@ def proj_scalar_mul_lm(P, digits):
     sixteen multiples a lane, then W windows of four doublings and one
     addition, all complete."""
     F = _FpLmAdapter
-    tab = [proj_identity(P[0]), P]
-    while len(tab) < 16:
-        doubles = proj_double_multi(tab[len(tab) // 2:])
-        odds = proj_add_multi([(d, P) for d in doubles])
-        for d, o in zip(doubles, odds):
-            tab += [d, o]
-    tab = tuple(jnp.stack([e[c] for e in tab]) for c in range(3))
+    tab = proj_multiples_lm(P)
 
     def step(acc, digit):
         for _ in range(4):
@@ -1113,21 +1123,56 @@ def proj_scalar_mul_lm(P, digits):
 
 
 def proj_scalar_mul_products(windows: int) -> int:
-    """Fp products of one lane of `proj_scalar_mul_lm`: seven doublings (8)
-    and seven additions (12) for the table, four doublings and one addition
-    a window."""
-    return 7 * (8 + 12) + windows * (4 * 8 + 12)
+    """Fp products of one lane of `proj_scalar_mul_lm`: the table, then
+    four doublings (8) and one addition (12) a window."""
+    return PROJ_MULTIPLES_PRODUCTS + windows * (4 * 8 + 12)
 
 
-def proj_sum_halves_lm(P, parts: int):
-    """Sum of ``parts`` (a power of two) equal stretches of the lanes of P:
-    a tree of halves, log2(parts) levels of one addition a lane."""
-    while parts > 1:
-        h = P[0].shape[1] // 2
-        (P,) = proj_add_multi([(tuple(c[:, :h] for c in P),
-                                tuple(c[:, h:] for c in P))])
-        parts //= 2
+def proj_sum_halves_lm(P, axis: int):
+    """Sum of the points of P along ``axis`` (a power of two long), which
+    is kept, of length one: a tree of halves, one addition a lane a level."""
+    while P[0].shape[axis] > 1:
+        h = P[0].shape[axis] // 2
+        (P,) = proj_add_multi([(
+            tuple(jax.lax.slice_in_dim(c, 0, h, axis=axis) for c in P),
+            tuple(jax.lax.slice_in_dim(c, h, 2 * h, axis=axis) for c in P))])
     return P
+
+
+def proj_straus_lm(tab, digits):
+    """Interleaved windowed sums (Straus) over shared tables: for every row
+    r and base b, sum_i k_{r,i,b}·T_{i,b}.  ``tab``: `proj_multiples_lm`'s
+    sixteen multiples of the bases T_{i,b}, uint32[16, 27, parts, B] a
+    coordinate (parts a power of two); ``digits``: the scalars' MSB-first
+    base-16 window digits uint32[W, rows, parts, B].  Each window picks
+    every lane's multiple (its base's entry broadcast along the rows: a
+    table a base, not a lane), sums the parts by `proj_sum_halves_lm` and
+    adds that to sixteen times the accumulators, uint32[27, rows, B].  The
+    lanes keep their axes through every launch: with B a multiple of 128
+    and parts of 8 or more, a level's operands are the resident kernel's
+    blocks as they lie.  All complete: a zero digit picks the identity."""
+    F = _FpLmAdapter
+    rows, _, B = digits.shape[1:]
+    tab = tuple(c[:, :, None] for c in tab)
+
+    def step(acc, digit):
+        S = proj_sum_halves_lm(_table_pick(F, tab, digit), 2)
+        for _ in range(4):
+            (acc,) = proj_double_multi([acc])
+        (acc,) = proj_add_multi([(acc, tuple(c[:, :, 0] for c in S))])
+        return acc, None
+
+    acc = tuple(c.reshape(bi.L, rows, B) for c in proj_identity(
+        jnp.zeros((bi.L, rows * B), jnp.uint32)))
+    acc, _ = jax.lax.scan(step, acc, digits)
+    return acc
+
+
+def proj_straus_products(windows: int, parts: int) -> int:
+    """Fp products of one accumulator of `proj_straus_lm`: a window's four
+    doublings (8), its parts - 1 additions of the sum (12) and the one
+    into the accumulator (12)."""
+    return windows * (4 * 8 + 12 + (parts - 1) * 12)
 
 
 def g1_fft_stage_lm(P, digits, rows: int):

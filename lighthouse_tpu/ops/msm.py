@@ -539,9 +539,9 @@ def _publish_thresholds() -> None:
 # column i, H is the inverse transform of sum_i T_i F_i (entry k of the
 # inverse is entry -k of the transform, over m), and the proofs are the
 # transform of H, which lands them in cell order (a_c = omega_m^brp(c)).
-# A blob: m x l fixed-base scalar multiplications, two G1 transforms of
-# size m.  Every point is projective under complete formulas
-# (ec.proj_add_multi), so zero blobs and zero quotients need no flag.
+# The fixed-base stage is one windowed sum over the l columns a (cell,
+# blob), on tables the blobs share (ec.proj_straus_lm), then two G1
+# transforms of size m a blob; complete formulas, so zeros need no flag.
 
 import functools as _functools  # noqa: E402
 
@@ -598,14 +598,17 @@ def _fk20_kernel(coeffs, tx, ty, tz):
         [cols, jnp.zeros((fr.L, m - K + 1, ell * rows), jnp.uint32)], axis=1)
     F = fr.ntt_lm(cols.reshape(fr.L, -1), jnp.asarray(fr_tw), ell * rows)
     # F / m as a plain value below 2^256, its 64 window digits; lanes from
-    # (u, i, r) to (i, u, r), the order of the setup lanes
+    # (u, i, r) to axes (r, i, u), the setup's (i, u) minor
     plain = fr.mont_mul_lm(F, inv_m)
     digits = _window_digits_lm(fr._carry_digits_lm(plain))
-    digits = digits.reshape(64, m, ell, rows).transpose(0, 2, 1, 3).reshape(
-        64, -1)
-    P = tuple(jnp.repeat(c.reshape(bi.L, -1), rows, axis=1)
-              for c in (tx, ty, tz))
-    P = ec.proj_sum_halves_lm(ec.proj_scalar_mul_lm(P, digits), ell)
+    digits = digits.reshape(64, m, ell, rows).transpose(0, 3, 2, 1)
+    # sum_i (F_i / m)·T_i for every (u, r): one windowed sum over the l
+    # columns, on one table of multiples a setup point (i, u)
+    tab = ec.proj_multiples_lm(tuple(c.reshape(bi.L, -1)
+                                     for c in (tx, ty, tz)))
+    P = ec.proj_straus_lm(tuple(c.reshape(16, bi.L, ell, m) for c in tab),
+                          digits)
+    P = tuple(c.transpose(0, 2, 1).reshape(bi.L, -1) for c in P)
     # both transforms of size m in one scan of forward stages: after the
     # first, entry -k of m·IDFT is H_k (natural order once the transform's
     # bit-reversed order is undone), entries K-1 and up are no part of H
@@ -642,14 +645,15 @@ def fk20_products(blobs: int, ell: int = 64, m: int = 128
     """(resident, materialized) lane-products of one `_fk20_kernel`
     dispatch over ``blobs`` blobs of cell size ``ell`` and ``m`` cells, all
     on `mont_mul_lm`: in Fr the coefficient columns' transforms and the
-    scaling by 1/m; in Fp the m·l scalar multiplications of a blob, the sum
-    over its l columns, the two G1 transforms and the Jacobian rows."""
+    scaling by 1/m; in Fp the multiples of the m·l setup points, the
+    windowed sums of the m·blobs (cell, blob) lanes, the two G1 transforms
+    and the Jacobian rows."""
     from lighthouse_tpu.ops import fr
 
     lanes = m * ell * blobs
     fr_side = fr.ntt_products(ell * blobs, m) + lanes
-    fp_side = (lanes * ec.proj_scalar_mul_products(64)
-               + 12 * (lanes - m * blobs)
+    fp_side = (m * ell * ec.PROJ_MULTIPLES_PRODUCTS
+               + m * blobs * ec.proj_straus_products(64, ell)
                + 2 * blobs * ec.g1_fft_products(m) + 3 * m * blobs)
     return fr_side + fp_side, 0
 

@@ -48,13 +48,29 @@ class _Blobs:
         for _ in range(blobs):
             blob = b"".join(rng.randrange(R).to_bytes(32, "big")
                             for _ in range(width))
-            evals = ref.compute_cells(blob, self.setup)
-            p_tau, c = self.setup.commit(ref.blob_to_polynomial(
-                blob, self.setup))
+            cells, proofs, c = self.known_tau(blob)
             self.blobs.append(blob)
-            self.cells.append([ref.cell_to_bytes(e) for e in evals])
-            self.proofs.append(self.setup.cell_proofs(p_tau, evals))
+            self.cells.append(cells)
+            self.proofs.append(proofs)
             self.commitments.append(c)
+
+    def known_tau(self, blob):
+        """The reference's cells, known-tau proofs and commitment."""
+        evals = ref.compute_cells(blob, self.setup)
+        p_tau, c = self.setup.commit(ref.blob_to_polynomial(blob, self.setup))
+        return ([ref.cell_to_bytes(e) for e in evals],
+                self.setup.cell_proofs(p_tau, evals), c)
+
+    def blob_of(self, coeffs):
+        """The blob whose polynomial has these coefficients."""
+        def at(w):
+            acc = 0
+            for c in reversed(coeffs):
+                acc = (acc * w + c) % R
+            return acc
+
+        return b"".join(at(w).to_bytes(32, "big")
+                        for w in self.settings.roots_brp)
 
     def held(self, ids):
         return [[cells[c] for c in ids] for cells in self.cells]
@@ -148,14 +164,30 @@ def per_cell(monkeypatch):
     return proofs
 
 
-def test_fk20_proofs_are_the_per_cell_and_known_tau_proofs(w256, per_cell):
+def _one_column(w256):
+    """Coefficients non-zero in one column i = 1 of the l: every window
+    of the fixed-base sum picks the identity in the other l - 1 columns."""
+    rng = random.Random(5)
+    return w256.blob_of([rng.randrange(1, R) if j % w256.size == 1 else 0
+                         for j in range(w256.width)])
+
+
+@pytest.mark.parametrize("kind", ["random", "one_column", "all_r_minus_1"])
+def test_fk20_proofs_are_the_per_cell_and_known_tau_proofs(w256, per_cell,
+                                                           kind):
     from lighthouse_tpu.crypto.bls import curve as cv
 
-    cells, proofs = das.compute_cells_and_kzg_proofs(w256.blobs[0],
-                                                     w256.settings)
-    assert cells == w256.cells[0]
-    assert proofs == w256.proofs[0]
-    assert per_cell(w256.blobs[0], w256.settings) == (cells, proofs)
+    blob = {"random": lambda: w256.blobs[0],
+            "one_column": lambda: _one_column(w256),
+            "all_r_minus_1": lambda: w256.blob_of([R - 1] * w256.width),
+            }[kind]()
+    want = ((w256.cells[0], w256.proofs[0]) if kind == "random"
+            else w256.known_tau(blob)[:2])
+    cells, proofs = das.compute_cells_and_kzg_proofs(blob, w256.settings)
+    assert (cells, proofs) == tuple(want)
+    assert per_cell(blob, w256.settings) == (cells, proofs)
+    if kind != "random":
+        return
     # a known answer: p(x) = x^l leaves the quotient 1 by every cell's
     # x^l - a, so every proof is the generator (ops/prewarm's gate)
     blob = b"".join(pow(w, w256.size, R).to_bytes(32, "big")
@@ -356,8 +388,15 @@ def test_fk20_products_are_what_the_program_traces(monkeypatch, rows, width):
     assert (tally["fr"] + tally["fp"], 0) == (resident, materialized)
     lanes = rows * m * ell
     assert tally["fr"] == 7 * 64 * ell * rows + lanes
+    # the fixed-base stage: one table a setup point, then a window's four
+    # doublings, l - 1 additions of the sum and one into it, a (cell, blob)
+    assert ec.PROJ_MULTIPLES_PRODUCTS == 140
+    assert ec.proj_straus_products(1, ell) == 4 * 8 + 12 + (ell - 1) * 12
     assert ec.proj_scalar_mul_products(64) == 140 + 64 * 44 == 2956
     assert ec.g1_fft_products(128) == 7 * 64 * (2956 + 24)
+    if rows == 21:   # the cell's request
+        assert ec.proj_straus_products(1, 64) == 800
+        assert resident == 195_626_368
     columns = jax.ShapeDtypeStruct((bi.L, m, ell), jnp.uint32)
     tally = _tally(monkeypatch, lambda fresh: jax.eval_shape(
         fresh(msm._fk20_tables._fn.__wrapped__), columns, columns, columns))
